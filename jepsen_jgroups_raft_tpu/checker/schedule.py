@@ -675,7 +675,7 @@ def run_chunked(launches: List[ChunkLaunch],
                 # the next one (streaming, not bulk-synchronous): a
                 # round barrier would drain every device queue while
                 # the host walks the collect order, and the bubble is
-                # pure loss on both the tunnel and the host.
+                # pure loss.
                 _dispatch(g)
     all_spans = [iv for g in groups for iv in g.intervals]
     if record_stats:

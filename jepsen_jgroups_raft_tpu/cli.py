@@ -304,6 +304,9 @@ def cmd_search(args) -> int:
 
 
 def main(argv=None) -> int:
+    from .platform import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="jepsen_jgroups_raft_tpu",
         description="TPU-native distributed-systems test harness")

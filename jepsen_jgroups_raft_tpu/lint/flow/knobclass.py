@@ -127,14 +127,10 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_AUTOTUNE_STORE": OPS,
     "JGRAFT_BENCH_ALLOW_DEGRADED": OPS,
     "JGRAFT_BENCH_CONSISTENCY": OPS,
-    "JGRAFT_BENCH_DEGRADED": OPS,
     "JGRAFT_BENCH_LIN_FASTPATH": OPS,
     "JGRAFT_BENCH_PLATFORM": OPS,
-    "JGRAFT_BENCH_PROBE_RETRY_S": OPS,
-    "JGRAFT_BENCH_PROBE_WINDOW_S": OPS,
     "JGRAFT_BENCH_REPS": OPS,
     "JGRAFT_BENCH_SAVE": OPS,
-    "JGRAFT_BENCH_TARGET": OPS,
     "JGRAFT_BENCH_VDEVS": OPS,
     "JGRAFT_BENCH_WATCHDOG_S": OPS,
     "JGRAFT_CLIENT_KEEPALIVE": OPS,

@@ -353,15 +353,14 @@ def shard_chunk_fns(init_fn, step_fn, mesh, n_init_args: int):
     imports the ops package at load time."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import _SHARD_MAP_CHECK_KW, shard_map
+    from jax import shard_map
 
     spec = P(mesh.axis_names[0])
     init_sm = shard_map(init_fn, mesh=mesh,
                         in_specs=(spec,) * n_init_args, out_specs=spec,
-                        **{_SHARD_MAP_CHECK_KW: False})
+                        check_vma=False)
     step_sm = shard_map(step_fn, mesh=mesh, in_specs=(spec, spec),
-                        out_specs=(spec,) * 5,
-                        **{_SHARD_MAP_CHECK_KW: False})
+                        out_specs=(spec,) * 5, check_vma=False)
     return init_sm, step_sm
 
 

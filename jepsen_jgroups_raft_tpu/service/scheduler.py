@@ -31,8 +31,8 @@ small and the head deadline is not imminent, the scheduler lingers
 classic batching-window trade (latency of the head vs occupancy of the
 launch).
 
-Resilience: the device path failing MID-CHECK (tunnel drop, injected
-fault) degrades the batch to the host-only ladder
+Resilience: the device path failing MID-CHECK (backend teardown,
+injected fault) degrades the batch to the host-only ladder
 (`checker.linearizable.check_encoded_host` — CPU frontier, budgeted
 DFS), stamping ``platform-degraded`` into every affected result and
 recording the root cause via `platform.note_degraded`; the request
@@ -436,13 +436,13 @@ class BatchScheduler:
                 results = self.check_fn(encs, model, algorithm=algorithm,
                                         **check_kw)
             except Exception as e:
-                # Device path died mid-check (tunnel drop, backend
-                # teardown, injected fault): degrade THIS batch to the
+                # Device path died mid-check (backend teardown,
+                # injected fault): degrade THIS batch to the
                 # host-only ladder — a slower sound verdict beats a
                 # failed request. The stamp is LOCAL to this batch's
                 # results; the process-wide first-note-wins registry is
                 # only written for platform-level failures (backend
-                # init / tunnel-drop flavors), where "this process is
+                # init / runtime-gone flavors), where "this process is
                 # degraded" is genuinely true of later batches too — a
                 # one-off non-platform error must not poison every
                 # healthy verdict a long-lived daemon produces after it

@@ -6,7 +6,7 @@ Measures the production weak-rung path (`check_histories`,
 bounded-backtrack certifier + exact cycle tier) enabled vs disabled
 (``JGRAFT_GREEDY_CERTIFY=0 JGRAFT_CYCLE_TIER=0``), interleaved with
 candidate rotation in ONE process — the methodology this repo requires
-for perf claims (cross-process comparisons measure the host/tunnel's
+for perf claims (cross-process comparisons measure the host's
 mood). Verdict identity between the arms is asserted before anything
 is timed (the tier-soundness gate), and the per-family decided
 fractions are reported from the cheap arm's verdicts.

@@ -4,7 +4,7 @@ linearizability (ISSUE-10 acceptance measurement).
 Runs the PRODUCTION path (check_histories, auto routing) with the
 ``consistency=`` knob flipped per rep, interleaved in one process — the
 methodology this repo requires for perf claims (cross-process
-comparisons measure the host/tunnel's mood). The rung-ordering
+comparisons measure the host's mood). The rung-ordering
 invariant is asserted before anything is timed: every history the
 linearizable pass accepts must be accepted by the weaker rung.
 

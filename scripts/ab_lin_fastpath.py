@@ -5,7 +5,7 @@ Measures the production LINEARIZABLE path (`check_histories`,
 ``algorithm="jax"``) with the pre-kernel certify fast path enabled vs
 force-disabled (``JGRAFT_LIN_FASTPATH=0``), interleaved with candidate
 rotation in ONE process — the methodology this repo requires for perf
-claims (cross-process comparisons measure the host/tunnel's mood).
+claims (cross-process comparisons measure the host's mood).
 Verdict identity between the arms is asserted before anything is timed
 (the fast path must never change a verdict, only who decides it), and
 the certified fraction is reported from the fast-path arm's verdicts.

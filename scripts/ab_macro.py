@@ -4,7 +4,7 @@ one-event-per-step stream (ISSUE-4 acceptance measurement).
 Runs the PRODUCTION path (check_histories, auto routing, default
 JGRAFT_SCAN_CHUNK) with JGRAFT_MACRO_EVENTS flipped per rep, interleaved
 in one process — the methodology this repo requires for perf claims
-(cross-process comparisons measure the host/tunnel's mood; identical
+(cross-process comparisons measure the host's mood; identical
 benches have spanned 249-677 hist/s across processes). Verdicts are
 asserted identical between the two variants before anything is timed.
 

@@ -7,7 +7,7 @@ failures, not planting failures), then runs the coverage-guided arm
 against the `JGRAFT_SEARCH_GUIDED=0` random-ablation arm over the SAME
 plant bases, operators, admission path and per-generation candidate
 budget, in ONE process — the methodology this repo requires for perf
-claims (cross-process comparisons measure the host/tunnel's mood).
+claims (cross-process comparisons measure the host's mood).
 
 Discipline, in order:
 

@@ -83,7 +83,7 @@ class TestResultStore:
 
     def test_degraded_never_stored(self, tmp_path):
         store = ResultStore(tmp_path)
-        bad = [dict(RESULTS[0], **{"platform-degraded": "tunnel drop"})]
+        bad = [dict(RESULTS[0], **{"platform-degraded": "device lost"})]
         assert is_degraded(bad)
         assert store.put("ab" * 32, bad) is False
         assert store.get("ab" * 32) is None

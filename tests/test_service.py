@@ -342,7 +342,7 @@ class TestDegradeToCpu:
 
         def dying(encs, model, algorithm="auto", **kw):
             calls["n"] += 1
-            raise RuntimeError("UNAVAILABLE: tunnel dropped mid-check")
+            raise RuntimeError("UNAVAILABLE: device lost mid-check")
 
         hists = [valid_hist(seed=1), invalid_hist()]
         svc = make_service(check_fn=dying, autostart=False)
