@@ -38,7 +38,6 @@ in which case we escalate instead of reporting.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -59,8 +58,8 @@ from ..platform import degraded_note, env_int
 from . import autotune
 from .base import Checker, INVALID, UNKNOWN, VALID
 from .dfs_cpu import SearchBudgetExceeded, check_encoded_dfs
-from .schedule import (ChunkLaunch, build_dense_launches, note_tier,
-                       run_chunked, scan_chunk)
+from .schedule import (ChunkLaunch, build_dense_launches, launch_span,
+                       note_tier, run_chunked, scan_chunk)
 from .wgl_cpu import FrontierOverflow, check_encoded_cpu
 
 
@@ -737,7 +736,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 triples.append((sub, plan, batch, tuned))
             launches, subs = build_dense_launches(
                 model, triples, host_route=_route_group_to_host)
-            with _maybe_profile():
+            with launch_span(rows=sum(len(sub) for sub in subs)):
                 outs = run_chunked(launches)
             for sub, out in zip(subs, outs):
                 # Slices overlap on devices, so per-launch kernel
@@ -760,7 +759,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             t0 = time.perf_counter()
             launched = []  # (sub, tag, ok_device, B)
             n_launched = 0
-            with _maybe_profile():
+            with launch_span(rows=sum(len(idxs) for idxs, _ in grouped)):
                 for idxs, plan in grouped:
                     sub = [fits[j] for j in idxs]
                     batch = _group_pack([encs[i] for i in sub])
@@ -881,7 +880,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                     mesh=getattr(rung_sharding, "mesh", None),
                     macro_p=batch.get("macro_p"))
                 e_sched = bucket_rows(batch["events"].shape[1], 32)
-                with _maybe_profile():
+                with launch_span(rows=len(remaining)):
                     [out] = run_chunked([ChunkLaunch(
                         events=batch["events"],
                         n_events=batch["n_events"],
@@ -899,12 +898,14 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 # instead of recompiling per batch size. Pad rows/events
                 # are EV_PAD no-ops.
                 ev, _, B = pad_batch_bucketed(batch["events"])
-                with _maybe_profile():
+                with launch_span(rows=len(remaining)):
                     ok, overflow = kernel(ev)
-                ok, overflow = ok[:B], overflow[:B]
-                # The ladder must block per rung to decide escalation.
-                ok = np.asarray(ok)  # lint: allow(host-sync)
-                overflow = np.asarray(overflow)  # lint: allow(host-sync)
+                    ok, overflow = ok[:B], overflow[:B]
+                    # The ladder must block per rung to decide
+                    # escalation.
+                    ok = np.asarray(ok)  # lint: allow(host-sync)
+                    overflow = np.asarray(  # lint: allow(host-sync)
+                        overflow)
             dt = time.perf_counter() - t0
             escalate = []
             for j, i in enumerate(remaining):
@@ -1035,16 +1036,6 @@ def _check_dfs(enc: EncodedHistory, model, witness: bool = False,
     if r.witness is not None:
         out["witness"] = r.witness
     return out
-
-
-def _maybe_profile():
-    """XLA profiler hook (SURVEY.md §5.1): set JGRAFT_PROFILE_DIR to
-    capture a TensorBoard-readable trace of the kernel launches."""
-    profile_dir = os.environ.get("JGRAFT_PROFILE_DIR")
-    if not profile_dir:
-        return contextlib.nullcontext()
-    import jax
-    return jax.profiler.trace(profile_dir)
 
 
 

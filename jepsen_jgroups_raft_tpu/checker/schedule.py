@@ -50,7 +50,11 @@ not have (pinned bitwise by tests/test_chunked_scan.py differentials).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
+import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -97,7 +101,13 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # SCCs hit, and blocked-closure tile programs run.
                "cycle_size_skips": 0, "cycle_nodes_pre": 0,
                "cycle_nodes_post": 0, "cycle_scc_hits": 0,
-               "cycle_tiles_run": 0}
+               "cycle_tiles_run": 0,
+               # compile counters (ISSUE 26): fed by the jax.monitoring
+               # listeners `platform.install_compile_counters` registers.
+               # Scoped like the rest, so a launch's `stats["scan"]`
+               # shows the programs THAT launch built.
+               "programs_built": 0, "compile_s": 0.0,
+               "compile_cache_misses": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -111,11 +121,19 @@ _STATS = dict(_STATS_ZERO)
 _SCOPES: List[tuple] = []
 
 
-def _add_stats(**kw) -> None:
+def _scope_targets() -> list:
+    """The scopes a record from the calling thread lands in (see
+    `_SCOPES`); the caller holds _STATS_LOCK."""
+    if not _SCOPES:
+        return []
     tid = threading.get_ident()
+    owned = [s for s, o in _SCOPES if o == tid]
+    return owned if owned else [s for s, _ in _SCOPES]
+
+
+def _add_stats(**kw) -> None:
     with _STATS_LOCK:
-        owned = [s for s, o in _SCOPES if o == tid]
-        targets = owned if owned else [s for s, _ in _SCOPES]
+        targets = _scope_targets()
         for k, v in kw.items():
             _STATS[k] += v
             for scope in targets:
@@ -217,14 +235,11 @@ def note_tier(tier: str, rows: int = 1, wall_s: float = 0.0) -> None:
     attributed to them). Scope targeting mirrors `_add_stats`: a thread
     owning scopes feeds only its own (each scope's tier dict lives
     under the non-counter key ``"tiers"``)."""
-    tid = threading.get_ident()
     with _STATS_LOCK:
         t = _TIERS.setdefault(tier, [0, 0.0])
         t[0] += rows
         t[1] += wall_s
-        owned = [s for s, o in _SCOPES if o == tid]
-        targets = owned if owned else [s for s, _ in _SCOPES]
-        for scope in targets:
+        for scope in _scope_targets():
             e = scope.setdefault("tiers", {}).setdefault(tier, [0, 0.0])
             e[0] += rows
             e[1] += wall_s
@@ -257,6 +272,179 @@ def consume_tiers() -> dict:
         out = _format_tiers(_TIERS)
         _TIERS = {}
         return out
+
+
+# ------------------------------------------------------------------ spans
+# ISSUE 26: named durations on the served path, in the same registry as
+# the counters and tiers above: process-wide totals plus thread-affine
+# scope attribution under the non-counter key ``"spans"``. While a
+# `jax.profiler` session is active every span is also a
+# `jax.profiler.TraceAnnotation` on the calling thread, so it lands on
+# the host plane of the same trace as the device's `XLA Ops` /
+# `XLA Modules` lines, on the profiler's clock. No switch: the totals
+# are always on, the annotations follow the profiler.
+#
+# Rule (tests/test_spans.py pins it statically): a span site runs per
+# request, per launch or per wavefront round, never inside a loop over
+# rows or events.
+
+_SPANS: dict = {}  # name -> [n, seconds]; guarded by _STATS_LOCK
+#: most recent compiles, newest last: (fun_name, seconds, innermost
+#: span open on the compiling thread or None); guarded by _STATS_LOCK
+_RECENT_COMPILES: collections.deque = collections.deque(maxlen=16)
+#: per-thread stack of open span names (`.names`), so that a compile
+#: can be stamped with the step it interrupted
+_OPEN = threading.local()
+_LAUNCH_SEQ = itertools.count(1)
+
+
+_PROFILE_STATE = None  # jax's profiler state, once JAX has loaded it
+
+
+def _profiling() -> bool:
+    """True while a `jax.profiler` session started from this process is
+    active. A process that has not imported JAX is not made to."""
+    global _PROFILE_STATE
+    state = _PROFILE_STATE
+    if state is None:
+        prof = sys.modules.get("jax._src.profiler")
+        if prof is None:
+            return False
+        state = _PROFILE_STATE = prof._profile_state
+    return state.profile_session is not None
+
+
+def note_span(name: str, seconds: float, n: int = 1) -> None:
+    """Add a duration taken from stamps (a request's phases) to span
+    `name`. Scope targeting mirrors `note_tier`."""
+    with _STATS_LOCK:
+        t = _SPANS.get(name)
+        if t is None:
+            t = _SPANS[name] = [0, 0.0]
+        t[0] += n
+        t[1] += seconds
+        for scope in _scope_targets():
+            e = scope.setdefault("spans", {}).setdefault(name, [0, 0.0])
+            e[0] += n
+            e[1] += seconds
+
+
+class annotate:
+    """Names a block without timing it: the name is what `open_span`
+    answers on this thread inside the block (so a compile is stamped
+    with it) and, while a profiler session is active, a
+    `jax.profiler.TraceAnnotation` with the keyword arguments as its
+    arguments. With no session no annotation object is built."""
+
+    __slots__ = ("name", "_args", "_ann")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self._args = args
+        self._ann = None
+
+    def __enter__(self):
+        try:
+            _OPEN.names.append(self.name)
+        except AttributeError:
+            _OPEN.names = [self.name]
+        if _profiling():
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self.name,
+                                                     **self._args)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        _OPEN.names.pop()
+        return False
+
+
+class span(annotate):
+    """``with span("launch.device", rows=256) as sp: ...``: an
+    `annotate` block that is also timed: the elapsed seconds are added
+    to the registry under `name` and left in ``sp.s``, so that a site
+    which also feeds a counter reads the clock once. `n` is what the
+    span counts (default: itself, 1)."""
+
+    __slots__ = ("n", "s", "_t0")
+
+    def __init__(self, name: str, n: int = 1, **args):
+        annotate.__init__(self, name, **args)
+        self.n = n
+        self.s = 0.0
+
+    def __enter__(self):
+        annotate.__enter__(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.s = time.perf_counter() - self._t0
+        annotate.__exit__(self, *exc)
+        note_span(self.name, self.s, self.n)
+        return False
+
+
+def open_span() -> Optional[str]:
+    """Innermost span open on the calling thread, or None."""
+    names = getattr(_OPEN, "names", None)
+    return names[-1] if names else None
+
+
+def _format_spans(raw: dict) -> dict:
+    return {k: {"n": v[0], "s": v[1]} for k, v in raw.items()}
+
+
+def snapshot_spans() -> dict:
+    """Fresh copy of the process-wide span totals,
+    ``{name: {"n", "s"}}``."""
+    with _STATS_LOCK:
+        return _format_spans(_SPANS)
+
+
+def note_compile(fun_name: str, seconds: float) -> None:
+    """One program built or loaded by the backend (the
+    `/jax/core/compile/backend_compile_duration` event), stamped with
+    the span it interrupted on this thread."""
+    _add_stats(programs_built=1, compile_s=seconds)
+    with _STATS_LOCK:
+        _RECENT_COMPILES.append((fun_name, seconds, open_span()))
+
+
+def note_cache_miss() -> None:
+    """One program the persistent compile cache did not hold."""
+    _add_stats(compile_cache_misses=1)
+
+
+def snapshot_compiles() -> dict:
+    """The process-wide compile counters and the most recent compiles,
+    newest last, as ``[fun_name, seconds, open span]``."""
+    with _STATS_LOCK:
+        return {"programs_built": _STATS["programs_built"],
+                "compile_s": _STATS["compile_s"],
+                "compile_cache_misses": _STATS["compile_cache_misses"],
+                "recent_compiles": [list(c) for c in _RECENT_COMPILES]}
+
+
+@contextlib.contextmanager
+def launch_span(rows: int):
+    """The one wrapper of a kernel launch site: a whole profiler session
+    around it when JGRAFT_PROFILE_DIR names a directory (the XLA
+    profiler hook, SURVEY.md section 5.1), and the `launch.device` span
+    inside it, so the span's annotation lands in that trace."""
+    profile_dir = os.environ.get("JGRAFT_PROFILE_DIR")
+    with contextlib.ExitStack() as stack:
+        if profile_dir and not _profiling():
+            import jax
+
+            stack.enter_context(jax.profiler.trace(profile_dir))
+        yield stack.enter_context(
+            span("launch.device", seq=next(_LAUNCH_SEQ), rows=rows))
 
 
 # ------------------------------------------------------------- wavefront
@@ -566,16 +754,17 @@ def _collect(g: _GroupState) -> None:
     recompact survivors when they fit a smaller row bucket."""
     import jax
 
-    t_disp, span, (carry, decided, exhausted, ok, overflow) = g.pending
+    t_disp, width, (carry, decided, exhausted, ok, overflow) = g.pending
     g.pending = None
     g.carry = carry
     # blocks: device → host (the wavefront's per-round sync point)
-    decided = np.asarray(decided)      # lint: allow(host-sync)
-    exhausted = np.asarray(exhausted)  # lint: allow(host-sync)
-    ok = np.asarray(ok)                # lint: allow(host-sync)
-    overflow = np.asarray(overflow)    # lint: allow(host-sync)
+    with span("launch.sync"):
+        decided = np.asarray(decided)      # lint: allow(host-sync)
+        exhausted = np.asarray(exhausted)  # lint: allow(host-sync)
+        ok = np.asarray(ok)                # lint: allow(host-sync)
+        overflow = np.asarray(overflow)    # lint: allow(host-sync)
     g.intervals.append((t_disp, time.perf_counter()))
-    g.cursor += span
+    g.cursor += width
     g.launches_run += 1
 
     real = g.slot_rows >= 0

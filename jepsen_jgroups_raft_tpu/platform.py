@@ -123,6 +123,36 @@ def enable_compile_cache() -> Optional[str]:
     return path
 
 
+_COMPILE_COUNTERS_INSTALLED = False
+
+
+def install_compile_counters() -> None:
+    """Register, once a process, the `jax.monitoring` listeners that
+    feed the registry's compile counters (`checker.schedule`:
+    `programs_built`, `compile_s`, `compile_cache_misses`, and the most
+    recent compiles with the span each interrupted). graftd calls it
+    when it starts, so an operator whose daemon never stops compiling
+    sees that in `/stats` as programs, not only as latency."""
+    global _COMPILE_COUNTERS_INSTALLED
+    if _COMPILE_COUNTERS_INSTALLED:
+        return
+    _COMPILE_COUNTERS_INSTALLED = True
+    from jax import monitoring
+
+    from .checker.schedule import note_cache_miss, note_compile
+
+    def on_duration(event, seconds, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            note_compile(str(kw.get("fun_name", "?")), seconds)
+
+    def on_event(event, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_misses":
+            note_cache_miss()
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
 def _cpu_pinned() -> bool:
     import jax
 

@@ -24,6 +24,7 @@ import time
 from collections import OrderedDict
 from typing import Callable, List, Optional
 
+from ..checker.schedule import span
 from ..platform import env_int
 from .request import CheckRequest
 
@@ -42,7 +43,8 @@ def admit_frame(payload) -> CheckRequest:
     from .frame import KIND_SUBMIT, FrameError, decode_frame
     from .request import admit_encoded
 
-    fr = decode_frame(payload)
+    with span("ingest.decode"):
+        fr = decode_frame(payload)
     if getattr(fr, "labels", None) is None:
         raise FrameError(f"expected a submit frame (kind {KIND_SUBMIT}); "
                          "got a stream segment")

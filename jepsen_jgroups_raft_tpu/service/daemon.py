@@ -36,6 +36,8 @@ from collections import deque
 from pathlib import Path
 from typing import Optional, Sequence
 
+from ..checker.schedule import snapshot_compiles, snapshot_spans, span
+from ..platform import install_compile_counters
 from .admission import (AdmissionQueue, QueueFull, ResultCache,
                         ServiceStopped)
 from .journal import AdmissionJournal, decode_request, journal_enabled
@@ -171,6 +173,7 @@ class CheckingService:
                  lease_ttl_s: Optional[float] = None,
                  autostart: bool = True):
         self.name = name
+        install_compile_counters()
         self.store_root = Path(store_root) if store_root else None
         self.queue = AdmissionQueue(queue_capacity,
                                     on_prune=self._finalize_pruned)
@@ -218,7 +221,7 @@ class CheckingService:
             "submitted": 0, "completed": 0, "failed": 0, "cancelled": 0,
             "rejected": 0, "cache_hits": 0, "batches": 0, "batch_rows": 0,
             "batched_requests": 0, "degraded_batches": 0,
-            "max_queue_depth": 0, "worker_restarts": 0, "trace_errors": 0,
+            "max_queue_depth": 0, "worker_restarts": 0,
             "recovered_requests": 0, "attached_requests": 0,
             "quarantined": 0, "watchdog_requeues": 0,
             # lin-rung fast lane (ISSUE 14): requests fully decided by
@@ -661,9 +664,9 @@ class CheckingService:
                 for tier, n in r.stats.get("decided_tier", {}).items():
                     self._tier_counts[tier] = \
                         self._tier_counts.get(tier, 0) + n
-        self._account_requests(done)
-        for r in done:
-            self._write_trace(r)
+        with span("demux.account"):
+            self._account_requests(done)
+        self._write_traces(done)
 
     def _fail_unexecuted(self, batch) -> None:
         """A shutdown is not a verdict: requests popped from admission
@@ -682,7 +685,8 @@ class CheckingService:
         requests; traces are written either way."""
         try:
             info = self.scheduler.execute(batch, placement=placement)
-            self._account_batch(batch, info)
+            with span("demux.account"):
+                self._account_batch(batch, info)
         except Exception:
             # Even the host fallback failed (or a scheduler bug):
             # fail THIS batch's requests, keep serving the queue.
@@ -692,8 +696,14 @@ class CheckingService:
                     r.finish(FAILED, error="batch execution raised; "
                              "see service log")
             self._account_requests(batch)
-        for r in batch:
-            self._write_trace(r)
+        self._write_traces(batch)
+
+    def _write_traces(self, reqs) -> None:
+        """The records of a batch's requests, written on the worker's
+        own thread before its next `take`."""
+        with span("demux.trace_write", n=len(reqs)):
+            for r in reqs:
+                self._write_trace(r)
 
     def _supervised_executor(self, k: int) -> None:
         """Shard executor k: drain this shard's routed batches. The
@@ -1051,6 +1061,12 @@ class CheckingService:
         if self.cluster is not None:
             out.update(self.cluster.stats())
         out.update(self.streams.stats())
+        # ISSUE 26: where the served path's time went, by span
+        # ({name: {"n", "s"}}, process-wide, a fresh copy), and the
+        # programs the backend built or loaded with the span each
+        # interrupted
+        out["spans"] = snapshot_spans()
+        out.update(snapshot_compiles())
         return out
 
     # ----------------------------------------------------- accounting
@@ -1206,7 +1222,7 @@ class CheckingService:
         """Persist one request's terminal record into the store layout
         (store/<service>/<ts>-<reqid>/: results.json + history.jsonl),
         browsable by `core/serve.py` next to test runs. Best-effort:
-        trace IO must never fail a verdict (counted, logged)."""
+        trace IO must never fail a verdict (logged)."""
         if self.store_root is None or req.status == QUEUED:
             return
         try:
@@ -1237,6 +1253,5 @@ class CheckingService:
                         f.write(row_line)  # lint: allow(fsync)
             os.replace(tmp, d / "history.jsonl")
         except OSError:
-            self._count("trace_errors")
             LOG.warning("trace write failed for request %s", req.id,
                         exc_info=True)

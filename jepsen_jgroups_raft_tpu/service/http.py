@@ -55,6 +55,7 @@ embed via `make_server` (tests, the bench's --service mode).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -66,6 +67,7 @@ from functools import partial
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
 
+from ..checker.schedule import span
 from ..platform import env_str
 from .admission import QueueFull
 from .daemon import CheckingService, ServiceStopped
@@ -178,7 +180,11 @@ class _Handler(BaseHTTPRequestHandler):
             self._post_frame(path)
             return
         try:
-            body = self._body()
+            # the JSON parse of a submission is the first part of its
+            # decode; `admit` counts the request (n=0 here)
+            with span("ingest.decode", n=0) if path == "/submit" \
+                    else contextlib.nullcontext():
+                body = self._body()
         except (ValueError, json.JSONDecodeError) as e:
             self._send(400, {"error": f"bad request body: {e}"})
             return

@@ -59,6 +59,7 @@ from typing import List, Optional
 import numpy as np
 
 from ..history.ops import History
+from ..checker.schedule import span
 from ..history.packing import EncodedHistory
 from ..platform import env_int
 from .request import DONE, CheckRequest
@@ -385,27 +386,42 @@ class AdmissionJournal:
             self._fh = open(self.path, "ab")
         return self._fh
 
-    def _append(self, rec: dict, fsync: bool) -> bool:
-        rec["crc"] = _crc_line(rec)
-        line = (json.dumps(rec, sort_keys=True,
-                           separators=(",", ":")) + "\n").encode()
-        group = journal_group_ms() if fsync else 0
-        if group > 0:
-            return self._append_grouped(line, rec, group)
-        t0 = time.perf_counter()
+    def _append(self, build, fsync: bool,
+                name: str = "journal.mark") -> bool:
+        """Append a record, or the record `build()` returns. One span
+        covers the whole of it (building and serialising the record, the write,
+        the wait for the commit that covers it): `journal.append` for a
+        submit record, on the handler thread inside the
+        acknowledgement, `journal.mark` for every other kind (terminal
+        markers, on the dispatcher thread; stream records). Its seconds
+        are the `append_ms` sample too."""
+        with span(name) as sp:
+            rec = build() if callable(build) else build
+            rec["crc"] = _crc_line(rec)
+            line = (json.dumps(rec, sort_keys=True,
+                               separators=(",", ":")) + "\n").encode()
+            group = journal_group_ms() if fsync else 0
+            if group > 0:
+                ok = self._append_grouped(line, group)
+            else:
+                ok = self._append_alone(line, rec, fsync)
+        # under the lock: stats() iterates append_ms while holding it
+        # (a bare deque.append is atomic, but sorted() mid-mutation is
+        # not)
+        with self._lock:
+            self.append_ms.append(sp.s * 1000.0)
+        return ok
+
+    def _append_alone(self, line: bytes, rec: dict, fsync: bool) -> bool:
         try:
             with self._lock:
                 fh = self._handle()
                 fh.write(line)
                 fh.flush()
                 if fsync:
-                    os.fsync(fh.fileno())
-                # counters under the same lock: stats() iterates
-                # append_ms while holding it (a bare deque.append is
-                # atomic, but sorted() mid-mutation is not)
+                    with span("journal.fsync"):
+                        os.fsync(fh.fileno())
                 self._appends += 1
-                self.append_ms.append(
-                    (time.perf_counter() - t0) * 1000.0)
         except OSError:
             # Durability degraded, availability kept: the daemon counts
             # and logs, the request is still served (module docstring).
@@ -416,8 +432,7 @@ class AdmissionJournal:
             return False
         return True
 
-    def _append_grouped(self, line: bytes, rec: dict,
-                        group_ms: int) -> bool:
+    def _append_grouped(self, line: bytes, group_ms: int) -> bool:
         """Leader/follower group commit (`journal_group_ms`). The
         caller's entry joins the pending queue; the first appender with
         no leader in flight LEADS: it drains the queue, writes every
@@ -434,7 +449,6 @@ class AdmissionJournal:
         per-append path. Under real concurrency no sleep is needed at
         all — followers pile into the queue during the current group's
         write+fsync and the next leader finds them already waiting."""
-        t0 = time.perf_counter()
         entry = [line, False, False]   # line, done, ok
         with self._gcond:
             self._gqueue.append(entry)
@@ -458,7 +472,8 @@ class AdmissionJournal:
                         fh = self._handle()
                         fh.write(b"".join(e[0] for e in batch))
                         fh.flush()
-                        os.fsync(fh.fileno())
+                        with span("journal.fsync"):
+                            os.fsync(fh.fileno())
                         self._appends += len(batch)
                         self._group_commits += 1
                         self._group_records += len(batch)
@@ -477,21 +492,20 @@ class AdmissionJournal:
                     self._gleader = False
                     self._glast_multi = len(batch) > 1
                     self._gcond.notify_all()
-        with self._lock:
-            self.append_ms.append((time.perf_counter() - t0) * 1000.0)
         return entry[2]
 
     def append_submit(self, req: CheckRequest) -> bool:
         """Durability point: returns only after the record is fsync'd
         (or after the failure was counted). Must be called BEFORE the
         202 is visible to the client."""
-        return self._append(encode_submit(req), fsync=True)
+        return self._append(lambda: encode_submit(req), fsync=True,
+                            name="journal.append")
 
     def append_terminal(self, req: CheckRequest) -> bool:
         """Mark a journaled request finished. fsync'd too — a lost
         terminal marker is only re-execution on replay (idempotent),
         but a persisted one is a warm cache entry worth the write."""
-        ok = self._append(encode_terminal(req), fsync=True)
+        ok = self._append(lambda: encode_terminal(req), fsync=True)
         with self._lock:
             self._finished_since_compact += 1
             # amortized: compact once the WAL holds ~2x the retention

@@ -23,6 +23,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ..checker.base import merge_valid
+from ..checker.schedule import span
 from ..history.ops import History, Op
 from ..history.packing import EncodedHistory, encode_history
 
@@ -171,6 +172,15 @@ class CheckRequest:
     #: late, not hung, and demoting healthy workers for it would
     #: amplify the overload.
     run_started: float = 0.0
+    #: the other stamps of a request's life (ISSUE 26; monotonic, 0.0 =
+    #: not reached): `taken` when the dispatcher's `take` returned it,
+    #: `scanned` when the fast lane's scan of THIS request ended,
+    #: `finished` when it turned terminal. With `submitted` and
+    #: `run_started` they give `stats["phases_ms"]`
+    #: (scheduler._stamp_phases).
+    taken: float = 0.0
+    scanned: float = 0.0
+    finished: float = 0.0
     replayed: bool = False
     attached_to: Optional[str] = None
     #: transactional-anomaly overlay (ISSUE 19): stamped at ADMISSION
@@ -229,6 +239,7 @@ class CheckRequest:
                 return False
             self.results = results
             self.error = error
+            self.finished = time.monotonic()
             self.status = status
             self._done.set()
             return True
@@ -306,8 +317,9 @@ def admit(histories: Sequence, workload: str, algorithm: str = "auto",
     from ..checker.consistency import normalize_consistency
 
     consistency = normalize_consistency(consistency)
-    model, units = build_units(histories, workload)
-    encs = [encode_history(h, model) for _, h in units]
+    with span("ingest.decode"):
+        model, units = build_units(histories, workload)
+        encs = [encode_history(h, model) for _, h in units]
     txn = None
     if getattr(model, "txn_anomaly_capable", False):
         # host-only (kernel=False inside): Tarjan + numpy closure on
@@ -318,6 +330,9 @@ def admit(histories: Sequence, workload: str, algorithm: str = "auto",
             (h if isinstance(h, History) else
              history_from_dicts(h)).client_ops()
             for h in histories])
+    with span("ingest.fingerprint"):
+        fingerprint = fingerprint_encodings(model, algorithm, encs,
+                                            consistency)
     now = time.monotonic()  # admission timestamp (txn overlay above)
     deadline = now + (deadline_ms / 1000.0 if deadline_ms is not None
                       else default_deadline_s)
@@ -328,8 +343,7 @@ def admit(histories: Sequence, workload: str, algorithm: str = "auto",
         algorithm=algorithm,
         units=units,
         encs=encs,
-        fingerprint=fingerprint_encodings(model, algorithm, encs,
-                                          consistency),
+        fingerprint=fingerprint,
         deadline=deadline,
         submitted=now,
         priority=clamp_priority(priority),
@@ -373,8 +387,9 @@ def admit_encoded(workload: str, labels: Sequence[str],
     if len(labels) != len(encs):
         raise ValueError(f"{len(labels)} labels for {len(encs)} "
                          "encodings")
-    fingerprint = fingerprint_encodings(model, algorithm, encs,
-                                        consistency)
+    with span("ingest.fingerprint"):
+        fingerprint = fingerprint_encodings(model, algorithm, encs,
+                                            consistency)
     now = time.monotonic()
     deadline = now + (deadline_ms / 1000.0 if deadline_ms is not None
                       else default_deadline_s)
@@ -418,13 +433,18 @@ def admit_run_dir(run_dir, algorithm: str = "auto",
     from ..models.base import Model
 
     consistency = normalize_consistency(consistency)
-    model, subs, wl = load_run_histories(run_dir, workload)
-    if not isinstance(model, Model):
-        raise ValueError(
-            f"{run_dir}: workload {wl!r} uses a non-frontier checker; "
-            "re-verify it with `python -m jepsen_jgroups_raft_tpu check`")
-    units = [(f"{wl}/u{i}", h) for i, h in enumerate(subs)]
-    encs = [encode_history(h, model) for _, h in units]
+    with span("ingest.decode"):
+        model, subs, wl = load_run_histories(run_dir, workload)
+        if not isinstance(model, Model):
+            raise ValueError(
+                f"{run_dir}: workload {wl!r} uses a non-frontier "
+                "checker; re-verify it with "
+                "`python -m jepsen_jgroups_raft_tpu check`")
+        units = [(f"{wl}/u{i}", h) for i, h in enumerate(subs)]
+        encs = [encode_history(h, model) for _, h in units]
+    with span("ingest.fingerprint"):
+        fingerprint = fingerprint_encodings(model, algorithm, encs,
+                                            consistency)
     now = time.monotonic()
     deadline = now + (deadline_ms / 1000.0 if deadline_ms is not None
                       else default_deadline_s)
@@ -435,8 +455,7 @@ def admit_run_dir(run_dir, algorithm: str = "auto",
         algorithm=algorithm,
         units=units,
         encs=encs,
-        fingerprint=fingerprint_encodings(model, algorithm, encs,
-                                          consistency),
+        fingerprint=fingerprint,
         deadline=deadline,
         submitted=now,
         priority=clamp_priority(priority),

@@ -47,7 +47,8 @@ import time
 from typing import Callable, List, Optional
 
 from ..checker import autotune
-from ..checker.schedule import stats_scope
+from ..checker.schedule import (annotate, note_span, note_tier, span,
+                                stats_scope)
 from ..history.packing import bucket_rows
 from ..platform import env_int, is_backend_init_failure, note_degraded
 from .admission import AdmissionQueue
@@ -147,6 +148,31 @@ def bucket_signature(req: CheckRequest) -> tuple:
             bucket_rows(max(e_max, 1), 32))
 
 
+def _stamp_taken(reqs: List[CheckRequest]) -> None:
+    now = time.monotonic()
+    for r in reqs:
+        r.taken = now
+
+
+def _stamp_phases(r: CheckRequest, results_at: float) -> None:
+    """Write ``r.stats["phases_ms"]`` from the request's stamps, just
+    before it turns terminal, and add each phase to its `request.*`
+    span. A phase whose closing stamp is unset (0.0: the lane did not
+    scan the request, or answered it before any launch) is left out and
+    the next one starts where the last ended, so the phases always sum
+    to submit -> now."""
+    last, phases = r.submitted, {}
+    for phase, t in (("queue_wait", r.taken), ("scan", r.scanned),
+                     ("formation_wait", r.run_started),
+                     ("run", results_at), ("finish", time.monotonic())):
+        if t:
+            seconds = max(0.0, t - last)
+            last = max(last, t)
+            phases[phase] = round(seconds * 1e3, 3)
+            note_span("request." + phase, seconds)
+    r.stats["phases_ms"] = phases
+
+
 class BatchScheduler:
     """Forms and executes coalesced batches from an AdmissionQueue."""
 
@@ -241,7 +267,6 @@ class BatchScheduler:
         from ..checker.linearizable import (LIN_FASTPATH_ALGOS,
                                             lin_fastpath_on,
                                             lin_fastpath_pass)
-        from ..checker.schedule import note_tier
 
         if not batch or not self.fastlane_enabled \
                 or not lin_fastpath_on():
@@ -256,23 +281,24 @@ class BatchScheduler:
                     or r.force_host or not r.encs):
                 live.append(r)
                 continue
-            t0 = time.monotonic()
-            rs = lin_fastpath_pass(r.encs, r.model, note=False)
-            # the pass deliberately leaves 0-event rows undecided (the
-            # kernel path stamps them "trivial"); here they are
-            # host-decidable for free and must not force an otherwise
-            # fully-certified request onto the batch path
-            for j, enc in enumerate(r.encs):
-                if rs[j] is None and enc.n_events <= 0:
-                    rs[j] = {"valid?": VALID, "algorithm": "trivial",
-                             "op-count": 0, "decided-tier": "trivial"}
+            with span("dispatch.scan") as scan:
+                rs = lin_fastpath_pass(r.encs, r.model, note=False)
+                # the pass deliberately leaves 0-event rows undecided
+                # (the kernel path stamps them "trivial"); here they are
+                # host-decidable for free and must not force an
+                # otherwise fully-certified request onto the batch path
+                for j, enc in enumerate(r.encs):
+                    if rs[j] is None and enc.n_events <= 0:
+                        rs[j] = {"valid?": VALID, "algorithm": "trivial",
+                                 "op-count": 0, "decided-tier": "trivial"}
             # the lane SCANNED this request: execute() may suppress the
             # redundant in-checker re-scan for it (and only for it)
             r._fp_tried = True
+            r.scanned = time.monotonic()
             if not all(res is not None for res in rs):
                 live.append(r)
                 continue
-            wall = time.monotonic() - t0
+            wall = scan.s
             # honor a cancel that landed DURING the scan — the batch
             # path's demux re-checks at the same point (first-wins
             # finish keeps the race harmless either way)
@@ -294,6 +320,7 @@ class BatchScheduler:
                 "placement": {"shard": None, "n_shards": 0},
                 "degraded": False,
             }
+            _stamp_phases(r, results_at=0.0)
             r.finish(DONE, results=rs)
             decided.append(r)
         return decided, live
@@ -311,9 +338,11 @@ class BatchScheduler:
         request's latency is the host scan, not the batching window —
         and decided requests are delivered to the callback instead of
         the returned batch (linger top-ups ride the lane too)."""
-        batch = self.queue.take(self._choose, timeout)
+        with span("dispatch.take"):
+            batch = self.queue.take(self._choose, timeout)
         if not batch:
             return batch
+        _stamp_taken(batch)
         if on_decided is not None:
             done, batch = self.fastlane(batch)
             if done:
@@ -325,7 +354,6 @@ class BatchScheduler:
         slack = head.deadline - time.monotonic()
         if (self.batch_wait > 0 and rows < self.max_batch_rows
                 and not head.solo and slack > self.batch_wait):
-            time.sleep(self.batch_wait)
             sig = bucket_signature(head)
 
             def topup(pending: List[CheckRequest]) -> List[CheckRequest]:
@@ -341,7 +369,10 @@ class BatchScheduler:
                     extra_rows += r.n_rows
                 return extra
 
-            extra = self.queue.take(topup, timeout=0.0)
+            with span("dispatch.linger"):
+                time.sleep(self.batch_wait)
+                extra = self.queue.take(topup, timeout=0.0)
+            _stamp_taken(extra)
             if on_decided is not None and extra:
                 done, extra = self.fastlane(extra)
                 if done:
@@ -418,8 +449,9 @@ class BatchScheduler:
         # neighbor shards' plans — the thread filter keeps each batch's
         # stamp to exactly the plans its own launch consulted.
         autotune_mark = autotune.applied_seq()
-        t0 = time.monotonic()
-        with stats_scope(label=label) as scan:
+        t0 = time.perf_counter()
+        with stats_scope(label=label) as scan, \
+                annotate("launch.host", seq=seq, rows=len(encs)):
             try:
                 if any(r.force_host for r in live):
                     # Hung-batch watchdog second strike (ISSUE 8): the
@@ -460,44 +492,58 @@ class BatchScheduler:
                            for enc in encs]
                 for res in results:
                     res["platform-degraded"] = degraded_note_local
-        wall = time.monotonic() - t0
+            wall = time.perf_counter() - t0
+            # Everything in the check outside the kernel launches: the
+            # grouping, the packing, building the launches, the result
+            # dicts, any host escalation. The scope holds this launch's
+            # `launch.device` seconds, so nothing in `check_fn` falls
+            # between the two spans.
+            device_s = scan.get("spans", {}).get("launch.device",
+                                                 (0, 0.0))[1]
+            note_span("launch.host", max(0.0, wall - device_s))
+        results_at = time.monotonic()
         scan_counters = {k: v for k, v in scan.items()
-                         if k not in ("label", "tiers")}
+                         if k not in ("label", "tiers", "spans")}
+        scan_counters["spans"] = {
+            k: {"n": v[0], "s": round(v[1], 6)}
+            for k, v in scan.get("spans", {}).items()}
         autotune_plans = autotune.applied_since(
             autotune_mark, thread_id=threading.get_ident())
         batch_tiers: dict = {}
         cursor = 0
         for r in live:
-            mine = results[cursor:cursor + r.n_rows]
-            cursor += r.n_rows
-            # Tier attribution (ISSUE 13): which decision-ladder tier
-            # decided each of this request's rows — the per-request
-            # trace record's capacity-model evidence, aggregated
-            # daemon-wide into /stats decided_tier.
-            tiers: dict = {}
-            for res in mine:
-                t = res.get("decided-tier") if res else None
-                if t is not None:
-                    tiers[t] = tiers.get(t, 0) + 1
-                    batch_tiers[t] = batch_tiers.get(t, 0) + 1
-            r.stats = {
-                "batched_requests": len(live),
-                "batch_rows": len(encs),
-                "batch_seq": seq,
-                "batch_wall_s": round(wall, 4),
-                "scan": dict(scan_counters, label=label),
-                "autotune_plans": autotune_plans,
-                "decided_tier": tiers,
-                "placement": dict(placement) if placement else
-                {"shard": 0, "n_shards": 1},
-                "degraded": degraded_note_local is not None,
-            }
+            with span("demux.results"):
+                mine = results[cursor:cursor + r.n_rows]
+                cursor += r.n_rows
+                # Tier attribution (ISSUE 13): which decision-ladder
+                # tier decided each of this request's rows — the
+                # per-request trace record's capacity-model evidence,
+                # aggregated daemon-wide into /stats decided_tier.
+                tiers: dict = {}
+                for res in mine:
+                    t = res.get("decided-tier") if res else None
+                    if t is not None:
+                        tiers[t] = tiers.get(t, 0) + 1
+                        batch_tiers[t] = batch_tiers.get(t, 0) + 1
+                r.stats = {
+                    "batched_requests": len(live),
+                    "batch_rows": len(encs),
+                    "batch_seq": seq,
+                    "batch_wall_s": round(wall, 4),
+                    "scan": dict(scan_counters, label=label),
+                    "autotune_plans": autotune_plans,
+                    "decided_tier": tiers,
+                    "placement": dict(placement) if placement else
+                    {"shard": 0, "n_shards": 1},
+                    "degraded": degraded_note_local is not None,
+                }
             if r.cancelled.is_set():
                 r.finish(CANCELLED)
             elif any(res is None for res in mine):
                 r.finish(FAILED, error="checker returned no verdict")
             else:
                 self._attach_counterexamples(r, mine)
+                _stamp_phases(r, results_at)
                 r.finish(DONE, results=mine)
         return {"requests": len(live), "rows": len(encs),
                 "degraded": degraded_note_local is not None,
@@ -520,16 +566,20 @@ class BatchScheduler:
         from ..checker.counterexample import attach_counterexample
         from ..checker.linearizable import DEFAULT_MAX_CPU_CONFIGS
 
-        for (label, hist), res in zip(r.units, mine):
-            if res.get("valid?") is not INVALID:
-                continue
-            if res.get("op-count", 0) > self.MAX_COUNTEREXAMPLE_OPS:
-                continue
-            try:
-                attach_counterexample(res, hist, r.model,
-                                      max_cpu_configs=
-                                      DEFAULT_MAX_CPU_CONFIGS,
-                                      consistency=r.consistency)
-            except Exception:
-                LOG.warning("counterexample attach failed for %s/%s",
-                            r.id, label, exc_info=True)
+        todo = [(label, hist, res) for (label, hist), res
+                in zip(r.units, mine)
+                if res.get("valid?") is INVALID
+                and res.get("op-count", 0) <= self.MAX_COUNTEREXAMPLE_OPS]
+        if not todo:
+            return
+        # one span a request; it counts the invalid rows explained
+        with span("demux.counterexample", n=len(todo)):
+            for label, hist, res in todo:
+                try:
+                    attach_counterexample(res, hist, r.model,
+                                          max_cpu_configs=
+                                          DEFAULT_MAX_CPU_CONFIGS,
+                                          consistency=r.consistency)
+                except Exception:
+                    LOG.warning("counterexample attach failed for %s/%s",
+                                r.id, label, exc_info=True)
