@@ -595,50 +595,70 @@ def tuned_sort_plan(model, encs: Sequence, n_configs: int,
 # The linearizable-rung pre-kernel certify pass (checker/linearizable
 # `lin_fastpath_pass`) has a measured worst case: a batch whose rows the
 # host certifier cannot decide pays the host scan AND the kernel. The
-# autotuner therefore grows a `lin_fastpath` dimension: per
-# (model family, event shape-bucket) it accumulates hit-rate and
-# marginal-wall samples — persisted in the SAME host-fingerprinted
-# store as the launch plans (a host change invalidates certify-speed
-# observations exactly like chunk timings) — and `lin_fastpath_route`
-# answers whether a bucket should try the host certifier first or go
-# kernel-first. Gating only ever affects ROUTING, never verdicts
-# (undecided rows always reach the kernels), and is part of the
-# measured autotuner: with JGRAFT_AUTOTUNE=0 the fast path always
+# autotuner therefore grows a `lin_fastpath` dimension: per (model
+# family, event shape-bucket, row class of the caller's batch) it
+# accumulates what the certifier cost and how many of its
+# verdicts the caller USED, beside what the kernels cost for the rows
+# they decided — persisted in the SAME host-fingerprinted store as the
+# launch plans (a host change invalidates both walls exactly like chunk
+# timings) — and `lin_fastpath_route` answers whether a bucket should
+# try the host certifier first or go kernel-first: host-first only while
+# a verdict from the certifier is cheaper than the same verdict from the
+# kernels (ISSUE 28). Gating only ever affects ROUTING, never verdicts
+# (undecided and gated rows always reach the kernels), and is part of
+# the measured autotuner: with JGRAFT_AUTOTUNE=0 the fast path always
 # tries (flag-only behavior, no host-state dependence — what the
 # deterministic test environment pins).
 
 #: lin-fastpath record schema version; unknown versions re-observe.
-LINFP_VERSION = 1
+#: 2 (ISSUE 28): `hits` are verdicts the caller used, not rows the scan
+#: certified, and the record carries the kernel side.
+LINFP_VERSION = 2
 
-_LINFP_MEM: dict = {}   # sig -> {"rows", "hits", "certify_wall_s"}
+#: Row class of a caller that rides no launch: the host ladder
+#: (`check_encoded_host`), whose alternative is the host search. No
+#: kernel sample is ever folded into it, so it stays on "tries unless
+#: nothing was ever delivered".
+LINFP_NO_LAUNCH = 0
 
+_LINFP_FIELDS = (("rows", int), ("hits", int), ("certify_wall_s", float),
+                 ("kernel_rows", int), ("kernel_wall_s", float))
 
-def lin_fastpath_min_hit() -> float:
-    """Hit-rate floor below which a measured bucket routes kernel-first
-    (JGRAFT_LIN_FASTPATH_MIN_HIT, default 0.05 — the ~5% worst-case
-    overhead bound the acceptance A/B pins; defensive parse)."""
-    return env_float("JGRAFT_LIN_FASTPATH_MIN_HIT", 0.05, minimum=0.0)
+_LINFP_MEM: dict = {}   # sig -> {field: value for _LINFP_FIELDS}
 
 
 def lin_fastpath_min_obs() -> int:
-    """Rows a bucket must have been observed over before the hit-rate
+    """Rows a side of a bucket must have been observed over before the
     gate may route it kernel-first (JGRAFT_LIN_FASTPATH_MIN_OBS,
     default 64): trying IS measuring, so unknown buckets always try."""
     return env_int("JGRAFT_LIN_FASTPATH_MIN_OBS", 64, minimum=1)
 
 
-def lin_fastpath_sig(family: str, n_events: int) -> tuple:
-    """Gating bucket: model family plus the pow2+midpoint event bucket
-    (the same floor-32 series the launch shapes pad to). Window/state
-    shape is deliberately absent — certify cost scales with E·W but the
-    hit-rate is a property of the WORKLOAD family, and fragmenting the
-    observations per window would starve the gate of samples."""
-    return ("linfp", str(family), bucket_rows(max(int(n_events), 1), 32))
+def lin_fastpath_sig(family: str, n_events: int,
+                     batch_rows: int = 1) -> tuple:
+    """Gating bucket: model family, the pow2+midpoint event bucket (the
+    same floor-32 series the launch shapes pad to), and the row class
+    of the caller's batch — `bucket_rows` of the rows it delivers
+    together: `check_encoded`'s batch (which is also its launch), one
+    request in graftd's lane (`LINFP_NO_LAUNCH` for the host ladder).
+    The row class is there because both sides depend on it: what the
+    lane can deliver whole depends on how many rows a request holds (a
+    one-history request mostly certifies, a 32-history request hardly
+    ever does), and a kernel row costs ~1.5 ms in a 256-row launch and
+    two orders more alone. Window/state shape is deliberately absent —
+    certify cost scales with E·W but fragmenting the observations per
+    window would starve the gate of samples."""
+    rows = int(batch_rows)
+    return ("linfp", str(family), bucket_rows(max(int(n_events), 1), 32),
+            bucket_rows(rows) if rows > 0 else LINFP_NO_LAUNCH)
+
+
+def _linfp_name(sig: tuple) -> str:
+    return f"linfp-{sig[1]}-e{sig[2]}-r{sig[3]}.json"
 
 
 def _linfp_path(sig: tuple) -> Path:
-    return store_root() / host_fingerprint() / \
-        f"linfp-{sig[1]}-e{sig[2]}.json"
+    return store_root() / host_fingerprint() / _linfp_name(sig)
 
 
 def linfp_shared_dir() -> Optional[Path]:
@@ -662,24 +682,25 @@ def _linfp_shared_path(sig: tuple) -> Optional[Path]:
     d = linfp_shared_dir()
     if d is None:
         return None
-    return d / f"linfp-{sig[1]}-e{sig[2]}.json"
+    return d / _linfp_name(sig)
 
 
 def _load_linfp(path: Path, sig: tuple, require_host: bool) -> \
         Optional[dict]:
     """Parse one gate record, or None. Shared records skip the
-    host-fingerprint check: the hit-RATE the gate routes on is a
-    property of the workload family, not the host (the wall figures
-    travel along but are advisory) — while host-local records keep the
-    strict check so a toolchain swap re-observes, exactly like plans."""
+    host-fingerprint check: what matters inside a wavefront is that
+    every rank routes off the SAME snapshot, so every field the rule
+    reads (both row counts, both walls) travels in the record and the
+    publisher's walls stand in for the reader's — routing only, never
+    verdicts. Host-local records keep the strict check so a toolchain
+    swap re-observes, exactly like plans."""
     try:
         raw = json.loads(path.read_text())
         if (raw.get("version") == LINFP_VERSION
                 and raw.get("signature") == list(sig)
                 and (not require_host
                      or raw.get("fingerprint") == host_fingerprint())):
-            return {"rows": int(raw["rows"]), "hits": int(raw["hits"]),
-                    "certify_wall_s": float(raw["certify_wall_s"])}
+            return {k: cast(raw[k]) for k, cast in _LINFP_FIELDS}
         _log.warning("autotune: stale lin-fastpath record %s — "
                      "re-observing", path)
     except FileNotFoundError:
@@ -708,50 +729,89 @@ def _linfp_record(sig: tuple) -> dict:
         if shared is not None:
             fresh = _load_linfp(shared, sig, require_host=False)
     if fresh is None:
-        fresh = {"rows": 0, "hits": 0, "certify_wall_s": 0.0}
+        fresh = {k: cast() for k, cast in _LINFP_FIELDS}
     with _LOCK:
         rec = _LINFP_MEM.setdefault(sig, fresh)
     return rec
 
 
+def _linfp_host_first(rec: dict) -> bool:
+    """The rule, on one record; the caller holds _LOCK."""
+    n = lin_fastpath_min_obs()
+    if rec["rows"] < n:
+        return True   # the certifier's side is still unknown: try
+    if rec["hits"] == 0:
+        return False  # nothing delivered is decisive alone
+    if rec["kernel_rows"] < n:
+        return True   # no kernel cost to hold the certifier's against
+    return (rec["certify_wall_s"] / rec["hits"]
+            < rec["kernel_wall_s"] / rec["kernel_rows"])
+
+
 def lin_fastpath_route(sig: tuple) -> bool:
     """True → run the host certifier first for this bucket; False →
-    the measured hit-rate says kernel-first. Routing only: a gated
-    bucket's rows take the ordinary kernel ladder unchanged."""
+    kernel-first. Host-first while the bucket is still unknown, or while
+    a verdict the caller used costs less from the certifier than a row
+    costs through the kernels at this row class
+    (``certify_wall_s / hits < kernel_wall_s / kernel_rows``); a bucket
+    whose first `lin_fastpath_min_obs` scanned rows delivered nothing
+    closes without waiting for a kernel sample. Routing only: a gated
+    bucket's rows take the ordinary kernel ladder unchanged. A closed
+    bucket is observed no further (neither side), so it stays closed
+    until its record is deleted (doc/running.md)."""
     if not autotune_on():
         return True
     rec = _linfp_record(sig)
     with _LOCK:
-        rows, hits = rec["rows"], rec["hits"]
-    if rows < lin_fastpath_min_obs():
-        return True
-    return hits / rows >= lin_fastpath_min_hit()
+        return _linfp_host_first(rec)
 
 
 def lin_fastpath_observe(sig: tuple, rows: int, hits: int,
                          wall_s: float) -> None:
-    """Fold one batch's certify outcome into the bucket's record and
-    persist it (atomic tmp+rename, best-effort — a read-only store
-    degrades gating to in-memory, never checking)."""
-    if rows <= 0 or not autotune_on():
+    """Fold one certify outcome into the bucket's record and persist
+    it: `rows` scanned in `wall_s`, of which the caller USED `hits`
+    verdicts (0 for a scan whose results were thrown away)."""
+    _linfp_fold(sig, rows=rows, hits=hits, certify_wall_s=wall_s)
+
+
+def lin_fastpath_observe_kernel(sig: tuple, rows: int,
+                                wall_s: float) -> None:
+    """Fold one kernel launch's cost into the bucket's record: `rows`
+    of this bucket decided through the kernel ladder in `wall_s` (their
+    share of the wall of the launch they rode). The caller leaves out a
+    launch that compiled. A bucket already routed kernel-first takes no
+    more samples: its record is settled, and a served launch should
+    not pay a file write for it."""
+    if rows <= 0 or not lin_fastpath_route(sig):
+        return
+    _linfp_fold(sig, kernel_rows=rows, kernel_wall_s=wall_s)
+
+
+def _linfp_fold(sig: tuple, **deltas) -> None:
+    """Add `deltas` to the bucket's record and persist it (atomic
+    tmp+rename, best-effort — a read-only store degrades gating to
+    in-memory, never checking)."""
+    if not autotune_on() or not any(deltas.values()):
         return
     rec = _linfp_record(sig)
     with _LOCK:
-        rec["rows"] += int(rows)
-        rec["hits"] += int(hits)
-        rec["certify_wall_s"] += float(wall_s)
+        for k, v in deltas.items():
+            rec[k] += v
         payload = {
             "version": LINFP_VERSION,
             "fingerprint": host_fingerprint(),
             "fingerprint_info": fingerprint_info(),
             "signature": list(sig),
-            "rows": rec["rows"],
-            "hits": rec["hits"],
-            "certify_wall_s": round(rec["certify_wall_s"], 6),
-            # the marginal-wall sample an operator reads the gate by
-            "certify_wall_per_row_s": round(
-                rec["certify_wall_s"] / max(rec["rows"], 1), 6),
-            "hit_rate": round(rec["hits"] / max(rec["rows"], 1), 4),
+            **{k: round(rec[k], 6) for k, _ in _LINFP_FIELDS},
+            # what an operator reads the gate by: the two costs the
+            # rule compares, and which way it currently falls
+            "certify_s_per_used_verdict": round(
+                rec["certify_wall_s"] / rec["hits"], 6)
+            if rec["hits"] else None,
+            "kernel_s_per_row": round(
+                rec["kernel_wall_s"] / rec["kernel_rows"], 6)
+            if rec["kernel_rows"] else None,
+            "host_first": _linfp_host_first(rec),
             "updated_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ",
                                          time.gmtime()),
         }

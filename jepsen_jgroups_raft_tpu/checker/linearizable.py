@@ -54,12 +54,13 @@ from ..ops.dense_scan import (MASK_DENSE_MAX_SLOTS, MERGE_MAX_EVENTS,
 from ..ops.linear_scan import (DEFAULT_N_CONFIGS, MAX_SLOTS, bucket_slots,
                                make_batch_checker, make_sort_chunk_checker)
 from ..ops.segment_scan import LONG_HISTORY_MIN_EVENTS, check_segmented_batch
-from ..platform import degraded_note, env_int
+from ..platform import degraded_note, env_int, install_compile_counters
 from . import autotune
 from .base import Checker, INVALID, UNKNOWN, VALID
 from .dfs_cpu import SearchBudgetExceeded, check_encoded_dfs
 from .schedule import (ChunkLaunch, build_dense_launches, launch_span,
-                       note_tier, run_chunked, scan_chunk)
+                       note_tier, run_chunked, scan_chunk,
+                       snapshot_compiles)
 from .wgl_cpu import FrontierOverflow, check_encoded_cpu
 
 
@@ -100,7 +101,8 @@ PLATFORM_ROUTE_MIN_CELLS = env_int("JGRAFT_ROUTE_MIN_CELLS", 64_000,
 # (JGRAFT_LIN_FASTPATH=0, the ablation/A-B arm). The worst case (host
 # scan AND kernel) is bounded two ways: a length-scaled abort budget
 # per row, and measured per-bucket gating (checker/autotune.py
-# lin_fastpath_route) that routes low-hit buckets kernel-first.
+# lin_fastpath_route) that routes a bucket kernel-first once a verdict
+# the caller used costs more from the certifier than from the kernels.
 
 #: Algorithms the fast path fronts: the kernel-launching selectors. An
 #: explicit "cpu"/"dfs" keeps its host engine (tests use them as
@@ -131,8 +133,8 @@ def lin_abort_steps() -> int:
 
 
 _FP_LOCK = threading.Lock()
-_FP_ZERO = {"rows_scanned": 0, "rows_certified": 0, "rows_gated": 0,
-            "rows_rung_skipped": 0, "events_scanned": 0,
+_FP_ZERO = {"rows_scanned": 0, "rows_certified": 0, "rows_delivered": 0,
+            "rows_gated": 0, "rows_rung_skipped": 0, "events_scanned": 0,
             "certify_wall_s": 0.0}
 _FP_COUNTERS = dict(_FP_ZERO)
 
@@ -145,11 +147,13 @@ def _fp_bump(**kw) -> None:
 
 def fastpath_counters() -> dict:
     """Process-wide lin-fastpath counters (non-destructive):
-    rows_scanned/rows_certified (hit-rate numerator/denominator),
-    rows_gated (routed kernel-first by the measured gate),
-    rows_rung_skipped (weak-rung re-entries that skipped the redundant
-    second scan — the ISSUE-14 double-scan satellite's evidence), and
-    the summed certify wall."""
+    rows_scanned/rows_certified (what the scan did), rows_delivered
+    (certified rows whose verdict the caller used: delivered over
+    scanned is the hit share the gate routes on), rows_gated (routed
+    kernel-first by the measured gate: gated over gated + scanned is
+    how often it engages), rows_rung_skipped (weak-rung re-entries that
+    skipped the redundant second scan — the ISSUE-14 double-scan
+    satellite's evidence), and the summed certify wall."""
     with _FP_LOCK:
         return dict(_FP_COUNTERS)
 
@@ -164,36 +168,73 @@ def consume_fastpath_counters() -> dict:
         return out
 
 
-def lin_fastpath_pass(encs: Sequence[EncodedHistory], model,
-                      note: bool = True) -> list:
-    """Run the certifier over a linearizable-rung batch; returns one
-    result dict per row, None where undecided (the caller sends those
-    through the kernel ladder). Rows are grouped into the autotuner's
-    gating buckets; gated buckets are skipped wholesale (counted), and
-    every scanned bucket's (rows, hits, wall) feeds the gate's record.
-    Also the graftd fast lane's engine (service/scheduler.py), which
-    passes ``note=False``: its all-or-nothing rule may DISCARD a
-    partially-certified request's results, and a discarded row must
-    not be tier-attributed here only to be attributed again by the
-    kernel that actually decides it — the lane notes tiers itself for
-    the requests it delivers. The `fastpath_counters` bumps stay
-    unconditional: rows_scanned/rows_certified count SCAN outcomes
-    (the gate's hit-rate evidence), not delivered verdicts."""
-    from .certify_batch import certify_many
-
-    results: list = [None] * len(encs)
+def _fp_buckets(encs: Sequence[EncodedHistory], model,
+                batch_rows: int) -> dict:
+    """Row indices of `encs` by the autotuner's gating bucket, for rows
+    of a batch of `batch_rows` rows. 0-event rows are in no bucket:
+    they keep their "trivial" tier."""
     fam = type(model).__name__
     buckets: dict = {}
     for i, e in enumerate(encs):
-        if e.n_events <= 0:
-            continue  # trivial rows keep their "trivial" tier
-        buckets.setdefault(
-            autotune.lin_fastpath_sig(fam, e.n_events), []).append(i)
-    abort = lin_abort_steps()
-    for sig, idxs in buckets.items():
-        if not autotune.lin_fastpath_route(sig):
+        if e.n_events > 0:
+            buckets.setdefault(autotune.lin_fastpath_sig(
+                fam, e.n_events, batch_rows), []).append(i)
+    return buckets
+
+
+def lin_fastpath_plan(encs: Sequence[EncodedHistory], model) -> list:
+    """Group a linearizable-rung batch (what its caller delivers
+    together: `check_encoded`'s batch, one request in graftd's lane)
+    into the autotuner's gating buckets and consult the gate once per
+    bucket: returns ``[(sig, row indices)]`` for the buckets it routes
+    host-first and counts the rows of the others as gated."""
+    plan = []
+    for sig, idxs in _fp_buckets(encs, model, len(encs)).items():
+        if autotune.lin_fastpath_route(sig):
+            plan.append((sig, idxs))
+        else:
             _fp_bump(rows_gated=len(idxs))
-            continue
+    return plan
+
+
+def lin_fastpath_commit(scans: list, used: bool) -> None:
+    """Hand the gate what `lin_fastpath_pass(defer=scans)` held back:
+    each bucket's scanned rows and wall, with its certified rows as
+    hits only if the caller `used` those verdicts."""
+    for sig, rows, certified, wall_s in scans:
+        hits = certified if used else 0
+        autotune.lin_fastpath_observe(sig, rows=rows, hits=hits,
+                                      wall_s=wall_s)
+        _fp_bump(rows_delivered=hits)
+
+
+def lin_fastpath_pass(encs: Sequence[EncodedHistory], model,
+                      plan: Optional[list] = None,
+                      defer: Optional[list] = None) -> list:
+    """Run the certifier over a linearizable-rung batch; returns one
+    result dict per row, None where undecided (the caller sends those
+    through the kernel ladder). Only the buckets of `plan`
+    (`lin_fastpath_plan`, made here when not given) are scanned. What
+    the gate learns from a scan is how many of its verdicts were USED,
+    which only the caller knows: `check_encoded` evicts every certified
+    row from the kernel batch, so without `defer` the pass commits its
+    certified rows as hits itself and notes their tiers. graftd's fast
+    lane (service/scheduler.py) passes a list as ``defer``: its
+    all-or-nothing rule may DISCARD a partially-certified request's
+    results, so the pass appends ``(sig, rows, certified, wall_s)`` per
+    bucket there and the lane commits them (`lin_fastpath_commit`) once
+    it knows, and notes tiers itself for the requests it delivers — a
+    discarded row must not be attributed here only to be attributed
+    again by the kernel that decides it. rows_scanned/rows_certified
+    count SCAN outcomes and stay unconditional."""
+    from .certify_batch import certify_many
+
+    results: list = [None] * len(encs)
+    if plan is None:
+        plan = lin_fastpath_plan(encs, model)
+    scans = [] if defer is None else defer
+    abort = lin_abort_steps()
+    for sig, idxs in plan:
         t0 = time.perf_counter()
         hits = 0
         # whole bucket through the batched certifier core (ISSUE 15;
@@ -223,16 +264,47 @@ def lin_fastpath_pass(encs: Sequence[EncodedHistory], model,
         # certified rows book that share, the undecided rows' verdict
         # cost is the kernel tier's wall.
         per_row = dt / max(len(idxs), 1)
-        if note:
+        if defer is None:
             for i in idxs:
                 if results[i] is not None:
                     note_tier(results[i]["decided-tier"],
                               wall_s=per_row)
-        autotune.lin_fastpath_observe(sig, rows=len(idxs), hits=hits,
-                                      wall_s=dt)
+        scans.append((sig, len(idxs), hits, dt))
         _fp_bump(rows_scanned=len(idxs), rows_certified=hits,
                  events_scanned=sum(encs[i].n_events for i in idxs),
                  certify_wall_s=dt)
+    if defer is None:
+        lin_fastpath_commit(scans, used=True)
+    return results
+
+
+def lin_fastpath_observe_kernel(encs: Sequence[EncodedHistory], model,
+                                batch_rows: int, wall_s_per_row: float
+                                ) -> None:
+    """Give the gate the kernel side of its comparison: `encs`, rows of
+    a batch of `batch_rows` rows (the row class the routing used), were
+    decided through the kernel ladder at `wall_s_per_row` — the wall of
+    the launch they rode over all its rows."""
+    if not autotune.autotune_on():
+        return
+    for sig, idxs in _fp_buckets(encs, model, batch_rows).items():
+        autotune.lin_fastpath_observe_kernel(
+            sig, rows=len(idxs), wall_s=wall_s_per_row * len(idxs))
+
+
+def _observe_kernel_cost(rest: Sequence[EncodedHistory], model,
+                         batch_rows: int, kernel_path) -> list:
+    """Run `kernel_path(rest)`, timed as the gate's kernel sample. A
+    call during which the backend built or loaded a program is not a
+    cost sample (the first launch at a shape is 5-10 s of compile)."""
+    install_compile_counters()
+    built = snapshot_compiles()["programs_built"]
+    t0 = time.perf_counter()
+    results = kernel_path(rest)
+    dt = time.perf_counter() - t0
+    if snapshot_compiles()["programs_built"] == built:
+        lin_fastpath_observe_kernel(rest, model, batch_rows,
+                                    dt / len(rest))
     return results
 
 
@@ -439,7 +511,7 @@ def check_encoded(
             r["consistency"] = consistency
         return results  # type: ignore[return-value]
 
-    def _kernel_path(rest):
+    def _run_kernels(rest):
         if distribute and distributed.wavefront_active() and len(rest) > 1:
             return distributed.run_sharded(
                 rest,
@@ -474,12 +546,25 @@ def check_encoded(
                     and len(encs) > 1)
     gate_shared = autotune.linfp_shared_dir() is not None
     fp = None
-    if (lin_fastpath is not False and encs
-            and (not distributing or gate_shared)
-            and algorithm in LIN_FASTPATH_ALGOS and lin_fastpath_on()):
+    fronted = bool(encs and (not distributing or gate_shared)
+                   and algorithm in LIN_FASTPATH_ALGOS
+                   and lin_fastpath_on())
+    if fronted and lin_fastpath is not False:
         fp = lin_fastpath_pass(encs, model)
         if not any(r is not None for r in fp):
             fp = None
+
+    def _kernel_path(rest):
+        # both paths meet here: whoever consulted the gate gives it the
+        # kernels' cost for the rows it kept, under the row class it
+        # consulted with (under lin_fastpath=False that was graftd's
+        # lane, per request: scheduler.execute feeds its launches)
+        if (fronted and lin_fastpath is not False
+                and autotune.autotune_on()):
+            return _observe_kernel_cost(rest, model, len(encs),
+                                        _run_kernels)
+        return _run_kernels(rest)
+
     if fp is not None:
         todo = [i for i, r in enumerate(fp) if r is None]
         results = fp
@@ -1145,8 +1230,14 @@ def check_encoded_host(enc: EncodedHistory, model, witness: bool = False,
         # gating bucket + abort budget as the device path's pass.
         from .consistency import certify_encoded
 
+        # Its own row class: this row rides no launch and the
+        # alternative is the host search, so a kernel-cost verdict
+        # must not take the certifier away from it. No kernel sample
+        # is ever folded into LINFP_NO_LAUNCH, which leaves the rule at
+        # "tries unless nothing was ever delivered".
         sig = autotune.lin_fastpath_sig(type(model).__name__,
-                                        enc.n_events)
+                                        enc.n_events,
+                                        autotune.LINFP_NO_LAUNCH)
         if autotune.lin_fastpath_route(sig):
             abort = lin_abort_steps()
             t0 = time.perf_counter()
@@ -1158,6 +1249,7 @@ def check_encoded_host(enc: EncodedHistory, model, witness: bool = False,
             autotune.lin_fastpath_observe(sig, rows=1, hits=int(ok),
                                           wall_s=dt)
             _fp_bump(rows_scanned=1, rows_certified=int(ok),
+                     rows_delivered=int(ok),
                      events_scanned=enc.n_events, certify_wall_s=dt)
             if ok:
                 note_tier(tier + "@lin", wall_s=dt)

@@ -95,7 +95,6 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_KERNEL": ROUTING,
     "JGRAFT_LIN_FASTPATH": ROUTING,
     "JGRAFT_LIN_FASTPATH_ABORT": ROUTING,
-    "JGRAFT_LIN_FASTPATH_MIN_HIT": ROUTING,
     "JGRAFT_LIN_FASTPATH_MIN_OBS": ROUTING,
     # shared lin-fastpath gate dir (ISSUE 18): where gate records
     # replicate FROM decides which engine tries first — routing, like

@@ -1067,6 +1067,13 @@ class CheckingService:
         # interrupted
         out["spans"] = snapshot_spans()
         out.update(snapshot_compiles())
+        # the host certifier's counters (process-wide, like the spans):
+        # rows_delivered / rows_scanned is the hit share its gate routes
+        # on, rows_gated / (rows_gated + rows_scanned) how often it
+        # engages
+        from ..checker.linearizable import fastpath_counters
+
+        out["lin_fastpath"] = fastpath_counters()
         return out
 
     # ----------------------------------------------------- accounting

@@ -50,7 +50,8 @@ from ..checker import autotune
 from ..checker.schedule import (annotate, note_span, note_tier, span,
                                 stats_scope)
 from ..history.packing import bucket_rows
-from ..platform import env_int, is_backend_init_failure, note_degraded
+from ..platform import (env_int, install_compile_counters,
+                        is_backend_init_failure, note_degraded)
 from .admission import AdmissionQueue
 from .request import CANCELLED, DONE, FAILED, RUNNING, CheckRequest
 
@@ -258,15 +259,24 @@ class BatchScheduler:
         All-or-nothing per request: a partially-certifiable request
         stays live whole — its rows ride one launch and demux by row
         count, so evicting a subset would tear the fingerprint/trace
-        contract. The abort budget + per-bucket gating inside
-        `lin_fastpath_pass` bound what a hopeless request costs here.
+        contract. What a hopeless request costs here is bounded by the
+        abort budget and by the measured gate (`lin_fastpath_plan`,
+        consulted per request: a request is what the lane delivers, so
+        its row count is the class the gate keeps its costs by), which
+        the lane tells what it DELIVERED (ISSUE 28): a request finished
+        here reports its rows as hits, one that goes live reports them
+        scanned with no hit — and `execute` reports what the launch it
+        then rode cost a row — so a row class whose requests never
+        certify whole stops being scanned.
         Tier attribution is noted HERE, only for delivered requests
-        (``note=False`` in the pass): a discarded partial result's
+        (the pass defers to the lane): a discarded partial result's
         rows are decided — and attributed — by the kernel launch they
         proceed to, never double-counted."""
         from ..checker.linearizable import (LIN_FASTPATH_ALGOS,
+                                            lin_fastpath_commit,
                                             lin_fastpath_on,
-                                            lin_fastpath_pass)
+                                            lin_fastpath_pass,
+                                            lin_fastpath_plan)
 
         if not batch or not self.fastlane_enabled \
                 or not lin_fastpath_on():
@@ -281,28 +291,38 @@ class BatchScheduler:
                     or r.force_host or not r.encs):
                 live.append(r)
                 continue
-            with span("dispatch.scan") as scan:
-                rs = lin_fastpath_pass(r.encs, r.model, note=False)
-                # the pass deliberately leaves 0-event rows undecided
-                # (the kernel path stamps them "trivial"); here they are
-                # host-decidable for free and must not force an
-                # otherwise fully-certified request onto the batch path
-                for j, enc in enumerate(r.encs):
-                    if rs[j] is None and enc.n_events <= 0:
-                        rs[j] = {"valid?": VALID, "algorithm": "trivial",
-                                 "op-count": 0, "decided-tier": "trivial"}
-            # the lane SCANNED this request: execute() may suppress the
-            # redundant in-checker re-scan for it (and only for it)
+            # the lane TRIED this request (scanned it, or was told by
+            # the gate not to): execute() may suppress the in-checker
+            # pass for it (and only for it)
             r._fp_tried = True
-            r.scanned = time.monotonic()
-            if not all(res is not None for res in rs):
-                live.append(r)
-                continue
-            wall = scan.s
+            plan = lin_fastpath_plan(r.encs, r.model)
+            rs: list = [None] * len(r.encs)
+            scans: list = []
+            wall = 0.0
+            if plan:
+                with span("dispatch.scan") as scan:
+                    rs = lin_fastpath_pass(r.encs, r.model, plan=plan,
+                                           defer=scans)
+                wall = scan.s
+                r.scanned = time.monotonic()
+            # the pass deliberately leaves 0-event rows undecided (the
+            # kernel path stamps them "trivial"); here they are
+            # host-decidable for free and must not force an otherwise
+            # fully-certified request onto the batch path
+            for j, enc in enumerate(r.encs):
+                if rs[j] is None and enc.n_events <= 0:
+                    rs[j] = {"valid?": VALID, "algorithm": "trivial",
+                             "op-count": 0, "decided-tier": "trivial"}
             # honor a cancel that landed DURING the scan — the batch
             # path's demux re-checks at the same point (first-wins
             # finish keeps the race harmless either way)
-            if r.cancelled.is_set():
+            whole = all(res is not None for res in rs)
+            deliver = whole and not r.cancelled.is_set()
+            lin_fastpath_commit(scans, used=deliver)
+            if not whole:
+                live.append(r)
+                continue
+            if not deliver:
                 r.finish(CANCELLED)
                 decided.append(r)
                 continue
@@ -413,6 +433,7 @@ class BatchScheduler:
         with self._seq_lock:
             self._seq += 1
             seq = self._seq
+        install_compile_counters()  # the scope below counts this launch's
         encs = [e for r in live for e in r.encs]
         model = live[0].model
         algorithm = live[0].algorithm
@@ -425,11 +446,12 @@ class BatchScheduler:
         if consistency == "linearizable" and self.fastlane_enabled \
                 and live and all(getattr(r, "_fp_tried", False)
                                  for r in live):
-            # ISSUE 14: the dispatch fast lane actually SCANNED every
-            # request in this batch — the in-checker fast path
-            # re-scanning them inside check_encoded would be the
+            # ISSUE 14: the dispatch fast lane TRIED every request in
+            # this batch (scanned it, or consulted the measured gate
+            # and was routed kernel-first) — the in-checker fast path
+            # scanning them again inside check_encoded would be the
             # double-scan the rung-skip satellite closes. Requests the
-            # lane skipped WITHOUT scanning (force_host retries,
+            # lane skipped WITHOUT trying (force_host retries,
             # cancelled-at-pop, non-kernel algorithms) keep the
             # checker/host-ladder fast path: for them nothing was
             # tried yet. Only on the default check path (injected
@@ -501,6 +523,19 @@ class BatchScheduler:
             device_s = scan.get("spans", {}).get("launch.device",
                                                  (0, 0.0))[1]
             note_span("launch.host", max(0.0, wall - device_s))
+        if (check_kw.get("lin_fastpath") is False
+                and degraded_note_local is None
+                and not scan.get("programs_built")):
+            # The lane consulted the gate for every request of this
+            # launch, so it owes the gate the other side: what a row
+            # cost through the kernels, under each request's own row
+            # class. A launch that built or loaded a program is not a
+            # cost sample (the scope counts this launch's own).
+            from ..checker.linearizable import lin_fastpath_observe_kernel
+
+            for r in live:
+                lin_fastpath_observe_kernel(r.encs, model, r.n_rows,
+                                            wall / len(encs))
         results_at = time.monotonic()
         scan_counters = {k: v for k, v in scan.items()
                          if k not in ("label", "tiers", "spans")}

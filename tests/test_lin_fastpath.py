@@ -248,6 +248,167 @@ def test_gating_off_without_autotune(monkeypatch, tmp_path):
     assert not list(tmp_path.glob("**/linfp-*.json"))
 
 
+def _gate_env(monkeypatch, tmp_path, min_obs="64"):
+    monkeypatch.setenv("JGRAFT_LIN_FASTPATH", "1")
+    monkeypatch.setenv("JGRAFT_AUTOTUNE", "1")
+    monkeypatch.setenv("JGRAFT_AUTOTUNE_STORE", str(tmp_path))
+    monkeypatch.setenv("JGRAFT_LIN_FASTPATH_MIN_OBS", min_obs)
+    autotune.reset_for_tests()
+
+
+#: (launch rows, rows scanned, verdicts used, certifier s a scanned row,
+#:  kernel rows, kernel s a row) -> host-first?
+COST_RULE_CASES = {
+    # the campaign cell, had partial eviction counted: 58 ms a used
+    # verdict against 3 ms a kernel row
+    "half-used-29ms-vs-3ms": ((256, 256, 128, 0.029, 256, 0.003), False),
+    # PR 22's 1000 register rows on the chip: 15.5 ms against 1.9 ms —
+    # the old 5 % floor never closed a 0.97 hit rate
+    "nearly-all-used-15ms-vs-1.9ms": ((1000, 1000, 970, 0.015,
+                                       1000, 0.0019), False),
+    # a one-history launch: 8.2 ms against 60 ms stays host-first
+    "row-class-1-8ms-vs-60ms": ((1, 100, 97, 0.008, 100, 0.060), True),
+    # nothing delivered is decisive alone: no kernel sample needed
+    "nothing-used-no-kernel-sample": ((256, 64, 0, 0.029, 0, 0.0),
+                                      False),
+    # either side under min_obs with something used: still unknown
+    "certifier-side-under-min-obs": ((256, 63, 1, 0.029, 256, 0.003),
+                                     True),
+    "kernel-side-under-min-obs": ((256, 256, 128, 0.029, 63, 0.003),
+                                  True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COST_RULE_CASES))
+def test_gate_routes_on_cost_per_used_verdict(case, monkeypatch,
+                                              tmp_path):
+    """ISSUE 28: host-first iff a side is still unknown or a verdict
+    the caller used is cheaper from the certifier than a row is through
+    the kernels at that row class — in memory and from the persisted
+    record alike."""
+    _gate_env(monkeypatch, tmp_path)
+    (launch, rows, used, cert_s, k_rows, k_s), host_first = \
+        COST_RULE_CASES[case]
+    sig = autotune.lin_fastpath_sig("Counter", 2000, launch)
+    autotune.lin_fastpath_observe(sig, rows=rows, hits=used,
+                                  wall_s=rows * cert_s)
+    autotune.lin_fastpath_observe_kernel(sig, rows=k_rows,
+                                         wall_s=k_rows * k_s)
+    assert autotune.lin_fastpath_route(sig) is host_first
+    autotune.reset_for_tests()   # a later process reads the record
+    assert autotune.lin_fastpath_route(sig) is host_first
+    # another row class of the same family and event bucket has its
+    # own record: nothing was learned for it
+    other = autotune.lin_fastpath_sig("Counter", 2000, 4 * launch + 64)
+    assert other != sig and autotune.lin_fastpath_route(other) is True
+
+
+def test_host_ladder_keeps_its_own_row_class(monkeypatch, tmp_path):
+    """`check_encoded_host`'s alternative is the host search: its
+    bucket (`LINFP_NO_LAUNCH`) is never closed by a kernel launch's
+    cost, only by having delivered nothing."""
+    _gate_env(monkeypatch, tmp_path, min_obs="4")
+    m = CasRegister()
+    good = encode_history(random_valid_history(
+        random.Random(1), "register", n_ops=20,
+        crash_p=0.0).client_ops(), m)
+    launch_sig = autotune.lin_fastpath_sig("CasRegister", good.n_events)
+    autotune.lin_fastpath_observe(launch_sig, rows=8, hits=0,
+                                  wall_s=1.0)
+    assert autotune.lin_fastpath_route(launch_sig) is False
+    consume_fastpath_counters()
+    for _ in range(6):
+        r = check_encoded_host(good, m)
+        assert r["decided-tier"] in ("greedy@lin", "backtrack@lin")
+    c = consume_fastpath_counters()
+    assert c["rows_scanned"] == 6 and c["rows_delivered"] == 6
+    assert c["rows_gated"] == 0
+    host_sig = autotune.lin_fastpath_sig("CasRegister", good.n_events,
+                                         autotune.LINFP_NO_LAUNCH)
+    assert host_sig[3] == autotune.LINFP_NO_LAUNCH != launch_sig[3]
+    assert autotune.lin_fastpath_route(host_sig) is True
+
+
+def test_kernel_sample_skipped_when_a_program_was_built(monkeypatch,
+                                                        tmp_path):
+    """A launch that compiled is not a cost sample: the kernel side
+    folds nothing when `programs_built` advanced during the call."""
+    from jepsen_jgroups_raft_tpu.checker import linearizable, schedule
+
+    _gate_env(monkeypatch, tmp_path)
+    m = CasRegister()
+    encs = [encode_history(random_valid_history(
+        random.Random(i), "register", n_ops=10,
+        crash_p=0.0).client_ops(), m) for i in range(4)]
+    sig = autotune.lin_fastpath_sig("CasRegister", encs[0].n_events, 4)
+    assert all(autotune.lin_fastpath_sig(
+        "CasRegister", e.n_events, 4) == sig for e in encs)
+
+    def compiling(rest):
+        schedule.note_compile("init_one", 7.5)
+        return ["r"] * len(rest)
+
+    def warm(rest):
+        return ["r"] * len(rest)
+
+    out = linearizable._observe_kernel_cost(encs, m, 4, compiling)
+    assert out == ["r"] * 4
+    assert autotune._linfp_record(sig)["kernel_rows"] == 0
+    linearizable._observe_kernel_cost(encs, m, 4, warm)
+    rec = autotune._linfp_record(sig)
+    assert rec["kernel_rows"] == 4 and rec["kernel_wall_s"] > 0.0
+    # the certifier's side is untouched by kernel samples
+    assert rec["rows"] == 0 and rec["hits"] == 0
+
+
+def test_check_encoded_feeds_both_sides(monkeypatch, tmp_path):
+    """Through the real entry: the pass commits the rows it evicted as
+    used verdicts, and the launch of the rest lands on the kernel side
+    of the same bucket (once no program is built during it)."""
+    _gate_env(monkeypatch, tmp_path)
+    m = CasRegister()
+    rng = random.Random(21)
+    hists = [random_valid_history(rng, "register", n_ops=12,
+                                  crash_p=0.0) for _ in range(3)]
+    hists.append(poisoned(random_valid_history(rng, "register",
+                                               n_ops=8, crash_p=0.0)))
+    encs = [encode_history(h.client_ops(), m) for h in hists]
+    sigs = {autotune.lin_fastpath_sig("CasRegister", e.n_events, 4)
+            for e in encs}
+    assert len(sigs) == 1
+    [sig] = sigs
+    consume_fastpath_counters()
+    for _ in range(2):   # the first call may compile
+        rs = check_encoded(encs, m, algorithm="jax")
+    assert [r["valid?"] for r in rs] == [VALID] * 3 + [INVALID]
+    c = consume_fastpath_counters()
+    assert c["rows_scanned"] == 8
+    assert c["rows_certified"] == c["rows_delivered"] == 6
+    rec = autotune._linfp_record(sig)
+    assert rec["rows"] == 8 and rec["hits"] == 6
+    assert 1 <= rec["kernel_rows"] <= 2 and rec["kernel_wall_s"] > 0.0
+
+
+def test_version_1_record_reobserves(monkeypatch, tmp_path):
+    """A record written under the old meaning of `hits` (rows the scan
+    certified) must not route: schema 1 reads as no record."""
+    import json
+
+    _gate_env(monkeypatch, tmp_path)
+    sig = autotune.lin_fastpath_sig("Counter", 2000, 256)
+    autotune.lin_fastpath_observe(sig, rows=64, hits=0, wall_s=1.9)
+    assert autotune.lin_fastpath_route(sig) is False
+    path = autotune._linfp_path(sig)
+    raw = json.loads(path.read_text())
+    assert raw["version"] == autotune.LINFP_VERSION == 2
+    assert raw["host_first"] is False
+    raw["version"] = 1
+    path.write_text(json.dumps(raw))
+    autotune.reset_for_tests()
+    assert autotune.lin_fastpath_route(sig) is True
+    assert autotune._linfp_record(sig)["rows"] == 0
+
+
 def test_shared_gate_dir_replicates_across_replicas(monkeypatch,
                                                     tmp_path):
     """ISSUE-18 satellite: two replicas with DISTINCT autotune stores
@@ -606,3 +767,271 @@ class TestServiceFastLane:
             assert st["batches"] >= 1
         finally:
             svc.shutdown(wait=True)
+
+    # ---- ISSUE 28: the lane tells the gate what it DELIVERED
+
+    @staticmethod
+    def _campaign_request(seed, rows=12):
+        """`rows` histories of one event bucket, the last undecidable:
+        the lane can never deliver such a request whole."""
+        from jepsen_jgroups_raft_tpu.service.request import admit
+
+        rng = random.Random(seed)
+        hists = [random_valid_history(rng, "register", n_ops=8,
+                                      crash_p=0.0)
+                 for _ in range(rows - 1)]
+        hists.append(poisoned(random_valid_history(
+            rng, "register", n_ops=4, crash_p=0.0)))
+        req = admit(hists, "register")
+        assert {autotune.lin_fastpath_sig(
+            "CasRegister", e.n_events)[2] for e in req.encs} == {32}
+        return req
+
+    @staticmethod
+    def _spied_scheduler():
+        """A real BatchScheduler on the default check path whose calls
+        into `check_encoded` are recorded (the lane stays enabled: the
+        seam is wrapped after construction)."""
+        from jepsen_jgroups_raft_tpu.service.admission import \
+            AdmissionQueue
+        from jepsen_jgroups_raft_tpu.service.scheduler import \
+            BatchScheduler
+
+        sched = BatchScheduler(AdmissionQueue())
+        real, calls = sched.check_fn, []
+
+        def spy(encs, model, **kw):
+            calls.append((len(encs), dict(kw)))
+            return real(encs, model, **kw)
+
+        sched.check_fn = spy
+        return sched, calls
+
+    def test_campaign_shape_closes_its_row_class(self, monkeypatch,
+                                                 tmp_path):
+        """Requests of several rows, each holding one undecidable row:
+        after `min_obs` scanned rows that delivered nothing the lane
+        stops scanning that row class — `rows_gated` fires,
+        `rows_scanned` and the `dispatch.scan` span stop growing,
+        nothing was delivered, `execute` still suppresses the
+        in-checker pass, and the verdicts are those of a
+        JGRAFT_LIN_FASTPATH=0 run row for row."""
+        from jepsen_jgroups_raft_tpu.checker.schedule import \
+            snapshot_spans
+
+        _gate_env(monkeypatch, tmp_path, min_obs="8")
+        sched, calls = self._spied_scheduler()
+        consume_fastpath_counters()
+        verdicts = []
+
+        def scan_span():
+            return dict(snapshot_spans().get("dispatch.scan",
+                                             {"n": 0, "s": 0.0}))
+
+        def one_batch(seed0):
+            batch = [self._campaign_request(seed0 + k)
+                     for k in range(2)]       # two requests of class 12
+            decided, live = sched.fastlane(batch)
+            assert not decided and live == batch
+            assert all(r._fp_tried for r in batch)
+            sched.execute(live)
+            for r in batch:
+                assert not r.stats.get("fastlane")
+                verdicts.extend(res["valid?"] for res in r.results)
+            return batch
+
+        span0 = scan_span()
+        first = one_batch(100)
+        c1 = fastpath_counters()
+        # one request of twelve rows reaches min_obs; the next rides
+        # the gate
+        assert c1["rows_scanned"] == 12 and c1["rows_gated"] == 12
+        assert c1["rows_certified"] == 11 and c1["rows_delivered"] == 0
+        assert [bool(r.scanned) for r in first] == [True, False]
+        span1 = scan_span()
+        assert span1["n"] == span0["n"] + 1
+        one_batch(200)
+        c2 = fastpath_counters()
+        assert c2["rows_scanned"] == 12 and c2["rows_gated"] == 36
+        assert c2["rows_delivered"] == 0
+        assert scan_span() == span1          # no span, not a short one
+        assert calls == [(24, {"algorithm": "auto",
+                               "lin_fastpath": False})] * 2
+        sig = autotune.lin_fastpath_sig("CasRegister", 20, 12)
+        assert autotune.lin_fastpath_route(sig) is False
+        # the same requests with the certifier forced off
+        monkeypatch.setenv("JGRAFT_LIN_FASTPATH", "0")
+        off = []
+        for seed0 in (100, 200):
+            batch = [self._campaign_request(seed0 + k)
+                     for k in range(2)]
+            sched.execute(batch)
+            for r in batch:
+                off.extend(res["valid?"] for res in r.results)
+        assert verdicts == off
+        assert verdicts == ([True] * 11 + [False]) * 4
+
+    def test_ci_shape_keeps_the_lane_after_campaign_closed(
+            self, monkeypatch, tmp_path):
+        """The bypass: one-history certifiable requests, interleaved
+        with campaign-shaped ones in the same family and event bucket,
+        are still delivered from the lane after the large row class has
+        closed — the gate keeps its costs by the request's row class."""
+        from jepsen_jgroups_raft_tpu.service.request import (CANCELLED,
+                                                             admit)
+
+        _gate_env(monkeypatch, tmp_path, min_obs="8")
+        sched, calls = self._spied_scheduler()
+        consume_fastpath_counters()
+        ci_sig = autotune.lin_fastpath_sig("CasRegister", 20, 1)
+        big_sig = autotune.lin_fastpath_sig("CasRegister", 20, 12)
+        assert ci_sig[:3] == big_sig[:3] and ci_sig != big_sig
+        for k in range(6):
+            big = self._campaign_request(300 + k)
+            ci = admit([random_valid_history(
+                random.Random(900 + k), "register", n_ops=8,
+                crash_p=0.0)], "register")
+            # popped together, as one bucket's requests are
+            decided, live = sched.fastlane([big, ci])
+            assert decided == [ci] and live == [big]
+            big.finish(CANCELLED)   # not executed: only routing matters
+            assert ci.stats["fastlane"] is True
+            assert ci.verdict() is True
+        assert autotune.lin_fastpath_route(big_sig) is False
+        assert autotune.lin_fastpath_route(ci_sig) is True
+        c = fastpath_counters()
+        assert c["rows_delivered"] == 6
+        assert c["rows_scanned"] == 12 + 6
+        assert c["rows_gated"] == 5 * 12
+        assert not calls
+
+    def test_execute_reports_the_launch_under_each_requests_class(
+            self, monkeypatch, tmp_path):
+        """The lane consulted the gate per request, so `execute` owes
+        it the kernel side under the same key: a request that went live
+        books the rows of the launch it rode at that launch's wall a
+        row (never a launch that built a program)."""
+        from jepsen_jgroups_raft_tpu.service.request import admit
+
+        _gate_env(monkeypatch, tmp_path)
+        sched, calls = self._spied_scheduler()
+        sig = autotune.lin_fastpath_sig("CasRegister", 20, 2)
+        for k in range(3):
+            rng = random.Random(700 + k)
+            req = admit([random_valid_history(rng, "register", n_ops=8,
+                                              crash_p=0.0),
+                         poisoned(random_valid_history(
+                             rng, "register", n_ops=4, crash_p=0.0))],
+                        "register")
+            decided, live = sched.fastlane([req])
+            assert live == [req] and not decided
+            sched.execute(live)
+            assert [r["valid?"] for r in req.results] == [True, False]
+        rec = autotune._linfp_record(sig)
+        assert rec["rows"] == 6 and rec["hits"] == 0
+        # the first launch may have compiled; the later ones are samples
+        assert rec["kernel_rows"] in (4, 6) and rec["kernel_wall_s"] > 0
+        assert all(kw["lin_fastpath"] is False for _, kw in calls)
+
+    def test_stats_serve_the_fastpath_counters(self, monkeypatch):
+        monkeypatch.setenv("JGRAFT_LIN_FASTPATH", "1")
+        svc = self._service()
+        try:
+            consume_fastpath_counters()
+            h = random_valid_history(random.Random(3), "register",
+                                     n_ops=24, crash_p=0.0)
+            req = svc.submit([h], workload="register")
+            assert req.wait(30) and req.stats.get("fastlane") is True
+            fp = svc.stats()["lin_fastpath"]
+            assert fp["rows_scanned"] == fp["rows_delivered"] == 1
+            assert fp["rows_gated"] == 0
+        finally:
+            svc.shutdown(wait=True)
+
+
+# ------------------- verdict identity on the benchmark's traffic shape
+
+
+def _campaign_traffic(seed, n=16, n_ops=60):
+    """`counter-1k.campaign` cut to what the CPU tier affords: counter
+    histories from five processes (value range 3, crash probability
+    0.05, at most three crashes), 10 % perturbed, and one planted
+    acknowledged read of a value nobody wrote."""
+    rng = random.Random(seed)
+    hists = [random_valid_history(rng, "counter", n_ops=n_ops, n_procs=5,
+                                  value_range=3, crash_p=0.05,
+                                  max_crashes=3) for _ in range(n)]
+    for i in rng.sample(range(n), max(1, round(0.1 * n))):
+        hists[i] = corrupt(rng, hists[i])
+    k = rng.randrange(n)
+    ops = list(hists[k])
+    t = max(op.time for op in ops) + 1
+    ops += [Op(process=10_000, type="invoke", f="read", value=None,
+               time=t),
+            Op(process=10_000, type="ok", f="read", value=-5,
+               time=t + 1)]
+    hists[k] = History(ops)
+    return hists, k
+
+
+def test_campaign_traffic_verdicts_identical_open_closed_off(
+        monkeypatch, tmp_path):
+    """Routing only: the benchmark's traffic through the served path
+    (lane, launch, demux with counterexamples) and through
+    `check_encoded` gives the same `valid?` and the same counterexample
+    for every row with the gate open (everything scanned), with the
+    gate closed (everything kernel-first) and with the certifier forced
+    off."""
+    from jepsen_jgroups_raft_tpu.service.admission import AdmissionQueue
+    from jepsen_jgroups_raft_tpu.service.request import admit
+    from jepsen_jgroups_raft_tpu.service.scheduler import BatchScheduler
+
+    hists, planted = _campaign_traffic(2803)
+    m = Counter()
+    encs = [encode_history(h.client_ops(), m) for h in hists]
+
+    def served():
+        reqs = [admit(hists[i:i + 4], "counter")
+                for i in range(0, len(hists), 4)]
+        sched = BatchScheduler(AdmissionQueue())
+        decided, live = sched.fastlane(reqs)
+        sched.execute(live)
+        return [(res["valid?"], res.get("failing-op-index"),
+                 res.get("counterexample"))
+                for r in reqs for res in r.results]
+
+    def library():
+        return [(r["valid?"], r.get("failing-op-index"))
+                for r in check_encoded(encs, m, algorithm="jax")]
+
+    arms = {}
+    _gate_env(monkeypatch, tmp_path / "open")
+    consume_fastpath_counters()
+    arms["open"] = (served(), library())
+    c = consume_fastpath_counters()
+    assert c["rows_gated"] == 0 and c["rows_certified"] > 0
+    assert c["rows_scanned"] >= len(hists)
+
+    _gate_env(monkeypatch, tmp_path / "closed", min_obs="1")
+    for e in encs:   # every bucket of either surface has delivered 0
+        for batch_rows in (4, len(encs)):   # a request; the library call
+            autotune.lin_fastpath_observe(
+                autotune.lin_fastpath_sig("Counter", e.n_events,
+                                          batch_rows),
+                rows=1, hits=0, wall_s=0.03)
+    arms["closed"] = (served(), library())
+    c = consume_fastpath_counters()
+    assert c["rows_scanned"] == 0
+    assert c["rows_gated"] == 2 * len(hists)
+
+    monkeypatch.setenv("JGRAFT_LIN_FASTPATH", "0")
+    arms["off"] = (served(), library())
+    assert not any(consume_fastpath_counters().values())
+
+    assert arms["open"] == arms["off"]
+    assert arms["closed"] == arms["off"]
+    served_off, library_off = arms["off"]
+    assert [v for v, _, _ in served_off] == [v for v, _ in library_off]
+    assert served_off[planted][0] is INVALID
+    assert served_off[planted][2], "no counterexample on the planted row"
+    assert sum(v is VALID for v, _, _ in served_off) >= len(hists) // 2
