@@ -5,6 +5,8 @@ fsync."""
 
 import statistics
 
+EXAMPLE = {"acks_ms": [5.0, 7.0, 100.0], "want": 7.0}
+
 
 def read(ctx):
     return statistics.median(ctx["acks_ms"]) if ctx["acks_ms"] else None

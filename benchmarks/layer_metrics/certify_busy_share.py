@@ -3,6 +3,12 @@
 
 from benchmarks.layer_metrics import delta
 
+EXAMPLE = {"fastpath_before": {"certify_wall_s": 1.0},
+           "fastpath_after": {"certify_wall_s": 21.0},
+           "want": 50.0}
+#: a window in which the certifier never ran reads 0 (ledger, PR 28)
+ZERO_IS_A_READING = True
+
 
 def read(ctx):
     if "certify_wall_s" not in ctx["after"]["fastpath"]:

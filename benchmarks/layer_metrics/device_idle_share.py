@@ -1,5 +1,9 @@
 """Share of the traced span in which no operation ran on the device."""
 
+EXAMPLE = {"trace": {"busy_s": 0.5, "window_s": 8.0, "kernel_rows": 250,
+                     "device_ops": [["while.17", 0.4]]},
+           "want": 93.75}
+
 
 def read(ctx):
     trace = ctx["trace"]

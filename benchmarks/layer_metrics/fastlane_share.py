@@ -3,6 +3,10 @@ answered: certified whole on the dispatcher thread, never in a batch."""
 
 from benchmarks.layer_metrics import delta
 
+EXAMPLE = {"stats_before": {"completed": 8, "fastpath_requests": 1},
+           "stats_after": {"completed": 48, "fastpath_requests": 11},
+           "want": 25.0}
+
 
 def read(ctx):
     n = delta(ctx, "stats", "completed")

@@ -1,63 +1,74 @@
-"""Each per-layer reader on a small hand-made context: the number where
-the run holds something to read, nothing where it does not."""
+"""Each per-layer reader on the example it brings in its own file: the
+number where the run holds something to read, nothing where it does
+not. The test owns no table: a reader added as a new file plus a
+`per_layer` entry is held to the same rules by arriving.
+
+`EXAMPLE` (a module constant beside `read`) is a window by its two ends
+(`util_bench.example_ctx` says which keys it may hold) and `want`, the
+number the reader gives on it. A reader whose healthy reading is 0 (a
+count or a time of faults) says `ZERO_IS_A_READING = True` in its file.
+"""
 
 import pytest
 
 from benchmarks import manifest as mf
-from util_bench import ROOT
+from util_bench import (ROOT, bare_ctx, example_ctx, reader_with_example,
+                        reads_the_program, still_ctx, zero_is_a_reading)
 
 MANIFEST = mf.load_manifest(ROOT)
 
 
-def ctx(**kw):
-    before = {"stats": {"submitted": 10, "completed": 8, "batches": 2,
-                        "batch_rows": 100, "fastpath_requests": 1,
-                        "journal_group_commits": 20},
-              "fastpath": {"certify_wall_s": 1.0},
-              "tiers": {"backtrack@lin": {"rows": 10, "wall_s": 0.0},
-                        "mask": {"rows": 90, "wall_s": 0.0}}}
-    after = {"stats": {"submitted": 50, "completed": 48, "batches": 6,
-                       "batch_rows": 1100, "fastpath_requests": 11,
-                       "journal_group_commits": 80},
-             "fastpath": {"certify_wall_s": 21.0},
-             "tiers": {"backtrack@lin": {"rows": 110, "wall_s": 0.0},
-                       "mask": {"rows": 990, "wall_s": 0.0}}}
-    base = {"window_s": 40.0, "before": before, "after": after,
-            "requests": [], "acks_ms": [5.0, 7.0, 100.0],
-            "compiles_in_window": 0,
-            "trace": {"busy_s": 0.5, "window_s": 8.0, "kernel_rows": 250,
-                      "device_ops": [["while.17", 0.4]]}}
-    base.update(kw)
-    return base
+def names(*sources):
+    return [m["name"] for m in MANIFEST["per_layer"]
+            if not sources or m["source"] in sources]
 
 
-WANT = {"ack_p50_ms": 7.0, "journal_fsyncs_per_req": 1.5,
-        "fastlane_share": 25.0, "batch_rows_mean": 250.0,
-        "certify_busy_share": 50.0, "host_certified_share": 10.0,
-        "kernel_ms_per_row": 2.0, "device_idle_share": 93.75,
-        "compiles_in_window": 0}
-
-
-@pytest.mark.parametrize("name", [m["name"] for m in MANIFEST["per_layer"]])
+@pytest.mark.parametrize("name", names())
 def test_reader_gives_the_known_number(name):
-    reader = mf.load_module(ROOT, "layer_metrics", name)
-    assert reader.read(ctx()) == pytest.approx(WANT[name])
+    reader = reader_with_example(name)
+    want = reader.EXAMPLE["want"]
+    assert want is not None
+    assert reader.read(example_ctx(reader.EXAMPLE)) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("name", ["kernel_ms_per_row", "device_idle_share"])
+@pytest.mark.parametrize("name", names("device_trace"))
 def test_trace_readers_give_nothing_without_device_time(name):
-    reader = mf.load_module(ROOT, "layer_metrics", name)
-    assert reader.read(ctx(trace=None)) is None
+    reader = reader_with_example(name)
+    ctx = example_ctx(reader.EXAMPLE)
+    assert reader.read(dict(ctx, trace=None)) is None
     idle = {"busy_s": 0.0, "window_s": 8.0, "kernel_rows": 0,
             "device_ops": []}
-    assert reader.read(ctx(trace=idle)) is None
+    assert reader.read(dict(ctx, trace=idle)) is None
 
 
-@pytest.mark.parametrize("name", ["journal_fsyncs_per_req", "fastlane_share",
-                                  "batch_rows_mean", "host_certified_share",
-                                  "ack_p50_ms"])
-def test_counter_readers_give_nothing_when_nothing_moved(name):
-    reader = mf.load_module(ROOT, "layer_metrics", name)
-    still = ctx(acks_ms=[])
-    still["after"] = still["before"]
-    assert reader.read(still) is None
+@pytest.mark.parametrize("name", names("program_span", "program_counter",
+                                       "host_clock"))
+def test_reader_gives_nothing_when_nothing_moved(name):
+    """A share, a mean or a time for each of something that did not
+    happen is nothing, never 0; a count or a time of faults is 0 on a
+    healthy window, and its reader's file says so."""
+    reader = reader_with_example(name)
+    got = reader.read(still_ctx(reader.EXAMPLE))
+    if zero_is_a_reading(reader):
+        assert got is not None and got == 0.0
+    else:
+        assert got is None, (
+            f"{name} reads {got!r} on a window in which nothing moved: "
+            "return None there, or, where 0 is the healthy reading of a "
+            "count or a time of faults, say ZERO_IS_A_READING = True in "
+            f"benchmarks/layer_metrics/{name}.py")
+
+
+@pytest.mark.parametrize("name", names("program_span", "program_counter"))
+def test_reader_gives_nothing_from_a_program_that_serves_nothing(name):
+    """A parent commit's `/stats` may have neither `spans` nor the
+    counters: the reader returns None and does not raise, so the result
+    line leaves the metric out. A reader whose example holds nothing of
+    the program reads what the harness counts itself, and its answer
+    does not depend on the program."""
+    reader = reader_with_example(name)
+    got = reader.read(bare_ctx(reader.EXAMPLE))
+    if reads_the_program(reader.EXAMPLE):
+        assert got is None
+    else:
+        assert got == pytest.approx(reader.EXAMPLE["want"])

@@ -68,10 +68,18 @@ def test_cell_resolves_to_files(cell):
                        ("loops", traffic["loop"]),
                        ("wires", traffic["wire"])):
         mf.load_module(ROOT, kind, name)
-    assert config["reduced"] == []
+    listed = [c for c in MANIFEST["configs"]
+              if c["name"] == entry["config"]][0]
+    # the cuts of scale the file states are those the manifest lists
+    assert config["reduced"] == listed["reduced"]
+    assert all(isinstance(k, str) and k for k in config["reduced"])
     assert set(config["guarantees"]) == {"verdict", "acknowledgement",
                                          "cache", "platform"}
-    assert mf.metrics_of(MANIFEST, "per_layer", cell["name"])
+    assert mf.metrics_of(MANIFEST, "per_layer", cell["name"]), (
+        f"no per_layer metric lists {cell['name']!r}: add one (a reader "
+        "under benchmarks/layer_metrics/ and its entry) whose "
+        "'workloads' names the cell; lengthening the list of a metric "
+        "that is there is a `benchmark` PR's to do")
     e2e = {m["name"] for m in mf.metrics_of(MANIFEST, "end_to_end",
                                            cell["name"])}
     assert "setup_s" in e2e and len(e2e) >= 2

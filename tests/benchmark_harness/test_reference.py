@@ -7,6 +7,7 @@ broken) has to disagree with the reference on every seed, or the
 comparison that decides `correct` could not tell a weakened checker
 from a sound one."""
 
+import json
 import random
 
 import pytest
@@ -21,7 +22,27 @@ CONFIG = {"history_kind": None, "ops_per_history": 120, "processes": 5,
           "value_range": 3, "crash_probability": 0.05, "max_crashes": 3}
 TRAFFIC = {"histories_per_request": 4, "perturbed_share": 0.25,
            "planted_every": 3}
-KINDS = {"counter": ("counter", "Counter")}
+MANIFEST = mf.load_manifest(ROOT)
+
+
+def kinds() -> dict:
+    """What the configurations of the manifest name, by the kind of
+    history each sends: its plain reference, its control, and the
+    program's workload that graftd checks it as. A configuration added
+    as a file has its reference held to the program's checker by
+    arriving."""
+    out = {}
+    for entry in MANIFEST["configs"]:
+        with open(ROOT / entry["file"]) as fh:
+            cfg = json.load(fh)
+        named = (cfg["reference"], cfg["control"], cfg["service_workload"])
+        assert out.setdefault(cfg["history_kind"], named) == named, (
+            f"{entry['name']}: another configuration of kind "
+            f"{cfg['history_kind']!r} names {out[cfg['history_kind']]}")
+    return out
+
+
+KINDS = kinds()
 
 
 def requests(kind, seed, n=12):
@@ -32,17 +53,17 @@ def requests(kind, seed, n=12):
 @pytest.mark.parametrize("kind", sorted(KINDS))
 @pytest.mark.parametrize("seed", [3, 2**31 + 7])
 def test_reference_agrees_with_the_programs_checker(kind, seed):
-    from jepsen_jgroups_raft_tpu import models
     from jepsen_jgroups_raft_tpu.checker.linearizable import check_histories
     from jepsen_jgroups_raft_tpu.history.synth import build_history
+    from jepsen_jgroups_raft_tpu.service.request import service_workloads
 
-    ref_name, model_name = KINDS[kind]
+    ref_name, _, workload = KINDS[kind]
+    model = service_workloads()[workload][0]
     ref = mf.load_module(ROOT, "references", ref_name)
     hs = [h for req in requests(kind, seed) for h in req]
     want = [frontier.linearizable(h, ref) for h in hs]
     got = [r["valid?"] for r in check_histories(
-        [build_history(h) for h in hs], getattr(models, model_name)(),
-        algorithm="auto")]
+        [build_history(h) for h in hs], model(), algorithm="auto")]
     assert got == want
     assert True in want and False in want
 
@@ -51,7 +72,7 @@ def test_reference_agrees_with_the_programs_checker(kind, seed):
 @pytest.mark.parametrize("seed", [11, 12, 2**31 + 13])
 def test_control_disagrees_with_the_reference(kind, seed):
     ref = mf.load_module(ROOT, "references", KINDS[kind][0])
-    control = mf.load_module(ROOT, "references", "crashed_ops_dropped")
+    control = mf.load_module(ROOT, "references", KINDS[kind][1])
     hs = [h for req in requests(kind, seed, n=15) for h in req]
     differ = sum(control.linearizable(h, ref)
                  is not frontier.linearizable(h, ref) for h in hs)
