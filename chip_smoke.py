@@ -560,7 +560,8 @@ def phase_mesh(sz, rng, model):
     carry = launch.init_fn(put(launch.val_of),
                            put(launch.n_events.astype(np.int32)))
     width = min(32, launch.events.shape[1])
-    out = launch.step_fn(carry, put(launch.events[:, :width]))
+    out = launch.step_fn(carry, put(launch.events[:, :width]),
+                         np.int32(0), np.int32(width))
     jax.block_until_ready(out)
 
     def homes(tree):

@@ -214,7 +214,39 @@ _MISS = object()          # negative-cache sentinel (see plan_for)
 _MEM: dict = {}           # sig -> TunedPlan | _MISS (this process)
 _APPLIED: List[dict] = []  # bounded log of applied plans (service stamps)
 _APPLIED_SEQ = 0           # monotone id of the last applied entry
-_COUNTERS = {"plans_loaded": 0, "plans_measured": 0, "plan_misses": 0}
+_COUNTERS = {"plans_loaded": 0, "plans_measured": 0, "plan_misses": 0,
+             "samples_run": 0}
+
+
+def preload_plans() -> int:
+    """Read every launch plan the store holds for this host into memory
+    (graftd calls it when it starts: its launches then take plans from
+    memory alone, `plan_for(disk=False)`, so that the launch shapes of
+    a served process are fixed when it starts); returns how many.
+    Unreadable, stale and foreign files are skipped exactly as
+    `plan_for` skips them."""
+    if not autotune_on():
+        return 0
+    n = 0
+    try:
+        paths = sorted((store_root() / host_fingerprint()).glob("*.json"))
+    except OSError:
+        return 0
+    for path in paths:
+        try:
+            raw = json.loads(path.read_text())
+            if not isinstance(raw, dict) or "plan" not in raw:
+                continue   # a gate or arm record, not a launch plan
+            sig = tuple(raw["signature"])
+            if _plan_path(sig) != path:
+                continue
+        except (OSError, ValueError, KeyError, TypeError):
+            continue
+        with _LOCK:
+            known = sig in _MEM
+        if not known and _load_plan(sig) is not None:
+            n += 1
+    return n
 
 
 def snapshot_counters() -> dict:
@@ -288,7 +320,7 @@ def _plan_path(sig: tuple) -> Path:
     return store_root() / host_fingerprint() / _sig_name(sig)
 
 
-def plan_for(sig: tuple) -> Optional[TunedPlan]:
+def plan_for(sig: tuple, disk: bool = True) -> Optional[TunedPlan]:
     """Look a bucket's plan up: in-memory first, then the fingerprint
     directory on disk. Corrupt files, schema drift, and fingerprint
     mismatch (an operator copying plan files across hosts) all return
@@ -301,7 +333,8 @@ def plan_for(sig: tuple) -> Optional[TunedPlan]:
     measures; a plan persisted by a DIFFERENT process mid-flight is
     picked up on the next process start (acceptable — cross-process
     plan sharing is a restart-time optimization, not a liveness
-    contract)."""
+    contract). `disk=False` (a service's launches) stops at memory:
+    what `preload_plans` read when the process started."""
     with _LOCK:
         plan = _MEM.get(sig)
     if plan is _MISS:
@@ -310,6 +343,18 @@ def plan_for(sig: tuple) -> Optional[TunedPlan]:
         _bump("plans_loaded")
         _record_applied(sig, plan, "memory")
         return plan
+    if not disk:
+        return _miss(sig)
+    plan = _load_plan(sig)
+    if plan is not None:
+        _bump("plans_loaded")
+        _record_applied(sig, plan, "disk")
+    return plan
+
+
+def _load_plan(sig: tuple) -> Optional[TunedPlan]:
+    """Disk half of `plan_for`: the plan into memory, or a negative
+    entry."""
     path = _plan_path(sig)
     try:
         raw = json.loads(path.read_text())
@@ -335,8 +380,6 @@ def plan_for(sig: tuple) -> Optional[TunedPlan]:
         return _miss(sig)
     with _LOCK:
         _MEM[sig] = plan
-    _bump("plans_loaded")
-    _record_applied(sig, plan, "disk")
     return plan
 
 
@@ -407,18 +450,21 @@ def resolve_plan(sig: tuple, candidates: Sequence[TunedPlan],
     return best
 
 
-def pack_group(encs: Sequence, tuned: Optional[TunedPlan]) -> dict:
+def pack_group(encs: Sequence, tuned: Optional[TunedPlan],
+               window: Optional[int] = None) -> dict:
     """Pack one group's encodings under a plan's macro payload cap —
     or under today's defaults when no plan applies (tuned None). The
     JGRAFT_MACRO_EVENTS=0 ablation is absolute: a persisted macro plan
-    must never re-enable the macro stream under it."""
+    must never re-enable the macro stream under it. `window`, the
+    group's kernel window, fixes the payload width by the kernel key
+    instead of by the rows (`pack_macro_batch`)."""
     if not macro_events_on():
         return pack_batch(encs)
     if tuned is None:
-        return pack_macro_batch(encs)
+        return pack_macro_batch(encs, window=window)
     if tuned.macro_p <= 0:
         return pack_batch(encs)
-    return pack_macro_batch(encs, cap=tuned.macro_p)
+    return pack_macro_batch(encs, cap=tuned.macro_p, window=window)
 
 
 def _chunk_candidates(default_chunk: int, e_sched: int) -> List[int]:
@@ -461,13 +507,17 @@ def _macro_candidates() -> List[int]:
     return [MACRO_MAX_OPENS, 4]
 
 
-def tuned_group_plan(model, plan, encs: Sequence) -> Optional[TunedPlan]:
+def tuned_group_plan(model, plan, encs: Sequence,
+                     measure: bool = True) -> Optional[TunedPlan]:
     """Consult (and, for large-enough groups, measure) the plan for one
     dense window group. `plan` is the group's ops.dense_scan.DensePlan;
     `encs` the group's encodings in plan row order. Returns None —
     today's exact behavior — when autotuning is off, the group is LONG
     (the merged-cluster policies are separately measured), or the group
-    is below the work gates with no persisted plan."""
+    is below the work gates with no persisted plan. `measure=False`
+    (a service's launch, ISSUE 32): the plan is what memory holds
+    (`preload_plans`) or None; the store is not read and no candidate
+    is compiled or timed."""
     if not autotune_on() or not encs:
         return None
     from ..ops.dense_scan import MERGE_MAX_EVENTS
@@ -477,21 +527,22 @@ def tuned_group_plan(model, plan, encs: Sequence) -> Optional[TunedPlan]:
         return None
     sig = bucket_signature(plan.kernel_tag, plan.n_slots, plan.n_states,
                            len(encs), e_max)
-    found = plan_for(sig)
+    found = plan_for(sig, disk=measure)
     if found is not None:
         return found
-    if len(encs) < min_rows() or len(encs) * e_max < min_cells():
+    if not measure or len(encs) < min_rows() \
+            or len(encs) * e_max < min_cells():
         return None
     k = min(len(encs), sample_rows_cap())
     sample = list(encs[:k])
     val_of = np.asarray(plan.val_of[:k])
     e_sched = bucket_rows(e_max, 32)
 
-    def measure(cand: TunedPlan) -> float:
+    def sample_wall(cand: TunedPlan) -> float:
         return _run_dense_sample(model, plan, sample, val_of, cand)
 
     candidates = _coordinate_candidates(plan.kernel_tag, e_sched)
-    return resolve_plan(sig, candidates, measure)
+    return resolve_plan(sig, candidates, sample_wall)
 
 
 def _coordinate_candidates(family: str, e_sched: int) -> List[TunedPlan]:
@@ -526,7 +577,8 @@ def _run_dense_sample(model, plan, sample: Sequence, val_of: np.ndarray,
     from ..parallel.mesh import chunk_sharding
     from .schedule import ChunkLaunch, run_chunked
 
-    batch = pack_group(sample, cand)
+    _bump("samples_run")
+    batch = pack_group(sample, cand, window=plan.n_slots)
     e_len = batch["events"].shape[1]
     e_sched = bucket_rows(e_len, 32)
     sharding = chunk_sharding(cand.mesh_fanout)
@@ -546,7 +598,8 @@ def _run_dense_sample(model, plan, sample: Sequence, val_of: np.ndarray,
 
 
 def tuned_sort_plan(model, encs: Sequence, n_configs: int,
-                    n_slots: int) -> Optional[TunedPlan]:
+                    n_slots: int, measure: bool = True
+                    ) -> Optional[TunedPlan]:
     """Sort-ladder twin of `tuned_group_plan` for one capacity rung;
     the rung's frontier capacity rides the signature's state slot (it
     picks the compiled kernel exactly like S does for the dense
@@ -563,15 +616,16 @@ def tuned_sort_plan(model, encs: Sequence, n_configs: int,
         return None
     e_max = max(e.n_events for e in encs)
     sig = bucket_signature("sort", n_slots, n_configs, len(encs), e_max)
-    found = plan_for(sig)
+    found = plan_for(sig, disk=measure)
     if found is not None:
         return found
-    if len(encs) < min_rows() or len(encs) * e_max < min_cells():
+    if not measure or len(encs) < min_rows() \
+            or len(encs) * e_max < min_cells():
         return None
     sample = list(encs[:min(len(encs), sample_rows_cap())])
     e_sched = bucket_rows(e_max, 32)
 
-    def measure(cand: TunedPlan) -> float:
+    def sample_wall(cand: TunedPlan) -> float:
         return _run_sort_sample(model, n_configs, n_slots, sample, cand)
 
     base = TunedPlan(**{**asdict(default_plan("sort")), "mesh_fanout": 1})
@@ -588,7 +642,7 @@ def tuned_sort_plan(model, encs: Sequence, n_configs: int,
         c = TunedPlan(**{**asdict(base), "macro_p": p})
         if c not in candidates:
             candidates.append(c)
-    return resolve_plan(sig, candidates, measure)
+    return resolve_plan(sig, candidates, sample_wall)
 
 
 # ------------------------------------- lin fast-path gating (ISSUE 14)
@@ -980,6 +1034,7 @@ def _run_sort_sample(model, n_configs: int, n_slots: int,
     from ..ops.linear_scan import make_sort_chunk_checker
     from .schedule import ChunkLaunch, run_chunked
 
+    _bump("samples_run")
     batch = pack_group(sample, cand)
     e_sched = bucket_rows(batch["events"].shape[1], 32)
     sharding = sort_rung_sharding(cand)

@@ -72,22 +72,27 @@ from .wgl_cpu import FrontierOverflow, check_encoded_cpu
 #: (reference doc/intro.md:35-41), but as a clean verdict, not an OOM.
 DEFAULT_MAX_CPU_CONFIGS = 1 << 18
 
-#: Per-shape platform routing: tiny dense batches are dominated by
-#: launch+transfer round trips, and the idle 8-way host mesh wins —
-#: measured on the config-3 shape (≈600 sub-histories of ≤33 events:
-#: CPU 2516 vs TPU 1391 hist/s, 2026-07-30 v5e, over a slower
-#: host↔chip link than today's — the gate is due a re-calibration) —
-#: while big batches amortize the trip (north star 1000×~1750 events:
-#: TPU 488 vs CPU 25.6). The gate is the
-#: group's scanned-cell count B×E; the default sits between the measured
-#: winners' shapes (config-3 ≈19k cells → host, config-4 ≈250k → TPU)
-#: and is env-tunable for re-ablation on other chip generations
-#: (doc/running.md "Re-tuning the measured gates").
+#: Per-shape platform routing: a dense window group whose scanned-cell
+#: count B×E is under this gate runs on the host cpu device even though
+#: the default backend is the chip. The gate was 64k cells, set where
+#: tiny batches were dominated by launch+transfer round trips over a
+#: slower host↔chip link (the config-3 shape, ≈600 sub-histories of ≤33
+#: events: CPU 2516 vs TPU 1391 hist/s, 2026-07-30 v5e). Re-read on
+#: today's v5e host (PERF.md section 6, PR 32, call M: one group through
+#: the wavefront, warm, chip | host cpu): that same shape 79-88 | 143-147
+#: ms; one window group of 1k-op histories, 24 rows, W 6 / 7 / 8: 54-62 |
+#: 88-125, 63-69 | 149-228, 74-81 | 241-299 ms, where the gate sent every
+#: such group of up to 24 rows to the host and the launch's other groups
+#: waited for it with the chip idle. Of fourteen shapes the host won one
+#: (8 rows at W 6: 20 | 40 ms). So `auto` routes nothing by default; the
+#: gate stays env-tunable for re-ablation on another host or chip
+#: generation (doc/running.md "Re-tuning the measured gates";
+#: scripts/calibrate_routing.py), and JGRAFT_PLATFORM_ROUTE forces either
+#: placement.
 #: Parsed defensively (platform.env_int): a malformed
 #: JGRAFT_ROUTE_MIN_CELLS used to crash every importer of this module at
 #: import time; now it warns and falls back to the measured default.
-PLATFORM_ROUTE_MIN_CELLS = env_int("JGRAFT_ROUTE_MIN_CELLS", 64_000,
-                                   minimum=0)
+PLATFORM_ROUTE_MIN_CELLS = env_int("JGRAFT_ROUTE_MIN_CELLS", 0, minimum=0)
 
 
 # --------------------------------------- lin-rung fast path (ISSUE 14)
@@ -377,6 +382,7 @@ def check_encoded(
     distribute: bool = True,
     consistency: str = "linearizable",
     lin_fastpath: Optional[bool] = None,
+    serve_rows: Optional[int] = None,
 ) -> list[dict]:
     """Pack-once/check-many entry: verify histories that are ALREADY
     encoded (`history.packing.encode_history`), one result dict each.
@@ -418,6 +424,16 @@ def check_encoded(
     on a superset-legality stream (a second scan of the relaxed bytes
     is pure waste — rows_rung_skipped counts the saved work), and
     graftd's fast lane passes False after certifying at dispatch.
+
+    ``serve_rows`` (ISSUE 32): the caller is a service whose launches
+    hold up to this many rows (graftd's scheduler passes its batch
+    cap). A kernel key the batch meets for the first time is then built
+    WHOLE, every row bucket up to that count, before it is launched
+    (checker/schedule.py `build_keys`), and launch plans are taken as
+    memory holds them (`autotune.preload_plans`, else the default):
+    nothing is read from the plan store and no candidate is compiled or
+    timed on the calling thread. None (a library caller) builds a
+    bucket when a launch reaches it and may measure a plan.
     """
     from ..parallel import distributed
 
@@ -499,7 +515,8 @@ def check_encoded(
                                 algorithm, n_configs, n_slots, witness,
                                 max_cpu_configs, distribute,
                                 consistency="linearizable",
-                                lin_fastpath=False)
+                                lin_fastpath=False,
+                                serve_rows=serve_rows)
             for i, r in zip(todo, sub):
                 results[i] = r
         if consistency == "session":
@@ -517,14 +534,15 @@ def check_encoded(
                 rest,
                 lambda sub: _check_encoded(sub, model, algorithm,
                                            n_configs, n_slots, witness,
-                                           max_cpu_configs),
+                                           max_cpu_configs, serve_rows),
                 # the result-detail exchange (ISSUE 11 tentpole (d))
                 # keys its store records over (model, algorithm, row
                 # encoding); inert unless a shared store dir is
                 # configured
                 model=model, algorithm=algorithm)
         return _check_encoded(rest, model, algorithm, n_configs,
-                              n_slots, witness, max_cpu_configs)
+                              n_slots, witness, max_cpu_configs,
+                              serve_rows)
 
     # Lin-rung pre-kernel fast path (ISSUE 14): certify on the host,
     # evict VALID rows from the batch BEFORE grouping/bucketing/
@@ -630,6 +648,7 @@ def _check_encoded(
     n_slots: Optional[int] = None,
     witness: bool = False,
     max_cpu_configs: Optional[int] = DEFAULT_MAX_CPU_CONFIGS,
+    serve_rows: Optional[int] = None,
 ) -> list[dict]:
     results: list[Optional[dict]] = [None] * len(encs)
 
@@ -665,7 +684,7 @@ def _check_encoded(
         # A backend failure propagates: a check that asked for the
         # accelerator never carries on on the host in its place.
         jax_res = _jax_pass(todo, model, n_configs, n_slots,
-                            kernel=want_pallas)
+                            kernel=want_pallas, serve_rows=serve_rows)
         it = iter(jax_res)
         results = [r if r is not None else next(it) for r in results]
         if algorithm in ("jax", "pallas"):
@@ -712,13 +731,14 @@ def _check_encoded(
 
 
 def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
-              note: bool = True):
+              note: bool = True, serve_rows: Optional[int] = None):
     """Run the on-device pass over a batch of encoded histories. Returns a
     result dict per history, or None where the kernel could not certify a
     verdict (window beyond MAX_SLOTS, or frontier overflow at top
     capacity) — the caller escalates those. `kernel="pallas"` (or the
     JGRAFT_KERNEL=pallas env override) routes dense-domain groups through
-    the Pallas kernel instead of the XLA dense kernel."""
+    the Pallas kernel instead of the XLA dense kernel. `serve_rows`: see
+    `check_encoded`."""
     results: list[Optional[dict]] = [None] * len(encs)
     cap = n_slots or MAX_SLOTS
     fits = [i for i, e in enumerate(encs)
@@ -810,19 +830,20 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 # Per-bucket autotuned plan (checker/autotune.py):
                 # consulted per window group — a persisted plan loads,
                 # a big-enough unplanned bucket measures once in
-                # process, everything else (JGRAFT_AUTOTUNE=0, small
-                # groups, LONG clusters) keeps today's defaults. The
+                # process (never for a service: `serve_rows`),
+                # everything else (JGRAFT_AUTOTUNE=0, small groups,
+                # LONG clusters) keeps today's defaults. The
                 # plan's macro payload cap acts here at pack time; its
                 # chunk/fan-out halves act in build_dense_launches.
-                tuned = autotune.tuned_group_plan(model, plan, sub_encs)
-                batch = (autotune.pack_group(sub_encs, tuned)
-                         if tuned is not None
-                         else _group_pack(sub_encs))
+                tuned = autotune.tuned_group_plan(
+                    model, plan, sub_encs, measure=serve_rows is None)
+                batch = autotune.pack_group(sub_encs, tuned,
+                                            window=plan.n_slots)
                 triples.append((sub, plan, batch, tuned))
             launches, subs = build_dense_launches(
                 model, triples, host_route=_route_group_to_host)
             with launch_span(rows=sum(len(sub) for sub in subs)):
-                outs = run_chunked(launches)
+                outs = run_chunked(launches, build_rows=serve_rows)
             for sub, out in zip(subs, outs):
                 # Slices overlap on devices, so per-launch kernel
                 # walls are not additive; each row reports its slice's
@@ -945,7 +966,8 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             # (family "sort", capacity in the signature).
             rung_encs = [encs[i] for i in remaining]
             tuned = (autotune.tuned_sort_plan(model, rung_encs,
-                                              eff_configs, eff_slots)
+                                              eff_configs, eff_slots,
+                                              measure=serve_rows is None)
                      if scan_chunk() > 0 else None)
             batch = (autotune.pack_group(rung_encs, tuned)
                      if tuned is not None else _group_pack(rung_encs))
@@ -973,7 +995,8 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                         e_sched=e_sched, device=rung_sharding,
                         tag="sort",
                         chunk=(tuned.scan_chunk or max(e_sched, 1))
-                        if tuned is not None else None)])
+                        if tuned is not None else None)],
+                        build_rows=serve_rows)
                 ok, overflow = out.ok, out.overflow
             else:
                 kernel = make_batch_checker(model, eff_configs, eff_slots,

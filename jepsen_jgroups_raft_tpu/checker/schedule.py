@@ -17,13 +17,14 @@ of batched inference; the fix here is the same shape as iteration-level
     min(alive `n_events`) — host data — so the scheduler launches one
     kernel up to the next possible-retirement boundary instead of one
     per chunk (`_span_chunks`; per-launch overhead otherwise eats the
-    eviction win).
+    eviction win). A group's events cross to the device once; a span's
+    offset and length are traced scalars of the step program.
   * **Eviction** — between chunks the flags come back to the host, the
     verdicts of finished rows are recorded, and survivors are
-    recompacted to a smaller row bucket (`history.packing.bucket_rows`,
-    the same pow2+midpoint series `pad_batch_bucketed` uses — so
-    recompaction hits jit-cache entries the initial padding already
-    compiled instead of triggering fresh XLA compiles).
+    recompacted to a smaller row bucket of the launch-shape set
+    (`launch_shapes`, below: the one enumeration of every program a
+    launch can ask for, built whole ahead of a key's launches — so
+    recompaction never triggers a fresh XLA compile).
   * **Early exit** — a group stops the moment all rows are decided.
     The chunk schedule covers the group's *bucketed* event length (what
     the legacy monolithic kernel scans), so skipping trailing pad
@@ -107,7 +108,15 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # Scoped like the rest, so a launch's `stats["scan"]`
                # shows the programs THAT launch built.
                "programs_built": 0, "compile_s": 0.0,
-               "compile_cache_misses": 0}
+               "compile_cache_misses": 0,
+               # launch-shape set (ISSUE 32): programs the build-ahead
+               # of a key built or loaded, and programs a launch built
+               # or loaded AFTER its key was built (healthy: 0).
+               "programs_built_ahead": 0, "shape_misses": 0,
+               # rows of launches the placement policy put on the host
+               # cpu device beside an accelerator (`...@host`): the tier
+               # counters fold them into `dense` / `mask`
+               "host_routed_rows": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -292,6 +301,11 @@ _SPANS: dict = {}  # name -> [n, seconds]; guarded by _STATS_LOCK
 #: most recent compiles, newest last: (fun_name, seconds, innermost
 #: span open on the compiling thread or None); guarded by _STATS_LOCK
 _RECENT_COMPILES: collections.deque = collections.deque(maxlen=16)
+#: most recent shape misses, newest last: (fun_name, seconds, program
+#: kind, key, rows, width); guarded by _STATS_LOCK
+_RECENT_MISSES: collections.deque = collections.deque(maxlen=16)
+#: the span a key's programs are built in, ahead of its launches
+BUILD_AHEAD = "build.ahead"
 #: per-thread stack of open span names (`.names`), so that a compile
 #: can be stamped with the step it interrupted
 _OPEN = threading.local()
@@ -410,10 +424,21 @@ def snapshot_spans() -> dict:
 def note_compile(fun_name: str, seconds: float) -> None:
     """One program built or loaded by the backend (the
     `/jax/core/compile/backend_compile_duration` event), stamped with
-    the span it interrupted on this thread."""
-    _add_stats(programs_built=1, compile_s=seconds)
+    the span it interrupted on this thread. Inside `build.ahead` it is
+    a program built ahead; inside a launch of the closed shape set
+    (`_launching`) it is a SHAPE MISS — the key was declared built and
+    the launch still had to build — and is logged with the
+    ``(key, rows, width)`` that asked for it."""
+    inside = open_span()
+    shape = getattr(_OPEN, "shape", None)
+    ahead = inside == BUILD_AHEAD
+    miss = shape is not None and not ahead
+    _add_stats(programs_built=1, compile_s=seconds,
+               programs_built_ahead=int(ahead), shape_misses=int(miss))
     with _STATS_LOCK:
-        _RECENT_COMPILES.append((fun_name, seconds, open_span()))
+        _RECENT_COMPILES.append((fun_name, seconds, inside))
+        if miss:
+            _RECENT_MISSES.append((fun_name, seconds) + shape)
 
 
 def note_cache_miss() -> None:
@@ -422,13 +447,17 @@ def note_cache_miss() -> None:
 
 
 def snapshot_compiles() -> dict:
-    """The process-wide compile counters and the most recent compiles,
-    newest last, as ``[fun_name, seconds, open span]``."""
+    """The process-wide compile counters, the most recent compiles,
+    newest last, as ``[fun_name, seconds, open span]``, and the most
+    recent shape misses as ``[fun_name, seconds, program, key, rows,
+    width]``."""
     with _STATS_LOCK:
-        return {"programs_built": _STATS["programs_built"],
-                "compile_s": _STATS["compile_s"],
-                "compile_cache_misses": _STATS["compile_cache_misses"],
-                "recent_compiles": [list(c) for c in _RECENT_COMPILES]}
+        out = {k: _STATS[k] for k in (
+            "programs_built", "compile_s", "compile_cache_misses",
+            "programs_built_ahead", "shape_misses")}
+        out["recent_compiles"] = [list(c) for c in _RECENT_COMPILES]
+        out["recent_shape_misses"] = [list(c) for c in _RECENT_MISSES]
+        return out
 
 
 @contextlib.contextmanager
@@ -492,6 +521,12 @@ class ChunkLaunch:
     #: the launch effectively monolithic (one span, one flag sync)
     #: while staying on the wavefront driver.
     chunk: Optional[int] = None
+    #: What names this launch's compiled kernel pair: everything
+    #: `make_*_chunk_checker` keys its cache on, as JSON-able values
+    #: (`build_dense_launches` fills it). With the event lanes, the
+    #: device width and the placement it is the KEY of the launch-shape
+    #: set (`launch_shapes`); None falls back to the pair's identity.
+    spec: Optional[dict] = None
 
 
 @dataclass
@@ -513,7 +548,9 @@ class GroupOutcome:
 @dataclass
 class _GroupState:
     launch: ChunkLaunch
-    padded_events: np.ndarray          # [B_real, E_pad, 5]
+    events: object                     # device [rows, width, lanes]
+    key: Optional[tuple]               # launch-shape key; None: exact rows
+    width: int                         # device event length
     chunk: int                         # this group's resolved chunk size
     scheduled: int                     # chunks the monolithic path implies
     slot_rows: np.ndarray              # [padded_B] original row id or -1
@@ -631,9 +668,150 @@ def build_dense_launches(model, groups, host_route=None):
             events=batch["events"], n_events=batch["n_events"],
             init_fn=init_fn, step_fn=step_fn, val_of=plan.val_of,
             e_sched=e_sched, device=placement, tag=tag,
-            exact_rows=exact, chunk=chunk_override))
+            exact_rows=exact, chunk=chunk_override,
+            spec={"model": type(model).__name__,
+                  "model_key": repr(model.cache_key()),
+                  "kind": plan.kind, "n_slots": int(plan.n_slots),
+                  "n_states": int(plan.n_states),
+                  "macro_p": batch.get("macro_p"), "host": host,
+                  "fanout": _n_shards(placement)}))
         subs.append(list(rows))
     return launches, subs
+
+
+def key_template(model, spec: dict, width: int, lanes: int,
+                 rows: int) -> Optional[ChunkLaunch]:
+    """A launch that holds no history and names the dense key a record
+    describes (`snapshot_built`: a launch's `spec`, its device width
+    and lanes, the rows built), for `build_keys`. It goes through
+    `build_dense_launches`, the one home of the placement policy; None
+    where this process would not place the key as recorded (another
+    fan-out; a host-routed key, whose placement is the gate's word on
+    a group, not a property of the key), or the record is of another
+    stream format."""
+    from ..ops.dense_scan import DensePlan
+
+    macro_p = spec.get("macro_p")
+    if spec.get("host") or \
+            lanes != (5 if macro_p is None else 3 + 4 * int(macro_p)):
+        return None
+    n_states = int(spec["n_states"])
+    plan = DensePlan(spec["kind"], int(spec["n_slots"]), n_states,
+                     np.zeros((rows, n_states), dtype=np.int32))
+    batch = {"events": np.broadcast_to(np.zeros((1, 1, 1), np.int32),
+                                       (rows, width, lanes)),
+             "n_events": np.zeros((rows,), np.int32), "legacy_events": 1}
+    if macro_p is not None:
+        batch["macro_p"] = int(macro_p)
+    [launch], _ = build_dense_launches(model, [(range(rows), plan, batch)])
+    return launch if launch.spec == spec else None
+
+
+# --------------------------------------------------- the launch-shape set
+# ISSUE 32. Every program a launch of the wavefront can ask the backend
+# for is named HERE, by one function, for a key and a row count:
+#
+#   key    what picks the compiled kernel pair and its operands' lanes:
+#          the kernel's own cache key (`ChunkLaunch.spec`), the event
+#          lanes (5, or 3 + 4·P), the device event length `width`, the
+#          placement.
+#   rows   a power of two from LAUNCH_ROW_FLOOR up and, from 32 rows on,
+#          the midpoints between them (rounded to the placement's shard
+#          count).
+#   width  a power of two from LAUNCH_WIDTH_FLOOR up: the group's whole
+#          padded event stream lives on the device, and a span's offset
+#          and length are traced scalars of the step program
+#          (ops/kernel_ir.chunk_step_fns), so a span is not a shape.
+#
+# For one key that is, per row bucket, one `init` program, one `step`
+# program and one `gather` program onto the next bucket down
+# (recompaction walks down bucket by bucket): 3·len(rows) - 1 programs,
+# 26 for the nine buckets 8…256 of a served launch. `launch_rows`,
+# `launch_width`, `_init_group`, `_dispatch`, `_collect` and the
+# build-ahead all read `launch_shapes`, so what is built ahead and what
+# is launched cannot drift apart (tests/test_launch_shapes.py holds them
+# together through `snapshot_launched`). A launch never builds: it asks
+# `build_keys` for its bucket (a service's: for its key whole, once) and
+# waits; what a launch builds all the same is a shape miss
+# (`note_compile`).
+
+#: smallest row bucket of a launch
+LAUNCH_ROW_FLOOR = 8
+#: the power of two from which the row buckets take midpoints too
+LAUNCH_ROW_MIDPOINTS = 32
+#: smallest device event length of a launch
+LAUNCH_WIDTH_FLOOR = 32
+
+
+def _pow2_from(n: int, floor: int) -> int:
+    b = floor
+    while b < n:
+        b *= 2
+    return b
+
+
+def launch_rows(n: int, shards: int = 1) -> int:
+    """Row bucket for `n` live rows: the next power of two from
+    LAUNCH_ROW_FLOOR or, from LAUNCH_ROW_MIDPOINTS up, the midpoint
+    between two (…, 32, 48, 64, 96, 128, 192, 256), padded up to a
+    multiple of the placement's shard count so a sharded launch always
+    splits evenly over the mesh. A group's time on the chip grows with
+    its padded rows (1k-op histories, W 6 to 8: 96 -> 128 rows costs
+    35-41 ms of 136-168, 24 -> 32 rows 2-10 ms of 54-81; PERF.md
+    section 6, PR 32, call M), so the midpoints earn their programs
+    where rows are many; below 32 rows a bucket more buys back
+    milliseconds."""
+    n = max(int(n), 1)
+    b = _pow2_from(n, LAUNCH_ROW_FLOOR)
+    if b > LAUNCH_ROW_MIDPOINTS and n <= b - b // 4:
+        b -= b // 4
+    return -(-b // shards) * shards
+
+
+def launch_width(e_pad: int) -> int:
+    """Device event length for a schedule of `e_pad` events."""
+    return _pow2_from(max(int(e_pad), 1), LAUNCH_WIDTH_FLOOR)
+
+
+@dataclass(frozen=True)
+class LaunchShapes:
+    """The programs of one key: `rows` ascending, every one at the one
+    `width`."""
+
+    rows: tuple
+    width: int
+
+    @property
+    def init(self) -> tuple:
+        return self.rows
+
+    @property
+    def step(self) -> tuple:
+        return tuple((r, self.width) for r in self.rows)
+
+    @property
+    def gather(self) -> tuple:
+        """(rows before, rows after): onto the next bucket down."""
+        return tuple(zip(self.rows[1:], self.rows[:-1]))
+
+    def __len__(self) -> int:
+        return 3 * len(self.rows) - 1 if self.rows else 0
+
+
+def launch_shapes(max_rows: int, width: int, shards: int = 1
+                  ) -> LaunchShapes:
+    """The finite set of programs a launch of up to `max_rows` rows of
+    one key can ever ask for: it starts at `launch_rows(max_rows)` and
+    recompaction only ever walks down the buckets."""
+    top = launch_rows(max_rows, shards)
+    rows, n = [], 1
+    while True:
+        b = launch_rows(n, shards)
+        rows.append(b)
+        if b >= top:
+            break
+        n = b + 1
+    return LaunchShapes(tuple(rows), launch_width(width))
 
 
 def _n_shards(placement) -> int:
@@ -643,73 +821,289 @@ def _n_shards(placement) -> int:
     return int(mesh.size) if mesh is not None else 1
 
 
-def _bucket_launch_rows(launch: ChunkLaunch, n: int) -> int:
-    """Row bucket for a launch's active set: the pow2+midpoint series,
-    padded up to a multiple of the placement's shard count so a
-    sharded launch always splits evenly over the mesh (the same
-    rounding `pad_batch_bucketed(multiple_b=mesh)` applies on the
-    legacy sharded path)."""
-    b = bucket_rows(n)
-    s = _n_shards(launch.device)
-    return -(-b // s) * s
+def _placement_name(placement) -> str:
+    if placement is None:
+        return "default"
+    mesh = getattr(placement, "mesh", None)
+    if mesh is not None:
+        return f"mesh{int(mesh.size)}"
+    return f"{placement.platform}:{placement.id}"
 
 
-def _pad_idx(positions: List[int], bucket: int) -> np.ndarray:
+def launch_key(launch: ChunkLaunch, width: int) -> tuple:
+    """The key of `launch`'s shape set (hashable, printable)."""
+    spec = launch.spec
+    kernel = (tuple(sorted((k, str(v)) for k, v in spec.items()))
+              if spec is not None
+              else (("tag", launch.tag), ("fns", id(launch.step_fn))))
+    return (kernel, int(launch.events.shape[2]), int(width),
+            _placement_name(launch.device))
+
+
+#: key -> {"rows": set of row buckets built, "spec", "width", "lanes"};
+#: guarded by _BUILD_LOCK
+_BUILT: dict = {}
+_BUILD_LOCK = threading.RLock()
+#: every (program, key, rows[, rows after], width) a launch asked for;
+#: guarded by _STATS_LOCK, bounded
+_LAUNCHED: set = set()
+_LAUNCHED_CAP = 8192
+
+
+def snapshot_built() -> list:
+    """The keys built in this process: ``{"key", "spec", "width",
+    "lanes", "rows"}`` each, rows ascending."""
+    with _BUILD_LOCK:
+        return [{"key": k, "spec": v["spec"], "width": v["width"],
+                 "lanes": v["lanes"], "rows": sorted(v["rows"])}
+                for k, v in _BUILT.items()]
+
+
+def snapshot_launched() -> list:
+    """What the launches of this process asked for, as ``("init", key,
+    rows, width)``, ``("step", key, rows, width)`` and ``("gather",
+    key, rows before, rows after, width)``."""
+    with _STATS_LOCK:
+        return sorted(_LAUNCHED, key=repr)
+
+
+class _launching:
+    """Names the program the calling thread is about to ask for, for
+    `note_compile` (a build there is a shape miss) and for
+    `snapshot_launched`. A launch outside the set (`key` None: exact
+    rows) is neither recorded nor ever a miss."""
+
+    __slots__ = ("shape",)
+
+    def __init__(self, program: str, key, *dims):
+        self.shape = None if key is None else (program, key) + dims
+
+    def __enter__(self):
+        if self.shape is not None:
+            _OPEN.shape = self.shape
+            with _STATS_LOCK:
+                if len(_LAUNCHED) < _LAUNCHED_CAP:
+                    _LAUNCHED.add(self.shape)
+        return self
+
+    def __exit__(self, *exc):
+        _OPEN.shape = None
+        return False
+
+
+def _put(launch: ChunkLaunch, x):
+    """Place a launch operand: under the launch's placement, or on the
+    default device. Always a device array, so that a group's events
+    cross to the device once."""
+    import jax
+
+    return jax.device_put(x, launch.device)
+
+
+def _init_carry(launch: ChunkLaunch, val_of, n_events):
+    if launch.val_of is not None:
+        return launch.init_fn(_put(launch, val_of), _put(launch, n_events))
+    return launch.init_fn(_put(launch, n_events))
+
+
+_GATHERS: dict = {}
+
+
+def _gather_fn(placement):
+    """The recompaction program of a placement: carry and events
+    gathered onto a smaller row bucket, compiled per (rows before, rows
+    after) like any jitted function. Under a batch-axis sharding the
+    outputs are pinned back to it, so the next step splits evenly
+    again."""
+    fn = _GATHERS.get(placement)
+    if fn is None:
+        import jax
+
+        def gather(carry, events, idx):
+            return jax.tree_util.tree_map(lambda x: x[idx],
+                                          (carry, events))
+
+        kw = ({"out_shardings": placement}
+              if getattr(placement, "mesh", None) is not None else {})
+        fn = _GATHERS[placement] = jax.jit(gather, **kw)
+    return fn
+
+
+def _build_rows(launch: ChunkLaunch, rows: int, lower: Optional[int],
+                width: int) -> None:
+    """Ask for the three programs of one row bucket, on operands shaped,
+    typed and placed exactly as `_init_group`, `_dispatch` and
+    `_collect` make them; the step scans no event."""
+    import jax
+
+    lanes = launch.events.shape[2]
+    with annotate(BUILD_AHEAD, rows=rows):
+        vo = None
+        if launch.val_of is not None:
+            vo = np.zeros((rows,) + launch.val_of.shape[1:],
+                          dtype=launch.val_of.dtype)
+        carry = _init_carry(launch, vo, np.zeros((rows,), np.int32))
+        events = _put(launch, np.zeros((rows, width, lanes),
+                                       dtype=launch.events.dtype))
+        out = launch.step_fn(carry, events, np.int32(0), np.int32(0))
+        if lower is not None:
+            out = (out, _gather_fn(launch.device)(
+                carry, events, np.zeros((lower,), np.int32)))
+        jax.block_until_ready(out)
+
+
+#: threads that build a key's row buckets side by side (XLA releases
+#: the GIL while it compiles)
+BUILD_THREADS = 8
+_BUILDERS = None   # the pool, made when first needed
+#: (key, row bucket) -> Future of a build asked for and not yet done;
+#: guarded by _BUILD_LOCK
+_PENDING: dict = {}
+
+
+def _build_task(launch: ChunkLaunch, key: tuple, rows: int,
+                lower: Optional[int], width: int, stop) -> None:
+    """One row bucket of one key, on a build thread. `stop()` true
+    leaves it unbuilt (the service that asked is gone)."""
+    try:
+        if stop is None or not stop():
+            _build_rows(launch, rows, lower, width)
+            with _BUILD_LOCK:
+                _BUILT[key]["rows"].add(rows)
+    finally:
+        with _BUILD_LOCK:
+            _PENDING.pop((key, rows), None)
+
+
+def _key_shapes(launch: ChunkLaunch, chunk: int, rows: int,
+                upto: Optional[int]) -> tuple:
+    """(key, its LaunchShapes, the row bucket needed) for a launch of
+    `rows` rows: the one bucket or, with `upto`, the key WHOLE, every
+    bucket up to the larger of the two."""
+    width = launch_width(_padded_len(launch, launch.chunk or chunk or 1))
+    shards = _n_shards(launch.device)
+    need = launch_rows(rows, shards)
+    shapes = launch_shapes(max(rows, upto or 0), width, shards)
+    return launch_key(launch, width), shapes, need
+
+
+def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
+               rows: Optional[int] = None, upto: Optional[int] = None,
+               stop: Optional[Callable[[], bool]] = None) -> int:
+    """Build what is not built yet of `launches`' keys (templates or
+    real launches; each names a key by its fns, lanes, schedule and
+    placement) on the build threads, and wait for it in ONE
+    `build.ahead` span. `rows` (default: the launch's own) is the row
+    count needed; `chunk` is the run's, as `run_chunked` resolves it.
+
+    Without `upto` that is the one row bucket needed: a library caller
+    builds a bucket when a launch or a recompaction reaches it, as
+    lazily as the jit cache did. With `upto` (a service's largest
+    launch) it is the key WHOLE, every bucket up to the larger of
+    `rows` and `upto`: one known pause the first time a key is met,
+    after which no launch of the key ever builds. A bucket that another
+    thread is building already is waited for, not built twice.
+    Returns the number of programs waited for. `stop()` true leaves
+    what has not started unbuilt."""
+    global _BUILDERS
+    chunk = scan_chunk() if chunk is None else chunk
+    waits = []   # (launch, key, row bucket, lower, width, future)
+    with _BUILD_LOCK:
+        if _BUILDERS is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            _BUILDERS = ThreadPoolExecutor(
+                BUILD_THREADS, thread_name_prefix="build-ahead")
+        for launch in launches:
+            key, shapes, need = _key_shapes(
+                launch, chunk,
+                launch.events.shape[0] if rows is None else rows, upto)
+            built = _BUILT.setdefault(key, {
+                "rows": set(), "spec": launch.spec, "width": shapes.width,
+                "lanes": int(launch.events.shape[2])})["rows"]
+            lower = dict(zip(shapes.rows[1:], shapes.rows[:-1]))
+            # the heaviest first: a step program's cost grows with its
+            # rows
+            for r in reversed(shapes.rows if upto else (need,)):
+                if r in built:
+                    continue
+                fut = _PENDING.get((key, r))
+                if fut is None:
+                    fut = _PENDING[(key, r)] = _BUILDERS.submit(
+                        _build_task, launch, key, r, lower.get(r),
+                        shapes.width, stop)
+                waits.append((launch, key, r, lower.get(r), shapes.width,
+                              fut))
+    if not waits:
+        return 0
+    n = sum(3 if w[3] is not None else 2 for w in waits)
+    with span(BUILD_AHEAD, n=n, keys=len(launches)):
+        for launch, key, r, low, width, fut in waits:
+            fut.result()
+            if r not in _BUILT[key]["rows"] and not (stop and stop()):
+                # another caller's `stop` left it unbuilt: build here
+                _build_rows(launch, r, low, width)
+                with _BUILD_LOCK:
+                    _BUILT[key]["rows"].add(r)
+    return n
+
+
+def _padded_len(launch: ChunkLaunch, chunk: int) -> int:
+    """Events the launch's chunk schedule covers."""
+    E = launch.events.shape[1]
+    e_sched = max(launch.e_sched or E, E, 1)
+    return ((e_sched + chunk - 1) // chunk) * chunk
+
+
+def _pad_idx(positions, bucket: int) -> np.ndarray:
     """Gather index padded to the bucket by repeating the first entry
     (pad slots are masked out of every flag read via slot_rows == -1)."""
-    idx = np.asarray(positions + [positions[0]] * (bucket - len(positions)),
-                     dtype=np.int32)
+    idx = np.full((bucket,), positions[0], dtype=np.int32)
+    idx[: len(positions)] = positions
     return idx
 
 
-def _init_group(launch: ChunkLaunch, chunk: int) -> _GroupState:
-    import jax
-
-    chunk = launch.chunk or chunk  # per-launch autotune override
-    B, E = launch.events.shape[0], launch.events.shape[1]
-    e_sched = max(launch.e_sched or E, E, 1)
-    e_pad = ((e_sched + chunk - 1) // chunk) * chunk
-    padded = launch.events
-    if e_pad != E:
-        # Row width follows the stream format: 5 legacy fields or
-        # 3 + 4·P macro lanes (history/packing.py macro_compact).
-        padded = np.zeros((B, e_pad, launch.events.shape[2]),
-                          dtype=launch.events.dtype)
-        padded[:, :E] = launch.events
-    padded_b = B if launch.exact_rows else _bucket_launch_rows(launch, B)
-    slot_rows = np.full((padded_b,), -1, dtype=np.int32)
+def _init_group(launch: ChunkLaunch, chunk: int,
+                build_rows: Optional[int] = None) -> _GroupState:
+    chunk = launch.chunk or chunk  # a stored plan's override
+    B, E, lanes = launch.events.shape
+    e_pad = _padded_len(launch, chunk)
+    if launch.exact_rows:
+        # outside the set: a LONG cluster's own rows and length, its
+        # programs built when it comes
+        rows, width, key = B, e_pad, None
+    else:
+        key, shapes, rows = _key_shapes(launch, chunk, B, build_rows)
+        width = shapes.width
+        built = _BUILT.get(key)
+        if built is None or not built["rows"].issuperset(
+                shapes.rows if build_rows else (rows,)):
+            # a launch never builds: it waits for its bucket or, for a
+            # service, for its key whole
+            build_keys([launch], chunk, upto=build_rows)
+    # Row width follows the stream format: 5 legacy fields or
+    # 3 + 4·P macro lanes (history/packing.py macro_compact). Pad rows
+    # and the tail past E are zeros: EV_PAD no-ops.
+    events = np.zeros((rows, width, lanes), dtype=launch.events.dtype)
+    events[:B, :E] = launch.events
+    slot_rows = np.full((rows,), -1, dtype=np.int32)
     slot_rows[:B] = np.arange(B, dtype=np.int32)
-
-    ne = np.zeros((padded_b,), dtype=np.int32)
+    ne = np.zeros((rows,), dtype=np.int32)
     ne[:B] = launch.n_events
-    put = (lambda x: jax.device_put(x, launch.device)) \
-        if launch.device is not None else (lambda x: x)
+    vo = None
     if launch.val_of is not None:
-        vo = np.empty((padded_b,) + launch.val_of.shape[1:],
+        vo = np.empty((rows,) + launch.val_of.shape[1:],
                       dtype=launch.val_of.dtype)
         vo[:B] = launch.val_of
         vo[B:] = launch.val_of[:1]
-        carry = launch.init_fn(put(vo), put(ne))
-    else:
-        carry = launch.init_fn(put(ne))
+    with _launching("init", key, rows, width):
+        carry = _init_carry(launch, vo, ne)
     return _GroupState(
-        launch=launch, padded_events=padded, chunk=chunk,
-        scheduled=e_pad // chunk,
+        launch=launch, events=_put(launch, events), key=key, width=width,
+        chunk=chunk, scheduled=e_pad // chunk,
         slot_rows=slot_rows, carry=carry,
         ok=np.zeros((B,), dtype=bool), overflow=np.zeros((B,), dtype=bool),
         recorded=np.zeros((B,), dtype=bool), t_start=time.perf_counter())
-
-
-def _chunk_slice(g: _GroupState, lo: int, width: int) -> np.ndarray:
-    """[padded_B, width, 5] host slice for the next launch: each slot's
-    mapped row's events (zeros for pad slots — EV_PAD no-ops)."""
-    rows = np.maximum(g.slot_rows, 0)
-    # Advanced indexing already materializes a fresh array, so the pad
-    # slots can be zeroed in place.
-    ev = g.padded_events[rows, lo:lo + width]
-    if (g.slot_rows < 0).any():
-        ev[g.slot_rows < 0] = 0
-    return ev
 
 
 def _span_chunks(g: _GroupState) -> int:
@@ -722,9 +1116,8 @@ def _span_chunks(g: _GroupState) -> int:
     collapses ~11 sync-free launches per group into 2, and per-launch
     dispatch overhead (multi-device rendezvous, flag readback) was
     measured to eat the entire eviction win when paid per chunk. The
-    span is rounded DOWN to a power-of-two multiple of the chunk so
-    launch shapes stay in a bounded set ({chunk·2^k} × the row-bucket
-    series) that hits the jit cache across groups. Soundness: a
+    span's length is a traced scalar of the step program, so any
+    length is the same program. Soundness: a
     `decided` (~ok) row inside a coalesced span is caught at the next
     sync — its verdict is frozen (see module docstring), so it is
     recorded late, never differently; only eviction latency moves."""
@@ -734,26 +1127,22 @@ def _span_chunks(g: _GroupState) -> int:
     lo = g.cursor * chunk
     first = int(g.launch.n_events[live].min()) if live.size else 0
     p = max(1, -(-(first - lo) // chunk))  # ceil, ≥1 once overdue
-    p = min(p, g.scheduled - g.cursor)
-    return 1 << (p.bit_length() - 1) if p > 1 else 1
+    return min(p, g.scheduled - g.cursor)
 
 
 def _dispatch(g: _GroupState) -> None:
-    import jax
-
-    span = _span_chunks(g)
-    ev = _chunk_slice(g, g.cursor * g.chunk, span * g.chunk)
-    if g.launch.device is not None:
-        ev = jax.device_put(ev, g.launch.device)
+    n_chunks = _span_chunks(g)
     t0 = time.perf_counter()
-    g.pending = (t0, span, g.launch.step_fn(g.carry, ev))
+    with _launching("step", g.key, g.slot_rows.shape[0], g.width):
+        out = g.launch.step_fn(g.carry, g.events,
+                               np.int32(g.cursor * g.chunk),
+                               np.int32(n_chunks * g.chunk))
+    g.pending = (t0, n_chunks, out)
 
 
 def _collect(g: _GroupState) -> None:
     """Block for the pending launch, record finished rows, evict, and
     recompact survivors when they fit a smaller row bucket."""
-    import jax
-
     t_disp, width, (carry, decided, exhausted, ok, overflow) = g.pending
     g.pending = None
     g.carry = carry
@@ -796,20 +1185,33 @@ def _collect(g: _GroupState) -> None:
         g.wall_s = time.perf_counter() - g.t_start
         return
 
-    if g.launch.exact_rows:
+    if g.key is None:
         return  # no recompaction (see ChunkLaunch.exact_rows)
-    bucket = _bucket_launch_rows(g.launch, int(alive.size))
-    if bucket < g.slot_rows.shape[0]:
-        idx = _pad_idx([int(p) for p in alive], bucket)
-        g.carry = jax.tree_util.tree_map(lambda x: x[idx], g.carry)
-        if g.launch.device is not None:
-            # Re-pin the gathered carry to the launch placement: the
-            # eager gather does not preserve the batch-axis sharding,
-            # and the next step_fn call must split evenly again.
-            g.carry = jax.device_put(g.carry, g.launch.device)
-        new_rows = np.full((bucket,), -1, dtype=np.int32)
-        new_rows[: alive.size] = g.slot_rows[alive]
-        g.slot_rows = new_rows
+    have = g.slot_rows.shape[0]
+    bucket = launch_rows(int(alive.size), _n_shards(g.launch.device))
+    if bucket >= have:
+        return
+    # Walk down the set's buckets, one `gather` program a step: the
+    # first brings the survivors to the front, the rest only shorten.
+    gather = _gather_fn(g.launch.device)
+    survivors = g.slot_rows[alive]
+    positions = alive
+    rows_down = [r for r in launch_shapes(
+        have, g.width, _n_shards(g.launch.device)).rows if r < have]
+    for after in reversed(rows_down):
+        if after < bucket:
+            break
+        if after not in _BUILT[g.key]["rows"]:
+            # a library caller builds a bucket when it reaches it; a
+            # service built them all before the key's first launch
+            build_keys([g.launch], g.chunk, rows=after)
+        with _launching("gather", g.key, have, after, g.width):
+            g.carry, g.events = gather(g.carry, g.events,
+                                       _pad_idx(positions, after))
+        positions = np.arange(alive.size, dtype=np.int32)
+        have = after
+    g.slot_rows = np.full((have,), -1, dtype=np.int32)
+    g.slot_rows[: alive.size] = survivors
 
 
 def _overlap_seconds(intervals: List[tuple]) -> float:
@@ -834,7 +1236,8 @@ def _overlap_seconds(intervals: List[tuple]) -> float:
 
 def run_chunked(launches: List[ChunkLaunch],
                 chunk: Optional[int] = None,
-                record_stats: bool = True) -> List[GroupOutcome]:
+                record_stats: bool = True,
+                build_rows: Optional[int] = None) -> List[GroupOutcome]:
     """Run window groups through the chunked wavefront; one
     GroupOutcome per launch, in order. Each round dispatches every live
     group's next chunk before blocking on any result, so group kernels
@@ -843,16 +1246,23 @@ def run_chunked(launches: List[ChunkLaunch],
     (autotuned per-group plans). `record_stats=False` keeps a run out
     of the process/scope counters — the autotuner's short candidate
     samples must not inflate the eviction evidence bench.py and the
-    per-run stores report."""
+    per-run stores report. `build_rows`, a service's largest launch:
+    a key these launches meet for the first time is built whole up to
+    that many rows before it is launched (`build_keys`); None builds a
+    row bucket when a launch reaches it."""
     chunk = scan_chunk() if chunk is None else chunk
     if chunk <= 0 and not (launches and all(ln.chunk for ln in launches)):
         raise ValueError("run_chunked needs a positive chunk size "
                          "(JGRAFT_SCAN_CHUNK=0 selects the legacy "
                          "monolithic path at the call site; per-launch "
                          "ChunkLaunch.chunk overrides may substitute)")
-    groups = [_init_group(ln, chunk) for ln in launches]
-    for g in groups:
+    groups = []
+    for ln in launches:
+        # a group's first span starts on the device while the host pads
+        # and places the next group's events
+        g = _init_group(ln, chunk, build_rows)
         _dispatch(g)
+        groups.append(g)
     while True:
         live = [g for g in groups if not g.done]
         if not live:
@@ -873,6 +1283,9 @@ def run_chunked(launches: List[ChunkLaunch],
                    groups_run=len(groups),
                    groups_early_exited=sum(1 for g in groups
                                            if g.early_exit),
+                   host_routed_rows=sum(
+                       g.launch.events.shape[0] for g in groups
+                       if g.launch.tag.endswith("@host")),
                    pipeline_overlap_s=_overlap_seconds(all_spans))
     return [GroupOutcome(ok=g.ok, overflow=g.overflow, wall_s=g.wall_s,
                          chunks_run=g.launches_run, evicted_rows=g.evicted,
@@ -962,7 +1375,8 @@ class CarriedScan:
                 padded[: span.shape[0]] = span
                 span = padded
             carry, _dec, _exh, ok, overflow = self._step(
-                self.carry, span[None, :, :])
+                self.carry, span[None, :, :], np.int32(0),
+                np.int32(span.shape[0]))
             self.carry = carry
             # blocks: device → host (the per-append sync point)
             self.ok = bool(np.asarray(ok)[0])  # lint: allow(host-sync)

@@ -587,10 +587,15 @@ def pack_macro_batch(
     encoded: Iterable[EncodedHistory],
     n_events: Optional[int] = None,
     cap: int = MACRO_MAX_OPENS,
+    window: Optional[int] = None,
 ) -> dict:
     """Macro-stream twin of `pack_batch`: compact every history of a
     batch at one shared payload width P (`bucket_opens` of the batch's
-    longest open run) and pad to a common macro-row count. Returns
+    longest open run — or, given the launch's `window`, of that: a run
+    of opens holds distinct slots, so it never outgrows the window, and
+    P then follows from the kernel's window instead of from the rows a
+    batch happens to hold, which keeps it out of the launch-shape set,
+    checker/schedule.py) and pad to a common macro-row count. Returns
     numpy arrays events [B, E_mac, 3+4·P], n_events [B] (MACRO row
     counts — the scheduler's exhaustion/span math runs on these),
     n_slots [B], plus the scalar "macro_p" the kernel builders key on
@@ -603,7 +608,8 @@ def pack_macro_batch(
     encs = list(encoded)
     if not encs:
         raise ValueError("empty batch")
-    P = bucket_opens(max(max_open_run(e.events) for e in encs), cap)
+    P = bucket_opens(window if window is not None
+                     else max(max_open_run(e.events) for e in encs), cap)
     compacted = [macro_compact(e.events, P) for e in encs]
     E = n_events or max(max(c.shape[0] for c in compacted), 1)
     if any(c.shape[0] > E for c in compacted):

@@ -293,8 +293,16 @@ def chunk_step_fns(parts: KernelParts):
     chunk builders). Returns single-row (init_one, step_one):
 
       init_one(*operands, n_ev) -> {"inner": scan carry, "left": int32}
-      step_one(carry, events [chunk, R]) -> (carry', decided,
+      step_one(carry, events [E, R], lo, n) -> (carry', decided,
           exhausted, ok, overflow)
+
+    `step_one` scans the `n` events from `lo` on. Both are TRACED
+    scalars (ISSUE 32): the whole of a row's event stream is the
+    operand, so one compiled program serves every span of a launch —
+    the span's offset and length are data, not shape. The scheduler
+    (checker/schedule.py) puts a group's events on the device once and
+    steps through them; a caller holding only a slab passes
+    ``lo=0, n=slab length``.
 
     Eviction soundness (the checker/linearizable.py contract): `ok` is
     monotone — it only ever ANDs in new conditions — and flips False
@@ -303,7 +311,7 @@ def chunk_step_fns(parts: KernelParts):
     pair is frozen mid-scan. An `exhausted` row (events_left ≤ 0) only
     has EV_PAD no-ops left, so its current pair is final too. Either
     flag makes the row safe to evict: eviction only ever removes rows
-    whose verdict is certain. Chaining step_one over E/chunk chunks
+    whose verdict is certain. Chaining step_one over consecutive spans
     applies the identical scan_step sequence as the monolithic
     `lax.scan`, so verdicts are bitwise-identical by construction
     (pinned by the tests/test_kernel_ir.py differentials)."""
@@ -312,10 +320,25 @@ def chunk_step_fns(parts: KernelParts):
         return {"inner": parts.init(*operands),
                 "left": jnp.asarray(n_ev, jnp.int32)}
 
-    def step_one(carry, events):
-        inner, _ = lax.scan(parts.scan_step, carry["inner"], events,
-                            unroll=scan_unroll())
-        left = carry["left"] - events.shape[0]
+    def step_one(carry, events, lo, n):
+        unroll = scan_unroll()
+        lo = jnp.asarray(lo, jnp.int32)
+        n = jnp.asarray(n, jnp.int32)
+
+        def at(i, inner):
+            ev = lax.dynamic_index_in_dim(events, lo + i, axis=0,
+                                          keepdims=False)
+            return parts.scan_step(inner, ev)[0]
+
+        def block(b, inner):
+            for k in range(unroll):
+                inner = at(b * unroll + k, inner)
+            return inner
+
+        inner = lax.fori_loop(0, n // unroll, block, carry["inner"])
+        if unroll > 1:  # the span's tail, shorter than one block
+            inner = lax.fori_loop((n // unroll) * unroll, n, at, inner)
+        left = carry["left"] - n
         ok, overflow = parts.verdict(inner)
         return ({"inner": inner, "left": left},
                 ~ok, left <= 0, ok, overflow)
@@ -325,15 +348,17 @@ def chunk_step_fns(parts: KernelParts):
 
 def batch_chunk_checker(parts: KernelParts, mesh=None, jit: bool = True):
     """Batch driver for the wavefront scheduler (checker/schedule.py):
-    vmapped (init_fn, step_fn) over the batch axis, optionally wrapped
-    in an explicit `shard_map` over `mesh` (see :func:`shard_chunk_fns`
-    — relying on jit's GSPMD sharding propagation *placed* the carry
-    sharded but compiled a ~3× slower per-chunk program than the
-    explicit wrap on the CPU mesh). Callers pad the batch to a multiple
-    of the mesh size (schedule._bucket_launch_rows)."""
+    vmapped (init_fn, step_fn) over the batch axis — the span's offset
+    and length are shared by the rows, so they stay scalars — optionally
+    wrapped in an explicit `shard_map` over `mesh` (see
+    :func:`shard_chunk_fns` — relying on jit's GSPMD sharding
+    propagation *placed* the carry sharded but compiled a ~3× slower
+    per-chunk program than the explicit wrap on the CPU mesh). Callers
+    pad the batch to a multiple of the mesh size
+    (schedule.launch_rows)."""
     init_one, step_one = chunk_step_fns(parts)
     init_fn = jax.vmap(init_one)
-    step_fn = jax.vmap(step_one)
+    step_fn = jax.vmap(step_one, in_axes=(0, 0, None, None))
     if mesh is not None:
         init_fn, step_fn = shard_chunk_fns(
             init_fn, step_fn, mesh, n_init_args=parts.n_operands + 1)
@@ -346,11 +371,12 @@ def batch_chunk_checker(parts: KernelParts, mesh=None, jit: bool = True):
 def shard_chunk_fns(init_fn, step_fn, mesh, n_init_args: int):
     """Wrap a vmapped (init_fn, step_fn) chunk-kernel pair in
     `shard_map` over the batch axis of `mesh`. P(axis) acts as a pytree
-    prefix over the carry dict (every leaf is batch-leading), and the
-    replication check is off for the same reason as the monolithic
-    sharded checkers: the computation is per-shard independent by
-    construction (parallel/mesh.py). Lazy import — parallel.mesh
-    imports the ops package at load time."""
+    prefix over the carry dict (every leaf is batch-leading), the
+    span's two scalars are replicated, and the replication check is off
+    for the same reason as the monolithic sharded checkers: the
+    computation is per-shard independent by construction
+    (parallel/mesh.py). Lazy import — parallel.mesh imports the ops
+    package at load time."""
     from jax.sharding import PartitionSpec as P
 
     from jax import shard_map
@@ -359,7 +385,8 @@ def shard_chunk_fns(init_fn, step_fn, mesh, n_init_args: int):
     init_sm = shard_map(init_fn, mesh=mesh,
                         in_specs=(spec,) * n_init_args, out_specs=spec,
                         check_vma=False)
-    step_sm = shard_map(step_fn, mesh=mesh, in_specs=(spec, spec),
+    step_sm = shard_map(step_fn, mesh=mesh,
+                        in_specs=(spec, spec, P(), P()),
                         out_specs=(spec,) * 5, check_vma=False)
     return init_sm, step_sm
 

@@ -36,8 +36,10 @@ from collections import deque
 from pathlib import Path
 from typing import Optional, Sequence
 
-from ..checker.schedule import snapshot_compiles, snapshot_spans, span
+from ..checker.schedule import (snapshot_compiles, snapshot_spans,
+                                snapshot_stats, span)
 from ..platform import install_compile_counters
+from . import buildahead
 from .admission import (AdmissionQueue, QueueFull, ResultCache,
                         ServiceStopped)
 from .journal import AdmissionJournal, decode_request, journal_enabled
@@ -204,6 +206,11 @@ class CheckingService:
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._started = False
+        #: set once the build-ahead of the keys this service can know
+        #: has run (ISSUE 32); `/stats` serves it as `warm`
+        self._warm = threading.Event()
+        self._build_ahead_info = {"source": "none", "keys": 0,
+                                  "programs": 0}
         self._worker: Optional[threading.Thread] = None
         self._latencies: deque = deque(maxlen=LATENCY_WINDOW)  # guarded_by(_lock)
         # Durability/resilience tier (ISSUE 8).
@@ -504,6 +511,8 @@ class CheckingService:
         self._started = True
         if self.cluster is not None:
             self.cluster.start()
+        if not self.scheduler.fastlane_enabled:
+            self._warm.set()   # an injected check_fn has no kernels
         self._ensure_worker()
 
     def _ensure_worker(self) -> None:
@@ -619,6 +628,7 @@ class CheckingService:
         forms batches and routes each to the least-loaded shard's
         executor, so independent shape buckets run concurrently."""
         tid = threading.get_ident()
+        self._build_ahead()
         while not self._stop.is_set() and not self._abandoned():
             batch = self.scheduler.next_batch(
                 timeout=IDLE_POLL_S, on_decided=self._fastlane_done)
@@ -650,6 +660,26 @@ class CheckingService:
                 # and routing: fail the batch loudly, like the drains.
                 self.shards.done(k, rows)
                 self._fail_unexecuted(batch)
+
+    def _build_ahead(self) -> None:
+        """Before the first batch is taken: build the launch-shape sets
+        of the keys this service can know (`buildahead`). It runs here,
+        on the dispatcher's side and not in the constructor, so that it
+        overlaps whatever the deployment does between starting graftd
+        and sending to it; requests that arrive meanwhile wait in the
+        admission queue — acknowledged after their WAL fsync, answered
+        by the kernels. A failure is logged and costs only the pause a
+        key's first launch then takes."""
+        if self._warm.is_set():
+            return
+        try:
+            self._build_ahead_info = buildahead.build_at_start(
+                self.scheduler.max_batch_rows, stop=self._stop.is_set)
+        except Exception:
+            LOG.exception("%s build-ahead failed; keys will be built "
+                          "on first sight", self.name)
+        finally:
+            self._warm.set()
 
     def _fastlane_done(self, done) -> None:
         """Account requests the dispatch fast lane decided (ISSUE 14):
@@ -697,6 +727,7 @@ class CheckingService:
                              "see service log")
             self._account_requests(batch)
         self._write_traces(batch)
+        buildahead.record_keys()
 
     def _write_traces(self, reqs) -> None:
         """The records of a batch's requests, written on the worker's
@@ -1067,6 +1098,12 @@ class CheckingService:
         # interrupted
         out["spans"] = snapshot_spans()
         out.update(snapshot_compiles())
+        # ISSUE 32: `warm` once the build-ahead at start has run, and
+        # what it built (`shape_misses`, `programs_built_ahead` and the
+        # `build.ahead` span are process-wide, above)
+        out["host_routed_rows"] = snapshot_stats()["host_routed_rows"]
+        out["warm"] = self._warm.is_set()
+        out["build_ahead"] = dict(self._build_ahead_info)
         # the host certifier's counters (process-wide, like the spans):
         # rows_delivered / rows_scanned is the hit share its gate routes
         # on, rows_gated / (rows_gated + rows_scanned) how often it

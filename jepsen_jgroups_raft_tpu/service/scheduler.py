@@ -194,10 +194,15 @@ class BatchScheduler:
             # daemon. Multi-host graftd is shard-routed per host
             # instead: one daemon per host, each with its own workers
             # (doc/checker-design.md §10).
+            # serve_rows (ISSUE 32): a key is built whole for this
+            # scheduler's largest batch before its first launch, and a
+            # launch plan is what memory held when graftd started, or
+            # the default; nothing is measured on this thread.
             return check_encoded(encs, model, algorithm=algorithm,
                                  distribute=False,
                                  consistency=consistency,
-                                 lin_fastpath=lin_fastpath)
+                                 lin_fastpath=lin_fastpath,
+                                 serve_rows=self.max_batch_rows)
 
         #: device-path seam (tests inject failures / gates here).
         self.check_fn = check_fn or _check_local

@@ -59,10 +59,14 @@ def run(args) -> None:
         traffic = json.load(f)
     if args.ops:
         config["ops_per_history"] = args.ops
+    if args.kind != config["history_kind"]:
+        # the same deployment over the other kind of history (ISSUE 32:
+        # the register's dense domain kernels beside the counter's mask)
+        config.update(history_kind=args.kind, service_workload=args.kind)
     import jax
 
     sched = BatchScheduler(AdmissionQueue())
-    out = {"arm": args.arm, "root": root,
+    out = {"arm": args.arm, "root": root, "kind": args.kind,
            "device": jax.devices()[0].device_kind, "seeds": {}}
     for seed in args.seeds:
         reqs = [admit([build_history(rows) for rows in req],
@@ -132,6 +136,9 @@ def main() -> None:
                             2801000039, 2801000043, 2801000057])
     r.add_argument("--requests", type=int, default=4,
                    help="requests a seed (32 histories each)")
+    r.add_argument("--kind", choices=["counter", "register"],
+                   default="counter",
+                   help="kind of history (default: the configuration's)")
     r.add_argument("--ops", type=int, default=0,
                    help="ops a history (default: the configuration's)")
     r.set_defaults(fn=run)

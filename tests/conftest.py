@@ -10,6 +10,9 @@ hasn't at conftest import time.
 
 import os
 import sys
+import time
+
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -43,3 +46,17 @@ os.environ.setdefault("JGRAFT_LIN_FASTPATH", "0")
 # out of kernel suites), and group commit only coalesces fsyncs.
 # Their differential tests (tests/test_hostpath_turbo.py) pin both
 # arms explicitly.
+
+
+@pytest.fixture(autouse=True)
+def _a_bench_run_in_process_starts_its_own_clock(request, monkeypatch):
+    """`benchmarks/run.py` counts its deadlines from its own import: a
+    process is one run. tests/benchmark_harness also runs `run_cell`
+    INSIDE the xdist worker, which imported the module when it
+    collected, so those runs had what was left of the pool's 150 s
+    after everything the worker ran before them, and passed or failed
+    with the length of the suite (PERF.md section 7). Their clock
+    starts with their test, as a run's does."""
+    run = sys.modules.get("benchmarks.run")
+    if run is not None and "benchmark_harness" in request.node.nodeid:
+        monkeypatch.setattr(run, "T0", time.monotonic())

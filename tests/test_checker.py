@@ -509,6 +509,11 @@ def test_platform_router_policy(monkeypatch):
             return ["cpu0"]
 
     monkeypatch.setitem(__import__("sys").modules, "jax", FakeJax)
+    # the measured default routes nothing (PR 32's re-reading)
+    assert lin.PLATFORM_ROUTE_MIN_CELLS == 0
+    assert lin._route_group_to_host(8, 32) is False
+    # an operator's gate (JGRAFT_ROUTE_MIN_CELLS), read at import
+    monkeypatch.setattr(lin, "PLATFORM_ROUTE_MIN_CELLS", 64_000)
     assert lin._route_group_to_host(8, 32) is True        # tiny → host
     assert lin._route_group_to_host(1000, 2048) is False  # big → chip
     monkeypatch.setenv("JGRAFT_PLATFORM_ROUTE", "tpu")

@@ -362,7 +362,7 @@ def test_malformed_route_gate_does_not_crash_import():
              "JGRAFT_ROUTE_MIN_CELLS": "sixty-four-thousand"},
     )
     assert out.returncode == 0, out.stderr
-    assert out.stdout.strip().splitlines()[-1] == "64000"
+    assert out.stdout.strip().splitlines()[-1] == "0"
 
 
 def test_degraded_platform_note_in_results(monkeypatch):

@@ -77,7 +77,8 @@ def _chunked_verdicts(init_fn, step_fn, events, n_events, val_of=None,
     out_ovf = np.zeros((B,), bool)
     recorded = np.zeros((B,), bool)
     for lo in range(0, e_pad, chunk):
-        carry, dec, exh, ok, ovf = step_fn(carry, events[:, lo:lo + chunk])
+        # the whole stream is the operand; the span is two scalars
+        carry, dec, exh, ok, ovf = step_fn(carry, events, lo, chunk)
         done = (np.asarray(dec) | np.asarray(exh)) & ~recorded
         out_ok[done] = np.asarray(ok)[done]
         out_ovf[done] = np.asarray(ovf)[done]
@@ -227,7 +228,7 @@ class TestIrPieces:
         carry = init_fn(plan.val_of, batch["n_events"])
         saw_decided = False
         for lo in range(0, e_pad, 4):
-            carry, dec, exh, ok, _ = step_fn(carry, padded[:, lo:lo + 4])
+            carry, dec, exh, ok, _ = step_fn(carry, padded, lo, 4)
             dec, ok = np.asarray(dec), np.asarray(ok)
             assert (dec == ~ok).all()
             saw_decided = saw_decided or dec[0]

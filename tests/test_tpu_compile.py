@@ -132,8 +132,9 @@ def compile_chunk_pair(fns, init_args, events):
     init_fn, step_fn = fns
     lowered = init_fn.lower(*init_args)
     lowered.compile()
+    # a span's offset and length are traced scalars of the step program
     return compile_for(step_fn, carry_like(lowered, events.sharding),
-                       events)
+                       events, np.int32(0), np.int32(events.shape[1]))
 
 
 def test_sort_chunk_pair_compiles(one_chip):
