@@ -101,7 +101,6 @@ KNOB_CLASS: Dict[str, str] = {
     # the rest of the linfp family; verdicts never depend on it.
     "JGRAFT_LINFP_DIR": ROUTING,
     "JGRAFT_MACRO_EVENTS": ROUTING,
-    "JGRAFT_MERGE_ALL": ROUTING,
     "JGRAFT_MERGE_LONG": ROUTING,
     "JGRAFT_PLATFORM_ROUTE": ROUTING,
     "JGRAFT_ROUTE_MIN_CELLS": ROUTING,

@@ -125,11 +125,6 @@ def dense_plan(model, encs: Sequence[EncodedHistory]) -> Optional[DensePlan]:
     return None
 
 
-#: Don't launch a dense kernel for fewer histories than this — merge the
-#: stragglers into the next-wider window group instead (launch + compile
-#: amortization beats a snugger W for tiny groups).
-DENSE_MIN_GROUP = 16
-
 #: Past this event count a history counts as LONG: launch amortization
 #: stops being the story and scan depth becomes it (see
 #: _merge_long_groups for the round-5 policy reversal).
@@ -143,21 +138,6 @@ MERGE_MAX_EVENTS = 4096
 #: 6..9); beyond it, clusters launch separately (still merged within
 #: each cluster).
 MERGE_LONG_MAX_SPREAD = 3
-
-
-def _merge_all_groups() -> bool:
-    """Experimental (JGRAFT_MERGE_ALL=1, off by default): extend the
-    merged-launch policy to SHORT histories too — one spread-capped
-    cluster per window neighborhood instead of per-window launches.
-    The same serial-depth argument applies (the north-star batch's 4
-    window groups scan ~8200 sequential steps where one W=8 launch
-    would scan ~2050 at ~1.9× the per-step cell work), but whether the
-    chip is latency- or throughput-bound at B≈1000 × [256,4] frontiers
-    is an open on-chip measurement (scripts/ab_merge_long.py --all);
-    short histories also lack the uniform event lengths that make
-    merging free for the long configs, so this stays opt-in until the
-    chip says otherwise."""
-    return os.environ.get("JGRAFT_MERGE_ALL", "0") == "1"
 
 
 def _merge_long_groups() -> bool:
@@ -204,14 +184,183 @@ def _pad_domains(domains, idxs):
     return S_b, val_of
 
 
+@dataclass(frozen=True)
+class GroupCost:
+    """What one window group of SHORT histories costs a backend: a
+    table of readings — seconds of `check_encoded` (pack, launch, the
+    wavefront's blocking reads) for ONE group, by kernel kind, padded
+    states, launch window and rows — interpolated. No closed form over
+    rows, 2^W · S and steps came within 15 % of the chip's readings (its
+    cost a row rises between 128 and 256 rows, sooner the wider the
+    window, and the two kernels differ), so the readings are the model.
+
+    rows: the row counts measured (launch buckets), ascending.
+    ms: kind -> {S: {W: milliseconds at each of `rows`}}; a window's
+        tuple may stop short of the largest counts.
+    steps: kind -> the scan length (longest member's LEGACY events,
+        under the default macro stream) the table was measured at; a
+        group's cost scales with its own but for `fixed_ms[kind]`, what
+        a group costs before its first step.
+    """
+
+    rows: tuple
+    ms: dict
+    steps: dict
+    fixed_ms: dict
+
+    def _ms(self, table: dict, w: int, rows: int) -> float:
+        at = table[w]
+        top = self.rows[len(at) - 1]
+        if rows > top:  # past the table: as many times the last reading
+            return at[-1] * rows / top
+        return float(np.interp(rows, self.rows[:len(at)], at))
+
+    def seconds(self, kind: str, w: int, states: int, rows: int,
+                steps: int) -> float:
+        """One group of `rows` (a launch bucket) at window `w`, padded
+        `states`, `steps` events."""
+        by_s = self.ms[kind]
+        # more states than were measured: booked as the window with as
+        # many cells
+        while states > max(by_s):
+            w, states = w + 1, states // 2
+        table = by_s[min(s for s in by_s if s >= states)]
+        lo, hi = min(table), max(table)
+        ms = self._ms(table, min(max(w, lo), hi), rows)
+        if w > hi:
+            # a window past the table: the widest measured, times what
+            # that cost over its narrower neighbour, a window
+            ms *= (ms / self._ms(table, hi - 1, rows)) ** (w - hi)
+        fixed = self.fixed_ms[kind]
+        return (fixed + (ms - fixed) * steps / self.steps[kind]) / 1e3
+
+
+#: One `TPU v5 lite` chip: the second sweep of ISSUE 33
+#: (`scripts/sweep_group_cost.py run`, then `table`: medians of five
+#: warm runs of ONE group whose every row is as wide as its launch —
+#: the batched closure waits for its widest row —, made monotone in W
+#: and S, gaps filled from the next S; the half-length readings
+#: `fixed_ms` comes from, and how the partitions the table picks
+#: compare with the fastest measured: PERF.md section 6, PR 33). S 4
+#: was read up to 128 rows (larger batches of the cell hold a history
+#: of five values).
+TPU_GROUP_COST = GroupCost(
+    rows=(8, 32, 64, 128, 256, 512, 1024),
+    ms={"mask": {1: {
+            5: (42.0, 71.0, 107.2, 199.7, 361.1, 822.3, 1796.9),
+            6: (56.7, 77.4, 111.6, 199.7, 361.1, 822.3, 1796.9),
+            7: (56.7, 77.4, 111.6, 199.7, 382.9, 822.3, 1936.7),
+            8: (62.4, 88.1, 123.6, 202.9, 414.4, 883.5, 2106.5),
+            9: (74.1, 111.0, 154.5, 235.2, 563.4),
+            10: (106.2, 150.1, 210.8, 294.0, 733.2)}},
+        "domain": {
+            4: {
+                5: (32.1, 44.4, 63.3, 101.9),
+                6: (40.9, 53.0, 75.7, 126.0),
+                7: (48.8, 68.7, 97.5, 159.3),
+                8: (63.3, 99.8, 143.9, 218.6),
+                9: (86.1, 133.8, 209.2, 360.0),
+                10: (134.6, 209.0, 338.0, 511.9)},
+            8: {
+                5: (32.1, 45.2, 65.7, 106.5, 193.2, 448.3, 910.0),
+                6: (40.9, 55.1, 78.6, 133.3, 242.3, 589.3, 1134.0),
+                7: (48.8, 75.8, 101.7, 170.2, 356.8, 773.0, 1698.3),
+                8: (63.3, 105.4, 159.4, 249.8, 526.8, 1095.7, 2373.2),
+                9: (86.1, 142.7, 209.2, 360.0, 810.4),
+                10: (134.6, 248.0, 338.0, 511.9, 1153.1)}}},
+    steps={"mask": 2000, "domain": 1614},
+    fixed_ms={"mask": 5.6, "domain": 19.1})
+
+#: Off the TPU no cost is fitted and a window group is one window: the
+#: host mesh is throughput-bound at these widths (merged launches
+#: measured 0.61 against 1.34 hist/s, `_merge_long_groups`). A window
+#: of fewer rows than this rides with the next wider one, the rule
+#: every backend had before ISSUE 33: a launch and its compile are not
+#: worth a handful of rows.
+HOST_MIN_GROUP = 16
+
+
+def _group_cost() -> Optional[GroupCost]:
+    """The cost short window groups are formed by, keyed by the backend
+    like `_merge_long_groups` and `hoist_transitions`: the chip's, or
+    None where none is fitted (one group a window)."""
+    import jax
+
+    return TPU_GROUP_COST if jax.default_backend() == "tpu" else None
+
+
+def best_partition(kind: str, windows: Sequence[tuple],
+                   cost: Optional[GroupCost], shards: int = 1) -> list:
+    """Partition one kind's windows into launch groups.
+
+    `windows`: (W, rows, states, steps) per window, W ascending —
+    `states` the widest domain among its rows (1 for "mask"), `steps`
+    its longest history's events. Returns blocks of indices into
+    `windows`, each a run of neighbours that launches as ONE group at
+    its widest window.
+
+    With a `cost` that is the partition whose summed `cost.seconds` is
+    least, found exactly (a kind has a handful of windows, and a block
+    is a run of neighbours: folding a window into a wider group without
+    the windows between them never pays). A group's rows count as the
+    launch will pad them (`schedule.launch_rows`), its states as
+    `_pad_domains` will. A domain merge whose padded frontier would
+    pass DENSE_MAX_CELLS is no candidate — every history here is
+    dense-eligible alone and merging must never shed one — while a
+    window on its own always is one (what `flush` does with it is
+    today's). Ties go to fewer groups.
+
+    Without a `cost`: one group a window, windows under HOST_MIN_GROUP
+    rows pushed up into the next."""
+    if cost is None:
+        blocks, cur, n = [], [], 0
+        for k, (_, rows, _, _) in enumerate(windows):
+            cur.append(k)
+            n += rows
+            if n >= HOST_MIN_GROUP:
+                blocks.append(cur)
+                cur, n = [], 0
+        return blocks + [cur] if cur else blocks
+    from ..checker.schedule import launch_rows
+
+    def seconds(i: int, j: int) -> float:
+        """windows[i..j] as one group."""
+        rows = sum(w[1] for w in windows[i:j + 1])
+        states = 1
+        while states < max(w[2] for w in windows[i:j + 1]):
+            states *= 2
+        cells = (1 << windows[j][0]) * states
+        if kind == "domain" and j > i and cells > DENSE_MAX_CELLS:
+            return float("inf")
+        return cost.seconds(kind, windows[j][0], states,
+                            launch_rows(rows, shards),
+                            max(w[3] for w in windows[i:j + 1]))
+
+    # best[j]: (seconds, groups, blocks) of the cheapest partition of
+    # windows[:j]
+    best = [(0.0, 0, [])]
+    for j in range(len(windows)):
+        best.append(min(
+            (best[i][0] + seconds(i, j), best[i][1] + 1,
+             best[i][2] + [list(range(i, j + 1))])
+            for i in range(j + 1)))
+    return best[-1][2]
+
+
 def dense_plans_grouped(model, encs: Sequence[EncodedHistory]):
     """Route each history of a batch to its cheapest dense kernel.
 
     Returns (groups, rest): `groups` is [(indices, DensePlan)] over the
-    dense-eligible histories, partitioned by kernel kind and concurrency
-    window — kernel cost is exponential in W and a real batch's windows
-    spread with per-history crash counts (the north-star batch measures
-    W=5..8), so snug per-group windows beat one batch-max kernel ~1.7×.
+    dense-eligible histories, partitioned by kernel kind and then into
+    WINDOW GROUPS, each launched at its widest member's window. A real
+    batch's windows spread with per-history crash counts (the
+    north-star batch measures W=5..8) and a step's work is exponential
+    in W, but a group also costs a launch and a thousand step latencies
+    before its first row: which windows share a launch is
+    `best_partition`'s answer for the backend's measured `GroupCost`
+    (on the chip a served 128-row batch is ONE group at W 8; off it, a
+    group a window). LONG histories (> MERGE_MAX_EVENTS) keep their own
+    policy, `_merge_long_groups`.
     `rest` holds the indices that need the sort-kernel ladder (window or
     domain beyond the dense caps); eligibility is per history, so one
     oversized history no longer drags the whole batch off the dense path.
@@ -262,94 +411,96 @@ def dense_plans_grouped(model, encs: Sequence[EncodedHistory]):
         return (pending, DensePlan("domain", w_eff, S, val_of))
 
     merge_long = _merge_long_groups()
-    # JGRAFT_MERGE_LONG=0 is the absolute off-switch: it forbids the
-    # experimental MERGE_ALL mode too (an operator pinning =0 on a host
-    # must never get merged launches by adding the experiment knob).
-    merge_all = (_merge_all_groups()
-                 and os.environ.get("JGRAFT_MERGE_LONG") != "0")
+    cost = _group_cost()
+    shards = 1
+    if cost is not None:
+        # rows pad to a multiple of the placement's shard count
+        from ..parallel.mesh import chunk_sharding
+
+        mesh = getattr(chunk_sharding(), "mesh", None)
+        shards = int(mesh.size) if mesh is not None else 1
+
+    def emit(kind, pending):
+        g = flush(kind, pending)
+        if g is not None:
+            groups.append(g)
+
+    def emit_run(kind, run):
+        """A run of neighbouring SHORT windows, as the backend's cost
+        partitions it."""
+        stats = [(w, len(buckets[(kind, w)]),
+                  max(len(domains[i]) for i in buckets[(kind, w)])
+                  if kind == "domain" else 1,
+                  max(encs[i].n_events for i in buckets[(kind, w)]))
+                 for w in run]
+        for block in best_partition(kind, stats, cost, shards):
+            emit(kind, [i for k in block for i in buckets[(kind, run[k])]])
+
     for kind in ("domain", "mask"):
         windows = sorted(w for k, w in buckets if k == kind)
-        if merge_long or merge_all:
+        long_pool = [i for w in windows for i in buckets[(kind, w)]
+                     if encs[i].n_events > MERGE_MAX_EVENTS] \
+            if merge_long else []
+        if long_pool:
             # Merge long histories of this kind into window-proximate
-            # cluster launches (see _merge_long_groups). Under the
-            # experimental MERGE_ALL, SHORT histories cluster too — but
-            # in a SEPARATE pool per event-length class: merging a
-            # short history into a long launch would pad its event
-            # stream E_long/E_short×, which no launch saving repays.
-            # Shorts not pooled here keep the per-window path below.
-            pools = []
-            long_pool = [i for w in windows for i in buckets[(kind, w)]
-                         if encs[i].n_events > MERGE_MAX_EVENTS]
-            if long_pool:
-                pools.append(long_pool)
-            if merge_all:
-                short_pool = [i for w in windows
-                              for i in buckets[(kind, w)]
-                              if encs[i].n_events <= MERGE_MAX_EVENTS]
-                if short_pool:
-                    pools.append(short_pool)
-            pooled = set(i for p in pools for i in p)
-            if pooled:
-                for w in windows:
-                    buckets[(kind, w)] = [
-                        i for i in buckets[(kind, w)] if i not in pooled]
-                windows = [w for w in windows if buckets[(kind, w)]]
-            for pool in pools:
-                by_w = sorted(pool, key=lambda i: encs[i].n_slots,
-                              reverse=True)
-                while by_w:
-                    w_top = encs[by_w[0]].n_slots
-                    cut = w_top - MERGE_LONG_MAX_SPREAD
-                    # Greedy take, re-checking the launch cell envelope
-                    # as members join (domains pad S to the cluster
-                    # max, pow2-bucketed): a member whose domain would
-                    # push 2^w_top · S_pad over the cap waits for a
-                    # later, narrower cluster instead of forcing flush
-                    # to shed the WIDEST member to the sort ladder —
-                    # every history here is dense-eligible alone and
-                    # must stay on the dense path. (A singleton always
-                    # fits: per-history eligibility used its own W and
-                    # unpadded S, and pow2 padding cannot double past
-                    # the cap at these sizes.)
-                    take, rest_pool, s_run = [], [], 1
-                    for i in by_w:
-                        if encs[i].n_slots < cut:
-                            rest_pool.append(i)
-                            continue
-                        s_new = max(s_run, len(domains[i])
-                                    if kind == "domain" else 1)
-                        s_pad = 1
-                        while s_pad < s_new:
-                            s_pad *= 2
-                        if take and (1 << w_top) * s_pad > DENSE_MAX_CELLS:
-                            rest_pool.append(i)
-                            continue
-                        take.append(i)
-                        s_run = s_new
-                    by_w = rest_pool
-                    g = flush(kind, take)
-                    if g is not None:
-                        groups.append(g)
-        pending: list = []
+            # cluster launches (see _merge_long_groups). Shorts never
+            # join them: a short history in a long launch would pad its
+            # event stream E_long/E_short×, which no launch saving
+            # repays.
+            pooled = set(long_pool)
+            for w in windows:
+                buckets[(kind, w)] = [
+                    i for i in buckets[(kind, w)] if i not in pooled]
+            windows = [w for w in windows if buckets[(kind, w)]]
+            by_w = sorted(long_pool, key=lambda i: encs[i].n_slots,
+                          reverse=True)
+            while by_w:
+                w_top = encs[by_w[0]].n_slots
+                cut = w_top - MERGE_LONG_MAX_SPREAD
+                # Greedy take, re-checking the launch cell envelope
+                # as members join (domains pad S to the cluster
+                # max, pow2-bucketed): a member whose domain would
+                # push 2^w_top · S_pad over the cap waits for a
+                # later, narrower cluster instead of forcing flush
+                # to shed the WIDEST member to the sort ladder —
+                # every history here is dense-eligible alone and
+                # must stay on the dense path. (A singleton always
+                # fits: per-history eligibility used its own W and
+                # unpadded S, and pow2 padding cannot double past
+                # the cap at these sizes.)
+                take, rest_pool, s_run = [], [], 1
+                for i in by_w:
+                    if encs[i].n_slots < cut:
+                        rest_pool.append(i)
+                        continue
+                    s_new = max(s_run, len(domains[i])
+                                if kind == "domain" else 1)
+                    s_pad = 1
+                    while s_pad < s_new:
+                        s_pad *= 2
+                    if take and (1 << w_top) * s_pad > DENSE_MAX_CELLS:
+                        rest_pool.append(i)
+                        continue
+                    take.append(i)
+                    s_run = s_new
+                by_w = rest_pool
+                emit(kind, take)
+
+        run: list = []
         for w in windows:
             bucket = buckets[(kind, w)]
-            long_bucket = any(encs[i].n_events > MERGE_MAX_EVENTS
-                              for i in bucket)
-            if long_bucket and pending:
-                # Flush accumulated short stragglers FIRST: merging them
-                # into the long launch would pad their event streams to
-                # the long history's length (E dominates kernel work).
-                g = flush(kind, pending)
-                if g is not None:
-                    groups.append(g)
-                pending = []
-            pending += bucket
-            min_group = 1 if long_bucket else DENSE_MIN_GROUP
-            if len(pending) >= min_group or w == windows[-1]:
-                g = flush(kind, pending)
-                if g is not None:
-                    groups.append(g)
-                pending = []
+            if any(encs[i].n_events > MERGE_MAX_EVENTS for i in bucket):
+                # A window that holds a long history (the merge above is
+                # off) launches alone, the shorts before it FIRST:
+                # merging them into the long launch would pad their
+                # event streams to the long history's length (E
+                # dominates kernel work).
+                emit_run(kind, run)
+                run = []
+                emit(kind, bucket)
+            else:
+                run.append(w)
+        emit_run(kind, run)
     return groups, rest
 
 

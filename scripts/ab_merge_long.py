@@ -12,6 +12,10 @@ spanned 249-677 hist/s).
 Runs the PRODUCTION path (check_histories, auto routing) with
 JGRAFT_MERGE_LONG flipped per rep, interleaved in one process.
 
+SHORT histories have no such knob: which of their windows share a
+launch is the backend's measured cost's answer (ops/dense_scan.py
+`best_partition`; scripts/sweep_group_cost.py measures it).
+
 Usage: python scripts/ab_merge_long.py [--reps 5]
 """
 import argparse
@@ -26,15 +30,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=5)
-    ap.add_argument("--n-histories", type=int, default=None,
-                    help="default: 16 (config-4 mode) / 1000 (--all)")
-    ap.add_argument("--n-ops", type=int, default=None,
-                    help="default: 10000 (config-4 mode) / 1000 (--all)")
-    ap.add_argument("--all", action="store_true",
-                    help="A/B JGRAFT_MERGE_ALL on the north-star shape "
-                         "(short histories; per-window vs one merged "
-                         "spread-capped cluster) instead of the long-"
-                         "history config-4 shape")
+    ap.add_argument("--n-histories", type=int, default=16)
+    ap.add_argument("--n-ops", type=int, default=10_000)
     args = ap.parse_args()
 
     import random
@@ -45,24 +42,10 @@ def main() -> None:
 
     rng = random.Random(3)
     model = CasRegister()
-    if args.all:
-        defaults, crash_p, max_crashes = (1000, 1000), 0.05, 3
-        knob = "JGRAFT_MERGE_ALL"
-        # An inherited JGRAFT_MERGE_LONG=0 is the absolute off-switch
-        # that would silently disable BOTH variants of this A/B.
-        if os.environ.pop("JGRAFT_MERGE_LONG", None) == "0":
-            print("# note: clearing inherited JGRAFT_MERGE_LONG=0 for "
-                  "the --all A/B (it forbids MERGE_ALL outright)")
-    else:
-        defaults, crash_p, max_crashes = (16, 10_000), 0.02, 4
-        knob = "JGRAFT_MERGE_LONG"
-    n_hist = args.n_histories if args.n_histories else defaults[0]
-    n_ops = args.n_ops if args.n_ops else defaults[1]
-    hists = [random_valid_history(rng, "register", n_ops=n_ops,
-                                  n_procs=5, crash_p=crash_p,
-                                  max_crashes=max_crashes)
-             for _ in range(n_hist)]
-    args.n_histories = n_hist
+    knob = "JGRAFT_MERGE_LONG"
+    hists = [random_valid_history(rng, "register", n_ops=args.n_ops,
+                                  n_procs=5, crash_p=0.02, max_crashes=4)
+             for _ in range(args.n_histories)]
 
     def run(merged: bool):
         os.environ[knob] = "1" if merged else "0"

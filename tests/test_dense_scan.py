@@ -306,7 +306,7 @@ def test_early_flush_keeps_stragglers_window_snug():
 
     m = CasRegister()
     rng = random.Random(4)
-    # A few short narrow histories (below DENSE_MIN_GROUP)...
+    # A few short narrow histories (below HOST_MIN_GROUP)...
     short = [encode_history(
         random_valid_history(rng, "register", n_ops=10, n_procs=2,
                              crash_p=0.0), m) for _ in range(3)]
@@ -474,37 +474,224 @@ def test_hoist_styles_verdict_parity(monkeypatch):
     assert verdicts["1"][0] is True
 
 
-def test_merge_all_pools_by_event_length(monkeypatch):
-    """JGRAFT_MERGE_ALL clusters short histories in their OWN pool: a
-    short history must never ride in a long launch (its event stream
-    would pad E_long/E_short x), even when windows are proximate."""
-    from jepsen_jgroups_raft_tpu.history.synth import random_valid_history
-    from jepsen_jgroups_raft_tpu.ops.dense_scan import (MERGE_MAX_EVENTS,
-                                                        dense_plans_grouped)
+# ISSUE 33: window groups are formed by what they cost the backend.
+# (W, rows, states, steps) a window; steps ~2000 and ~1620 are the
+# benchmark cell's 1k-op counter and register histories. The batches
+# marked "measured" are those the chip sweep timed partition by
+# partition (PERF.md section 6, PR 33): the pick is the fastest
+# measured, or within 2 % of it.
+_E = 1998
+_R = 1616
 
-    monkeypatch.setenv("JGRAFT_MERGE_ALL", "1")
-    monkeypatch.delenv("JGRAFT_MERGE_LONG", raising=False)
-    m = CasRegister()
-    rng = random.Random(21)
-    long_encs = [encode_history(
-        random_valid_history(rng, "register", n_ops=MERGE_MAX_EVENTS + 256,
-                             n_procs=5, crash_p=0.02, max_crashes=3), m)
-        for _ in range(3)]
-    short_encs = [encode_history(
-        random_valid_history(rng, "register", n_ops=40, n_procs=5,
-                             crash_p=0.05, max_crashes=3), m)
-        for _ in range(6)]
-    encs = long_encs + short_encs
-    is_long = [e.n_events > MERGE_MAX_EVENTS for e in encs]
-    assert all(is_long[:3]) and not any(is_long[3:])
-    groups, rest = dense_plans_grouped(m, encs)
+
+def _tpu():
+    from jepsen_jgroups_raft_tpu.ops.dense_scan import TPU_GROUP_COST
+
+    return TPU_GROUP_COST
+
+
+@pytest.mark.parametrize("kind, windows, cost, want", [
+    # a served 128-row counter batch of the cell: one launch at W 8
+    ("mask", [(6, 26, 1, _E), (7, 61, 1, _E), (8, 41, 1, _E)], _tpu,
+     [[0, 1, 2]]),
+    # measured: 128 and 256 rows of the cell, both kinds
+    ("mask", [(5, 5, 1, _E), (6, 27, 1, _E), (7, 58, 1, _E),
+              (8, 38, 1, _E)], _tpu, [[0, 1, 2, 3]]),
+    ("domain", [(5, 4, 4, _R), (6, 32, 4, _R), (7, 59, 5, _R),
+                (8, 33, 5, _R)], _tpu, [[0, 1, 2, 3]]),
+    ("mask", [(5, 9, 1, _E), (6, 58, 1, _E), (7, 116, 1, _E),
+              (8, 73, 1, _E)], _tpu, [[0, 1, 2, 3]]),
+    # ... where the domain kernel's rows at W 8 cost half as much again
+    # as at W 7, and past 128 rows a launch no longer hides it
+    ("domain", [(5, 9, 4, _R), (6, 65, 4, _R), (7, 108, 5, _R),
+                (8, 74, 5, _R)], _tpu, [[0, 1, 2], [3]]),
+    # measured: the library's 1000-row batch, where every group is
+    # long enough to amortise its launch and width decides
+    ("mask", [(5, 32, 1, _E), (6, 230, 1, _E), (7, 440, 1, _E),
+              (8, 298, 1, _E)], _tpu, [[0], [1], [2], [3]]),
+    ("domain", [(5, 30, 5, _R), (6, 243, 5, _R), (7, 426, 5, _R),
+                (8, 301, 5, _R)], _tpu, [[0], [1], [2], [3]]),
+    # a window alone
+    ("mask", [(7, 40, 1, _E)], _tpu, [[0]]),
+    # three stragglers beside 60 rows: their launch costs more than
+    # the width they add
+    ("mask", [(5, 3, 1, _E), (7, 60, 1, _E)], _tpu, [[0, 1]]),
+    # a step's cells outgrow the launch saved: W 10 stays apart from
+    # W 6 (the domain kernel at 60 rows, the mask kernel at 200)
+    ("domain", [(6, 60, 4, _R), (10, 60, 4, _R)], _tpu, [[0], [1]]),
+    ("mask", [(6, 200, 1, _E), (10, 200, 1, _E)], _tpu, [[0], [1]]),
+    # short histories never pay a long scan for a launch saved
+    ("mask", [(6, 60, 1, 600), (7, 60, 1, 4000)], _tpu, [[0], [1]]),
+    # a merge whose padded frontier passes DENSE_MAX_CELLS is no
+    # candidate: 2^10 * 16 cells
+    ("domain", [(7, 6, 16, _E), (10, 6, 8, _E)], _tpu, [[0], [1]]),
+    # windows under the table's narrowest are booked as that one
+    ("mask", [(2, 4, 1, 100), (3, 5, 1, 100), (4, 6, 1, 120)], _tpu,
+     [[0, 1, 2]]),
+    # off the TPU: a group a window, windows under 16 rows pushed up
+    ("mask", [(6, 26, 1, _E), (7, 61, 1, _E), (8, 41, 1, _E)],
+     lambda: None, [[0], [1], [2]]),
+    ("domain", [(5, 3, 4, _E), (6, 20, 4, _E), (7, 5, 4, _E)],
+     lambda: None, [[0, 1], [2]]),
+])
+def test_best_partition(kind, windows, cost, want):
+    from jepsen_jgroups_raft_tpu.ops.dense_scan import best_partition
+
+    assert best_partition(kind, windows, cost()) == want
+
+
+def test_group_cost_reads_its_table():
+    """A reading at a measured point is the table's; the scan's length
+    scales all but the fixed part; what the table does not hold is
+    booked from what it does."""
+    c = _tpu()
+    at = dict(zip(c.rows, c.ms["mask"][1][8]))
+    fixed = c.fixed_ms["mask"]
+    assert c.seconds("mask", 8, 1, 128, 2000) == pytest.approx(
+        at[128] / 1e3)
+    assert c.seconds("mask", 8, 1, 128, 1000) == pytest.approx(
+        (fixed + (at[128] - fixed) / 2) / 1e3)
+    assert c.seconds("mask", 8, 1, 96, 2000) == pytest.approx(
+        (at[64] + at[128]) / 2e3)
+    assert c.seconds("mask", 3, 1, 8, 2000) == \
+        c.seconds("mask", 5, 1, 8, 2000)
+    # rows past a window's last reading, a window past the table, 16
+    # states as one window more of 8, and 4 states from their own table
+    w9, w10 = c.ms["mask"][1][9], c.ms["mask"][1][10]
+    assert c.seconds("mask", 10, 1, 512, 2000) == pytest.approx(
+        2 * w10[-1] / 1e3)
+    assert c.seconds("mask", 11, 1, 8, 2000) == pytest.approx(
+        w10[0] * w10[0] / w9[0] / 1e3)
+    assert c.seconds("domain", 7, 16, 64, 1614) == \
+        c.seconds("domain", 8, 8, 64, 1614)
+    assert c.seconds("domain", 8, 4, 128, 1614) < \
+        c.seconds("domain", 8, 8, 128, 1614)
+    # a launch a window wider, or of more states, never reads cheaper
+    for by_s in c.ms.values():
+        for table in by_s.values():
+            for w in sorted(table)[1:]:
+                assert all(a >= b for a, b in zip(table[w], table[w - 1]))
+
+
+def _burst(model, window, n_ops, n_vals=3):
+    """Sequential churn, then `window` concurrent completed ops: a
+    history whose window is `window` exactly and whose register domain
+    holds the initial value and `n_vals` more."""
+    counter = isinstance(model, Counter)
+    h = History()
+    for i in range(n_ops):
+        v = 1 if counter else i % n_vals
+        h.append(Op(0, INVOKE, "add" if counter else "write", v))
+        h.append(Op(0, OK, "add" if counter else "write", v))
+    for p in range(window):
+        h.append(Op(p + 1, INVOKE, "add" if counter else "write",
+                    1 if counter else p % n_vals))
+    for p in range(window):
+        h.append(Op(p + 1, OK, "add" if counter else "write",
+                    1 if counter else p % n_vals))
+    return encode_history(h, model)
+
+
+@pytest.mark.parametrize("model", [CasRegister(), Counter()],
+                         ids=["domain", "mask"])
+def test_grouping_follows_the_backends_cost(model, monkeypatch):
+    """`dense_plans_grouped` over real encodings: merged at the widest
+    window under the chip's cost, today's partition off it."""
+    from jepsen_jgroups_raft_tpu.ops import dense_scan
+
+    encs = [_burst(model, w, 20) for w in (3, 3, 4, 5, 5, 5)]
+    groups, rest = dense_scan.dense_plans_grouped(model, encs)
     assert not rest
-    for idxs, _ in groups:
-        kinds = {is_long[i] for i in idxs}
-        assert len(kinds) == 1, f"mixed-length cluster: {idxs}"
-    # And the shorts really did cluster across windows (the experiment).
-    short_groups = [idxs for idxs, _ in groups if not is_long[idxs[0]]]
-    ws = sorted(encs[i].n_slots for g in short_groups for i in g)
-    if len({encs[i].n_slots for i in range(3, 9)}) > 1:
-        assert any(len({encs[i].n_slots for i in g}) > 1
-                   for g in short_groups)
+    # off the TPU six rows are stragglers of one group, as ever
+    assert [(sorted(i), p.n_slots) for i, p in groups] == \
+        [([0, 1, 2, 3, 4, 5], 5)]
+    many = [_burst(model, w, 20) for w in [3] * 16 + [4] * 3 + [5] * 17]
+    groups, _ = dense_scan.dense_plans_grouped(model, many)
+    assert [(len(i), p.n_slots) for i, p in groups] == \
+        [(16, 3), (20, 5)]
+    monkeypatch.setattr(dense_scan, "_group_cost", _tpu)
+    groups, rest = dense_scan.dense_plans_grouped(model, many)
+    assert not rest
+    assert [(len(i), p.n_slots) for i, p in groups] == [(36, 5)]
+
+
+def test_cost_merge_never_sheds_past_the_cell_cap(monkeypatch):
+    """Two windows that are dense-eligible alone and whose merge would
+    launch 2^10 * 16 cells stay two groups under the chip's cost;
+    nothing goes to the sort ladder."""
+    from jepsen_jgroups_raft_tpu.ops import dense_scan
+
+    m = CasRegister()
+    monkeypatch.setattr(dense_scan, "_group_cost", _tpu)
+    encs = [_burst(m, 10, 30, n_vals=7), _burst(m, 7, 30, n_vals=15),
+            _burst(m, 7, 30, n_vals=15)]
+    assert (1 << 10) * 16 > dense_scan.DENSE_MAX_CELLS
+    groups, rest = dense_scan.dense_plans_grouped(m, encs)
+    assert rest == []
+    assert sorted((sorted(i), p.n_slots, p.n_states)
+                  for i, p in groups) == [([0], 10, 8), ([1, 2], 7, 16)]
+
+
+@pytest.mark.parametrize("merge_long", ["0", "1"])
+def test_long_histories_keep_their_policy_under_the_cost(monkeypatch,
+                                                         merge_long):
+    """The chip's cost forms SHORT groups only: a long history launches
+    with its own (merged cluster, or its window's bucket), the shorts
+    around it never ride in that launch."""
+    from jepsen_jgroups_raft_tpu.ops import dense_scan
+
+    m = CasRegister()
+    monkeypatch.setattr(dense_scan, "_group_cost", _tpu)
+    monkeypatch.setenv("JGRAFT_MERGE_LONG", merge_long)
+    long_h = _burst(m, 6, dense_scan.MERGE_MAX_EVENTS // 2 + 8)
+    assert long_h.n_events > dense_scan.MERGE_MAX_EVENTS
+    encs = [_burst(m, 4, 20), _burst(m, 5, 20), long_h,
+            _burst(m, 7, 20), _burst(m, 8, 20)]
+    groups, rest = dense_scan.dense_plans_grouped(m, encs)
+    assert not rest
+    got = sorted((sorted(i), p.n_slots) for i, p in groups)
+    if merge_long == "1":
+        # the long pool first, then every short in one group
+        assert got == [([0, 1, 3, 4], 8), ([2], 6)]
+    else:
+        # the long window is a barrier between the runs of shorts
+        assert got == [([0, 1], 5), ([2], 6), ([3, 4], 8)]
+
+
+@pytest.mark.parametrize("kind, model", [("register", CasRegister()),
+                                         ("counter", Counter())])
+def test_merged_groups_verdict_parity(kind, model, monkeypatch):
+    """One launch at the widest window and a launch a window are the
+    same search over the same events: identical verdicts, planted
+    invalid rows included."""
+    from jepsen_jgroups_raft_tpu.ops import dense_scan
+
+    rng = random.Random(33)
+    hs = [random_valid_history(rng, kind, n_ops=60, n_procs=p,
+                               crash_p=0.1, max_crashes=c)
+          for p, c in [(2, 0), (3, 1), (5, 3), (4, 2), (5, 0), (3, 0),
+                       (5, 2), (2, 1)] * 3]
+    planted = (1, 6, 13, 20)
+    for k in planted:
+        bad, flipped = History(), False
+        for op in hs[k]:
+            if not flipped and op.type == OK and op.f == "read" \
+                    and op.value is not None:
+                op = Op(op.process, op.type, op.f, op.value + 100)
+                flipped = True
+            bad.append(op)
+        assert flipped
+        hs[k] = bad
+    encs = [encode_history(h, model) for h in hs]
+    assert len({e.n_slots for e in encs}) >= 3
+    verdicts, n_groups = {}, {}
+    for name, cost in (("per-window", lambda: None), ("merged", _tpu)):
+        monkeypatch.setattr(dense_scan, "_group_cost", cost)
+        n_groups[name] = len(dense_scan.dense_plans_grouped(model,
+                                                            encs)[0])
+        verdicts[name] = [r["valid?"] for r in check_histories(
+            hs, model, algorithm="jax")]
+    assert n_groups["merged"] == 1 < n_groups["per-window"]
+    assert verdicts["merged"] == verdicts["per-window"]
+    assert all(verdicts["merged"][k] is False for k in planted)
+    assert verdicts["merged"].count(True) >= len(hs) - len(planted) - 4

@@ -321,6 +321,9 @@ def test_a_request_during_the_build_waits_in_the_queue(served, monkeypatch):
     after = svc.stats()
     assert after["warm"] is True
     assert after["degraded_batches"] == before["degraded_batches"] == 0
+    # its one batch, and the window groups that ran, both served
+    assert after["groups_run"] - before["groups_run"] >= \
+        after["batches"] - before["batches"] == 1
     assert rec["cached"] is False
     for res in rec["results"]:
         assert res["decided-tier"] in ("dense", "mask")
