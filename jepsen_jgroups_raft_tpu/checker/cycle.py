@@ -112,8 +112,7 @@ def _condense_env() -> Optional[bool]:
 
 def _use_kernel() -> bool:
     """Closure-kernel routing: the batched matmul pass where matmul is
-    effectively free (TPU), the O(V+E) host DFS everywhere else — same
-    measured-routing stance as PLATFORM_ROUTE_MIN_CELLS.
+    effectively free (TPU), the O(V+E) host DFS everywhere else.
     JGRAFT_CYCLE_KERNEL=1/0 forces the arm (tests, ablation)."""
     forced = os.environ.get("JGRAFT_CYCLE_KERNEL")
     if forced is not None:

@@ -72,29 +72,6 @@ from .wgl_cpu import FrontierOverflow, check_encoded_cpu
 #: (reference doc/intro.md:35-41), but as a clean verdict, not an OOM.
 DEFAULT_MAX_CPU_CONFIGS = 1 << 18
 
-#: Per-shape platform routing: a dense window group whose scanned-cell
-#: count B×E is under this gate runs on the host cpu device even though
-#: the default backend is the chip. The gate was 64k cells, set where
-#: tiny batches were dominated by launch+transfer round trips over a
-#: slower host↔chip link (the config-3 shape, ≈600 sub-histories of ≤33
-#: events: CPU 2516 vs TPU 1391 hist/s, 2026-07-30 v5e). Re-read on
-#: today's v5e host (PERF.md section 6, PR 32, call M: one group through
-#: the wavefront, warm, chip | host cpu): that same shape 79-88 | 143-147
-#: ms; one window group of 1k-op histories, 24 rows, W 6 / 7 / 8: 54-62 |
-#: 88-125, 63-69 | 149-228, 74-81 | 241-299 ms, where the gate sent every
-#: such group of up to 24 rows to the host and the launch's other groups
-#: waited for it with the chip idle. Of fourteen shapes the host won one
-#: (8 rows at W 6: 20 | 40 ms). So `auto` routes nothing by default; the
-#: gate stays env-tunable for re-ablation on another host or chip
-#: generation (doc/running.md "Re-tuning the measured gates";
-#: scripts/calibrate_routing.py), and JGRAFT_PLATFORM_ROUTE forces either
-#: placement.
-#: Parsed defensively (platform.env_int): a malformed
-#: JGRAFT_ROUTE_MIN_CELLS used to crash every importer of this module at
-#: import time; now it warns and falls back to the measured default.
-PLATFORM_ROUTE_MIN_CELLS = env_int("JGRAFT_ROUTE_MIN_CELLS", 0, minimum=0)
-
-
 # --------------------------------------- lin-rung fast path (ISSUE 14)
 # The cheap-decision tier (checker/consistency.certify_encoded) runs as
 # a PRE-KERNEL pass on the un-relaxed stream at the linearizable rung:
@@ -164,8 +141,7 @@ def fastpath_counters() -> dict:
 
 
 def consume_fastpath_counters() -> dict:
-    """Return and reset the counters (bench.py reads one window's
-    worth)."""
+    """Return and reset the counters."""
     global _FP_COUNTERS
     with _FP_LOCK:
         out = dict(_FP_COUNTERS)
@@ -313,34 +289,6 @@ def _observe_kernel_cost(rest: Sequence[EncodedHistory], model,
     return results
 
 
-def _route_group_to_host(n_rows: int, n_events: int) -> bool:
-    """True when a dense window group should run on the host CPU backend
-    even though the default backend is a TPU. JGRAFT_PLATFORM_ROUTE
-    forces the answer (tpu|cpu); auto applies the measured cell gate."""
-    mode = os.environ.get("JGRAFT_PLATFORM_ROUTE", "auto")
-    if mode == "tpu":
-        return False
-    if mode == "cpu":
-        return True
-    if n_events > MERGE_MAX_EVENTS:
-        # LONG groups are depth-bound, not launch-bound: the B·E cell
-        # gate (calibrated on config-3's tiny uniform-window batches)
-        # undercounts their kernel work by the 2^W·S factor, and a
-        # small merged cluster (e.g. 2×20k events = 40k cells) would
-        # otherwise land on the throughput-bound host — the exact
-        # placement the merged-launch policy measured 2.2× slower.
-        return False
-    import jax
-
-    if jax.default_backend() != "tpu":
-        return False  # already on the host — nothing to route
-    try:
-        jax.local_devices(backend="cpu")
-    except RuntimeError:
-        return False  # cpu backend unavailable (JAX_PLATFORMS pinned)
-    return n_rows * n_events < PLATFORM_ROUTE_MIN_CELLS
-
-
 def check_histories(
     histories: Sequence[History],
     model,
@@ -402,8 +350,8 @@ def check_encoded(
     and the per-row verdicts are exchanged so every process returns the
     full batch (parallel/distributed.run_sharded; placement model in
     doc/checker-design.md §10). The caller contract is SPMD: every
-    process calls with the same batch — true of the bench and the
-    `check` CLI run once per host. `distribute=False` (graftd's
+    process calls with the same batch — true of the `check` CLI run
+    once per host. `distribute=False` (graftd's
     per-host scheduler, whose admission queues are host-local) and
     ``JGRAFT_DISTRIBUTED=0`` both pin the single-process path; outside
     a cluster the seam is inert by construction.
@@ -817,12 +765,10 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             # covers the event length the MONOLITHIC kernel would scan
             # (pad_batch_bucketed's floor_e=32 series for short
             # groups, exact for LONG ones) so `early_exit` reports
-            # genuinely skipped reference work; host-routed groups
-            # (PLATFORM_ROUTE_MIN_CELLS) carry their chunks on the
-            # host device. JGRAFT_SCAN_CHUNK=0 restores the monolithic
-            # reference launch loop below; the Pallas ablation keeps
-            # the monolithic path (its grid kernel owns its own event
-            # loop).
+            # genuinely skipped reference work. JGRAFT_SCAN_CHUNK=0
+            # restores the monolithic reference launch loop below; the
+            # Pallas ablation keeps the monolithic path (its grid
+            # kernel owns its own event loop).
             triples = []
             for idxs, plan in grouped:
                 sub = [fits[j] for j in idxs]
@@ -840,8 +786,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 batch = autotune.pack_group(sub_encs, tuned,
                                             window=plan.n_slots)
                 triples.append((sub, plan, batch, tuned))
-            launches, subs = build_dense_launches(
-                model, triples, host_route=_route_group_to_host)
+            launches, subs = build_dense_launches(model, triples)
             with launch_span(rows=sum(len(sub) for sub in subs)):
                 outs = run_chunked(launches, build_rows=serve_rows)
             for sub, out in zip(subs, outs):
@@ -879,10 +824,10 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                     # can exceed 16 rows, so exactness keys on
                     # long-ness alone, not group size).
                     e_len = batch["events"].shape[1]
-                    # Exactness (and the host gate below) key on the
-                    # LEGACY event length: the policies were calibrated
-                    # on it, and a macro batch's ~2× shorter row count
-                    # must not silently halve their thresholds.
+                    # Exactness keys on the LEGACY event length: the
+                    # policy was calibrated on it, and a macro batch's
+                    # ~2× shorter row count must not silently halve its
+                    # threshold.
                     e_legacy = batch.get("legacy_events", e_len)
                     exact = e_legacy > MERGE_MAX_EVENTS
                     ev, (val_of,), B = pad_batch_bucketed(
@@ -907,21 +852,6 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                         kernel = make_dense_batch_checker(
                             model, plan.kind, plan.n_slots, plan.n_states,
                             macro_p=batch.get("macro_p"))
-                        if _route_group_to_host(
-                                ev.shape[0],
-                                e_legacy if exact
-                                else bucket_rows(e_legacy, 32)):
-                            # Tiny batch: the host mesh wins (see
-                            # PLATFORM_ROUTE_MIN_CELLS).
-                            # Committed inputs carry the computation to
-                            # the CPU backend; the jit cache keys on
-                            # sharding, so both placements coexist.
-                            import jax
-
-                            host = jax.local_devices(backend="cpu")[0]
-                            ev = jax.device_put(ev, host)
-                            val_of = jax.device_put(val_of, host)
-                            tag += "@host"
                     ok, _ = kernel(ev, val_of)
                     launched.append((sub, tag, ok, B))
                     n_launched += len(sub)

@@ -89,9 +89,9 @@ def scan_chunk() -> int:
 
 # ------------------------------------------------------------------ stats
 # Aggregated across every wavefront this process runs (thread-safe: race
-# mode drives the jax pass from a worker thread). bench.py consumes them
-# per rep; checker/perf.py reads the innermost `stats_scope` so stored
-# per-run artifacts never accumulate across checker invocations.
+# mode drives the jax pass from a worker thread). checker/perf.py reads
+# the innermost `stats_scope` so stored per-run artifacts never
+# accumulate across checker invocations.
 
 _STATS_LOCK = threading.Lock()
 _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
@@ -112,11 +112,7 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # launch-shape set (ISSUE 32): programs the build-ahead
                # of a key built or loaded, and programs a launch built
                # or loaded AFTER its key was built (healthy: 0).
-               "programs_built_ahead": 0, "shape_misses": 0,
-               # rows of launches the placement policy put on the host
-               # cpu device beside an accelerator (`...@host`): the tier
-               # counters fold them into `dense` / `mask`
-               "host_routed_rows": 0}
+               "programs_built_ahead": 0, "shape_misses": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -153,9 +149,10 @@ def note_cycle(**kw) -> None:
     """Record cycle-tier counters (ISSUE 19) into the active scopes +
     process totals — ``cycle_size_skips`` is the previously-invisible
     cap skip (satellite: a row too big for the exact tier now leaves a
-    trace in every stats surface), the rest feed the bench rung rows'
-    ``cycle_*`` fields. Unknown keys are a programming error, caught
-    loudly here rather than silently minted as new counters."""
+    trace in every stats surface), the rest are the ``cycle_*``
+    fields of a run's stored stats. Unknown keys are a programming
+    error, caught loudly here rather than silently minted as new
+    counters."""
     for k in kw:
         if k not in _STATS_ZERO:
             raise KeyError(f"unknown cycle counter {k!r}")
@@ -168,11 +165,10 @@ def stats_scope(label: Optional[str] = None):
     accumulated while the scope is active land in the yielded dict too,
     isolated from everything before it. `core/runner.run_test` wraps
     each test's checking phase in one, so a process running
-    back-to-back checks (soaks, `bench.py --suite` in one interpreter)
-    stores per-run counters instead of process-lifetime accumulation.
-    Nesting-safe (scopes stack) and thread-safe; the process-wide
-    totals that `consume_stats` serves (the bench's per-rep read) are
-    untouched.
+    back-to-back checks (soaks) stores per-run counters instead of
+    process-lifetime accumulation. Nesting-safe (scopes stack) and
+    thread-safe; the process-wide totals that `consume_stats` serves
+    are untouched.
 
     `label` threads a caller identity through the scope (ISSUE-5: the
     checking service labels each coalesced launch with the request ids
@@ -217,9 +213,9 @@ def snapshot_stats(scoped: bool = False) -> dict:
 
 
 def consume_stats() -> dict:
-    """Return and reset the accumulated process-wide counters (bench.py
-    reads one timed rep's worth at a time). Active scopes are not
-    reset — they already hold only their own span's counters."""
+    """Return and reset the accumulated process-wide counters. Active
+    scopes are not reset — they already hold only their own span's
+    counters."""
     global _STATS
     with _STATS_LOCK:
         out = dict(_STATS)
@@ -233,8 +229,8 @@ def consume_stats() -> dict:
 # trivial). At fleet scale the cheap-tier hit-rate IS the capacity
 # model, so the per-tier decided counts and wall time are first-class
 # counters next to the chunked-scan stats: same process-wide totals +
-# thread-affine scope attribution, surfaced by checker/perf.py,
-# bench.py rows, and graftd's per-request stats.
+# thread-affine scope attribution, surfaced by checker/perf.py and
+# graftd's per-request stats.
 
 _TIERS: dict = {}  # tier -> [rows, wall_s]; guarded by _STATS_LOCK
 
@@ -273,9 +269,8 @@ def snapshot_tiers(scoped: bool = False) -> dict:
 
 
 def consume_tiers() -> dict:
-    """Return and reset the process-wide per-tier counters (bench.py
-    reads one timed window's worth at a time); active scopes keep
-    their own accumulations, like `consume_stats`."""
+    """Return and reset the process-wide per-tier counters; active
+    scopes keep their own accumulations, like `consume_stats`."""
     global _TIERS
     with _STATS_LOCK:
         out = _format_tiers(_TIERS)
@@ -494,7 +489,7 @@ class ChunkLaunch:
         length the legacy monolithic kernel would scan (so early exit
         measures real savings vs the reference path); defaults to E.
     device: placement for this group's carry + chunk slices — a jax
-        Device (host-routed groups), a batch-axis Sharding
+        Device, a batch-axis Sharding
         (`parallel.mesh.chunk_sharding`: rows spread over the mesh,
         row buckets padded to a multiple of the shard count), or None
         for default single-device placement.
@@ -569,10 +564,9 @@ class _GroupState:
     intervals: List[tuple] = field(default_factory=list)  # in-flight spans
 
 
-def build_dense_launches(model, groups, host_route=None):
+def build_dense_launches(model, groups):
     """Build the wavefront launch list for dense window groups — the
-    one home of the placement policy (checker/_jax_pass and
-    bench.run_chunks both route through it).
+    one home of the placement policy.
 
     groups: iterable of (rows, plan, batch) or (rows, plan, batch,
     tuned) — `rows` the caller's row ids, `plan` a DensePlan, `batch`
@@ -584,12 +578,10 @@ def build_dense_launches(model, groups, host_route=None):
     (the macro payload half of a plan acts earlier, at pack time —
     autotune.pack_group). The launch order is policy and lives HERE:
     largest group first, so big groups' chunks queue ahead of small
-    ones on every device (callers must not pre-sort — the bench and
-    the checker must measure the same schedule).
-    host_route(n_rows_bucketed, e_len) -> bool optionally routes a
-    whole group to the host cpu device (the PLATFORM_ROUTE_MIN_CELLS
-    gate). Returns (launches, subs): subs[k] holds the row ids behind
-    launches[k], in row order.
+    ones on every device (callers must not pre-sort). Returns
+    (launches, subs): subs[k] holds the row ids behind launches[k], in
+    row order. Nothing is placed on the host cpu beside an accelerator
+    (PERF.md section 6, PR 32, call M: the chip's loser).
 
     Groups stay WHOLE and each chunk's kernel is an explicit
     `shard_map` over the batch axis of the device mesh
@@ -607,8 +599,7 @@ def build_dense_launches(model, groups, host_route=None):
     device, so the host blocking on one group's flags never idles the
     ring. LONG merged clusters (exact_rows) keep exact row counts on
     the default device — depth-bound few-row launches, sharding buys
-    nothing — and host-routed groups pin whole to the host cpu
-    device."""
+    nothing."""
     from ..ops.dense_scan import MERGE_MAX_EVENTS, make_dense_chunk_checker
     from ..parallel.mesh import chunk_sharding
 
@@ -619,33 +610,14 @@ def build_dense_launches(model, groups, host_route=None):
         rows, plan, batch = grp[:3]
         tuned = grp[3] if len(grp) > 3 else None
         e_len = batch["events"].shape[1]
-        # Both the LONG-group exact-padding policy and the host/TPU
-        # cell gate were calibrated on LEGACY event counts; a macro
-        # batch's ~2× shorter row count must not silently halve their
-        # thresholds (a merged long cluster losing its depth-bound
-        # exemption would host-route onto the placement measured 2.2×
-        # slower). The scan schedule itself runs on macro rows.
+        # The LONG-group exact-padding policy was calibrated on LEGACY
+        # event counts; a macro batch's ~2× shorter row count must not
+        # silently halve its threshold. The scan schedule itself runs
+        # on macro rows.
         e_legacy = batch.get("legacy_events", e_len)
         exact = e_legacy > MERGE_MAX_EVENTS
         e_sched = e_len if exact else bucket_rows(e_len, 32)
-        tag = plan.kernel_tag
-        # Gate on the same PADDED shapes the legacy path feeds
-        # _route_group_to_host (pad_batch_bucketed's row bucket and
-        # floor_e=32 event bucket): an unbucketed length would flip
-        # routing for groups near the PLATFORM_ROUTE_MIN_CELLS boundary.
-        host = bool(host_route
-                    and host_route(bucket_rows(len(rows)),
-                                   e_legacy if exact
-                                   else bucket_rows(e_legacy, 32)))
-        if host:
-            import jax
-
-            tag += "@host"
-            # Local cpu device: in a multi-process runtime
-            # jax.devices("cpu") lists every host's cpu devices and
-            # [0] may be a non-addressable remote one.
-            placement = jax.local_devices(backend="cpu")[0]
-        elif exact:
+        if exact:
             placement = None
         elif tuned is not None and tuned.mesh_fanout > 0:
             # Per-group fan-out from the measured plan; the env knob
@@ -667,13 +639,17 @@ def build_dense_launches(model, groups, host_route=None):
         launches.append(ChunkLaunch(
             events=batch["events"], n_events=batch["n_events"],
             init_fn=init_fn, step_fn=step_fn, val_of=plan.val_of,
-            e_sched=e_sched, device=placement, tag=tag,
+            e_sched=e_sched, device=placement, tag=plan.kernel_tag,
             exact_rows=exact, chunk=chunk_override,
             spec={"model": type(model).__name__,
                   "model_key": repr(model.cache_key()),
                   "kind": plan.kind, "n_slots": int(plan.n_slots),
                   "n_states": int(plan.n_states),
-                  "macro_p": batch.get("macro_p"), "host": host,
+                  "macro_p": batch.get("macro_p"),
+                  # always False (nothing is placed on the host cpu);
+                  # the field stays so that a host's `launch-keys.json`
+                  # reads as it was written
+                  "host": False,
                   "fanout": _n_shards(placement)}))
         subs.append(list(rows))
     return launches, subs
@@ -686,14 +662,11 @@ def key_template(model, spec: dict, width: int, lanes: int,
     and lanes, the rows built), for `build_keys`. It goes through
     `build_dense_launches`, the one home of the placement policy; None
     where this process would not place the key as recorded (another
-    fan-out; a host-routed key, whose placement is the gate's word on
-    a group, not a property of the key), or the record is of another
-    stream format."""
+    fan-out), or the record is of another stream format."""
     from ..ops.dense_scan import DensePlan
 
     macro_p = spec.get("macro_p")
-    if spec.get("host") or \
-            lanes != (5 if macro_p is None else 3 + 4 * int(macro_p)):
+    if lanes != (5 if macro_p is None else 3 + 4 * int(macro_p)):
         return None
     n_states = int(spec["n_states"])
     plan = DensePlan(spec["kind"], int(spec["n_slots"]), n_states,
@@ -1245,8 +1218,8 @@ def run_chunked(launches: List[ChunkLaunch],
     A launch's own `chunk` field overrides the run-wide `chunk`
     (autotuned per-group plans). `record_stats=False` keeps a run out
     of the process/scope counters — the autotuner's short candidate
-    samples must not inflate the eviction evidence bench.py and the
-    per-run stores report. `build_rows`, a service's largest launch:
+    samples must not inflate the eviction evidence the per-run stores
+    report. `build_rows`, a service's largest launch:
     a key these launches meet for the first time is built whole up to
     that many rows before it is launched (`build_keys`); None builds a
     row bucket when a launch reaches it."""
@@ -1283,9 +1256,6 @@ def run_chunked(launches: List[ChunkLaunch],
                    groups_run=len(groups),
                    groups_early_exited=sum(1 for g in groups
                                            if g.early_exit),
-                   host_routed_rows=sum(
-                       g.launch.events.shape[0] for g in groups
-                       if g.launch.tag.endswith("@host")),
                    pipeline_overlap_s=_overlap_seconds(all_spans))
     return [GroupOutcome(ok=g.ok, overflow=g.overflow, wall_s=g.wall_s,
                          chunks_run=g.launches_run, evicted_rows=g.evicted,

@@ -63,8 +63,7 @@ def encode_vector_on() -> bool:
     `IncrementalEncoder` settles suffixes columnar-ly.
     ``JGRAFT_ENCODE_VECTOR=0`` forces the per-pair Python loop — the
     differential ORACLE arm (byte-identical output, pinned by
-    tests/test_fast_encode.py) and the A/B denominator
-    (scripts/ab_hostpath.py). Parsed defensively via `env_int`:
+    tests/test_fast_encode.py). Parsed defensively via `env_int`:
     garbage warns and keeps the default (on)."""
     return env_int("JGRAFT_ENCODE_VECTOR", 1, minimum=0) != 0
 
@@ -711,7 +710,7 @@ def pack_macro_batch_shard(
     doc/checker-design.md §10; identity pinned by
     tests/test_distributed.py). This parallelizes the dominant
     host-side pack cost — `macro_compact` + array fill — across host
-    CPUs (`scripts/ab_distributed.py` measures the win)."""
+    CPUs."""
     encs = list(encoded)
     if not encs:
         raise ValueError("empty batch")

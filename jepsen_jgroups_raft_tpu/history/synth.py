@@ -7,10 +7,9 @@ Crashed ops may linearize and then never report (→ info), reproducing the
 ambiguous-completion semantics the reference's checker must handle
 (reference workload/client.clj:52-63, doc/intro.md:35-41).
 
-Used three ways (SURVEY.md §4 implications):
+Used two ways (SURVEY.md §4 implications):
   * differential testing of the CPU and TPU checkers against each other,
-  * adversarial tests via `corrupt` (perturb a completion, oracle decides),
-  * bench.py workload synthesis (north-star configs, BASELINE.md).
+  * adversarial tests via `corrupt` (perturb a completion, oracle decides).
 """
 
 from __future__ import annotations

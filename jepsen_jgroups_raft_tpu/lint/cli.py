@@ -211,8 +211,8 @@ def main(argv=None) -> int:
     findings = run(paths, rules, vmem_budget=args.vmem_budget,
                    timings=timings)
 
-    # The knob registry is a whole-repo harvest (it also covers bench.py
-    # and the scripts, which the per-file walk does not visit) — run it
+    # The knob registry is a whole-repo harvest (it also covers the
+    # scripts, which the per-file walk does not visit) — run it
     # on any default-path envknobs run, and whenever the artifact is
     # requested explicitly.
     if args.knob_registry or ("envknobs" in rules and not args.paths):

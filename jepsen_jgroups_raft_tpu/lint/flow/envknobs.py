@@ -1,7 +1,7 @@
 """Env-knob registry analyzer: every ``JGRAFT_*`` read, accounted for.
 
 Fifteen PRs of growth left ``JGRAFT_*`` knobs scattered across the
-checker, service, parallel and bench tiers. Three failure modes keep
+checker, service and parallel tiers. Three failure modes keep
 recurring: a raw ``int(os.environ.get(...))`` that crashes the importer
 on a blank/garbage value (the PR 7 lesson platform.env_int exists to
 prevent), two call sites parsing the same knob with *different
@@ -26,8 +26,7 @@ Rules:
   (cross-file; reported by ``build_registry``, which the full-repo CLI
   run invokes).
 
-Scan set: the whole package plus ``bench.py`` and the in-scope scripts
-(the bench tier is where raw parses historically accumulate).
+Scan set: the whole package plus the in-scope scripts.
 """
 
 from __future__ import annotations
@@ -45,7 +44,7 @@ RULE_DUP = "flow-env-dup-default"
 
 #: files outside the package covered by build_registry (and by the
 #: per-file rules when the CLI full run invokes it).
-EXTRA_FILES = ("bench.py", "scripts/chaos_graftd.py")
+EXTRA_FILES = ("scripts/chaos_graftd.py",)
 
 _KNOB_RE = re.compile(r"JGRAFT_[A-Z0-9_]+")
 _BRACE_RE = re.compile(r"(JGRAFT_[A-Z0-9_]*)\{([A-Z0-9_,\s]+)\}")

@@ -10,9 +10,9 @@ in two halves:
   envknobs harvest finds must have a row in :data:`KNOB_CLASS`:
   ``routing`` (engine/tier selection, batching and fastpath gates,
   chunk/unroll/fanout shapes), ``durability`` (what is persisted and
-  where), ``ops`` (fleet operation: workers, watchdogs, bench drivers,
-  time budgets), or ``semantic`` (declared verdict-affecting — the
-  class is deliberately EMPTY today; a future knob that genuinely
+  where), ``ops`` (fleet operation: workers, watchdogs, time
+  budgets), or ``semantic`` (declared verdict-affecting — the class
+  is deliberately EMPTY today; a future knob that genuinely
   changes verdict semantics must self-declare here and thereby exempt
   itself from the taint rule below, in writing).
 * **taint** (``flow-knob-verdict``) — from every ``env_int`` /
@@ -86,7 +86,6 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_CYCLE_TILE": ROUTING,
     "JGRAFT_DISTRIBUTED": ROUTING,
     "JGRAFT_DISTRIBUTED_AUTODETECT": ROUTING,
-    "JGRAFT_DISTRIBUTED_VDEVS": ROUTING,
     "JGRAFT_ENCODE_VECTOR": ROUTING,
     "JGRAFT_GREEDY_BACKTRACK": ROUTING,
     "JGRAFT_GREEDY_CERTIFY": ROUTING,
@@ -102,8 +101,6 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_LINFP_DIR": ROUTING,
     "JGRAFT_MACRO_EVENTS": ROUTING,
     "JGRAFT_MERGE_LONG": ROUTING,
-    "JGRAFT_PLATFORM_ROUTE": ROUTING,
-    "JGRAFT_ROUTE_MIN_CELLS": ROUTING,
     "JGRAFT_SCAN_CHUNK": ROUTING,
     "JGRAFT_SCAN_UNROLL": ROUTING,
     # search-arm knobs route which CANDIDATES get generated/checked
@@ -121,16 +118,8 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_SERVICE_CLUSTER_DIR": DURABILITY,
     "JGRAFT_SERVICE_JOURNAL": DURABILITY,
     "JGRAFT_SERVICE_RETAIN": DURABILITY,
-    # -- ops: fleet operation, bench drivers, budgets -----------------
+    # -- ops: fleet operation, budgets --------------------------------
     "JGRAFT_AUTOTUNE_STORE": OPS,
-    "JGRAFT_BENCH_ALLOW_DEGRADED": OPS,
-    "JGRAFT_BENCH_CONSISTENCY": OPS,
-    "JGRAFT_BENCH_LIN_FASTPATH": OPS,
-    "JGRAFT_BENCH_PLATFORM": OPS,
-    "JGRAFT_BENCH_REPS": OPS,
-    "JGRAFT_BENCH_SAVE": OPS,
-    "JGRAFT_BENCH_VDEVS": OPS,
-    "JGRAFT_BENCH_WATCHDOG_S": OPS,
     "JGRAFT_CLIENT_KEEPALIVE": OPS,
     "JGRAFT_CLUSTER_SKEW_S": OPS,
     "JGRAFT_CLUSTER_TTL_S": OPS,
@@ -138,18 +127,10 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_PROFILE_DIR": OPS,
     "JGRAFT_SEARCH_DIR": OPS,
     "JGRAFT_SEARCH_GENERATIONS": OPS,
-    "JGRAFT_SEARCH_PLANTS": OPS,
     "JGRAFT_SEARCH_POP": OPS,
     "JGRAFT_SEARCH_SEED": OPS,
     "JGRAFT_SEARCH_SURVIVORS": OPS,
     "JGRAFT_SERVICE_ADVERTISE_URL": OPS,
-    "JGRAFT_SERVICE_BENCH_CLIENTS": OPS,
-    "JGRAFT_SERVICE_BENCH_FASTLANE": OPS,
-    "JGRAFT_SERVICE_BENCH_GROUPAB": OPS,
-    "JGRAFT_SERVICE_BENCH_HISTORIES": OPS,
-    "JGRAFT_SERVICE_BENCH_INGESTAB": OPS,
-    "JGRAFT_SERVICE_BENCH_OPS": OPS,
-    "JGRAFT_SERVICE_BENCH_REQUESTS": OPS,
     "JGRAFT_SERVICE_CACHE": OPS,
     "JGRAFT_SERVICE_CRASH_CAP": OPS,
     "JGRAFT_SERVICE_QUEUE": OPS,
@@ -158,15 +139,11 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_SERVICE_UDS": OPS,
     "JGRAFT_SERVICE_WATCHDOG_S": OPS,
     "JGRAFT_SERVICE_WORKERS": OPS,
-    "JGRAFT_STREAM_BENCH_OPS": OPS,
-    "JGRAFT_STREAM_BENCH_SEGMENTS": OPS,
-    "JGRAFT_STREAM_BENCH_SESSIONS": OPS,
     "JGRAFT_STREAM_BYTES_PER_S": OPS,
     "JGRAFT_STREAM_IDLE_S": OPS,
     "JGRAFT_STREAM_RESIDENT_EVENTS": OPS,
     "JGRAFT_STREAM_SEGS_PER_S": OPS,
     "JGRAFT_STREAM_SESSIONS": OPS,
-    "JGRAFT_SUITE_SCALE": OPS,
     # -- semantic: verdict-affecting by declaration (EMPTY: the PR-13/14
     # -- contract is that no knob changes verdict semantics) -----------
 }

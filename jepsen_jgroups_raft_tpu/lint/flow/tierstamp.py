@@ -2,10 +2,10 @@
 
 PR 13's attribution contract: every terminal verdict records which
 tier of the escalation ladder decided it (``decided-tier``), so the
-fleet's tier counters, the bench's decided-tiers summary and the
-incident playbook in doc/running.md stay trustworthy as new tiers
-land. The invariant is *totality* — a construction site someone adds
-next year must not silently ship unstamped rows.
+fleet's tier counters and the incident playbook in doc/running.md
+stay trustworthy as new tiers land. The invariant is *totality* — a
+construction site someone adds next year must not silently ship
+unstamped rows.
 
 Every dict literal carrying a ``"valid?"`` key on the verdict surface
 (checker ladder, host ladder, fast lanes, stream mid-run/finish,

@@ -88,7 +88,7 @@ class DensePlan:
 
     @property
     def kernel_tag(self) -> str:
-        """Reporting label (checker results, bench JSON)."""
+        """Reporting label (checker results)."""
         return "dense" if self.kind == "domain" else "dense-mask"
 
 
@@ -147,10 +147,10 @@ def _merge_long_groups() -> bool:
     ~15-20k events ≈ 70k sequential steps), while one merged launch at
     the widest window pays max-E once (~20k steps) at a higher
     per-step width. At config-4 frontier sizes the depth cut wins:
-    interleaved in-process A/B on v5e (scripts/ab_merge_long.py,
-    2026-07-31, 5 reps each): merged min 2.348 s / median 2.514 s vs
-    per-window 3.187 / 3.342 — 1.36× at min, every merged rep faster
-    than every per-window rep. (The round-3 number that set the old
+    interleaved in-process A/B on v5e (2026-07-31, 5 reps each):
+    merged min 2.348 s / median 2.514 s vs per-window 3.187 / 3.342 —
+    1.36× at min, every merged rep faster than every per-window rep.
+    (The round-3 number that set the old
     policy — merged 1.9 s vs per-window 1.3 s — was a cross-process
     comparison, a methodology later proved unusable: identical benches
     span 249-677 hist/s across processes.)

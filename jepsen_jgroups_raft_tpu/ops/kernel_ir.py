@@ -110,8 +110,7 @@ CYCLE_TILE = 256
 
 def scan_unroll() -> int:
     """Events per lax.scan step across the event-scan kernels (dense,
-    mask, segment, sort) — an ablation knob for the on-chip sweep
-    (scripts/calibrate_routing.py --unroll), JGRAFT_SCAN_UNROLL to
+    mask, segment, sort) — an ablation knob, JGRAFT_SCAN_UNROLL to
     override. Default 1 EVERYWHERE: CPU-mesh measurements did not
     survive re-measurement through the production path (a hand-built
     kernel probe showed unroll=2 at 1.49× on a B=4 × 15.7k-event
@@ -588,7 +587,7 @@ def cycle_closure_tile_bytes(n_nodes: int, tile: int) -> int:
 
 def cycle_closure_tiles(n_nodes: int, tile: int) -> int:
     """Tile-program count of one blocked-closure pass — bookkeeping for
-    the cycle_tiles_run counter (checker/schedule.py) and bench rows:
+    the cycle_tiles_run counter (checker/schedule.py):
     per pivot block one diagonal closure, N/T row-panel products, N/T
     column-panel products, and N/T streamed fold products of N/T tiles
     each."""

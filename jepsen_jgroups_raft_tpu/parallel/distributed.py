@@ -91,7 +91,7 @@ def parse_cluster_env() -> Optional[Tuple[str, int, int]]:
     cluster env, or None when absent OR malformed. Malformed values
     warn and record a degrade note instead of raising: a typo'd
     ``JAX_NUM_PROCESSES`` used to surface as a ``ValueError`` out of
-    ``int()`` at CLI/bench start — the single-host degrade must be
+    ``int()`` at CLI start — the single-host degrade must be
     loud, not fatal."""
     coord = os.environ.get("JAX_COORDINATOR_ADDRESS")
     nproc_raw = os.environ.get("JAX_NUM_PROCESSES")
@@ -119,7 +119,7 @@ def parse_cluster_env() -> Optional[Tuple[str, int, int]]:
 def maybe_init_distributed() -> bool:
     """Initialize `jax.distributed` when a cluster environment is
     present. Returns True iff the distributed runtime is (now)
-    initialized. Idempotent; safe from bench/CLI entry points.
+    initialized. Idempotent; safe from CLI entry points.
 
     Resolution order: the explicit env triple (defensively parsed —
     see `parse_cluster_env`); then, ONLY when
@@ -263,7 +263,7 @@ def placement_granularity() -> int:
 
 #: Exchange sequence counter. Every process makes the same sequence of
 #: exchange/barrier calls (SPMD discipline — documented contract of
-#: `run_sharded` and the bench), so a per-process counter yields
+#: `run_sharded`), so a per-process counter yields
 #: cluster-identical tags without any coordination of its own.
 _SEQ = itertools.count()
 
@@ -414,7 +414,7 @@ def run_sharded(encs: Sequence, check_local: Callable[[list], List[dict]],
     to an error.
 
     SPMD contract: every process must call with the same batch (same
-    row count, same order) — the bench and the `check` CLI satisfy it
+    row count, same order) — the `check` CLI satisfies it
     by construction (same inputs, same code path). Placement: shard
     boundaries align to `placement_granularity` so each host's rows
     split evenly over its local mesh."""

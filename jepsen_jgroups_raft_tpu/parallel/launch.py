@@ -9,20 +9,17 @@ verdict exchange) a real pod uses — on a pod the operator instead runs
 the same command once per host with the standard cluster env set (see
 doc/running.md "Multi-host checking").
 
-Consumers: ``bench.py --distributed N`` (the parent side lives here so
-the subprocess/socket lifetimes sit inside the lint scan scope),
-``scripts/ab_distributed.py``, and tests/test_distributed.py.
+Consumer: tests/test_distributed.py (the subprocess/socket lifetimes
+live here so that they sit inside the lint scan scope).
 """
 
 from __future__ import annotations
 
-import os
 import socket
 import subprocess
-import sys
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..platform import cpu_subprocess_env, env_int
+from ..platform import cpu_subprocess_env
 
 
 def free_coordinator_port() -> int:
@@ -41,9 +38,7 @@ def cluster_child_env(process_id: int, n_processes: int, port: int,
     """Environment for one child of the local CPU-mesh topology: the
     standard JAX cluster triple over a localhost coordinator, the CPU
     pin (`platform.cpu_subprocess_env`), and an optional per-process
-    virtual device count
-    (`vdevs`, also exported as ``JGRAFT_BENCH_VDEVS`` so bench.py's
-    cpu pin respects the split instead of raising it back to 8)."""
+    virtual device count (`vdevs`)."""
     env = cpu_subprocess_env()
     # The child pins its own platform/device count; an inherited
     # XLA_FLAGS count would override it (pin_cpu only ever raises).
@@ -55,7 +50,6 @@ def cluster_child_env(process_id: int, n_processes: int, port: int,
     })
     if vdevs:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={vdevs}"
-        env["JGRAFT_BENCH_VDEVS"] = str(vdevs)
     if extra:
         env.update(extra)
     return env
@@ -95,43 +89,3 @@ def launch_local_cluster(n_processes: int, command: Sequence[str],
             if p.poll() is None:
                 p.kill()
                 p.wait()
-
-
-def run_distributed_bench(argv: Sequence[str]) -> int:
-    """Parent side of ``bench.py --distributed N``: strip the flag,
-    spawn the N-process CPU-mesh topology running the SAME bench argv,
-    and forward process 0's output (the JSON-line contract — every
-    process computes the globally merged counts, so one emitter
-    suffices). The children's intended platform defaults to cpu (this
-    launcher IS the CPU-mesh recipe; a pod runs bench.py per host
-    without it), so the degraded-platform gate stays quiet unless the
-    operator pinned something else. Exit: 0 when every process exited
-    0, else 1 (with the failing processes' output tails on stderr)."""
-    argv = list(argv)
-    i = argv.index("--distributed")
-    try:
-        n = int(argv[i + 1])
-        if n < 1:
-            raise ValueError(n)
-    except (IndexError, ValueError):
-        print('{"metric": "histories_per_sec", "value": 0.0, '
-              '"unit": "hist/s", "vs_baseline": 0.0, '
-              '"error": "--distributed needs a positive process count"}',
-              flush=True)
-        return 2
-    child_argv = [sys.executable, os.path.abspath(argv[0])] \
-        + argv[1:i] + argv[i + 2:]
-    vdevs = env_int("JGRAFT_DISTRIBUTED_VDEVS", max(1, 8 // n), minimum=1)
-    extra: Dict[str, str] = {}
-    if not os.environ.get("JGRAFT_BENCH_PLATFORM"):
-        extra["JGRAFT_BENCH_PLATFORM"] = "cpu"
-    outs = launch_local_cluster(n, child_argv, vdevs=vdevs, env_extra=extra)
-    rc0, out0 = outs[0]
-    sys.stdout.write(out0)
-    sys.stdout.flush()
-    failed = [pid for pid, (rc, _) in enumerate(outs) if rc != 0]
-    for pid in failed:
-        print(f"# distributed worker {pid} exited "
-              f"{outs[pid][0]}:\n{outs[pid][1][-2000:]}",
-              file=sys.stderr, flush=True)
-    return 0 if not failed else 1

@@ -6,7 +6,7 @@ Tests and dry runs run on the host (`pin_cpu`: JAX_PLATFORMS=cpu plus N
 virtual devices, set before the CPU backend initializes); everything
 else initialises the default backend in its own process, and a backend
 that fails to come up fails the run. Used by tests/conftest.py,
-bench.py, cli.py, chip_smoke.py and __graft_entry__.dryrun_multichip.
+cli.py, chip_smoke.py and __graft_entry__.dryrun_multichip.
 """
 
 from __future__ import annotations
@@ -42,10 +42,9 @@ def degraded_note() -> Optional[str]:
 def env_int(name: str, default: int, minimum: Optional[int] = None) -> int:
     """Parse an integer env gate defensively: a non-integer value warns
     and falls back to the default instead of crashing at import time
-    (`JGRAFT_ROUTE_MIN_CELLS=yes` used to kill every importer of
-    checker/linearizable.py with a ValueError). `minimum` clamps with a
-    warning — the gates this serves are counts/sizes where a negative
-    or undersized value is always operator error, never intent."""
+    with a ValueError. `minimum` clamps with a warning — the gates this
+    serves are counts/sizes where a negative or undersized value is
+    always operator error, never intent."""
     raw = os.environ.get(name)
     if raw is None or not raw.strip():
         return default
@@ -100,7 +99,7 @@ def env_str(name: str, default: str = "") -> str:
 def enable_compile_cache() -> Optional[str]:
     """Turn on JAX's persistent compilation cache at a placeable path
     and return the directory in use (None: no cache). Called first
-    thing by every entry point (cli.main, bench.py, chip_smoke.py).
+    thing by every entry point (cli.main, chip_smoke.py).
 
     ``JAX_COMPILATION_CACHE_DIR`` wins — jax reads it itself, nothing
     is set in code. Otherwise the cache lives at
@@ -199,7 +198,7 @@ def is_backend_init_failure(e: BaseException) -> bool:
 
 def cpu_subprocess_env(base: dict | None = None) -> dict:
     """Environment for a CPU-only child interpreter (soak workers,
-    sanitizer runs, the distributed bench's children): the parent's
+    sanitizer runs, `parallel/launch.py`'s cluster children): the parent's
     environment with JAX_PLATFORMS=cpu, so the child can never reach
     for a chip its parent may hold."""
     env = dict(os.environ if base is None else base)

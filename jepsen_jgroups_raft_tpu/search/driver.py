@@ -23,8 +23,7 @@ and candidate evaluation pins ``JGRAFT_AUTOTUNE=0`` for the duration
 of the run — the measured per-bucket gates (lin fastpath, certify
 batch) are host-mood state that would otherwise let tier attribution,
 hence fitness, hence SELECTION, differ between two identical runs.
-Same seed ⇒ identical corpus fingerprints, asserted by ab_search
-before any timing.
+Same seed ⇒ identical corpus fingerprints (tests/test_search.py).
 """
 
 from __future__ import annotations

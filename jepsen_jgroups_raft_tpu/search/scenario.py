@@ -7,8 +7,7 @@ pure function of the genome: the base history comes from
 (family, seed), nemesis params are folded in via
 `nemesis/package.schedule_pressure`, and each edit replays under its
 own derived RNG. Same genome ⇒ same bytes ⇒ same admission
-fingerprint — that identity is what makes the corpus reproducible and
-the ab_search determinism assertion meaningful.
+fingerprint — that identity is what makes the corpus reproducible.
 """
 
 from __future__ import annotations

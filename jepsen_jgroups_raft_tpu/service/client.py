@@ -1,14 +1,14 @@
 """Thin HTTP client for graftd — stdlib http.client, JSON in/out.
 
-The tenant-side counterpart of service/http.py: tests, the bench's
---service throughput mode, and any external submitter use this instead
+The tenant-side counterpart of service/http.py: tests, the benchmark's
+clients, and any external submitter use this instead
 of hand-rolling requests.
 
 Connection reuse (ISSUE 18 satellite): calls keep-alive their
 connection per (thread, replica) and reuse it across submits, polls,
 and retries — at wire-speed ingest rates the TCP handshake per call is
-a measurable tax (the `conn_opened`/`conn_reused` counters are the
-bench's A/B evidence). The daemon speaks HTTP/1.1 persistent
+a measurable tax (the `conn_opened`/`conn_reused` counters count
+it). The daemon speaks HTTP/1.1 persistent
 connections already; a STALE kept-alive socket (daemon restarted
 between calls) is retried once on a fresh connection without consuming
 the caller's attempt budget, so restart-survival is as good as the old
@@ -85,7 +85,7 @@ FRAME_CONTENT_TYPE = "application/x-jgraft-frame"
 
 def client_keepalive() -> bool:
     """JGRAFT_CLIENT_KEEPALIVE gate (default on; 0 restores the
-    connection-per-call client — the bench's A/B arm)."""
+    connection-per-call client)."""
     return env_int("JGRAFT_CLIENT_KEEPALIVE", 1, minimum=0) != 0
 
 
@@ -186,7 +186,7 @@ class ServiceClient:
         #: cluster-wide Retry-After floor (module docstring).
         self._floor_until = 0.0
         #: connection-level failovers performed (a replica died and the
-        #: call moved on) — the bench's failover-latency evidence.
+        #: call moved on).
         self.failovers = 0
         #: request id → the replica that answered for it (bounded):
         #: result/cancel polls go straight to the owner instead of
@@ -198,7 +198,7 @@ class ServiceClient:
         self._answered_by: Optional[str] = None
         #: per-THREAD keep-alive pool, netloc → live HTTPConnection.
         #: Thread-local because http.client connections are not
-        #: thread-safe and the bench drives one client from many
+        #: thread-safe and a caller may drive one client from many
         #: submitter threads.
         self._local = threading.local()
         self._counter_lock = threading.Lock()
@@ -457,8 +457,8 @@ class ServiceClient:
             # across the fleet. Scheduling metadata (deadline,
             # priority) stays out of the key — it does not change the
             # verdict identity. `affinity=False` keeps the configured
-            # replica order (the bench's failover phase pins the dead
-            # replica at the head this way).
+            # replica order (a failover drill pins the dead replica
+            # at the head this way).
             key = hashlib.sha256(json.dumps(
                 [workload, algorithm, consistency, rows],
                 sort_keys=True, default=str).encode()).hexdigest()

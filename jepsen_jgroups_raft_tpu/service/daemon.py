@@ -1101,11 +1101,9 @@ class CheckingService:
         # ISSUE 32: `warm` once the build-ahead at start has run, and
         # what it built (`shape_misses`, `programs_built_ahead` and the
         # `build.ahead` span are process-wide, above)
-        scan = snapshot_stats()
-        out["host_routed_rows"] = scan["host_routed_rows"]
         # ISSUE 33: window groups the launches ran (process-wide);
         # over `batches`, how many scans a served batch is on the chip
-        out["groups_run"] = scan["groups_run"]
+        out["groups_run"] = snapshot_stats()["groups_run"]
         out["warm"] = self._warm.is_set()
         out["build_ahead"] = dict(self._build_ahead_info)
         # the host certifier's counters (process-wide, like the spans):

@@ -50,7 +50,7 @@ loopback port, one less copy per request.  ``JGRAFT_SERVICE_UDS=
 /path.sock`` makes `serve_checker` listen on both.
 
 Run it: ``python -m jepsen_jgroups_raft_tpu serve-checker`` (cli.py) or
-embed via `make_server` (tests, the bench's --service mode).
+embed via `make_server` (tests).
 """
 
 from __future__ import annotations
@@ -365,7 +365,7 @@ class _Handler(BaseHTTPRequestHandler):
 def make_server(service: CheckingService, host: str = "127.0.0.1",
                 port: int = 0) -> Tuple[ThreadingHTTPServer, int]:
     """Bind the service's HTTP front (port 0 → ephemeral); the caller
-    owns `serve_forever` (thread it for tests/bench)."""
+    owns `serve_forever` (thread it for tests)."""
     httpd = ThreadingHTTPServer((host, port),
                                 partial(_Handler, service=service))
     return httpd, httpd.server_address[1]
@@ -473,7 +473,7 @@ def serve_checker(store_root: str = "store", host: str = "0.0.0.0",
 def serve_in_thread(service: CheckingService, host: str = "127.0.0.1",
                     port: int = 0):
     """Start the HTTP front on a daemon thread; returns (httpd, port,
-    thread). Tests and the bench use this; shut down with
+    thread). Tests use this; shut down with
     `httpd.shutdown(); httpd.server_close()`."""
     httpd, bound = make_server(service, host, port)
     t = threading.Thread(target=httpd.serve_forever, daemon=True,

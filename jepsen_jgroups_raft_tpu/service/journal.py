@@ -87,8 +87,7 @@ STREAM_VERSION = 2
 #: The stream record kinds (`kind` field values).
 STREAM_KINDS = ("stream-open", "stream-seg", "stream-bseg", "stream-fin")
 
-#: Appends timed for the bench's admission-overhead evidence
-#: (`journal_append_p50_ms` in `bench.py --service` rows).
+#: Appends timed for `/stats` `journal_append_p50_ms`.
 APPEND_WINDOW = 4096
 
 #: Default group-commit linger (ms). See `journal_group_ms`.
@@ -106,7 +105,7 @@ def journal_group_ms() -> int:
 
     With N concurrent appenders, per-append fsync serializes into a
     lock convoy (measured: solo fsync ~0.15 ms on this host, but
-    `journal_append_p50_ms` 6.5 ms under the bench's 8 clients).
+    `journal_append_p50_ms` 6.5 ms under 8 clients).
     Group commit coalesces: one appender becomes the LEADER, writes
     every queued record, and issues ONE fsync covering the whole
     group; each member's append returns only after THAT fsync — the
@@ -120,7 +119,7 @@ def journal_group_ms() -> int:
 
     ``JGRAFT_JOURNAL_GROUP_MS=0`` restores today's exact per-append
     write+fsync behavior (the same-process A/B arm). Resolved per
-    append so the bench can flip arms against one live daemon."""
+    append."""
     return env_int("JGRAFT_JOURNAL_GROUP_MS", DEFAULT_GROUP_MS,
                    minimum=0)
 

@@ -46,7 +46,7 @@ MAX_PRIORITY = 8
 #: independent decomposition checker/recorded.py applies to stored
 #: runs), so one submitted multi-register history becomes one check
 #: unit per key. "register"/"counter" accept plain single-key histories
-#: — the shape tests and the bench submit.
+#: — the shape tests and the benchmark submit.
 def service_workloads() -> dict:
     from ..models import CasRegister, Counter, GSet, ListAppend, TicketQueue
 

@@ -10,8 +10,7 @@ shape the reference's golden histories use
 
 Modes:
   --north-star OUT   synthesize the BASELINE north-star batch (1000 ×
-                     1k-op CAS-register histories, seed 20260729 — the
-                     byte-identical batch bench.py times on TPU).
+                     1k-op CAS-register histories, seed 20260729).
   --store RUN OUT    export a recorded run dir's history.jsonl,
                      splitting multi-register tuples per key the way
                      `independent/checker` does (register.clj:106).
@@ -70,13 +69,14 @@ def write_histories(histories, out_dir: str) -> int:
 
 
 def north_star_histories(n: int = 1000):
-    """First `n` histories of bench.py's exact batch (same seed/params —
-    the comparison is only meaningful on identical inputs)."""
+    """First `n` histories of BASELINE.md's north-star batch (one
+    seed and shape — the comparison is only meaningful on identical
+    inputs)."""
     import random
 
     from jepsen_jgroups_raft_tpu.history.synth import random_valid_history
 
-    rng = random.Random(20260729)  # bench.py's exact seed and shape
+    rng = random.Random(20260729)
     out = []
     for _ in range(n):
         h = random_valid_history(rng, "register", n_ops=1000, n_procs=5,

@@ -173,7 +173,7 @@ class TestEndToEnd:
         """The production checker under JGRAFT_AUTOTUNE=1 (measuring +
         applying real plans) must report bitwise-identical verdicts to
         JGRAFT_AUTOTUNE=0 — the ISSUE-6 acceptance differential at test
-        scale (scripts/ab_autotune.py is the perf half)."""
+        scale."""
         from jepsen_jgroups_raft_tpu.checker.linearizable import (
             check_histories)
         from jepsen_jgroups_raft_tpu.models import CasRegister

@@ -483,60 +483,6 @@ def test_counterexample_per_key_in_independent(tmp_path):
     assert (tmp_path / "counterexample-7.html").exists()
 
 
-def test_platform_router_policy(monkeypatch):
-    """Per-shape platform routing (VERDICT r3 #4): tiny dense batches go
-    to the host backend when the chip is remote; big ones stay. Policy
-    gates on default_backend=tpu and the measured cell threshold; env
-    forces override."""
-    from jepsen_jgroups_raft_tpu.checker import linearizable as lin
-
-    # Not on a TPU → never route (nothing to route away from).
-    assert lin._route_group_to_host(8, 32) is False
-
-    class FakeJax:
-        @staticmethod
-        def default_backend():
-            return "tpu"
-
-        @staticmethod
-        def devices(kind=None):
-            return ["cpu0"]
-
-        @staticmethod
-        def local_devices(backend=None):
-            # the router probes THIS process's cpu devices (a global
-            # jax.devices("cpu") would list remote hosts' too)
-            return ["cpu0"]
-
-    monkeypatch.setitem(__import__("sys").modules, "jax", FakeJax)
-    # the measured default routes nothing (PR 32's re-reading)
-    assert lin.PLATFORM_ROUTE_MIN_CELLS == 0
-    assert lin._route_group_to_host(8, 32) is False
-    # an operator's gate (JGRAFT_ROUTE_MIN_CELLS), read at import
-    monkeypatch.setattr(lin, "PLATFORM_ROUTE_MIN_CELLS", 64_000)
-    assert lin._route_group_to_host(8, 32) is True        # tiny → host
-    assert lin._route_group_to_host(1000, 2048) is False  # big → chip
-    monkeypatch.setenv("JGRAFT_PLATFORM_ROUTE", "tpu")
-    assert lin._route_group_to_host(8, 32) is False
-    monkeypatch.setenv("JGRAFT_PLATFORM_ROUTE", "cpu")
-    assert lin._route_group_to_host(1000, 2048) is True
-
-
-def test_platform_router_forced_host_path_end_to_end(monkeypatch):
-    """JGRAFT_PLATFORM_ROUTE=cpu exercises the device_put branch (a
-    no-op placement on a CPU-only host, but the committed-input path and
-    the @host kernel tag must work end to end)."""
-    monkeypatch.setenv("JGRAFT_PLATFORM_ROUTE", "cpu")
-    rs = check_histories(
-        [H((0, INVOKE, "write", 1), (0, OK, "write", 1),
-           (1, INVOKE, "read", None), (1, OK, "read", 1)),
-         H((0, INVOKE, "write", 1), (0, OK, "write", 1),
-           (1, INVOKE, "read", None), (1, OK, "read", 9))],
-        CasRegister(), algorithm="jax")
-    assert [r["valid?"] for r in rs] == [True, False]
-    assert all(r["kernel"].endswith("@host") for r in rs), rs
-
-
 def test_unavailable_pinned_backend_raises():
     """A pinned backend that cannot initialize fails the check: the
     error propagates out of `check_histories` — no verdict is produced
@@ -574,23 +520,3 @@ def test_unavailable_pinned_backend_raises():
                              os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-2000:]
     assert "RAISED_OK" in out.stdout, out.stdout + out.stderr[-2000:]
-
-
-def test_calibrate_routing_script_runs():
-    """The routing-gate calibration script (doc/running.md "Measured
-    routing gates") must stay runnable — on a CPU-only session it
-    reports the degenerate single-backend case and exits 0."""
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    from jepsen_jgroups_raft_tpu.platform import cpu_subprocess_env
-
-    repo = Path(__file__).resolve().parents[1]
-    out = subprocess.run(
-        [sys.executable, str(repo / "scripts" / "calibrate_routing.py"),
-         "--quick", "--repeats", "1"],
-        capture_output=True, text=True, timeout=360,
-        env=cpu_subprocess_env(), cwd=repo)
-    assert out.returncode == 0, out.stderr[-1500:]
-    assert "cells" in out.stdout

@@ -350,19 +350,21 @@ def test_scan_chunk_env_gate(monkeypatch):
 
 
 @pytest.mark.slow
-def test_malformed_route_gate_does_not_crash_import():
-    """JGRAFT_ROUTE_MIN_CELLS=bogus used to raise ValueError at import
-    time in checker/linearizable.py; now it warns and uses the default."""
+def test_malformed_gate_does_not_crash_import():
+    """A malformed integer knob used to raise ValueError out of the
+    importer of checker/linearizable.py; now it warns and uses the
+    default."""
     out = subprocess.run(
         [sys.executable, "-c",
          "from jepsen_jgroups_raft_tpu.checker import linearizable as m; "
-         "print(m.PLATFORM_ROUTE_MIN_CELLS)"],
+         "print(m.scan_chunk())"],
         capture_output=True, text=True, timeout=120,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu",
-             "JGRAFT_ROUTE_MIN_CELLS": "sixty-four-thousand"},
+             "JGRAFT_SCAN_CHUNK": "one-hundred-and-twenty-eight"},
     )
     assert out.returncode == 0, out.stderr
-    assert out.stdout.strip().splitlines()[-1] == "0"
+    assert out.stdout.strip().splitlines()[-1] == \
+        str(schedule.DEFAULT_SCAN_CHUNK)
 
 
 def test_degraded_platform_note_in_results(monkeypatch):

@@ -327,9 +327,9 @@ def test_early_flush_keeps_stragglers_window_snug():
 def test_merge_long_clusters_by_window_spread(monkeypatch):
     """Round-5 policy: long histories merge into cluster launches while
     their windows stay within MERGE_LONG_MAX_SPREAD of the cluster's
-    widest member (measured 1.36x on config 4, scripts/ab_merge_long.py)
-    — but a window outlier must NOT be folded in (width inflation 2^dW
-    per step outruns any depth saving)."""
+    widest member (measured 1.36x on config 4) — but a window outlier
+    must NOT be folded in (width inflation 2^dW per step outruns any
+    depth saving)."""
     from jepsen_jgroups_raft_tpu.history.synth import random_valid_history
     from jepsen_jgroups_raft_tpu.ops.dense_scan import (
         MERGE_LONG_MAX_SPREAD, MERGE_MAX_EVENTS, dense_plans_grouped)

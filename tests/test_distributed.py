@@ -5,8 +5,8 @@ packers pinned against global-pack-then-shard, the defensive cluster
 env parse, and graftd's least-loaded shard routing with placement
 stamps. Slow coverage: REAL 2-process clusters over localhost gRPC —
 verdicts asserted bitwise-identical to a single-process run of the same
-batch (dense grouped + sort rung, macro on and off), the global-mesh
-capability probe, and the `bench.py --distributed` topology.
+batch (dense grouped + sort rung, macro on and off) and the global-mesh
+capability probe.
 """
 
 import json
@@ -383,28 +383,3 @@ def test_two_process_global_mesh_capability():
         assert marker, out[-1000:]
         markers.add(marker[-1].split(" ")[0])
     assert len(markers) == 1, markers  # both processes agree
-
-
-@pytest.mark.slow
-def test_distributed_bench_two_process(tmp_path):
-    """bench.py --distributed 2: the launcher brings up the topology,
-    process 0 emits one JSON row with the new placement fields and the
-    globally merged (all-valid) verdict counts."""
-    import subprocess
-
-    env = dict(os.environ)
-    env.update({"JGRAFT_AUTOTUNE": "0", "JGRAFT_BENCH_REPS": "1",
-                "JAX_PLATFORMS": "cpu"})
-    out = subprocess.run(
-        [sys.executable, str(REPO / "bench.py"), "--distributed", "2",
-         "16", "24"], capture_output=True, text=True, timeout=420, env=env)
-    assert out.returncode == 0, out.stderr[-2000:] + out.stdout[-2000:]
-    rows = [json.loads(ln) for ln in out.stdout.splitlines()
-            if ln.strip().startswith("{")]
-    [row] = [r for r in rows if r.get("metric") == "histories_per_sec"]
-    assert "error" not in row, row
-    assert row["n_processes"] == 2
-    assert row["process_id"] == 0
-    assert 0 < row["rows_local"] < 16
-    assert "per_host_pack_s" in row
-    assert row["value"] > 0

@@ -50,9 +50,9 @@ def test_store_split_per_key(tmp_path):
     assert [o["value"] for o in k9] == [None, None]
 
 
-def test_north_star_export_is_benchs_batch(tmp_path):
-    """First history of the export must be byte-equal in shape to what
-    bench.py synthesizes (same seed/params) — the comparison is only
+def test_north_star_export_is_the_seeded_batch(tmp_path):
+    """First history of the export must be byte-equal in shape to the
+    north-star batch's (same seed/params) — the comparison is only
     meaningful on identical inputs."""
     import random
 

@@ -576,20 +576,40 @@ def test_launches_of_one_key_from_two_threads_agree(monkeypatch):
 
 
 @pytest.mark.parametrize("serve_rows", [None, 16])
-def test_placement_is_the_gates_word_whoever_calls(serve_rows, monkeypatch):
-    """The platform gate decides a group's placement for a service's
-    launch exactly as for a library caller's (no caller is special), and
-    a host-placed key is a key of the set like any other."""
-    monkeypatch.setenv("JGRAFT_PLATFORM_ROUTE", "cpu")
+def test_placement_is_the_same_whoever_calls(serve_rows, monkeypatch):
+    """A service's launch is placed exactly as a library caller's (no
+    caller is special): by `build_dense_launches`, on the accelerator;
+    its rows' groups are counted, and its key is a key of the set."""
     monkeypatch.setenv("JGRAFT_SCAN_CHUNK", "16")
+    monkeypatch.setattr(schedule, "_BUILT", {})
     model = Counter()
     encs = [encode_history(h, model) for h in sixty_four("counter")[:12]]
-    routed = schedule.snapshot_stats()["host_routed_rows"]
+    groups = schedule.snapshot_stats()["groups_run"]
     rs = check_encoded(encs, model, algorithm="jax", serve_rows=serve_rows)
-    assert all(r["kernel"].endswith("@host") for r in rs)
-    assert schedule.snapshot_stats()["host_routed_rows"] - routed == 12
-    host_keys = [b for b in snapshot_built()
-                 if b["spec"] and b["spec"]["host"]]
-    assert host_keys
+    assert {r["kernel"] for r in rs} == {"dense-mask"}
+    assert schedule.snapshot_stats()["groups_run"] - groups >= 1
+    keys = [b for b in snapshot_built() if b["spec"]]
+    assert keys and not any(b["spec"]["host"] for b in keys)
+    assert all("cpu" not in str(b["key"][-1]) for b in keys)
     if serve_rows:   # built whole for the service's largest launch
-        assert any(b["rows"] == [8, 16] for b in host_keys)
+        assert any(b["rows"] == [8, 16] for b in keys)
+
+
+@pytest.mark.parametrize("n_rows", [8, 24, 256])
+def test_nothing_is_placed_on_the_host_cpu_beside_a_chip(n_rows,
+                                                         monkeypatch):
+    """With a TPU as the default backend and a cpu device at hand (as on
+    every chip host), no launch of any size is tagged `@host` or placed
+    on a cpu device: the host-cpu route lost on the chip (PERF.md
+    section 6, PR 32, call M) and is not in the tree."""
+    import jax
+
+    assert jax.local_devices(backend="cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    launch = _one_launch(n_rows)
+    assert launch.events.shape[0] == n_rows
+    assert not launch.tag.endswith("@host")
+    assert launch.spec["host"] is False
+    # the mesh's sharding or the default device, never a concrete one
+    assert launch.device is None or hasattr(launch.device, "mesh")
+    assert "cpu" not in schedule.launch_key(launch, 128)[-1]

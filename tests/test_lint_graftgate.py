@@ -370,7 +370,7 @@ class TestKnobClass:
         assert "unclassified" not in set(classes.values()), classes
         assert classes["JGRAFT_SCAN_CHUNK"] == knobclass.ROUTING
         assert classes["JGRAFT_SERVICE_JOURNAL"] == knobclass.DURABILITY
-        assert classes["JGRAFT_BENCH_REPS"] == knobclass.OPS
+        assert classes["JGRAFT_SERVICE_WORKERS"] == knobclass.OPS
         assert not any(v["verdict_reachable"] for v in knobs.values()), \
             [k for k, v in knobs.items() if v["verdict_reachable"]]
 

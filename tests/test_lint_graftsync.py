@@ -15,11 +15,15 @@ knob-parsing regressions the envknobs findings were fixed with, and the
 analyzers import no jax.
 """
 
+import functools
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 from jepsen_jgroups_raft_tpu.lint import cli, report
 from jepsen_jgroups_raft_tpu.lint.base import SourceFile
@@ -473,9 +477,9 @@ class TestEnvKnobs:
 
     def test_doc_brace_groups_expand(self):
         names = envknobs.doc_knob_names(
-            "| `JGRAFT_SERVICE_BENCH_{REQUESTS,HISTORIES}` | shape |\n")
-        assert {"JGRAFT_SERVICE_BENCH_REQUESTS",
-                "JGRAFT_SERVICE_BENCH_HISTORIES"} <= names
+            "| `JGRAFT_STREAM_{IDLE_S,SEGS_PER_S}` | budget |\n")
+        assert {"JGRAFT_STREAM_IDLE_S",
+                "JGRAFT_STREAM_SEGS_PER_S"} <= names
 
     def test_registry_harvests_the_repo_clean(self):
         registry, findings = envknobs.build_registry(REPO)
@@ -484,26 +488,88 @@ class TestEnvKnobs:
         assert registry["version"] == 2  # PR-17 adds class columns
         # the PR 12-15 knobs the audit reconciled are all present,
         # typed, and documented
-        for name in ("JGRAFT_SERVICE_WATCHDOG_S", "JGRAFT_BENCH_REPS",
-                     "JGRAFT_JOURNAL_GROUP_MS", "JGRAFT_SUITE_SCALE",
-                     "JGRAFT_STREAM_BENCH_SESSIONS"):
+        for name in ("JGRAFT_SERVICE_WATCHDOG_S", "JGRAFT_SCAN_CHUNK",
+                     "JGRAFT_JOURNAL_GROUP_MS", "JGRAFT_CLUSTER_TTL_S",
+                     "JGRAFT_STREAM_IDLE_S"):
             assert name in knobs, name
             assert knobs[name]["documented"], name
             assert knobs[name]["sites"], name
-        via = {s["via"] for s in knobs["JGRAFT_BENCH_REPS"]["sites"]}
+        via = {s["via"] for s in knobs["JGRAFT_SCAN_CHUNK"]["sites"]}
         assert via == {"env_int"}
 
-    def test_mutation_reverted_bench_parse_fires(self):
-        text = (REPO / "bench.py").read_text()
-        good = 'env_float("JGRAFT_BENCH_WATCHDOG_S", 300.0, minimum=0.0)'
+    def test_mutation_reverted_parse_fires(self):
+        text = (SERVICE / "daemon.py").read_text()
+        good = 'env_float("JGRAFT_SERVICE_WATCHDOG_S", 30.0, minimum=0.0)'
         assert good in text
         mutated = text.replace(
-            good, 'float(os.environ.get("JGRAFT_BENCH_WATCHDOG_S",'
-                  ' "300"))')
-        f = envknobs.analyze_source(src_of(mutated, "bench.py"),
+            good, 'float(os.environ.get("JGRAFT_SERVICE_WATCHDOG_S",'
+                  ' "30"))')
+        f = envknobs.analyze_source(src_of(mutated, "service/daemon.py"),
                                     doc_names=None)
         raw = [x for x in f if x.rule == envknobs.RULE_RAW]
-        assert raw and "JGRAFT_BENCH_WATCHDOG_S" in raw[0].message
+        assert raw and "JGRAFT_SERVICE_WATCHDOG_S" in raw[0].message
+
+
+# --------------------------------------- the knob count only falls
+
+
+#: Distinct ``JGRAFT_*`` names (brace-group prefixes in test strings
+#: included) the Python tree holds. A ratchet: lower it with every name
+#: a PR deletes; a PR that has to raise it says why in ROADMAP D3.
+KNOB_NAMES_MAX = 77
+_PY_ROOTS = ("jepsen_jgroups_raft_tpu", "tests", "scripts", "benchmarks",
+             "provision")
+
+
+def test_knob_names_only_fall_and_none_is_a_bench_knob():
+    files = [p for r in _PY_ROOTS for p in (REPO / r).rglob("*.py")]
+    files += list(REPO.glob("*.py"))
+    names = set()
+    for p in files:
+        names |= set(re.findall(r"JGRAFT_[A-Z0-9_]+", p.read_text()))
+    assert not [n for n in names if "BENCH" in n], sorted(names)
+    assert len(names) <= KNOB_NAMES_MAX, sorted(names)
+
+
+# ------------------------------ a document's commands are in the tree
+
+
+@functools.lru_cache(maxsize=None)
+def _tree_py():
+    """Every `*.py` of the tree by its path from the root (hidden
+    directories and what a chip call brought back apart)."""
+    return frozenset(
+        str(p) for p in (q.relative_to(REPO) for q in REPO.rglob("*.py"))
+        if not any(d.startswith(".") or d == "chiprun_out"
+                   for d in p.parts[:-1]))
+
+
+@pytest.mark.parametrize("doc", [
+    "README.md", "doc/running.md", "doc/checker-design.md",
+    "doc/intro.md", ".claude/skills/verify/SKILL.md",
+    ".github/workflows/lint.yml"])
+def test_every_script_a_document_runs_or_names_by_path_exists(doc):
+    """Every `python x.py` / `python -m pkg.mod` a document tells its
+    reader to run, and every `dir/file.py` it names, is in the tree."""
+    text = (REPO / doc).read_text()
+    tree = _tree_py()
+
+    def in_tree(path):
+        return any(f == path or f.endswith("/" + path) for f in tree)
+
+    gone = []
+    for mod, path in re.findall(
+            r"python3?\s+(?:-m\s+([\w.]+)|([\w./-]+\.py))", text):
+        if path and not (REPO / path).is_file():
+            gone.append(path)
+        if mod.startswith("jepsen_jgroups_raft_tpu"):
+            base = mod.replace(".", "/")
+            if not in_tree(base + ".py") and \
+                    not in_tree(base + "/__main__.py"):
+                gone.append(mod)
+    gone += [t for t in re.findall(r"(?<![\w/.-])([\w.-]+/[\w./-]*\w\.py)\b",
+                                   text) if not in_tree(t)]
+    assert not gone, (doc, sorted(set(gone)))
 
 
 # ------------------------------------------- knob-parse regressions
@@ -531,20 +597,23 @@ class TestKnobParsing:
         monkeypatch.setenv("JGRAFT_SERVICE_WATCHDOG_S", "banana")
         assert daemon.default_watchdog_margin() == 30.0
 
-    def test_bench_imports_with_garbage_knobs(self):
+    def test_importers_survive_garbage_knobs(self):
         # the PR 7 rule: a blank or garbage knob must never crash an
-        # importer (bench.py's parses used to be module-level raw
-        # float()/int() calls)
+        # importer (module-level raw float()/int() parses used to)
         for raw in ("garbage", "", " "):
-            env = dict(os.environ, JGRAFT_BENCH_WATCHDOG_S=raw,
+            env = dict(os.environ, JGRAFT_SERVICE_WATCHDOG_S=raw,
+                       JGRAFT_SCAN_CHUNK=raw, JGRAFT_SERVICE_WORKERS=raw,
                        JAX_PLATFORMS="cpu")
             out = subprocess.run(
                 [sys.executable, "-c",
-                 "import bench; print(bench.WATCHDOG_GAP_S)"],
+                 "import jepsen_jgroups_raft_tpu.checker.linearizable\n"
+                 "import chip_smoke\n"
+                 "from jepsen_jgroups_raft_tpu.service import daemon\n"
+                 "print(daemon.default_watchdog_margin())"],
                 cwd=REPO, env=env, capture_output=True, text=True,
                 timeout=120)
             assert out.returncode == 0, out.stderr
-            assert out.stdout.split() == ["300.0"], out.stdout
+            assert out.stdout.split() == ["30.0"], out.stdout
 
 
 # ------------------------------------------------------ CLI workflow

@@ -1,5 +1,5 @@
 """Long-history scaling: BASELINE.json configs #4 and #5 at suite-friendly
-sizes (full sizes run in bench.py). The checker's event scan is linear in
+sizes. The checker's event scan is linear in
 history length with fixed frontier width, so these must stay seconds-fast
 — the axis the reference's checker could not scale on (doc/intro.md:35-41,
 SURVEY.md §5.7)."""

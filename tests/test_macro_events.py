@@ -2,8 +2,8 @@
 differentials across the dense/mask/sort kernels and the chunked
 scheduler (incl. crashed-op trailing latches, P-bucket boundary shapes,
 pad_batch_bucketed round-trips, the JGRAFT_MACRO_EVENTS env-gate
-ablation), a Pallas interpret-mode differential, the per-run scan-stats
-scope, and the bench host-fingerprint/cold-warm satellites."""
+ablation), a Pallas interpret-mode differential, and the per-run
+scan-stats scope."""
 
 import random
 
@@ -365,7 +365,7 @@ def test_stats_scope_isolates_back_to_back_runs():
     """The ISSUE-4 regression: back-to-back checker invocations in one
     process must not accumulate counters in per-run reads — each scope
     sees only its own work while the process totals keep accumulating
-    for the bench's consume_stats."""
+    for consume_stats."""
     model = CasRegister()
     rng = random.Random(47)
     with stats_scope() as first:
@@ -453,53 +453,29 @@ def test_stats_scope_nested_zero_scopes_exit_cleanly():
     assert not schedule._SCOPES
 
 
-def test_routing_gates_key_on_legacy_event_lengths():
-    """The host/TPU cell gate and the LONG-group exact-padding policy
-    were calibrated on legacy event counts; macro batches must feed
-    them their legacy_events, not the ~2×-shorter macro row count."""
+def test_long_group_policy_keys_on_legacy_event_lengths():
+    """The LONG-group exact-padding policy was calibrated on legacy
+    event counts; macro batches must feed it their legacy_events, not
+    the ~2×-shorter macro row count."""
     from jepsen_jgroups_raft_tpu.checker.schedule import build_dense_launches
     from jepsen_jgroups_raft_tpu.ops.dense_scan import (DensePlan,
                                                         MERGE_MAX_EVENTS)
 
-    seen = []
-
-    def probe_route(n_rows, n_events):
-        seen.append((n_rows, n_events))
-        return False
-
     model = CasRegister()
     plan = DensePlan("mask", 2, 1, np.zeros((2, 1), np.int32))
     # A "long" group: legacy length over the merge threshold, macro
-    # rows well under it — exactness and the gate must see the former.
+    # rows well under it — exactness must see the former.
     legacy_e = MERGE_MAX_EVENTS + 100
     batch = {"events": np.zeros((2, legacy_e // 2, 11), np.int32),
              "n_events": np.full((2,), legacy_e // 2, np.int32),
              "n_slots": np.full((2,), 2, np.int32),
              "macro_p": 2, "legacy_events": legacy_e}
-    launches, _ = build_dense_launches(model, [([0, 1], plan, batch)],
-                                       host_route=probe_route)
+    launches, _ = build_dense_launches(model, [([0, 1], plan, batch)])
     assert launches[0].exact_rows  # long-ness keyed on legacy length
-    # gate fed the (bucketed) row count and the LEGACY event count
-    from jepsen_jgroups_raft_tpu.history.packing import bucket_rows
-    assert seen == [(bucket_rows(2), legacy_e)]
+    assert launches[0].device is None  # exact rows, default placement
     # And pack_macro_batch actually stamps the key it depends on.
     rng = random.Random(61)
     encs = [encode_history(random_valid_history(rng, "register", n_ops=8),
                            model) for _ in range(3)]
     mb = pack_macro_batch(encs)
     assert mb["legacy_events"] == max(e.n_events for e in encs)
-
-
-# ------------------------------------------------------- bench satellites
-
-
-def test_bench_host_fingerprint_and_cold_warm():
-    import bench
-
-    fp = bench.host_fingerprint()
-    for key in ("cpu_count", "loadavg_1m", "loadavg_5m", "jax", "jaxlib"):
-        assert key in fp
-    assert fp["cpu_count"] >= 1
-    assert bench.cold_warm([3.0, 1.0, 2.0]) == \
-        {"cold_rep_s": 3.0, "warm_rep_s": 1.0}
-    assert bench.cold_warm([1.5]) == {"cold_rep_s": 1.5, "warm_rep_s": 1.5}

@@ -4,7 +4,7 @@ BASELINE config #3's shape with real data: run actual native-cluster tests
 (real raft_server processes, real faults), then reload their persisted
 history.jsonl files and verify every per-key sub-history as one device
 batch — proving the production path (not synthetic histories) drives the
-kernel. Full 512-history scale runs in bench.py --suite.
+kernel.
 """
 
 import json

@@ -286,8 +286,7 @@ class TestCorpus:
 
 class TestDriver:
     def test_seed_determinism_identical_corpus(self, tmp_path, service):
-        """Same seed ⇒ identical corpus fingerprints — the contract
-        ab_search asserts before timing anything."""
+        """Same seed ⇒ identical corpus fingerprints."""
         reports = []
         for rep in range(2):
             cfg = tiny_config(tmp_path / f"rep{rep}")

@@ -289,16 +289,35 @@ def test_the_fast_lane_stamps_the_scan_phase(tmp_path, monkeypatch):
     assert total("request.formation_wait", "n", before) == 1
 
 
-def test_nine_spans_tile_the_dispatcher_loop(tmp_path):
+def test_nine_spans_tile_the_dispatcher_loop(tmp_path, monkeypatch):
+    from jepsen_jgroups_raft_tpu.service import scheduler
+
+    ended = []      # (thread, name, clock at its end, seconds), in order
+    note = schedule.note_span
+
+    def noting(name, seconds, n=1):
+        ended.append((threading.get_ident(), name, time.perf_counter(),
+                      seconds))
+        note(name, seconds, n)
+
+    monkeypatch.setattr(schedule, "note_span", noting)
+    monkeypatch.setattr(scheduler, "note_span", noting)
     before = snapshot_spans()
-    t0 = time.perf_counter()
     svc = CheckingService(store_root=str(tmp_path), n_workers=1)
     try:
         reqs = serve_waves(svc, waves=3, per_wave=3, salt0=100)
+        worker = svc._worker.ident
     finally:
         svc.shutdown()       # joins the worker: every span has ended
-    wall = time.perf_counter() - t0
-    tiled = sum(total(name, "s", before) for name in TILING)
+    # The dispatcher's own loop, on its own thread: from the start of
+    # its first `dispatch.take` to the end of its last tiling span (the
+    # take the shutdown woke). Building the service, a loaded host's
+    # thread start-up and another service's dispatcher in this process
+    # are not this loop's to tile.
+    loop = [(end, s) for t, name, end, s in ended
+            if t == worker and name in TILING]
+    wall = loop[-1][0] - (loop[0][0] - loop[0][1])
+    tiled = sum(s for _, s in loop)
     assert tiled >= 0.9 * wall, (tiled, wall)
     assert tiled <= 1.02 * wall
     # one row in four is invalid, and each is explained
