@@ -330,7 +330,8 @@ def main(argv=None) -> int:
                     help="admission queue capacity "
                          "(default: JGRAFT_SERVICE_QUEUE or 64)")
     sc.add_argument("--batch-wait-ms", type=int, default=None,
-                    help="batch-formation linger "
+                    help="batch-formation linger window, from the "
+                         "admission of a batch's oldest request "
                          "(default: JGRAFT_SERVICE_BATCH_WAIT_MS or 50)")
     sc.add_argument("--workers", type=int, default=None,
                     help="worker shards — one per host/device group; "

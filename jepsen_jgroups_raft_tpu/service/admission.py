@@ -19,6 +19,7 @@ admission time without touching the queue or the mesh.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -95,11 +96,43 @@ class AdmissionQueue:
         self._closed = False  # guarded_by(_cond)
         #: called (outside the lock) for each cancelled entry pruned out.
         self._on_prune = on_prune
+        #: submissions announced (`announce`) and not yet in the queue:
+        #: company the linger can see coming
+        self._arriving = 0  # guarded_by(_cond)
+        self._announced = threading.local()
 
     @property
     def depth(self) -> int:
         with self._cond:
             return len(self._pending)
+
+    @property
+    def arriving(self) -> int:
+        with self._cond:
+            return self._arriving
+
+    @contextlib.contextmanager
+    def announce(self):
+        """Count the calling thread's submission as ARRIVING from here
+        until `put` has it, or until the block ends without one (a
+        cache hit, an attach, a refusal, malformed input). Entered
+        before the decode, so the scheduler's linger sees a request
+        some tens of milliseconds before it can take it. One a thread
+        at a time: the service's `submit*` never call one another."""
+        with self._cond:
+            self._arriving += 1
+        self._announced.live = True
+        try:
+            yield
+        finally:
+            self._landed()
+
+    def _landed(self) -> None:
+        if getattr(self._announced, "live", False):
+            self._announced.live = False
+            with self._cond:
+                self._arriving -= 1
+                self._cond.notify_all()
 
     def put(self, req: CheckRequest, retry_after_s: float) -> None:
         """Admit, or raise QueueFull (caller-computed estimate) /
@@ -111,6 +144,7 @@ class AdmissionQueue:
             if len(self._pending) >= self.capacity:
                 raise QueueFull(len(self._pending), retry_after_s)
             self._pending.append(req)
+            self._landed()
             self._cond.notify_all()
 
     def close(self) -> None:
@@ -137,10 +171,13 @@ class AdmissionQueue:
 
     def take(self, chooser: Callable[[List[CheckRequest]],
                                      List[CheckRequest]],
-             timeout: float) -> List[CheckRequest]:
+             timeout: float,
+             while_arriving: bool = False) -> List[CheckRequest]:
         """Block up to `timeout` for `chooser` to select a non-empty
         batch from the pending snapshot; selected requests are removed
-        atomically. Cancelled entries — and already-terminal ones (the
+        atomically. With `while_arriving` the wait also ends, empty,
+        once no announced submission is on its way in (`announce`).
+        Cancelled entries — and already-terminal ones (the
         stale twin of a watchdog requeue whose other copy finished
         first) — are pruned (and reported via on_prune) before every
         selection, so neither ever reaches execution."""
@@ -156,14 +193,15 @@ class AdmissionQueue:
                 chosen = chooser(list(self._pending)) if self._pending else []
                 for r in chosen:
                     self._pending.remove(r)
-                if not chosen:
+                alone = while_arriving and not self._arriving
+                if not chosen and not alone:
                     remaining = deadline - time.monotonic()
                     if remaining > 0 and not pruned:
                         self._cond.wait(remaining)
             for r in pruned:
                 if self._on_prune is not None:
                     self._on_prune(r)
-            if chosen or time.monotonic() >= deadline:
+            if chosen or alone or time.monotonic() >= deadline:
                 return chosen
 
     def requeue(self, reqs: List[CheckRequest]) -> None:

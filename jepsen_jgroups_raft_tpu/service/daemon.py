@@ -900,10 +900,11 @@ class CheckingService:
         a cache hit). Raises QueueFull with a retry-after estimate when
         the queue is at capacity, ValueError on malformed input (unknown
         workload/consistency included)."""
-        req = admit(histories, workload, algorithm=algorithm,
-                    deadline_ms=deadline_ms, priority=priority,
-                    consistency=consistency)
-        return self._admit(req)
+        with self.queue.announce():
+            req = admit(histories, workload, algorithm=algorithm,
+                        deadline_ms=deadline_ms, priority=priority,
+                        consistency=consistency)
+            return self._admit(req)
 
     def submit_frame(self, payload) -> CheckRequest:
         """Admit a binary columnar submission frame (service/frame.py,
@@ -915,7 +916,8 @@ class CheckingService:
         matches `submit` (FrameError is a ValueError → 400)."""
         from .admission import admit_frame
 
-        return self._admit(admit_frame(payload))
+        with self.queue.announce():
+            return self._admit(admit_frame(payload))
 
     def submit_run_dir(self, run_dir, algorithm: str = "auto",
                        deadline_ms: Optional[float] = None,
@@ -923,10 +925,11 @@ class CheckingService:
                        workload: Optional[str] = None,
                        consistency: str = "linearizable") -> CheckRequest:
         """Admit a recorded-run directory (store/<name>/<ts>/)."""
-        req = admit_run_dir(run_dir, algorithm=algorithm,
-                            deadline_ms=deadline_ms, priority=priority,
-                            workload=workload, consistency=consistency)
-        return self._admit(req)
+        with self.queue.announce():
+            req = admit_run_dir(run_dir, algorithm=algorithm,
+                                deadline_ms=deadline_ms, priority=priority,
+                                workload=workload, consistency=consistency)
+            return self._admit(req)
 
     def _admit(self, req: CheckRequest) -> CheckRequest:
         if self._stop.is_set():
@@ -1070,6 +1073,9 @@ class CheckingService:
             out = dict(self._stats)
             out["decided_tier"] = dict(self._tier_counts)
             lat = list(self._latencies)
+        # ISSUE 37: batches taken with their linger window already
+        # spent in the queue (over span `dispatch.linger`'s `n`)
+        out["lingers_elapsed"] = self.scheduler.lingers_elapsed
         out["queue_depth"] = self.queue.depth
         out["cache_entries"] = len(self.cache)
         out["queue_capacity"] = self.queue.capacity

@@ -26,10 +26,18 @@ far-deadline request can be overtaken (starvation-free: after
 time breaks ties), and priority buys a fixed head start rather than a
 strict class (a priority flood cannot starve the plain tier forever).
 A batch is formed from the head request's shape bucket; when it is
-small and the head deadline is not imminent, the scheduler lingers
-``JGRAFT_SERVICE_BATCH_WAIT_MS`` for more same-bucket arrivals — the
-classic batching-window trade (latency of the head vs occupancy of the
-launch).
+small and the head deadline is not imminent, the scheduler lingers for
+more same-bucket arrivals until ``JGRAFT_SERVICE_BATCH_WAIT_MS`` have
+passed since the batch's OLDEST member was admitted — the classic
+batching-window trade (latency of the head vs occupancy of the launch):
+time spent queued behind a busy dispatcher counts against the window,
+so a saturated service never waits blind for company it already has.
+A LONE request has none yet, however long it queued: its window opens
+at the take, as it always did, so light closed-loop traffic (two
+clients taking turns) coalesces again. Past the window a batch waits
+only for company in sight: a submission a handler has announced and is
+still decoding (``AdmissionQueue.announce``), and never longer than one
+window from the take.
 
 Resilience: the device path failing MID-CHECK (backend teardown,
 injected fault) degrades the batch to the host-only ladder
@@ -57,9 +65,15 @@ from .request import CANCELLED, DONE, FAILED, RUNNING, CheckRequest
 
 LOG = logging.getLogger("jgraft.service")
 
-#: Default linger for batch formation (ms). Small against check time,
-#: large against localhost submit bursts: concurrent tenants submitting
-#: within one RPC round trip coalesce, a lone request pays ≤ this.
+#: Default linger window for batch formation (ms), counted from the
+#: admission of the batch's oldest member, or from the take for a lone
+#: request. Small against check time, large against localhost submit
+#: bursts: concurrent tenants submitting within one RPC round trip
+#: coalesce, a lone request pays ≤ this after the take, and requests
+#: that found company while they queued behind a busy dispatcher pay
+#: ≤ this in all, queue wait included: past it they launch at once,
+#: unless a submission is being decoded right then: that one is waited
+#: for, at most one window from the take.
 DEFAULT_BATCH_WAIT_MS = 50
 
 #: A request waiting this long is as urgent as scheduling ever treats
@@ -115,7 +129,8 @@ class ShardLoads:
 
 
 def batch_wait_s() -> float:
-    """Resolved linger window (JGRAFT_SERVICE_BATCH_WAIT_MS; defensive
+    """Resolved linger window (JGRAFT_SERVICE_BATCH_WAIT_MS: its length
+    from the oldest member's admission, 0 = never wait; defensive
     parse — garbage warns and keeps the default)."""
     return env_int("JGRAFT_SERVICE_BATCH_WAIT_MS", DEFAULT_BATCH_WAIT_MS,
                    minimum=0) / 1000.0
@@ -224,6 +239,12 @@ class BatchScheduler:
         self.aging_cap_s = aging_cap_s
         self.queue = queue
         self._seq = 0  # guarded_by(_seq_lock)
+        #: batches of two requests or more whose linger window had
+        #: already passed when they were taken (`/stats`
+        #: `lingers_elapsed`, beside `batches`);
+        #: over span `dispatch.linger`'s `n`, how often the window was
+        #: spent in the queue and not on the dispatcher
+        self.lingers_elapsed = 0  # guarded_by(_seq_lock)
         self._seq_lock = threading.Lock()
 
     # ------------------------------------------------------ formation
@@ -354,9 +375,33 @@ class BatchScheduler:
                    on_decided=None) -> List[CheckRequest]:
         """Block up to `timeout` for a batch. After the first pick, if
         the launch is far from full and the head's deadline allows,
-        linger one batch-wait window and sweep in same-bucket arrivals
-        (deadline order is preserved: the linger only ever ADDS rows to
-        the head's launch, it never reorders across buckets).
+        linger for same-bucket arrivals until the batch-wait window
+        closes (deadline order is preserved: the linger only ever ADDS
+        rows to the head's launch, it never reorders across buckets).
+
+        The window opened when the batch's OLDEST member was admitted
+        (`min(r.submitted)`; `_choose` orders by effective deadline, so
+        that need not be the head), not at the take: the dispatcher
+        waits ``batch_wait - age`` and no longer. A batch that queued
+        for part of the window waits the rest, and one that queued
+        longer behind a busy dispatcher launches at once — whatever
+        was going to arrive with it is in the queue and was taken with
+        it, or is being decoded right now: while a submission is
+        announced (`AdmissionQueue.announce`; any bucket's, the queue
+        cannot tell before the decode) a batch whose window has passed
+        is held for it, until it lands or gives up and at most one
+        window from the take, because the launch it misses by
+        milliseconds costs it a whole cycle. A batch of ONE request
+        was taken without company, so its age says nothing of who is
+        still coming: its window opens at the take, at an idle service
+        and behind a busy dispatcher alike (two clients in a closed
+        loop would otherwise take turns, each launched alone the moment
+        the other's launch ends). The window also closes when the launch
+        is full: the wait is on the admission queue's condition, not a
+        sleep. Span `dispatch.linger` is entered for every batch the
+        linger applies to and records the seconds waited;
+        `lingers_elapsed` counts those whose window had passed at the
+        take (never a lone request's).
 
         ``on_decided`` (ISSUE 14): when given, the fast lane certifies
         the popped requests FIRST — before the linger, so a decided
@@ -376,10 +421,16 @@ class BatchScheduler:
                 return []
         head = batch[0]
         rows = sum(r.n_rows for r in batch)
-        slack = head.deadline - time.monotonic()
+        now = time.monotonic()
         if (self.batch_wait > 0 and rows < self.max_batch_rows
-                and not head.solo and slack > self.batch_wait):
+                and not head.solo
+                and head.deadline - now > self.batch_wait):
             sig = bucket_signature(head)
+            # a lone request has no company yet: its window opens here
+            opened = (now if len(batch) == 1
+                      else min(r.submitted for r in batch))
+            closes = opened + self.batch_wait
+            holds = now + self.batch_wait
 
             def topup(pending: List[CheckRequest]) -> List[CheckRequest]:
                 extra, extra_rows = [], rows
@@ -394,15 +445,38 @@ class BatchScheduler:
                     extra_rows += r.n_rows
                 return extra
 
+            extras: List[CheckRequest] = []
             with span("dispatch.linger"):
-                time.sleep(self.batch_wait)
-                extra = self.queue.take(topup, timeout=0.0)
-            _stamp_taken(extra)
-            if on_decided is not None and extra:
-                done, extra = self.fastlane(extra)
+                if closes <= now:
+                    with self._seq_lock:
+                        self.lingers_elapsed += 1
+                while rows < self.max_batch_rows:
+                    t = time.monotonic()
+                    # either wait wakes on every admission; a different
+                    # bucket's arrival is left in the queue and the
+                    # wait goes on
+                    if t < closes:
+                        more = self.queue.take(topup, timeout=closes - t)
+                    elif t < holds:
+                        # the window has passed: only company in sight
+                        # is waited for. A submission a handler is
+                        # decoding lands in tens of milliseconds and
+                        # would otherwise wait out this whole launch;
+                        # with none announced this returns at once
+                        more = self.queue.take(topup, timeout=holds - t,
+                                               while_arriving=True)
+                        if not more:
+                            break
+                    else:
+                        break
+                    _stamp_taken(more)
+                    rows += sum(r.n_rows for r in more)
+                    extras.extend(more)
+            if on_decided is not None and extras:
+                done, extras = self.fastlane(extras)
                 if done:
                     on_decided(done)
-            batch.extend(extra)
+            batch.extend(extras)
         # Requests cancelled between pop and here stay in the batch:
         # execute() finalizes them as CANCELLED (dropping them silently
         # would leave their waiters blocked forever).
