@@ -17,7 +17,7 @@ Invocations that never complete by the end of the history are treated as
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Any, Iterable, Iterator, Optional, Union
+from typing import Any, Iterable, Iterator, NamedTuple, Optional, Union
 
 INVOKE = "invoke"
 OK = "ok"
@@ -90,6 +90,23 @@ class Op:
             error=d.get("error"),
             extra={k: v for k, v in d.items() if k not in known},
         )
+
+
+class OpRow(NamedTuple):
+    """The five fields of an `Op` that pairing and encoding read
+    (`pair_ops_indexed`, the models' `encode_pairs_columnar`,
+    `packing.encode_history`), as a slotted tuple: what a wire history
+    becomes on its way to an encoding (`service/request.rows_from_dicts`
+    zips them from the op dicts' columns), where nothing reads `time`,
+    `error` or `extra` and a dataclass an event was two thirds of a
+    submission's host time (ISSUE 39). `index` is already resolved
+    (never negative), as `History.append` leaves an `Op`'s."""
+
+    process: Union[int, str]
+    type: str
+    f: str
+    value: Any
+    index: int
 
 
 def invoke_op(process, f, value=None, time=-1) -> Op:

@@ -157,6 +157,18 @@ def _netloc(url: str) -> str:
     return url.rstrip("/")
 
 
+class EncodeStats:
+    """How a client's binary submissions were encoded (ISSUE 39):
+    `columns` of them from their rows' columns, `objects` through `Op`
+    objects (`request.encode_units` says which inputs go where), and the
+    `seconds` all of them spent between `submit` and a finished frame."""
+
+    def __init__(self):
+        self.columns = 0
+        self.objects = 0
+        self.seconds = 0.0
+
+
 class ServiceClient:
     def __init__(self, base_url: str, timeout: float = 30.0,
                  max_attempts: int = 4, backoff_base_s: float = 0.1,
@@ -206,6 +218,9 @@ class ServiceClient:
         #: vs. calls served on an already-open connection.
         self.conn_opened = 0
         self.conn_reused = 0
+        #: binary submissions by how they were encoded, and the seconds
+        #: that took (ISSUE 39)
+        self.encode_stats = EncodeStats()
 
     # ---------------------------------------------------- connections
 
@@ -475,26 +490,33 @@ class ServiceClient:
                        priority: int, retry: bool, consistency: str,
                        affinity: bool) -> dict:
         """Client-side encode + one columnar frame (ISSUE 18 tentpole):
-        the SAME `build_units` + `encode_history` the server's JSON
-        path runs, executed here — so the server-derived fingerprint
-        over the shipped tensors is byte-identical to the JSON path's,
-        and the locally computed digest doubles as the rendezvous
-        affinity key (replica cache locality for free). The frame is
-        built ONCE; every retry re-sends identical bytes."""
+        the SAME `encode_units` the server's JSON path runs, executed
+        here — so the server-derived fingerprint over the shipped
+        tensors is byte-identical to the JSON path's, and the locally
+        computed digest doubles as the rendezvous affinity key (replica
+        cache locality for free). Histories that arrive as op-dict rows
+        are encoded from those rows' columns, `History` objects through
+        their `Op`s: the same frame either way, `encode_stats` counts
+        which (ISSUE 39). The frame is built ONCE; every retry re-sends
+        identical bytes."""
         from ..checker.consistency import normalize_consistency
         from .frame import encode_submit_frame
-        from .request import build_units, fingerprint_encodings
+        from .request import encode_units, fingerprint_encodings
 
-        from ..history.packing import encode_history
-
+        t0 = time.perf_counter()
         consistency = normalize_consistency(consistency)
-        model, units = build_units(histories, workload)
-        encs = [encode_history(h, model) for _, h in units]
+        model, units, encs, from_columns = encode_units(histories, workload)
         fp = fingerprint_encodings(model, algorithm, encs, consistency)
         frame = encode_submit_frame(
             workload, algorithm, consistency,
             [label for label, _ in units], encs,
             deadline_ms=deadline_ms, priority=priority, fingerprint=fp)
+        with self._counter_lock:
+            if from_columns:
+                self.encode_stats.columns += 1
+            else:
+                self.encode_stats.objects += 1
+            self.encode_stats.seconds += time.perf_counter() - t0
         rec = self._call("POST", "/submit", retry=retry,
                          affinity=fp if affinity else None, raw=frame)
         self._remember_owner(rec.get("id"))

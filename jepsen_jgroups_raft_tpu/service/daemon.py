@@ -236,6 +236,10 @@ class CheckingService:
             # shard queue, or a kernel launch. Always in the schema,
             # zero when the lane is off.
             "fastpath_requests": 0,
+            # JSON submissions by how `admit` encoded them (ISSUE 39;
+            # `request.encode_units`): from their rows' columns, or
+            # through `Op` objects
+            "encoded_from_columns": 0, "encoded_through_objects": 0,
             # cluster tier (ISSUE 11) — always in the schema, zero when
             # clustering is not configured (the seam stays inert)
             "store_hits": 0, "store_puts": 0,
@@ -904,6 +908,8 @@ class CheckingService:
             req = admit(histories, workload, algorithm=algorithm,
                         deadline_ms=deadline_ms, priority=priority,
                         consistency=consistency)
+            self._count("encoded_from_columns" if req.from_columns
+                        else "encoded_through_objects")
             return self._admit(req)
 
     def submit_frame(self, payload) -> CheckRequest:
@@ -1297,8 +1303,8 @@ class CheckingService:
             tmp = d / "history.jsonl.tmp"
             with open(tmp, "w") as f:
                 for label, hist in req.units:
-                    for op in hist:
-                        row = dict(op.to_dict(), unit=label)
+                    for row in hist.to_dicts():
+                        row["unit"] = label
                         row_line = json.dumps(_jsonable(row)) + "\n"
                         # best-effort trace: atomic via the replace
                         # below, durability deliberately not promised

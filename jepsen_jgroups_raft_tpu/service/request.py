@@ -18,14 +18,16 @@ import threading
 import time
 import uuid
 from dataclasses import dataclass, field
+from itertools import repeat
 from typing import List, Optional, Sequence
 
 import numpy as np
 
 from ..checker.base import merge_valid
 from ..checker.schedule import span
-from ..history.ops import History, Op
-from ..history.packing import EncodedHistory, encode_history
+from ..history.ops import NEMESIS, History, Op, OpRow
+from ..history.packing import (EncodedHistory, encode_history,
+                               encode_vector_on)
 
 # Request lifecycle states.
 QUEUED = "queued"
@@ -72,6 +74,85 @@ def history_from_dicts(rows: Sequence[dict]) -> History:
             d["value"] = tuple(d["value"])
         h.append(Op.from_dict(d))
     return h
+
+
+#: the keys of an op dict that `Op` holds as fields (`Op.from_dict`);
+#: the rest ride in `extra`
+_OP_KEYS = frozenset(
+    ("process", "type", "f", "value", "time", "index", "error"))
+
+
+def _wire_columns(dicts: Sequence[dict]):
+    """The columns of a wire history that its encoding reads, under
+    `history_from_dicts`'s rules: a missing `process` / `type` / `f` is
+    a KeyError, list values are retupled, and `index` defaults to the
+    row's position among ALL rows (what `History.append` gives an `Op`
+    whose index is unset)."""
+    procs = [d["process"] for d in dicts]
+    types = [d["type"] for d in dicts]
+    fs = [d["f"] for d in dicts]
+    values = [d.get("value") for d in dicts]
+    index = [d.get("index", -1) for d in dicts]
+    if any(issubclass(t, list) for t in set(map(type, values))):
+        values = [tuple(v) if isinstance(v, list) else v for v in values]
+    if index and min(index) < 0:
+        index = [i if x < 0 else x for i, x in enumerate(index)]
+    return procs, types, fs, values, index
+
+
+def _client_rows(cols) -> List[OpRow]:
+    """`_wire_columns`' answer zipped into `OpRow`s, nemesis rows left
+    out (`History.client_ops`). `tuple.__new__` is what `OpRow._make`
+    calls, without a Python frame a row."""
+    rows = list(map(tuple.__new__, repeat(OpRow), zip(*cols)))
+    if NEMESIS in cols[0]:
+        rows = [r for r in rows if r.process != NEMESIS]
+    return rows
+
+
+def rows_from_dicts(dicts: Sequence[dict]) -> List[OpRow]:
+    """Wire format → the client ops as `OpRow`s: what
+    `history_from_dicts(dicts).client_ops()` holds, field for field of
+    the five an encoding reads, with no `Op` built an event — the
+    columns are read straight from the dicts and zipped."""
+    return _client_rows(_wire_columns(dicts))
+
+
+class WireHistory(History):
+    """`history_from_dicts(dicts).client_ops()` whose `Op`s are built
+    when something first reads them: the unit of a submission that was
+    encoded from its rows' columns (`encode_units`). Nothing on the way
+    to a verdict reads them; a counterexample does (`scheduler.
+    _attach_counterexamples`, invalid rows only), and the trace record
+    reads `to_dicts`, which answers from the rows without them."""
+
+    def __init__(self, dicts: Sequence[dict]):
+        self._dicts = dicts
+        self._ops: Optional[List[Op]] = None
+
+    @property
+    def ops(self) -> List[Op]:
+        if self._ops is None:
+            self._ops = history_from_dicts(self._dicts).client_ops().ops
+        return self._ops
+
+    def to_dicts(self) -> List[dict]:
+        """`[op.to_dict() for op in self]`, key for key and in
+        `Op.to_dict`'s order."""
+        if self._ops is not None:
+            return super().to_dicts()
+        out = []
+        for d, p, t, f, v, i in zip(self._dicts,
+                                    *_wire_columns(self._dicts)):
+            if p == NEMESIS:
+                continue
+            row = {"process": p, "type": t, "f": f, "value": v,
+                   "time": d.get("time", -1), "index": i}
+            if d.get("error") is not None:
+                row["error"] = d["error"]
+            row.update((k, x) for k, x in d.items() if k not in _OP_KEYS)
+            out.append(row)
+        return out
 
 
 def fingerprint_encodings(model, algorithm: str,
@@ -193,6 +274,10 @@ class CheckRequest:
     #: differ in cross-key session order). The binary lane
     #: (admit_encoded) ships encodings only, so it has no overlay.
     txn_anomalies: Optional[dict] = None
+    #: `admit` encoded it from its rows' columns (`encode_units`); the
+    #: daemon counts the two ways in `/stats`. Says how the encoding was
+    #: made, never what it is: the arrays are equal either way.
+    from_columns: bool = False
     _done: threading.Event = field(default_factory=threading.Event)
     _finish_lock: threading.Lock = field(default_factory=threading.Lock)
 
@@ -272,6 +357,17 @@ class CheckRequest:
         return d
 
 
+def _workload_model(workload: str):
+    """(model instance, split per key?) of a service workload, or the
+    ValueError every admission path answers an unknown one with."""
+    workloads = service_workloads()
+    if workload not in workloads:
+        raise ValueError(f"unknown workload {workload!r} "
+                         f"(have: {', '.join(sorted(workloads))})")
+    model_factory, independent = workloads[workload]
+    return model_factory(), independent
+
+
 def build_units(histories: Sequence, workload: str):
     """Normalize raw submission material into (model, units): the
     workload's model instance plus (label, History) pairs — one
@@ -279,13 +375,10 @@ def build_units(histories: Sequence, workload: str):
     ONE home for the unit decomposition, shared by server-side `admit`
     and the binary lane's CLIENT-side encoder (ISSUE 18): both sides
     must derive identical unit lists from identical histories, or the
-    server-derived fingerprint would diverge from the JSON path's."""
-    workloads = service_workloads()
-    if workload not in workloads:
-        raise ValueError(f"unknown workload {workload!r} "
-                         f"(have: {', '.join(sorted(workloads))})")
-    model_factory, independent = workloads[workload]
-    model = model_factory()
+    server-derived fingerprint would diverge from the JSON path's.
+    (Both reach it through `encode_units`, below, which also owns the
+    one other way to the same units and encodings.)"""
+    model, independent = _workload_model(workload)
     units: List[tuple] = []
     for i, h in enumerate(histories):
         if not isinstance(h, History):
@@ -304,6 +397,47 @@ def build_units(histories: Sequence, workload: str):
     return model, units
 
 
+def encode_units(histories: Sequence, workload: str):
+    """(model, units, encs, from_columns): `build_units` and an
+    `encode_history` a unit, for both wires (`admit` for a JSON body,
+    `ServiceClient._submit_binary` for a frame) so that they cannot
+    drift. It adapts on what it can observe in its input, no knob
+    (ISSUE 39): histories that all arrive as lists of op DICTS, for a
+    workload that is not split per key and a model with a columnar twin
+    (`encode_pairs_columnar`), are encoded from the rows' columns —
+    `rows_from_dicts`, then the same `pair_ops_indexed`, the same twin
+    and the same `encode_history` body reading `OpRow`s, with no `Op`,
+    `History` walk or `OpPair` an event; the units are `WireHistory`s.
+    Anything else (`History` objects, independent workloads, a model
+    without the twin, ``JGRAFT_ENCODE_VECTOR=0``) takes the object path
+    as it stood, which is the differential oracle
+    (tests/test_submit_columns.py): equal labels, equal `EncodedHistory`
+    arrays, so one fingerprint and one frame, byte for byte; and the
+    same errors, since every rule but the four of `_wire_columns` /
+    `rows_from_dicts` is the same code."""
+    from ..models.base import Model
+
+    model, independent = _workload_model(workload)
+    from_columns = (
+        not independent and bool(histories) and encode_vector_on()
+        and type(model).encode_pairs_columnar
+        is not Model.encode_pairs_columnar
+        and all(isinstance(h, (list, tuple))
+                and set(map(type, h)) <= {dict} for h in histories))
+    if not from_columns:
+        model, units = build_units(histories, workload)
+        encs = [encode_history(h, model) for _, h in units]
+        return model, units, encs, False
+    # every history's columns first (a malformed row anywhere is
+    # refused before any pairing error, as `build_units` does); a
+    # history's rows then live only while it is encoded, so the
+    # collector never walks a submission's worth of them
+    cols = [_wire_columns(h) for h in histories]
+    encs = [encode_history(_client_rows(c), model) for c in cols]
+    units = [(f"h{i}", WireHistory(h)) for i, h in enumerate(histories)]
+    return model, units, encs, True
+
+
 def admit(histories: Sequence, workload: str, algorithm: str = "auto",
           deadline_ms: Optional[float] = None, priority: int = 0,
           default_deadline_s: float = 3600.0,
@@ -318,8 +452,7 @@ def admit(histories: Sequence, workload: str, algorithm: str = "auto",
 
     consistency = normalize_consistency(consistency)
     with span("ingest.decode"):
-        model, units = build_units(histories, workload)
-        encs = [encode_history(h, model) for _, h in units]
+        model, units, encs, from_columns = encode_units(histories, workload)
     txn = None
     if getattr(model, "txn_anomaly_capable", False):
         # host-only (kernel=False inside): Tarjan + numpy closure on
@@ -349,6 +482,7 @@ def admit(histories: Sequence, workload: str, algorithm: str = "auto",
         priority=clamp_priority(priority),
         consistency=consistency,
         txn_anomalies=txn,
+        from_columns=from_columns,
     )
 
 
@@ -377,11 +511,7 @@ def admit_encoded(workload: str, labels: Sequence[str],
     from ..checker.consistency import normalize_consistency
 
     consistency = normalize_consistency(consistency)
-    workloads = service_workloads()
-    if workload not in workloads:
-        raise ValueError(f"unknown workload {workload!r} "
-                         f"(have: {', '.join(sorted(workloads))})")
-    model = workloads[workload][0]()
+    model, _ = _workload_model(workload)
     if not encs:
         raise ValueError("empty submission: no checkable history units")
     if len(labels) != len(encs):
