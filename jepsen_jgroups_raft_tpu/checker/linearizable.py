@@ -51,6 +51,7 @@ from ..history.packing import (EncodedHistory, bucket_rows, encode_history,
                                pack_macro_batch, pad_batch_bucketed)
 from ..ops.dense_scan import (MASK_DENSE_MAX_SLOTS, MERGE_MAX_EVENTS,
                               dense_plans_grouped, make_dense_batch_checker)
+from ..ops.kernel_ir import SEGMENT_MAX_SLOTS
 from ..ops.linear_scan import (DEFAULT_N_CONFIGS, MAX_SLOTS, bucket_slots,
                                make_batch_checker, make_sort_chunk_checker)
 from ..ops.segment_scan import LONG_HISTORY_MIN_EVENTS, check_segmented_batch
@@ -59,8 +60,8 @@ from . import autotune
 from .base import Checker, INVALID, UNKNOWN, VALID
 from .dfs_cpu import SearchBudgetExceeded, check_encoded_dfs
 from .schedule import (ChunkLaunch, build_dense_launches, launch_span,
-                       note_tier, run_chunked, scan_chunk,
-                       snapshot_compiles)
+                       note_tier, note_wide, run_chunked, scan_chunk,
+                       snapshot_compiles, span)
 from .wgl_cpu import FrontierOverflow, check_encoded_cpu
 
 
@@ -608,6 +609,10 @@ def _check_encoded(
         return _race(encs, model, n_configs, n_slots, witness,
                      max_cpu_configs)
 
+    wide = [e.n_slots > SEGMENT_MAX_SLOTS and e.n_events > 0 for e in encs]
+    if algorithm in ("jax", "auto", "pallas") and any(wide):
+        note_wide(wide_rows=sum(wide))
+
     if algorithm == "auto":
         # Wide-window fast path: a history whose concurrency window is
         # beyond every dense kernel is frontier-hostile (breadth-first
@@ -616,13 +621,17 @@ def _check_encoded(
         # in 4.3k DFS configs after 70s of doomed frontier work). Spend
         # a small DFS budget first; undecided histories take the normal
         # kernel → CPU → full-budget-DFS ladder below.
-        for i, e in enumerate(encs):
-            if results[i] is None and e.n_slots > MASK_DENSE_MAX_SLOTS \
-                    and e.n_events > 0:
-                r = _check_dfs(e, model, witness,
-                               max_steps=FAST_DFS_BUDGET)
-                if r["valid?"] is not UNKNOWN:
-                    results[i] = r
+        first = [i for i, e in enumerate(encs)
+                 if e.n_slots > MASK_DENSE_MAX_SLOTS and e.n_events > 0]
+        if first:
+            with span("launch.escalate", n=len(first)):
+                for i in first:
+                    r = _check_dfs(encs[i], model, witness,
+                                   max_steps=FAST_DFS_BUDGET)
+                    if r["valid?"] is not UNKNOWN:
+                        results[i] = r
+            note_wide(wide_rows_host=sum(
+                results[i] is not None for i in first))
 
     if algorithm in ("jax", "auto", "pallas"):
         undecided = [e if results[i] is None else None
@@ -647,9 +656,30 @@ def _check_encoded(
                     }
             return results  # type: ignore[return-value]
 
-    for i, r in enumerate(results):
+    left = [i for i, r in enumerate(results) if r is None]
+    if not left:
+        return results  # type: ignore[return-value]
+    if algorithm != "auto":   # "cpu": the host engine was what was asked
+        _escalate(encs, results, left, model, algorithm, witness,
+                  max_cpu_configs)
+        return results  # type: ignore[return-value]
+    # What the device pass left undecided goes to the host engines, on
+    # the calling thread (graftd's dispatcher): one span over all of it.
+    with span("launch.escalate", n=len(left)):
+        _escalate(encs, results, left, model, algorithm, witness,
+                  max_cpu_configs)
+    note_wide(wide_rows_host=sum(
+        wide[i] and results[i].get("valid?") is not UNKNOWN for i in left))
+    return results  # type: ignore[return-value]
+
+
+def _escalate(encs, results, left, model, algorithm, witness,
+              max_cpu_configs) -> None:
+    """The host engines of `algorithm="auto"` (and of "cpu") for the
+    rows `left` undecided: budgeted DFS and the CPU frontier twin."""
+    for i in left:
         dfs_exhausted = False
-        if r is None and algorithm == "auto" and \
+        if algorithm == "auto" and \
                 encs[i].n_slots > MASK_DENSE_MAX_SLOTS:
             # Wide windows that the kernels couldn't decide: try the
             # budgeted DFS BEFORE the CPU frontier twin — it explores in
@@ -675,7 +705,6 @@ def _check_encoded(
                             max_steps=DEFAULT_DFS_BUDGET)
             if r2["valid?"] is not UNKNOWN:
                 results[i] = r2
-    return results  # type: ignore[return-value]
 
 
 def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,

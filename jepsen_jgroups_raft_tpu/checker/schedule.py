@@ -112,7 +112,11 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # launch-shape set (ISSUE 32): programs the build-ahead
                # of a key built or loaded, and programs a launch built
                # or loaded AFTER its key was built (healthy: 0).
-               "programs_built_ahead": 0, "shape_misses": 0}
+               "programs_built_ahead": 0, "shape_misses": 0,
+               # wide windows (ISSUE 40): rows past SEGMENT_MAX_SLOTS that
+               # entered the kernel ladder and, of those, the rows a host
+               # engine decided
+               "wide_rows": 0, "wide_rows_host": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -143,6 +147,12 @@ def _add_stats(**kw) -> None:
             _STATS[k] += v
             for scope in targets:
                 scope[k] += v
+
+
+def note_wide(**kw) -> None:
+    """Record the wide-window counters (ISSUE 40), like `note_cycle`:
+    `wide_rows`, `wide_rows_host`."""
+    _add_stats(**kw)
 
 
 def note_cycle(**kw) -> None:

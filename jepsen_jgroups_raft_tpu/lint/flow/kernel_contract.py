@@ -321,7 +321,7 @@ CONTRACTS: Dict[str, Contract] = {
          "window cap would consume the sentinel bit of the last word"),
     ]),
     "ops/segment_scan.py": Contract(const_asserts=[
-        ("MAX_BASIS * DENSE_MAX_CELLS * 4", 16 << 20,
+        ("MAX_BASIS * SEGMENT_MAX_CELLS * 4", 16 << 20,
          "segment seed-basis frontier at the caps exceeds VMEM"),
         ("DEFAULT_BLOCK_EVENTS * 5 * 4", 16 << 20,
          "segment event slab exceeds VMEM"),

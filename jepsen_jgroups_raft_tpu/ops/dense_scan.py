@@ -243,7 +243,12 @@ class GroupCost:
 #: `fixed_ms` comes from, and how the partitions the table picks
 #: compare with the fastest measured: PERF.md section 6, PR 33). S 4
 #: was read up to 128 rows (larger batches of the cell hold a history
-#: of five values).
+#: of five values). W 11 and 12 at S 8 are ISSUE 40's (the same shapes,
+#: medians of three warm runs): the edge of the table had booked them at
+#: 1.42 times a window where the cells double (128 rows: 727 and 1,033
+#: ms against 901 and 1,614 read), and merged every batch of the
+#: partition cell into one group at its widest window (PERF.md
+#: section 6, PR 40).
 TPU_GROUP_COST = GroupCost(
     rows=(8, 32, 64, 128, 256, 512, 1024),
     ms={"mask": {1: {
@@ -267,7 +272,9 @@ TPU_GROUP_COST = GroupCost(
                 7: (48.8, 75.8, 101.7, 170.2, 356.8, 773.0, 1698.3),
                 8: (63.3, 105.4, 159.4, 249.8, 526.8, 1095.7, 2373.2),
                 9: (86.1, 142.7, 209.2, 360.0, 810.4),
-                10: (134.6, 248.0, 338.0, 511.9, 1153.1)}}},
+                10: (134.6, 248.0, 338.0, 511.9, 1153.1),
+                11: (222.6, 403.7, 574.1, 901.2, 1872.7),
+                12: (443.8, 756.5, 1068.4, 1614.3, 3306.1)}}},
     steps={"mask": 2000, "domain": 1614},
     fixed_ms={"mask": 5.6, "domain": 19.1})
 

@@ -69,9 +69,37 @@ from ..history.packing import EV_FORCE, EV_OPEN, MACRO_MAX_OPENS
 #: reserved for genuinely small problems — which the reference's own
 #: workload shapes are (window ≈ n_procs, domain ≈ 5 values; a few
 #: crashed ops' never-retiring slots push long histories to W ≈ 10).
-DENSE_MAX_SLOTS = 10
+#: Since ISSUE 40 the caps stand at the widest window the family has
+#: been read at on a chip (one TPU v5 lite; PERF.md section 5, PR 40):
+#: one group of S 8 register rows of 1,000 ops costs 223 / 444 /
+#: 885-925 ms at W 11 / 12 / 13 for 8 rows and 901 / 1,614 ms at W 11 /
+#: 12 for 128 (the cells double with a window and so does the time).
+#: Read on two inputs only: crash-free register histories widened to
+#: their launch's window (W 11-12, `scripts/sweep_group_cost.py`) and
+#: the partition-nemesis histories of `benchmarks/generators/
+#: partition.py` (W 11-13, served); no other model, S or length. What
+#: the route replaced for those rows: the sort ladder, whose top rung
+#: (C = 256) 122 of 124 of them overflowed (frontiers of 1,900-24,800
+#: configurations), then the host engines on the caller's thread, 0.6
+#: to 8.8 s for a row the first DFS budget leaves. A caller whose W
+#: 11-13 rows have frontiers the first rung (C = 64) holds now pays the
+#: dense sweep of 16k-65k cells for them: not measured. Past W 13 no
+#: memory limit refuses (2^W x S x 4 B a row: 512 KiB at W 14), but a W
+#: 14 group would cost twice W 13's 0.9 s, what the host's full DFS
+#: budget costs: unmeasured, so not taken.
+DENSE_MAX_SLOTS = 13
 DENSE_MAX_STATES = 16
-DENSE_MAX_CELLS = 8192  # 2^W · S
+DENSE_MAX_CELLS = 65536  # 2^W · S
+
+#: The caps every route held until ISSUE 40. The segmented long-history
+#: route (ops/segment_scan.py) keeps them: MAX_BASIS frontiers of this
+#: many cells are what the kernel-contract analyzer holds to the VMEM
+#: budget. A window past SEGMENT_MAX_SLOTS is WIDE: only the plain dense
+#: family holds it, and `ops/dense_scan.TPU_GROUP_COST` was first read
+#: up to it (`/stats` `wide_rows`, `wide_rows_host`: counted, never
+#: routed on).
+SEGMENT_MAX_SLOTS = 10
+SEGMENT_MAX_CELLS = 8192
 
 #: Mask mode has no state dimension (S² → 1), so it affords a wider
 #: window: 2^12 bool cells + an int32 subset-sum lane per history.

@@ -60,8 +60,9 @@ from .dense_scan import _pad_domains
 # kernel IR (not via dense_scan re-exports): the kernel-contract
 # analyzer resolves this module's cap expressions by loading the
 # sibling the import names, and it does not chase re-export chains.
-from .kernel_ir import (DENSE_MAX_CELLS, DENSE_MAX_SLOTS, DENSE_MAX_STATES,
-                        closure_fixpoint, force_arith, scan_unroll)
+from .kernel_ir import (DENSE_MAX_STATES, SEGMENT_MAX_CELLS,
+                        SEGMENT_MAX_SLOTS, closure_fixpoint, force_arith,
+                        scan_unroll)
 
 #: Segment the stream only when it is long enough to be worth the basis
 #: overhead; shorter histories take the plain dense kernel.
@@ -158,9 +159,9 @@ def plan_segments(model, enc: EncodedHistory,
         return None
     W = max(enc.n_slots, 1)
     domain = model.dense_domain(enc.events)
-    if domain is None or W > DENSE_MAX_SLOTS or \
+    if domain is None or W > SEGMENT_MAX_SLOTS or \
             len(domain) > DENSE_MAX_STATES or \
-            (1 << W) * len(domain) > DENSE_MAX_CELLS:
+            (1 << W) * len(domain) > SEGMENT_MAX_CELLS:
         return None
     S, val_of = _pad_domains([np.asarray(domain, np.int32)], [0])
     positions, crash_sets, open_rows = find_cuts(enc.events)

@@ -1115,7 +1115,14 @@ class CheckingService:
         # `build.ahead` span are process-wide, above)
         # ISSUE 33: window groups the launches ran (process-wide);
         # over `batches`, how many scans a served batch is on the chip
-        out["groups_run"] = snapshot_stats()["groups_run"]
+        scan = snapshot_stats()
+        out["groups_run"] = scan["groups_run"]
+        # ISSUE 40: wide windows (process-wide; 0 from a service that
+        # never met one): rows past SEGMENT_MAX_SLOTS that entered the
+        # kernel ladder and those of them a host engine decided on the
+        # dispatcher thread
+        out["wide_rows"] = scan["wide_rows"]
+        out["wide_rows_host"] = scan["wide_rows_host"]
         out["warm"] = self._warm.is_set()
         out["build_ahead"] = dict(self._build_ahead_info)
         # the host certifier's counters (process-wide, like the spans):

@@ -396,14 +396,14 @@ def test_merge_long_cap_overflow_splits_not_sheds(monkeypatch):
 
     monkeypatch.setenv("JGRAFT_MERGE_LONG", "1")
     half = MERGE_MAX_EVENTS  # events ≈ 2 ops each → long
-    x = mk(7, 10, half)            # W=10, S=8 → 8192 = cap, eligible
-    y1 = mk(15, 7, half)           # W=7, S=16 padded
-    y2 = mk(15, 7, half)
+    x = mk(7, 13, half)            # W=13, S=8 → 65536 = cap, eligible
+    y1 = mk(15, 10, half)          # W=10, S=16 padded (inside the spread)
+    y2 = mk(15, 10, half)
     encs = [x, y1, y2]
-    assert x.n_slots == 10 and y1.n_slots == 7
+    assert x.n_slots == 13 and y1.n_slots == 10
     assert all(e.n_events > MERGE_MAX_EVENTS for e in encs)
-    # Merged at w_top=10 with S padded to 16 would be 16384 > cap.
-    assert (1 << 10) * 16 > DENSE_MAX_CELLS
+    # Merged at w_top=13 with S padded to 16 would be 131072 > cap.
+    assert (1 << 13) * 8 == DENSE_MAX_CELLS < (1 << 13) * 16
     groups, rest = dense_plans_grouped(m, encs)
     assert rest == [], "dense-eligible history shed to the sort ladder"
     got = sorted(tuple(sorted(idxs)) for idxs, _ in groups)
@@ -523,8 +523,8 @@ def _tpu():
     # short histories never pay a long scan for a launch saved
     ("mask", [(6, 60, 1, 600), (7, 60, 1, 4000)], _tpu, [[0], [1]]),
     # a merge whose padded frontier passes DENSE_MAX_CELLS is no
-    # candidate: 2^10 * 16 cells
-    ("domain", [(7, 6, 16, _E), (10, 6, 8, _E)], _tpu, [[0], [1]]),
+    # candidate: 2^13 * 16 cells
+    ("domain", [(7, 6, 16, _E), (13, 6, 8, _E)], _tpu, [[0], [1]]),
     # windows under the table's narrowest are booked as that one
     ("mask", [(2, 4, 1, 100), (3, 5, 1, 100), (4, 6, 1, 120)], _tpu,
      [[0, 1, 2]]),
@@ -617,19 +617,19 @@ def test_grouping_follows_the_backends_cost(model, monkeypatch):
 
 def test_cost_merge_never_sheds_past_the_cell_cap(monkeypatch):
     """Two windows that are dense-eligible alone and whose merge would
-    launch 2^10 * 16 cells stay two groups under the chip's cost;
+    launch 2^13 * 16 cells stay two groups under the chip's cost;
     nothing goes to the sort ladder."""
     from jepsen_jgroups_raft_tpu.ops import dense_scan
 
     m = CasRegister()
     monkeypatch.setattr(dense_scan, "_group_cost", _tpu)
-    encs = [_burst(m, 10, 30, n_vals=7), _burst(m, 7, 30, n_vals=15),
+    encs = [_burst(m, 13, 30, n_vals=7), _burst(m, 7, 30, n_vals=15),
             _burst(m, 7, 30, n_vals=15)]
-    assert (1 << 10) * 16 > dense_scan.DENSE_MAX_CELLS
+    assert (1 << 13) * 16 > dense_scan.DENSE_MAX_CELLS
     groups, rest = dense_scan.dense_plans_grouped(m, encs)
     assert rest == []
     assert sorted((sorted(i), p.n_slots, p.n_states)
-                  for i, p in groups) == [([0], 10, 8), ([1, 2], 7, 16)]
+                  for i, p in groups) == [([0], 13, 8), ([1, 2], 7, 16)]
 
 
 @pytest.mark.parametrize("merge_long", ["0", "1"])
