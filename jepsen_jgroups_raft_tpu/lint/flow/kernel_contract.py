@@ -145,8 +145,11 @@ def _ir_chunk_budget(interp: Interp) -> List[str]:
                             f"dense_chunk_carry_bytes({W}, {S}) "
                             "not evaluable"))
             elif n > 16 << 20:
-                out.append(f"chunked dense carry at (W={W}, S={S}) = {n} "
-                           "B exceeds usable per-core VMEM")
+                out.append(f"chunked dense carry (the domain family's "
+                           f"frontier of one uint32 word a configuration, "
+                           f"the mask family's bool column and subset "
+                           f"sums) at (W={W}, S={S}) = {n} B exceeds "
+                           "usable per-core VMEM")
     fn_s = interp.functions.get("sort_chunk_carry_bytes")
     n_cfg = interp.module_env.get("SORT_DEFAULT_CONFIGS")
     n_slots = interp.module_env.get("SORT_MAX_SLOTS")

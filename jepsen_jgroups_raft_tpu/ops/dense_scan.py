@@ -8,31 +8,45 @@ workload/register.clj:21-34 draws values from [0,5)) has a *reachable
 state domain* enumerable straight from the history — the initial value
 plus every written / cas-to value. When the domain S and the concurrency
 window W are both small, the entire powerset-of-window × domain fits in a
-**dense boolean frontier F[2^W, S]**: F[m, s] = "some linearization of
-exactly the ops in mask m ends in state s".
+**dense frontier of 2^W configurations × S states**: "some linearization
+of exactly the ops in mask m ends in state s".
+
+Since ISSUE 41 the domain family holds it **packed: F[2^W] of uint32
+words, one word a configuration, bit s of word m the state s** (S ≤
+DENSE_MAX_STATES 16 fits any S in one dtype; the v5e's vector unit is a
+32-bit one, and a uint8 frontier read the same or slower on the chip).
+Until then it was F[2^W, S] bool and a slot's flow a float32 matmul of
+an [S, S] matrix; that kernel lives on in tests/test_packed_frontier.py
+as the oracle. The mask family keeps its own F[2^W, 1] bool.
 
 This is the on-device visited-*bitset* form of the search (the shape
 BASELINE.json's north star names): dedup is free (a bit can only be set
 once), overflow cannot happen (the array IS the configuration space), and
-every kernel operation is a static reshape, tiny matmul, or elementwise
-op — no sort, no scatter, no gather. Measured ~10× over the sort kernel
-on the north-star shape (W=5, S=6); it is selected automatically by the
-checker whenever a model can enumerate the domain (`Model.dense_domain`)
-and the [2^W, S] cells fit DENSE_MAX_CELLS, with the sort kernel as the
+every kernel operation is a static shift along the configuration axis or
+an elementwise integer op — no sort, no scatter, no gather, no matmul,
+no convert. Measured ~10× over the sort kernel on the north-star shape
+(W=5, S=6) in its bool form; it is selected automatically by the checker
+whenever a model can enumerate the domain (`Model.dense_domain`) and the
+2^W · S cells fit DENSE_MAX_CELLS, with the sort kernel as the
 general-case fallback.
 
 Mechanics per event (same event stream as linear_scan — packing.py):
 
-  OPEN w:  latch (f, a, b) into slot registers, mark the slot open.
+  OPEN w:  latch (f, a, b) into slot registers, mark the slot open; the
+           hoisted style packs the op's transition rows once, here:
+           R[w, s] = the bitset of the states s steps to (`pack_rows`).
   closure: repeat until fixpoint (≤W sweeps): for each slot w (static
-           unroll), configurations without bit w flow through the slot's
-           transition matrix T_w[s, s'] = legal(s) & (step(s) == s') into
-           the bit-w=1 half — a butterfly reshape exposing bit w as its
-           own axis plus an [?, S] @ [S, S] matmul.
+           unroll), configurations without bit w flow through the
+           slot's row masks into their twins with bit w: the OR over
+           the states a configuration holds of those states' rows,
+           moved 2^w places along the configuration axis
+           (`expand_packed`). Integer multiplies, ANDs, ORs and
+           shifts only.
   FORCE w: survivors must hold bit w (mask with the bit column derived
            arithmetically from the dynamic slot id), then the bit is
            recycled by moving the bit-w=1 half onto the bit-w=0 half —
-           one `dynamic_slice` down-shift (kernel_ir.force_arith;
+           one `dynamic_slice` down-shift (kernel_ir.force_arith, which
+           takes a frontier of either representation;
            switch-free,
            ISSUE 4 — the old `lax.switch` evaluated all W branches
            under vmap).
@@ -73,7 +87,8 @@ from .kernel_ir import macro_row_ints  # noqa: F401  (re-export)
 class DensePlan:
     """How to run a batch on a dense kernel.
 
-    kind "domain": frontier F[2^W, S] over an enumerated value domain;
+    kind "domain": frontier of 2^W x S cells (F[2^W] words, the states
+    their bits) over an enumerated value domain;
     `val_of` [B, S] is the per-history id→value table (kernel input).
     kind "mask": frontier F[2^W] for order-independent models
     (model.mask_determined) — per-mask states are subset sums; `val_of`
@@ -235,20 +250,24 @@ class GroupCost:
         return (fixed + (ms - fixed) * steps / self.steps[kind]) / 1e3
 
 
-#: One `TPU v5 lite` chip: the second sweep of ISSUE 33
+#: One `TPU v5 lite` chip. The mask rows: the second sweep of ISSUE 33
 #: (`scripts/sweep_group_cost.py run`, then `table`: medians of five
 #: warm runs of ONE group whose every row is as wide as its launch —
 #: the batched closure waits for its widest row —, made monotone in W
 #: and S, gaps filled from the next S; the half-length readings
 #: `fixed_ms` comes from, and how the partitions the table picks
-#: compare with the fastest measured: PERF.md section 6, PR 33). S 4
-#: was read up to 128 rows (larger batches of the cell hold a history
-#: of five values). W 11 and 12 at S 8 are ISSUE 40's (the same shapes,
-#: medians of three warm runs): the edge of the table had booked them at
-#: 1.42 times a window where the cells double (128 rows: 727 and 1,033
-#: ms against 901 and 1,614 read), and merged every batch of the
-#: partition cell into one group at its widest window (PERF.md
-#: section 6, PR 40).
+#: compare with the fastest measured: PERF.md section 6, PR 33).
+#: **The domain rows are ISSUE 41's, read on the packed kernel** (the
+#: same sweep, `--kinds register`, five-run medians; the files:
+#: `chiprun_out/p41b/narrow.json`, W 5-10 at S 4 and 8, and
+#: `wide.json`, W 11-12 at S 8; PERF.md section 6, PR 41, call b): a
+#: window costs next to nothing up to W 10 (128 rows: 175 ms at W 5,
+#: 205 at W 10, where the bool kernel read 107 and 512) and x 1.2-1.5
+#: past it, so a served batch merges into one or two groups. S 4 was
+#: read up to 128 rows (larger batches of the cell hold a history of
+#: five values). W 13 is booked from the edge (ISSUE 41 asked for it;
+#: the sweep sent the group through `auto`, whose host budget takes
+#: such rows first, and failed: `_once` now sends it to the kernels).
 TPU_GROUP_COST = GroupCost(
     rows=(8, 32, 64, 128, 256, 512, 1024),
     ms={"mask": {1: {
@@ -260,23 +279,23 @@ TPU_GROUP_COST = GroupCost(
             10: (106.2, 150.1, 210.8, 294.0, 733.2)}},
         "domain": {
             4: {
-                5: (32.1, 44.4, 63.3, 101.9),
-                6: (40.9, 53.0, 75.7, 126.0),
-                7: (48.8, 68.7, 97.5, 159.3),
-                8: (63.3, 99.8, 143.9, 218.6),
-                9: (86.1, 133.8, 209.2, 360.0),
-                10: (134.6, 209.0, 338.0, 511.9)},
+                5: (31.9, 59.2, 95.3, 161.4),
+                6: (32.8, 60.7, 95.8, 161.8),
+                7: (34.0, 62.0, 98.5, 173.0),
+                8: (34.5, 62.5, 100.7, 174.4),
+                9: (34.8, 65.3, 106.6, 188.4),
+                10: (36.3, 70.3, 114.8, 205.3)},
             8: {
-                5: (32.1, 45.2, 65.7, 106.5, 193.2, 448.3, 910.0),
-                6: (40.9, 55.1, 78.6, 133.3, 242.3, 589.3, 1134.0),
-                7: (48.8, 75.8, 101.7, 170.2, 356.8, 773.0, 1698.3),
-                8: (63.3, 105.4, 159.4, 249.8, 526.8, 1095.7, 2373.2),
-                9: (86.1, 142.7, 209.2, 360.0, 810.4),
-                10: (134.6, 248.0, 338.0, 511.9, 1153.1),
-                11: (222.6, 403.7, 574.1, 901.2, 1872.7),
-                12: (443.8, 756.5, 1068.4, 1614.3, 3306.1)}}},
+                5: (33.4, 59.2, 97.3, 175.2, 379.5, 690.6, 1548.3),
+                6: (34.8, 60.7, 97.3, 175.2, 379.5, 698.0, 1548.3),
+                7: (36.0, 62.0, 99.1, 175.2, 379.5, 746.2, 1644.1),
+                8: (36.9, 63.3, 100.7, 177.0, 379.5, 769.2, 1725.0),
+                9: (36.9, 65.7, 106.6, 188.4, 450.0),
+                10: (36.9, 70.3, 114.8, 205.3, 490.5),
+                11: (39.8, 82.7, 136.0, 311.8, 577.1),
+                12: (40.7, 89.0, 153.1, 372.6, 728.2)}}},
     steps={"mask": 2000, "domain": 1614},
-    fixed_ms={"mask": 5.6, "domain": 19.1})
+    fixed_ms={"mask": 5.6, "domain": 21.2})
 
 #: Off the TPU no cost is fitted and a window group is one window: the
 #: host mesh is throughput-bound at these widths (merged launches
@@ -517,6 +536,87 @@ def _bit_table(M: int, W: int) -> np.ndarray:
 
 
 
+#: The domain frontier's word: bit s of word m = state s reachable in
+#: configuration m. One dtype for every S <= DENSE_MAX_STATES: the
+#: v5e's vector unit is a 32-bit one, and a uint8 frontier read the same
+#: or slower on the chip (PERF.md section 6, PR 41, call a).
+FRONTIER_WORD = jnp.uint32
+
+
+def _fields(n_states: int):
+    """(field width, rows a word) of packed row masks: g rows of one
+    slot lie side by side in a word, each in a field of `width` bits
+    (S rounded up to a power of two). g <= width / 2 keeps the multiply
+    that spreads g state bits over the g fields free of carries."""
+    width = 1
+    while width < n_states:
+        width *= 2
+    g = 1
+    while 2 * g * width <= 32 and 2 * g < width:
+        g *= 2
+    return width, g
+
+
+def row_words(n_states: int) -> int:
+    """Words that hold one slot's S row masks, g to a word."""
+    return -(-n_states // _fields(n_states)[1])
+
+
+def pack_rows(rows):
+    """[..., S, S'] bool transition rows -> [..., ceil(S / g)] words:
+    the bitset of the states s steps to (bit s' of its row) in field
+    s % g of word s // g (`_fields`). Any bitset is a row: a padded
+    domain repeats id 0, so a step may land on several ids."""
+    S = rows.shape[-1]
+    width, g = _fields(S)
+    n = row_words(S)
+    # constants are numpy's: a jnp one is an eager device op a trace,
+    # and a key's 26 programs are traced with the GIL held (`setup_s`)
+    bit = np.uint32(1) << np.arange(S, dtype=np.uint32)
+    R = jnp.bitwise_or.reduce(
+        jnp.where(rows, bit, np.uint32(0)), axis=-1)
+    R = jnp.concatenate(
+        [R, np.zeros(R.shape[:-1] + (n * g - S,), np.uint32)],
+        axis=-1).reshape(R.shape[:-1] + (n, g))
+    return jnp.bitwise_or.reduce(
+        R << (np.arange(g, dtype=np.uint32) * np.uint32(width)), axis=-1)
+
+
+def expand_packed(w: int, F, R_w, n_states: int):
+    """One slot's flow over a packed frontier F [M]: configurations
+    without bit w linearize op w through its row masks R_w
+    (`pack_rows` of its [S, S'] rows) into their twins with bit w.
+
+    A configuration's contribution is the OR, over the states it holds,
+    of those states' rows: g state bits at a time are spread to the g
+    fields of a word by one multiply, ANDed with the word that holds
+    their g rows, and the fields folded by OR — 2 words a slot at S 8,
+    and no operand a state (read on the chip beside a select a state
+    and a reduce over a state axis: PERF.md section 6, PR 41). Moving
+    the contributions 2^w places up the configuration axis is a static
+    roll; the wrap lands where bit w is clear and is masked with the
+    rest."""
+    M = F.shape[0]
+    width, g = _fields(n_states)
+    c = np.uint32  # numpy constants: see pack_rows
+    mult = sum(1 << (j * (width - 1)) for j in range(g))
+    ones = sum(1 << (j * width) for j in range(g))
+    field = (1 << width) - 1
+    acc = jnp.zeros_like(F)
+    for k in range(R_w.shape[0]):
+        bits = (F >> c(k * g) if k else F) & c((1 << g) - 1)
+        if g > 1:
+            bits = (bits * c(mult)) & c(ones)
+        acc = acc | (R_w[k] & (bits * c(field)))
+    sh = width * g // 2
+    while sh >= width:
+        acc = acc | (acc >> c(sh))
+        sh //= 2
+    # static: all ones where configuration m holds bit w
+    with_w = (-((np.arange(M, dtype=np.int64) >> w) & 1)).astype(np.uint32)
+    return F | (jnp.roll(acc & c(field), 1 << w) & with_w)
+
+
 def hoist_transitions() -> bool:
     """Whether the DOMAIN kernel keeps transition matrices in the scan
     carry (refreshed once per OPEN) instead of re-deriving them from
@@ -568,67 +668,82 @@ def dense_step_parts(model, n_slots: int, n_states: int,
     identical (pinned by tests/test_macro_events.py); None keeps the
     legacy [E, 5] row format (the JGRAFT_MACRO_EVENTS=0 ablation).
 
-    Step shape note (round-5): a gather-based rewrite of this kernel
-    (Jacobi closure over one [W,M,S] gather + einsum, gather-based
-    FORCE) measured ~2× SLOWER on v5e than this butterfly form
-    (config-4 5.2 s vs 2.4 s, counter suite 12.3 s vs 7.0 s, same
-    session) — TPU gathers at these tiny shapes cost more than the
-    fusion count they save, which is exactly why the design invariant
-    in the module docstring says "no sort, no scatter, no gather".
-    The transition-matrix placement (carry-hoisted vs in-sweep) is
+    The frontier is packed (ISSUE 41): F [2^W] of FRONTIER_WORD, bit s
+    of word m = state s reachable in configuration m; `init` sets bit 0
+    of word 0. The hoisted style carries the slots' row masks
+    (`pack_rows`: [W, ceil(S / g)] words) where it carried [W, S, S]
+    bool matrices; the in-sweep style packs the rows it derives. Both
+    styles, both stream formats and both drivers share `expand_packed`
+    and the one `force_tail` below.
+
+    Step shape notes, each a reading on one TPU v5 lite:
+    (round-5) a gather-based rewrite of the bool kernel (Jacobi closure
+    over one [W,M,S] gather + einsum, gather-based FORCE) measured ~2×
+    SLOWER than the butterfly form (config-4 5.2 s vs 2.4 s, counter
+    suite 12.3 s vs 7.0 s, same session) — TPU gathers at these tiny
+    shapes cost more than the fusion count they save, which is why the
+    module docstring says "no sort, no scatter, no gather".
+    (ISSUE 41) one group of 1k-op register histories, every row as wide
+    as its launch, S 8, ms at 128 / 8 rows (PERF.md section 6, PR 41,
+    calls a and a2; five-run medians): the bool frontier with a float
+    matmul a slot (the kernel this replaced) W 12 1,609 / 444, W 10 603
+    / 127, W 8 251 / 59; the packed frontier with a select a state W 12
+    429 / 61, W 10 311 / 52, W 8 185 / 47; with a reduce over a state
+    axis W 12 1,289 / 58, W 10 476 / 43, W 8 185 / 38; **with the
+    multiply that spreads g states over a word's fields (this one) W 12
+    356 / 43, W 10 285 / 39, W 8 177 / 37**. A butterfly reshape in the
+    roll's place read 596 against 545 (W 12, 128 rows, a select a
+    state), a uint8 word 604 against 545. A compiled W 12 sweep holds
+    10 fusions, 15 slices and a copy where the bool kernel's held 36
+    fusions (12 of them the matmuls, lowered to convolutions), 25
+    copies and 10 slices.
+    The transition placement (carry-hoisted vs in-sweep) is
     backend-keyed: see hoist_transitions()."""
     if hoist is None:
         hoist = hoist_transitions()
     W, S = int(n_slots), int(n_states)
     M = 1 << W
     slot_ids = jnp.arange(W, dtype=jnp.int32)
-
-    def expand_w(w, F, T_w):
-        """One slot's flow: configs without bit w linearize op w
-        through its [S, S'] transition matrix."""
-        Fb = F.reshape(M >> (w + 1), 2, 1 << w, S)
-        src = Fb[:, 0].reshape(-1, S).astype(jnp.float32)
-        contrib = (src @ T_w).reshape(M >> (w + 1), 1 << w, S) > 0
-        return jnp.concatenate(
-            [Fb[:, :1], (Fb[:, 1] | contrib)[:, None]], axis=1
-        ).reshape(M, S)
+    word = np.uint32  # FRONTIER_WORD's numpy twin, for constants
+    n_words = row_words(S)
 
     # The two carry styles (hoist_transitions) differ ONLY in how a
-    # slot's transition matrix is produced — everything else (OPEN
-    # latch, dirty gating, closure, FORCE kill+recycle, ok accounting)
-    # is the shared scan skeleton below, so a semantic fix can never
-    # apply to one style and miss the other.
+    # slot's row masks are produced — everything else (OPEN latch,
+    # dirty gating, closure, FORCE kill+recycle, ok accounting) is the
+    # shared scan skeleton below, so a semantic fix can never apply to
+    # one style and miss the other.
     if hoist:
-        extra0 = (jnp.zeros((W, S, S), bool),)
+        extra0 = (jnp.zeros((W, n_words), FRONTIER_WORD),)
 
         def style_update(extra, upd, f, a, b, val_of):
-            (T,) = extra
+            (R,) = extra
             ns, legal = model.jax_step(val_of, f, a, b)
-            row = (ns[:, None] == val_of[None, :]) & legal[:, None]
-            return (jnp.where(upd[:, None, None], row[None], T),)
+            row = pack_rows((ns[:, None] == val_of[None, :]) &
+                            legal[:, None])               # [n_words]
+            return (jnp.where(upd[:, None], row[None], R),)
 
         def style_macro_latch(extra, eq, upd, pf, pa, pb, val_of):
-            # Per-payload transition rows, selected into the slot axis
-            # by the (at-most-one-match) eq matrix — the batched twin
-            # of style_update's single-row write.
-            (T,) = extra
+            # Per-payload row masks, selected into the slot axis by the
+            # (at-most-one-match) eq matrix — the batched twin of
+            # style_update's single-row write.
+            (R,) = extra
             ns, legal = jax.vmap(
                 lambda f_, a_, b_: model.jax_step(val_of, f_, a_, b_)
             )(pf, pa, pb)                                 # [P, S] each
-            rows = ((ns[:, :, None] == val_of[None, None, :]) &
-                    legal[:, :, None])                    # [P, S, S']
-            Tnew = jnp.tensordot(eq.astype(jnp.float32),
-                                 rows.astype(jnp.float32),
-                                 axes=([1], [0])) > 0     # [W, S, S']
-            return (jnp.where(upd[:, None, None], Tnew, T),)
+            rows = pack_rows((ns[:, :, None] == val_of[None, None, :]) &
+                             legal[:, :, None])     # [P, n_words]
+            Rnew = jnp.bitwise_or.reduce(
+                jnp.where(eq[:, :, None], rows[None],
+                          word(0)), axis=1)        # [W, n_words]
+            return (jnp.where(upd[:, None], Rnew, R),)
 
         def style_sweep(extra, slot_open, val_of):
-            (T,) = extra
-            Te = (T & slot_open[:, None, None]).astype(jnp.float32)
+            (R,) = extra
+            Re = jnp.where(slot_open[:, None], R, word(0))
 
             def sweep(F):  # static unroll; expansions chain w ascending
                 for w in range(W):
-                    F = expand_w(w, F, Te[w])
+                    F = expand_packed(w, F, Re[w], S)
                 return F
 
             return sweep
@@ -654,10 +769,9 @@ def dense_step_parts(model, n_slots: int, n_states: int,
                 for w in range(W):
                     ns, legal = model.jax_step(val_of, sf[w], sa[w],
                                                sb[w])
-                    T_w = ((ns[:, None] == val_of[None, :]) &
-                           legal[:, None] &
-                           slot_open[w]).astype(jnp.float32)
-                    F = expand_w(w, F, T_w)
+                    R_w = pack_rows((ns[:, None] == val_of[None, :]) &
+                                    legal[:, None] & slot_open[w])
+                    F = expand_packed(w, F, R_w, S)
                 return F
 
             return sweep
@@ -704,7 +818,7 @@ def dense_step_parts(model, n_slots: int, n_states: int,
                                  macro_p)
 
     def init(val_of):
-        F = jnp.zeros((M, S), dtype=bool).at[0, 0].set(True)
+        F = jnp.zeros((M,), FRONTIER_WORD).at[0].set(1)
         return (
             F, extra0, jnp.zeros((W,), bool),
             jnp.bool_(True), jnp.bool_(False), val_of,

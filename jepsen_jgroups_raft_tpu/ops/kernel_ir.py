@@ -70,10 +70,15 @@ from ..history.packing import EV_FORCE, EV_OPEN, MACRO_MAX_OPENS
 #: workload shapes are (window ≈ n_procs, domain ≈ 5 values; a few
 #: crashed ops' never-retiring slots push long histories to W ≈ 10).
 #: Since ISSUE 40 the caps stand at the widest window the family has
-#: been read at on a chip (one TPU v5 lite; PERF.md section 5, PR 40):
-#: one group of S 8 register rows of 1,000 ops costs 223 / 444 /
-#: 885-925 ms at W 11 / 12 / 13 for 8 rows and 901 / 1,614 ms at W 11 /
-#: 12 for 128 (the cells double with a window and so does the time).
+#: been read at on a chip (one TPU v5 lite). The readings then, on the
+#: bool frontier [2^W, S] (PERF.md section 5, PR 40): one group of S 8
+#: register rows of 1,000 ops 223 / 444 / 885-925 ms at W 11 / 12 / 13
+#: for 8 rows and 901 / 1,614 ms at W 11 / 12 for 128. Since ISSUE 41
+#: the frontier is one uint32 word a configuration (ops/dense_scan.py
+#: `expand_packed`) and the same groups read 39.8 / 40.7 ms at W 11 /
+#: 12 for 8 rows and 311.8 / 372.6 ms for 128 (PERF.md section 6, PR
+#: 41): the caps did NOT move with it (ROADMAP R14 is its own issue,
+#: and the partition generator's gate reads the name).
 #: Read on two inputs only: crash-free register histories widened to
 #: their launch's window (W 11-12, `scripts/sweep_group_cost.py`) and
 #: the partition-nemesis histories of `benchmarks/generators/
@@ -84,9 +89,10 @@ from ..history.packing import EV_FORCE, EV_OPEN, MACRO_MAX_OPENS
 #: to 8.8 s for a row the first DFS budget leaves. A caller whose W
 #: 11-13 rows have frontiers the first rung (C = 64) holds now pays the
 #: dense sweep of 16k-65k cells for them: not measured. Past W 13 no
-#: memory limit refuses (2^W x S x 4 B a row: 512 KiB at W 14), but a W
-#: 14 group would cost twice W 13's 0.9 s, what the host's full DFS
-#: budget costs: unmeasured, so not taken.
+#: memory limit refuses (2^W x 4 B a row: 64 KiB at W 14, where the
+#: bool frontier's float sweep held 512 KiB), and the packed kernel's
+#: cost a window (x 1.2-1.5 from W 10 to 12) says W 14-15 would cost
+#: well under the host's DFS budget: unmeasured, so not taken here.
 DENSE_MAX_SLOTS = 13
 DENSE_MAX_STATES = 16
 DENSE_MAX_CELLS = 65536  # 2^W · S
@@ -230,17 +236,24 @@ def force_arith(F, slot_w):
     stream share this dispatch, so the macro A/B stays a pure
     stream-length comparison).
 
-    F: [M, S] bool (mask mode passes S=1); slot_w pre-clipped to
-    [0, W). Returns (F', any_survivor)."""
-    M, S = F.shape
+    F: the configuration axis M leading, of either representation —
+    [M, S] bool (the mask family and the segmented route, S = 1 or the
+    padded states) or [M] words whose bits are the states (the domain
+    family, ops/dense_scan.dense_step_parts); a configuration is dead
+    where its entry is all False / 0. slot_w pre-clipped to [0, W).
+    Returns (F', any_survivor)."""
+    M = F.shape[0]
+    tail = (1,) * (F.ndim - 1)
     ids = jnp.arange(M, dtype=jnp.int32)
-    has = ((ids >> slot_w) & 1) == 1            # [M] bit slot_w of m
-    Fk = F & has[:, None]
-    alive = jnp.any(Fk)
-    ext = jnp.concatenate([Fk, jnp.zeros_like(Fk)], axis=0)  # [2M, S]
+    has = (((ids >> slot_w) & 1) == 1).reshape((M,) + tail)  # bit slot_w
+    dead = jnp.zeros((), F.dtype)
+    Fk = F & jnp.where(has, ~dead, dead)
+    alive = jnp.any(Fk != dead)
+    ext = jnp.concatenate([Fk, jnp.zeros_like(Fk)], axis=0)  # [2M, ...]
     shifted = lax.dynamic_slice(
-        ext, (jnp.int32(1) << slot_w, jnp.int32(0)), (M, S))
-    return jnp.where(has[:, None], False, shifted), alive
+        ext, (jnp.int32(1) << slot_w,) + (jnp.int32(0),) * (F.ndim - 1),
+        F.shape)
+    return jnp.where(has, dead, shifted), alive
 
 
 # ---------------------------------------------------------- stream step
@@ -573,12 +586,17 @@ def make_cycle_closure_tiled(n_nodes: int, tile: int = CYCLE_TILE):
 
 
 def dense_chunk_carry_bytes(n_slots: int, n_states: int) -> int:
-    """Chunked domain/mask carry: frontier F [2^W, S] bool + hoisted
-    transitions [W, S, S] bool (worst style) + slot registers + the
-    events_left lane. Mask mode runs at S=1; its subset-sum lane is
-    covered by the conservative register term."""
-    return ((1 << n_slots) * n_states          # F
-            + n_slots * n_states * n_states    # hoisted T (worst style)
+    """Chunked domain/mask carry. Domain (since ISSUE 41): the packed
+    frontier F [2^W] of uint32 words, the states a word's bits — 4 B a
+    configuration whatever S <= DENSE_MAX_STATES — + the hoisted row
+    masks R [W, ceil(S / g)] words (worst style; booked at S words a
+    slot) + slot registers + the events_left lane. Mask mode runs at
+    S=1 and keeps its own F [2^W, 1] bool beside an int32 subset-sum
+    lane [2^W]: the word term books the lane, the bool term its
+    frontier, so one bound holds both families."""
+    return ((1 << n_slots) * 4                 # F words | mask sums
+            + (1 << n_slots)                   # mask F [2^W, 1] bool
+            + n_slots * n_states * 4           # hoisted R (worst style)
             + 4 * n_slots * 4                  # slot registers (int32)
             + 8)                               # ok/dirty/events_left
 

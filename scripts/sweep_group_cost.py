@@ -4,6 +4,11 @@ the model's partitions to the fastest ones measured.
 
     python scripts/sweep_group_cost.py run --out chiprun_out/sweep33/sweep.json
     python scripts/sweep_group_cost.py table chiprun_out/sweep33/sweep.json
+    python scripts/sweep_group_cost.py run --kinds register --windows 11 12 13 \
+        --states 8 --rows 8 32 64 128 256 --half-windows --batches \
+        --out chiprun_out/p41b/wide.json      # one family's wide rows
+    python scripts/sweep_group_cost.py table chiprun_out/p41b/narrow.json \
+        chiprun_out/p41b/wide.json            # several runs, one table
 
 `run` (one process, it holds the chip) times `check_encoded` on batches
 of the benchmark's own histories (`benchmarks/generators/synth.py`, the
@@ -131,8 +136,12 @@ def _once(forced, encs, model, groups, serve_rows):
     forced.groups = groups
     before = snapshot_spans()
     t0 = time.perf_counter()
-    res = check_encoded(encs, model, algorithm="auto", lin_fastpath=False,
-                        serve_rows=serve_rows)
+    # past window 12 `auto` spends a host DFS budget before the device
+    # pass and hands the kernels what it leaves: the sweep reads the
+    # kernels, so such a group goes to them whole
+    wide = any(w > 12 for _, _, w, _ in groups)
+    res = check_encoded(encs, model, algorithm="jax" if wide else "auto",
+                        lin_fastpath=False, serve_rows=serve_rows)
     wall = time.perf_counter() - t0
     after = snapshot_spans()
     spans = {k: after.get(k, {}).get("s", 0.0)
@@ -188,7 +197,8 @@ def run(args) -> None:
             json.dump(out, f, indent=1)
 
     t_start = time.perf_counter()
-    for kind, family in KINDS.items():
+    kinds = {k: KINDS[k] for k in args.kinds}
+    for kind, family in kinds.items():
         for w in args.windows:
             upto = big if w <= args.big_window else 256
             half_rows = [r for r in args.half_rows
@@ -225,7 +235,7 @@ def run(args) -> None:
     print(f"shapes done at {time.perf_counter() - t_start:.0f} s",
           file=sys.stderr, flush=True)
 
-    for kind, family in KINDS.items():
+    for kind, family in kinds.items():
         model, cell = _histories(kind, args.seed + 2,
                                  max(args.batches, default=0), ops,
                                  crashes=True)
@@ -295,7 +305,9 @@ def cost_from(doc: dict):
                 slope = (statistics.mean(whole) - h["ms"]) / (
                     steps[kind] - h["steps"])
                 est.append(statistics.mean(whole) - slope * steps[kind])
-        fixed[kind] = round(statistics.median(est), 1)
+        # a file that read no second length keeps the program's
+        fixed[kind] = round(statistics.median(est), 1) if est else \
+            dense_scan.TPU_GROUP_COST.fixed_ms[kind]
         print(f"  {kind}: fixed part {[round(e, 1) for e in est]} ms")
     # kind -> S -> W -> {rows: ms}: means, then what a session's noise
     # must not teach the partition: a reading a smaller S lacks is the
@@ -318,7 +330,8 @@ def cost_from(doc: dict):
                 by = by_s[S][w]
                 for r in rows:
                     low = max(floor.get(r, 0.0),
-                              by_s[sizes[k - 1]][w].get(r, 0.0) if k else 0.0)
+                              by_s[sizes[k - 1]].get(w, {}).get(r, 0.0)
+                              if k else 0.0)
                     if r in by or (low and any(q > r for q in by)):
                         by[r] = floor[r] = max(by.get(r, 0.0), low)
     cost = dense_scan.GroupCost(
@@ -335,8 +348,11 @@ def table(args) -> None:
     program's own table pick for the file's batches."""
     from jepsen_jgroups_raft_tpu.ops import dense_scan
 
-    with open(args.file) as f:
+    with open(args.file[0]) as f:
         doc = json.load(f)
+    for more in args.file[1:]:  # one session's runs: their shapes as one
+        with open(more) as f:
+            doc["shapes"] += json.load(f)["shapes"]
     batches = doc["partitions"]
     if args.partitions:  # the batches of another run of the session
         with open(args.partitions) as f:
@@ -372,11 +388,15 @@ def main() -> None:
                    default=[8, 32, 64, 128, 256, 512, 1024])
     r.add_argument("--windows", type=int, nargs="+",
                    default=[5, 6, 7, 8, 9, 10])
+    r.add_argument("--kinds", nargs="+", choices=sorted(KINDS),
+                   default=list(KINDS),
+                   help="which families to read (a kernel change re-reads "
+                        "its own family's rows)")
     r.add_argument("--big-window", type=int, default=8,
                    help="rows past 256 only up to this window")
     r.add_argument("--states", type=int, nargs="+", default=[4, 8])
     r.add_argument("--half-rows", type=int, nargs="+", default=[8, 128])
-    r.add_argument("--half-windows", type=int, nargs="+", default=[6, 8])
+    r.add_argument("--half-windows", type=int, nargs="*", default=[6, 8])
     r.add_argument("--batches", type=int, nargs="*",
                    default=[128, 256, 1000])
     r.add_argument("--build-threads", type=int, default=12)
@@ -385,7 +405,7 @@ def main() -> None:
                         "its times say nothing)")
     r.set_defaults(fn=run)
     t = sub.add_parser("table")
-    t.add_argument("file")
+    t.add_argument("file", nargs="+")
     t.add_argument("--partitions",
                    help="take the batches from this file's `partitions`")
     t.set_defaults(fn=table)

@@ -244,6 +244,13 @@ class TestIrPieces:
             kernel_ir.SORT_DEFAULT_CONFIGS, kernel_ir.SORT_MAX_SLOTS)
         assert 0 < d <= 16 << 20
         assert 0 < s <= 16 << 20
+        # ISSUE 41: the domain frontier is one uint32 word a
+        # configuration whatever S, so the widest row's carry is the
+        # words (beside the mask family's bool column) plus registers
+        # — not 2^W x S cells
+        configs = 1 << kernel_ir.DENSE_MAX_SLOTS
+        assert 5 * configs < d < 6 * configs
+        assert d < configs * kernel_ir.DENSE_MAX_STATES
         assert kernel_ir.macro_row_ints() == 67
 
     def test_families_reexport_ir_caps(self):

@@ -177,9 +177,10 @@ class TestKernelContract:
         # launches; inflating the carry accounting past VMEM at the
         # eligibility caps must fail the gate.
         text = (PKG / "ops" / "kernel_ir.py").read_text()
-        assert "(1 << n_slots) * n_states" in text
-        mutated = text.replace("(1 << n_slots) * n_states          # F",
-                               "(1 << n_slots) * n_states * 4096   # F")
+        # (since ISSUE 41 the frontier is a uint32 word a configuration)
+        assert "(1 << n_slots) * 4                 # F words" in text
+        mutated = text.replace("(1 << n_slots) * 4                 # F",
+                               "(1 << n_slots) * 4 * 4096          # F")
         found = kc(mutated, path="ops/kernel_ir.py")
         assert "kernel-vmem-budget" in rules_of(found)
 

@@ -157,6 +157,26 @@ def test_dense_chunk_pair_compiles_on_one_chip(one_chip, tpu_branches, kind):
         sds((512, DEFAULT_SCAN_CHUNK, row_ints(MACRO_P)), one_chip))
 
 
+def test_packed_domain_step_compiles_at_the_widest_window(one_chip,
+                                                          tpu_branches):
+    # ISSUE 41: the packed frontier at the caps' corner the partition
+    # cell serves (W 13, S 8, a 128-row group): a uint32 word a
+    # configuration in and out, and no matmul or convert left in the
+    # step (the float sweep lowered its W `dot`s to convolutions)
+    from jepsen_jgroups_raft_tpu.ops.kernel_ir import DENSE_MAX_SLOTS
+
+    assert DENSE_MAX_SLOTS == 13
+    fns = make_dense_chunk_checker(CasRegister(), "domain",
+                                   DENSE_MAX_SLOTS, S, macro_p=MACRO_P)
+    compiled = compile_chunk_pair(
+        fns, (sds((128, S), one_chip), sds((128,), one_chip)),
+        sds((128, DEFAULT_SCAN_CHUNK, row_ints(MACRO_P)), one_chip))
+    text = compiled.as_text()
+    assert "u32[128,8192]" in text and "pred[128,8192,8]" not in text
+    assert " convolution(" not in text and " dot(" not in text
+    assert "f32[" not in text
+
+
 def test_dense_chunk_pair_compiles_on_four_chip_mesh(mesh4, tpu_branches):
     # parallel/mesh.chunk_sharding's layout: rows over a 1-D mesh, the
     # kernels wrapped in an explicit batch-axis shard_map
