@@ -113,6 +113,11 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # of a key built or loaded, and programs a launch built
                # or loaded AFTER its key was built (healthy: 0).
                "programs_built_ahead": 0, "shape_misses": 0,
+               # a key at a time (ISSUE 42): keys whose first build has
+               # ended and, of those, the keys a launch waited for (after
+               # a host's first run the record builds them at the start:
+               # healthy 0)
+               "keys_built": 0, "keys_met_by_launch": 0,
                # wide windows (ISSUE 40): rows past SEGMENT_MAX_SLOTS that
                # entered the kernel ladder and, of those, the rows a host
                # engine decided
@@ -304,15 +309,27 @@ def consume_tiers() -> dict:
 
 _SPANS: dict = {}  # name -> [n, seconds]; guarded by _STATS_LOCK
 #: most recent compiles, newest last: (fun_name, seconds, innermost
-#: span open on the compiling thread or None); guarded by _STATS_LOCK
+#: span open on the compiling thread or None, "load" where the persistent
+#: cache gave the program and "compile" where XLA compiled it from
+#: source); guarded by _STATS_LOCK
 _RECENT_COMPILES: collections.deque = collections.deque(maxlen=16)
 #: most recent shape misses, newest last: (fun_name, seconds, program
 #: kind, key, rows, width); guarded by _STATS_LOCK
 _RECENT_MISSES: collections.deque = collections.deque(maxlen=16)
 #: the span a key's programs are built in, ahead of its launches
 BUILD_AHEAD = "build.ahead"
+#: the stages of one program's build (ISSUE 42), each a span
+#: `build.<stage>` in thread-seconds with `n` programs, from JAX's own
+#: events (`platform.install_compile_counters` says which): Python
+#: tracing it to a jaxpr, the jaxpr to an MLIR module (both the build
+#: thread's own CPU seconds: a wait for the GIL is not a second of
+#: tracing), then either the persistent cache loading it or XLA
+#: compiling it from source (both the backend's wall seconds)
+BUILD_STAGES = ("trace", "lower", "load", "compile")
 #: per-thread stack of open span names (`.names`), so that a compile
-#: can be stamped with the step it interrupted
+#: can be stamped with the step it interrupted; `.shape`, the program a
+#: launch is asking for (`_launching`); `.building`, the `_BUILT` entry
+#: of the key the thread is building (`_building`)
 _OPEN = threading.local()
 _LAUNCH_SEQ = itertools.count(1)
 
@@ -333,19 +350,24 @@ def _profiling() -> bool:
     return state.profile_session is not None
 
 
+def _span_add(name: str, seconds: float, n: int) -> None:
+    """`note_span`'s body; the caller holds _STATS_LOCK."""
+    t = _SPANS.get(name)
+    if t is None:
+        t = _SPANS[name] = [0, 0.0]
+    t[0] += n
+    t[1] += seconds
+    for scope in _scope_targets():
+        e = scope.setdefault("spans", {}).setdefault(name, [0, 0.0])
+        e[0] += n
+        e[1] += seconds
+
+
 def note_span(name: str, seconds: float, n: int = 1) -> None:
     """Add a duration taken from stamps (a request's phases) to span
     `name`. Scope targeting mirrors `note_tier`."""
     with _STATS_LOCK:
-        t = _SPANS.get(name)
-        if t is None:
-            t = _SPANS[name] = [0, 0.0]
-        t[0] += n
-        t[1] += seconds
-        for scope in _scope_targets():
-            e = scope.setdefault("spans", {}).setdefault(name, [0, 0.0])
-            e[0] += n
-            e[1] += seconds
+        _span_add(name, seconds, n)
 
 
 class annotate:
@@ -426,11 +448,34 @@ def snapshot_spans() -> dict:
         return _format_spans(_SPANS)
 
 
-def note_compile(fun_name: str, seconds: float) -> None:
+def _stage_add(stage: str, seconds: float, programs: int = 0) -> None:
+    """`seconds` of build stage `stage` to its span and to the key the
+    calling thread is building, if any, with the `programs` the stage
+    handed over; the caller holds _STATS_LOCK."""
+    _span_add("build." + stage, seconds, 1)
+    entry = getattr(_OPEN, "building", None)
+    if entry is not None:
+        entry[stage + "_s"] += seconds
+        entry["programs"] += programs
+
+
+def note_build_stage(stage: str, seconds: float) -> None:
+    """One program traced (`"trace"`) or lowered (`"lower"`) on this
+    thread, in the thread's own CPU seconds."""
+    with _STATS_LOCK:
+        _stage_add(stage, seconds)
+
+
+def note_compile(fun_name: str, seconds: float,
+                 loaded: bool = False) -> None:
     """One program built or loaded by the backend (the
     `/jax/core/compile/backend_compile_duration` event), stamped with
-    the span it interrupted on this thread. Inside `build.ahead` it is
-    a program built ahead; inside a launch of the closed shape set
+    the span it interrupted on this thread. `loaded`: the persistent
+    cache gave it (span `build.load`); otherwise XLA compiled it from
+    source (`build.compile`: a cold cache, a new tree, or a read lost to
+    the cache's file lock), so the two spans' `n` sum to
+    `programs_built` and their `s` to `compile_s`. Inside `build.ahead`
+    it is a program built ahead; inside a launch of the closed shape set
     (`_launching`) it is a SHAPE MISS — the key was declared built and
     the launch still had to build — and is logged with the
     ``(key, rows, width)`` that asked for it."""
@@ -438,10 +483,12 @@ def note_compile(fun_name: str, seconds: float) -> None:
     shape = getattr(_OPEN, "shape", None)
     ahead = inside == BUILD_AHEAD
     miss = shape is not None and not ahead
+    stage = "load" if loaded else "compile"
     _add_stats(programs_built=1, compile_s=seconds,
                programs_built_ahead=int(ahead), shape_misses=int(miss))
     with _STATS_LOCK:
-        _RECENT_COMPILES.append((fun_name, seconds, inside))
+        _stage_add(stage, seconds, programs=1)
+        _RECENT_COMPILES.append((fun_name, seconds, inside, stage))
         if miss:
             _RECENT_MISSES.append((fun_name, seconds) + shape)
 
@@ -453,13 +500,14 @@ def note_cache_miss() -> None:
 
 def snapshot_compiles() -> dict:
     """The process-wide compile counters, the most recent compiles,
-    newest last, as ``[fun_name, seconds, open span]``, and the most
-    recent shape misses as ``[fun_name, seconds, program, key, rows,
-    width]``."""
+    newest last, as ``[fun_name, seconds, open span, "load" |
+    "compile"]``, and the most recent shape misses as ``[fun_name,
+    seconds, program, key, rows, width]``."""
     with _STATS_LOCK:
         out = {k: _STATS[k] for k in (
             "programs_built", "compile_s", "compile_cache_misses",
-            "programs_built_ahead", "shape_misses")}
+            "programs_built_ahead", "shape_misses", "keys_built",
+            "keys_met_by_launch")}
         out["recent_compiles"] = [list(c) for c in _RECENT_COMPILES]
         out["recent_shape_misses"] = [list(c) for c in _RECENT_MISSES]
         return out
@@ -823,8 +871,11 @@ def launch_key(launch: ChunkLaunch, width: int) -> tuple:
             _placement_name(launch.device))
 
 
-#: key -> {"rows": set of row buckets built, "spec", "width", "lanes"};
-#: guarded by _BUILD_LOCK
+#: key -> {"rows": set of row buckets built, "spec", "width", "lanes",
+#: "met", "wait_s"}, guarded by _BUILD_LOCK, and the key's build on the
+#: program's own clock, "programs" and "<stage>_s" a BUILD_STAGES stage,
+#: guarded by _STATS_LOCK (the listeners' lock; taken inside the other,
+#: never around it)
 _BUILT: dict = {}
 _BUILD_LOCK = threading.RLock()
 #: every (program, key, rows[, rows after], width) a launch asked for;
@@ -833,13 +884,63 @@ _LAUNCHED: set = set()
 _LAUNCHED_CAP = 8192
 
 
+def _new_key_entry(launch: ChunkLaunch, width: int, met: str) -> dict:
+    entry = {"rows": set(), "spec": launch.spec, "width": width,
+             "lanes": int(launch.events.shape[2]), "met": met,
+             "wait_s": 0.0, "programs": 0}
+    entry.update((stage + "_s", 0.0) for stage in BUILD_STAGES)
+    return entry
+
+
 def snapshot_built() -> list:
     """The keys built in this process: ``{"key", "spec", "width",
-    "lanes", "rows"}`` each, rows ascending."""
-    with _BUILD_LOCK:
-        return [{"key": k, "spec": v["spec"], "width": v["width"],
-                 "lanes": v["lanes"], "rows": sorted(v["rows"])}
+    "lanes", "rows"}`` each, rows ascending, and each key's build
+    (ISSUE 42): `met`, ``"start"`` where graftd's start built it from
+    the host's record and ``"launch"`` where a launch met it first and
+    waited; `programs` built or loaded for it on the build threads and
+    their thread-seconds by stage, `trace_s`, `lower_s`, `load_s`,
+    `compile_s`; `wait_s`, the wall seconds callers waited for it in
+    `build.ahead` (a call that waits for several keys at once books
+    each second to the key whose bucket it was waiting for then, so the
+    keys' `wait_s` sum to the span's seconds)."""
+    with _BUILD_LOCK, _STATS_LOCK:
+        return [{"key": k, **v, "rows": sorted(v["rows"])}
                 for k, v in _BUILT.items()]
+
+
+def snapshot_build_keys() -> list:
+    """`snapshot_built` as `/stats` serves it under `build_keys`: what
+    names the key from its `spec` (`model`, `kind`, `n_slots`,
+    `n_states`; None for a launch without one) in the place of `key`,
+    `spec` and `lanes`."""
+    out = []
+    for k in snapshot_built():
+        spec = k.pop("spec") or {}
+        del k["key"], k["lanes"]
+        out.append({**{f: spec.get(f) for f in (
+            "model", "kind", "n_slots", "n_states")}, **k})
+    return out
+
+
+def key_name(entry: dict) -> str:
+    """A key for a human: the argument of `launch.build` and of the
+    start's log line."""
+    spec = entry["spec"]
+    if spec is None:
+        return f"unnamed/w{entry['width']}"
+    return (f"{spec['model']}/{spec['kind']}/W{spec['n_slots']}"
+            f"/S{spec['n_states']}/w{entry['width']}")
+
+
+@contextlib.contextmanager
+def _building(entry: dict):
+    """Names the key the calling thread builds, for the listeners'
+    per-key seconds (`_stage_add`)."""
+    _OPEN.building = entry
+    try:
+        yield
+    finally:
+        _OPEN.building = None
 
 
 def snapshot_launched() -> list:
@@ -950,7 +1051,8 @@ def _build_task(launch: ChunkLaunch, key: tuple, rows: int,
     leaves it unbuilt (the service that asked is gone)."""
     try:
         if stop is None or not stop():
-            _build_rows(launch, rows, lower, width)
+            with _building(_BUILT[key]):
+                _build_rows(launch, rows, lower, width)
             with _BUILD_LOCK:
                 _BUILT[key]["rows"].add(rows)
     finally:
@@ -972,7 +1074,8 @@ def _key_shapes(launch: ChunkLaunch, chunk: int, rows: int,
 
 def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
                rows: Optional[int] = None, upto: Optional[int] = None,
-               stop: Optional[Callable[[], bool]] = None) -> int:
+               stop: Optional[Callable[[], bool]] = None,
+               met: str = "launch") -> int:
     """Build what is not built yet of `launches`' keys (templates or
     real launches; each names a key by its fns, lanes, schedule and
     placement) on the build threads, and wait for it in ONE
@@ -987,10 +1090,19 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
     after which no launch of the key ever builds. A bucket that another
     thread is building already is waited for, not built twice.
     Returns the number of programs waited for. `stop()` true leaves
-    what has not started unbuilt."""
+    what has not started unbuilt.
+
+    `met` says who asks: a launch (`_init_group`, recompaction) or
+    graftd's start (``"start"``, `service/buildahead.py`); a key keeps
+    the first. A launch's wait is named on the thread that takes it,
+    `annotate("launch.build", key=, programs=)` around the span: on the
+    dispatcher's line of a profiler session the pause lies inside
+    `launch.device` under a name of its own. A launch whose key is
+    built never enters it."""
     global _BUILDERS
     chunk = scan_chunk() if chunk is None else chunk
     waits = []   # (launch, key, row bucket, lower, width, future)
+    fresh = []   # the entries of the keys this call met first
     with _BUILD_LOCK:
         if _BUILDERS is None:
             from concurrent.futures import ThreadPoolExecutor
@@ -1001,9 +1113,12 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
             key, shapes, need = _key_shapes(
                 launch, chunk,
                 launch.events.shape[0] if rows is None else rows, upto)
-            built = _BUILT.setdefault(key, {
-                "rows": set(), "spec": launch.spec, "width": shapes.width,
-                "lanes": int(launch.events.shape[2])})["rows"]
+            entry = _BUILT.get(key)
+            if entry is None:
+                entry = _BUILT[key] = _new_key_entry(launch, shapes.width,
+                                                     met)
+                fresh.append(entry)
+            built = entry["rows"]
             lower = dict(zip(shapes.rows[1:], shapes.rows[:-1]))
             # the heaviest first: a step program's cost grows with its
             # rows
@@ -1020,14 +1135,30 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
     if not waits:
         return 0
     n = sum(3 if w[3] is not None else 2 for w in waits)
-    with span(BUILD_AHEAD, n=n, keys=len(launches)):
+    named = (annotate("launch.build", key=key_name(_BUILT[waits[0][1]]),
+                      programs=n)
+             if met == "launch" else contextlib.nullcontext())
+    waited: dict = {}   # key -> seconds of this call's wait booked to it
+    with named, span(BUILD_AHEAD, n=n, keys=len(launches)):
+        t0 = time.perf_counter()
         for launch, key, r, low, width, fut in waits:
             fut.result()
             if r not in _BUILT[key]["rows"] and not (stop and stop()):
                 # another caller's `stop` left it unbuilt: build here
-                _build_rows(launch, r, low, width)
+                with _building(_BUILT[key]):
+                    _build_rows(launch, r, low, width)
                 with _BUILD_LOCK:
                     _BUILT[key]["rows"].add(r)
+            t1 = time.perf_counter()
+            waited[key] = waited.get(key, 0.0) + t1 - t0
+            t0 = t1
+    with _BUILD_LOCK:
+        for key, s in waited.items():
+            _BUILT[key]["wait_s"] += s
+        done = [e for e in fresh if e["rows"]]
+    if done:
+        _add_stats(keys_built=len(done), keys_met_by_launch=sum(
+            1 for e in done if e["met"] == "launch"))
     return n
 
 

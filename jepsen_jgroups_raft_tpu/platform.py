@@ -14,6 +14,8 @@ from __future__ import annotations
 import logging
 import os
 import re
+import threading
+import time
 from typing import Optional
 
 _log = logging.getLogger(__name__)
@@ -124,30 +126,91 @@ def enable_compile_cache() -> Optional[str]:
 
 _COMPILE_COUNTERS_INSTALLED = False
 
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+#: the two stages of a build that are Python's, by JAX's event
+_PYTHON_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
+
 
 def install_compile_counters() -> None:
     """Register, once a process, the `jax.monitoring` listeners that
-    feed the registry's compile counters (`checker.schedule`:
-    `programs_built`, `compile_s`, `compile_cache_misses`, and the most
-    recent compiles with the span each interrupted). graftd calls it
-    when it starts, so an operator whose daemon never stops compiling
-    sees that in `/stats` as programs, not only as latency."""
+    feed the registry's compile counters and build stages
+    (`checker.schedule`: `programs_built`, `compile_s`,
+    `compile_cache_misses`, spans `build.trace|lower|load|compile`, a
+    key's seconds in `build_keys`, and the most recent compiles with the
+    span each interrupted). graftd calls it when it starts, so an
+    operator whose daemon never stops compiling sees that in `/stats`
+    as programs, not only as latency.
+
+    The events it listens to, all JAX's own:
+
+    * ``/jax/core/compile/jaxpr_trace_duration`` → `build.trace`
+      (Python tracing a program to a jaxpr) and
+      ``/jax/core/compile/jaxpr_to_mlir_module_duration`` →
+      `build.lower` (the jaxpr to an MLIR module), each as a scalar
+      when the stage opens on a thread and as a duration when it ends.
+      A `jit` traced inside another's trace (every `jnp` operator is
+      one) and a trace inside a lowering fire events of their own whose
+      seconds lie INSIDE the outer event's: the scalars count how many
+      are open on the thread, and only the outermost is added, so a
+      program's trace and lowering are counted once and its four stages
+      sum to no more than its wall. What is added is the thread's own
+      CPU seconds between the two events (`time.thread_time`), not the
+      event's duration: both stages are Python, so with eight build
+      threads the duration is mostly the wait for the GIL, counted
+      eight times (a CPU rehearsal: 153 s of durations against a wait
+      of 23 s).
+    * ``/jax/core/compile/backend_compile_duration`` → `programs_built`,
+      `compile_s`, and span `build.load` where
+      ``/jax/compilation_cache/cache_hits`` fired on that thread since
+      the program's last (the persistent cache gave it), `build.compile`
+      otherwise (XLA compiled it from source).
+    * ``/jax/compilation_cache/cache_misses`` → `compile_cache_misses`
+      (a program compiled and written to the persistent cache)."""
     global _COMPILE_COUNTERS_INSTALLED
     if _COMPILE_COUNTERS_INSTALLED:
         return
     _COMPILE_COUNTERS_INSTALLED = True
     from jax import monitoring
 
-    from .checker.schedule import note_cache_miss, note_compile
+    from .checker.schedule import (BUILD_STAGES, note_build_stage,
+                                   note_cache_miss, note_compile, note_span)
+
+    for stage in BUILD_STAGES:   # served from the start: a stage that
+        note_span("build." + stage, 0.0, 0)   # never ran reads 0.0
+    #: `.open`: trace and lower stages open on this thread; `.cpu0`:
+    #: the thread's CPU clock where the outermost opened; `.hit`: the
+    #: cache gave the program this thread is loading
+    here = threading.local()
+
+    def on_scalar(event, value, **kw) -> None:
+        if event in _PYTHON_STAGES:
+            n_open = getattr(here, "open", 0)
+            if not n_open:
+                here.cpu0 = time.thread_time()
+            here.open = n_open + 1
 
     def on_duration(event, seconds, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            note_compile(str(kw.get("fun_name", "?")), seconds)
+        if event == _BACKEND_EVENT:
+            loaded, here.hit = getattr(here, "hit", False), False
+            note_compile(str(kw.get("fun_name", "?")), seconds, loaded)
+        elif event in _PYTHON_STAGES:
+            here.open = still_open = max(getattr(here, "open", 1) - 1, 0)
+            cpu0 = getattr(here, "cpu0", None)
+            if not still_open and cpu0 is not None:
+                note_build_stage(_PYTHON_STAGES[event],
+                                 time.thread_time() - cpu0)
 
     def on_event(event, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_misses":
+        if event == _CACHE_MISS_EVENT:
             note_cache_miss()
+        elif event == _CACHE_HIT_EVENT:
+            here.hit = True
 
+    monitoring.register_scalar_listener(on_scalar)
     monitoring.register_event_duration_secs_listener(on_duration)
     monitoring.register_event_listener(on_event)
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import time
 from typing import Callable, List, Optional
 
 from ..checker import autotune, schedule
@@ -102,15 +103,41 @@ def build_at_start(max_rows: int,
                    stop: Optional[Callable[[], bool]] = None) -> dict:
     """Build the keys this service can know (module docstring), each
     whole for launches of up to `max_rows` rows. Returns ``{"source",
-    "keys", "programs"}`` for `/stats`."""
+    "keys", "programs", "seconds"}`` for `/stats`, `seconds` the wall
+    of the three steps, each a span: `start.plans` (the tuner's plans
+    read into memory), `start.record` (the record read and a template
+    made of each entry), `build.ahead` (the wait for the programs). One
+    INFO line a key says what its build cost, by stage."""
     global _written
     from ..history.packing import macro_events_on
 
-    autotune.preload_plans()
-    keys = read_record()
-    _written = len(keys)
-    if not macro_events_on():
-        keys = []   # the records are of the macro stream
+    t0 = time.perf_counter()
+    with schedule.span("start.plans"):
+        autotune.preload_plans()
+    with schedule.span("start.record") as reading:
+        keys = read_record()
+        _written = len(keys)
+        if not macro_events_on():
+            keys = []   # the records are of the macro stream
+        launches = _templates(keys)
+        reading.n = len(launches)
+    programs = (schedule.build_keys(launches, upto=max_rows, stop=stop,
+                                    met="start")
+                if launches else 0)
+    for k in schedule.snapshot_built():
+        if k["met"] == "start":
+            LOG.info("built %s at the start: %d programs, trace %.2f lower "
+                     "%.2f load %.2f compile %.2f s", schedule.key_name(k),
+                     k["programs"], k["trace_s"], k["lower_s"], k["load_s"],
+                     k["compile_s"])
+    return {"source": "record" if launches else "none",
+            "keys": len(launches), "programs": programs,
+            "seconds": time.perf_counter() - t0}
+
+
+def _templates(keys: List[dict]) -> list:
+    """A launch template for each entry of the record that this process
+    serves and would place as recorded."""
     models = _service_models()
     launches = []
     for k in keys:
@@ -127,7 +154,4 @@ def build_at_start(max_rows: int,
             continue
         if launch is not None:
             launches.append(launch)
-    programs = (schedule.build_keys(launches, upto=max_rows, stop=stop)
-                if launches else 0)
-    return {"source": "record" if launches else "none",
-            "keys": len(launches), "programs": programs}
+    return launches

@@ -36,8 +36,8 @@ from collections import deque
 from pathlib import Path
 from typing import Optional, Sequence
 
-from ..checker.schedule import (snapshot_compiles, snapshot_spans,
-                                snapshot_stats, span)
+from ..checker.schedule import (snapshot_build_keys, snapshot_compiles,
+                                snapshot_spans, snapshot_stats, span)
 from ..platform import install_compile_counters
 from . import buildahead
 from .admission import (AdmissionQueue, QueueFull, ResultCache,
@@ -175,6 +175,9 @@ class CheckingService:
                  lease_ttl_s: Optional[float] = None,
                  autostart: bool = True):
         self.name = name
+        #: construction, on the monotonic clock: `warm_after_s` counts
+        #: from here
+        self._constructed = time.monotonic()
         install_compile_counters()
         self.store_root = Path(store_root) if store_root else None
         self.queue = AdmissionQueue(queue_capacity,
@@ -209,8 +212,10 @@ class CheckingService:
         #: set once the build-ahead of the keys this service can know
         #: has run (ISSUE 32); `/stats` serves it as `warm`
         self._warm = threading.Event()
+        #: seconds from construction to `_warm`; None until then
+        self._warm_after_s: Optional[float] = None
         self._build_ahead_info = {"source": "none", "keys": 0,
-                                  "programs": 0}
+                                  "programs": 0, "seconds": 0.0}
         self._worker: Optional[threading.Thread] = None
         self._latencies: deque = deque(maxlen=LATENCY_WINDOW)  # guarded_by(_lock)
         # Durability/resilience tier (ISSUE 8).
@@ -290,7 +295,8 @@ class CheckingService:
         # idle-park resumability need one (stream.py docstring).
         self.streams = StreamManager(self)
         if self._journal is not None:
-            self._recover()
+            with span("start.recover"):
+                self._recover()
         if autostart:
             self.start()
 
@@ -516,7 +522,7 @@ class CheckingService:
         if self.cluster is not None:
             self.cluster.start()
         if not self.scheduler.fastlane_enabled:
-            self._warm.set()   # an injected check_fn has no kernels
+            self._set_warm()   # an injected check_fn has no kernels
         self._ensure_worker()
 
     def _ensure_worker(self) -> None:
@@ -673,17 +679,33 @@ class CheckingService:
         and sending to it; requests that arrive meanwhile wait in the
         admission queue — acknowledged after their WAL fsync, answered
         by the kernels. A failure is logged and costs only the pause a
-        key's first launch then takes."""
+        key's first launch then takes.
+
+        The start in spans (ISSUE 42; with `start.recover`, the journal
+        replay in the constructor): `start.backend`, the first touch of
+        the devices, made here on purpose so that a backend that takes
+        seconds to come up is not read as slow plans (`preload_plans`
+        asks for the devices to name its store); then `build_at_start`'s
+        `start.plans`, `start.record` and `build.ahead`."""
         if self._warm.is_set():
             return
         try:
+            with span("start.backend"):
+                import jax
+
+                jax.devices()
             self._build_ahead_info = buildahead.build_at_start(
                 self.scheduler.max_batch_rows, stop=self._stop.is_set)
         except Exception:
             LOG.exception("%s build-ahead failed; keys will be built "
                           "on first sight", self.name)
         finally:
-            self._warm.set()
+            self._set_warm()
+
+    def _set_warm(self) -> None:
+        if self._warm_after_s is None:
+            self._warm_after_s = time.monotonic() - self._constructed
+        self._warm.set()
 
     def _fastlane_done(self, done) -> None:
         """Account requests the dispatch fast lane decided (ISSUE 14):
@@ -1125,6 +1147,13 @@ class CheckingService:
         out["wide_rows_host"] = scan["wide_rows_host"]
         out["warm"] = self._warm.is_set()
         out["build_ahead"] = dict(self._build_ahead_info)
+        # ISSUE 42: what the start cost (absent until warm; a service
+        # made with autostart=False counts the time it stood parked),
+        # and each key's build on the program's clock: how it was met,
+        # its programs and their seconds by stage
+        if self._warm_after_s is not None:
+            out["warm_after_s"] = self._warm_after_s
+        out["build_keys"] = snapshot_build_keys()
         # the host certifier's counters (process-wide, like the spans):
         # rows_delivered / rows_scanned is the hit share its gate routes
         # on, rows_gated / (rows_gated + rows_scanned) how often it

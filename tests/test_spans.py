@@ -295,13 +295,20 @@ def test_nine_spans_tile_the_dispatcher_loop(tmp_path, monkeypatch):
     ended = []      # (thread, name, clock at its end, seconds), in order
     note = schedule.note_span
 
+    waited_in = []  # the span open where a `build.ahead` wait ended
+
     def noting(name, seconds, n=1):
         ended.append((threading.get_ident(), name, time.perf_counter(),
                       seconds))
+        if name == "build.ahead":
+            waited_in.append((threading.get_ident(), schedule.open_span()))
         note(name, seconds, n)
 
     monkeypatch.setattr(schedule, "note_span", noting)
     monkeypatch.setattr(scheduler, "note_span", noting)
+    # no key is built: the first launch waits for its own, in
+    # `launch.build`, an annotation nested in the `launch.device` tile
+    monkeypatch.setattr(schedule, "_BUILT", {})
     before = snapshot_spans()
     svc = CheckingService(store_root=str(tmp_path), n_workers=1)
     try:
@@ -320,6 +327,7 @@ def test_nine_spans_tile_the_dispatcher_loop(tmp_path, monkeypatch):
     tiled = sum(s for _, s in loop)
     assert tiled >= 0.9 * wall, (tiled, wall)
     assert tiled <= 1.02 * wall
+    assert (worker, "launch.build") in waited_in
     # one row in four is invalid, and each is explained
     assert total("demux.counterexample", "n", before) == len(reqs)
     assert total("demux.results", "n", before) == len(reqs)
@@ -379,10 +387,11 @@ def test_compile_counters_name_the_step_that_compiled():
     built = after["programs_built"] - before["programs_built"]
     assert built >= 1 and scope["programs_built"] == built
     assert after["compile_s"] > before["compile_s"]
-    [(name, seconds, inside)] = [
+    [(name, seconds, inside, stage)] = [
         c for c in after["recent_compiles"]
         if "never_seen_before_26" in c[0]]
     assert seconds > 0 and inside == "t.compiling"
+    assert stage == "compile"           # tests keep no persistent cache
     assert len(after["recent_compiles"]) <= 16
 
 
