@@ -59,7 +59,7 @@ import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -118,6 +118,10 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # a host's first run the record builds them at the start:
                # healthy 0)
                "keys_built": 0, "keys_met_by_launch": 0,
+               # one trace a program kind (ISSUE 43): programs built or
+               # loaded by calling a key's shared trace at a row count
+               # (`_RowShared`); on one device, every program of the set
+               "programs_from_shared_trace": 0,
                # wide windows (ISSUE 40): rows past SEGMENT_MAX_SLOTS that
                # entered the kernel ladder and, of those, the rows a host
                # engine decided
@@ -485,7 +489,9 @@ def note_compile(fun_name: str, seconds: float,
     miss = shape is not None and not ahead
     stage = "load" if loaded else "compile"
     _add_stats(programs_built=1, compile_s=seconds,
-               programs_built_ahead=int(ahead), shape_misses=int(miss))
+               programs_built_ahead=int(ahead), shape_misses=int(miss),
+               programs_from_shared_trace=int(
+                   SHARED_SUFFIX in fun_name))
     with _STATS_LOCK:
         _stage_add(stage, seconds, programs=1)
         _RECENT_COMPILES.append((fun_name, seconds, inside, stage))
@@ -507,7 +513,7 @@ def snapshot_compiles() -> dict:
         out = {k: _STATS[k] for k in (
             "programs_built", "compile_s", "compile_cache_misses",
             "programs_built_ahead", "shape_misses", "keys_built",
-            "keys_met_by_launch")}
+            "keys_met_by_launch", "programs_from_shared_trace")}
         out["recent_compiles"] = [list(c) for c in _RECENT_COMPILES]
         out["recent_shape_misses"] = [list(c) for c in _RECENT_MISSES]
         return out
@@ -603,6 +609,7 @@ class _GroupState:
     launch: ChunkLaunch
     events: object                     # device [rows, width, lanes]
     key: Optional[tuple]               # launch-shape key; None: exact rows
+    programs: "_Programs"              # what the key's launches call
     width: int                         # device event length
     chunk: int                         # this group's resolved chunk size
     scheduled: int                     # chunks the monolithic path implies
@@ -887,7 +894,7 @@ _LAUNCHED_CAP = 8192
 def _new_key_entry(launch: ChunkLaunch, width: int, met: str) -> dict:
     entry = {"rows": set(), "spec": launch.spec, "width": width,
              "lanes": int(launch.events.shape[2]), "met": met,
-             "wait_s": 0.0, "programs": 0}
+             "wait_s": 0.0, "programs": 0, "traced": 0}
     entry.update((stage + "_s", 0.0) for stage in BUILD_STAGES)
     return entry
 
@@ -978,16 +985,21 @@ class _launching:
 def _put(launch: ChunkLaunch, x):
     """Place a launch operand: under the launch's placement, or on the
     default device. Always a device array, so that a group's events
-    cross to the device once."""
+    cross to the device once, and always COMMITTED to its place: a call
+    of a shared trace (`_RowShared`) commits what it returns, and a jit
+    keeps a program apart for operands that are not, so events put and
+    left uncommitted would meet a second program of the same shapes the
+    first time they came back from a gather."""
     import jax
 
-    return jax.device_put(x, launch.device)
+    return jax.device_put(x, launch.device if launch.device is not None
+                          else jax.local_devices()[0])
 
 
-def _init_carry(launch: ChunkLaunch, val_of, n_events):
+def _init_carry(launch: ChunkLaunch, init, val_of, n_events):
     if launch.val_of is not None:
-        return launch.init_fn(_put(launch, val_of), _put(launch, n_events))
-    return launch.init_fn(_put(launch, n_events))
+        return init(_put(launch, val_of), _put(launch, n_events))
+    return init(_put(launch, n_events))
 
 
 _GATHERS: dict = {}
@@ -995,8 +1007,7 @@ _GATHERS: dict = {}
 
 def _gather_fn(placement):
     """The recompaction program of a placement: carry and events
-    gathered onto a smaller row bucket, compiled per (rows before, rows
-    after) like any jitted function. Under a batch-axis sharding the
+    gathered onto a smaller row bucket. Under a batch-axis sharding the
     outputs are pinned back to it, so the next step splits evenly
     again."""
     fn = _GATHERS.get(placement)
@@ -1013,26 +1024,141 @@ def _gather_fn(placement):
     return fn
 
 
-def _build_rows(launch: ChunkLaunch, rows: int, lower: Optional[int],
-                width: int) -> None:
+# ------------------------------------------- one trace a program kind
+# ISSUE 43. The row count is `vmap`'s batch axis and nothing else: the
+# nine step programs of a key are the same Python, and tracing it nine
+# times was the wall graftd's start waited for (PERF.md section 5). So a
+# key's three program kinds are each traced and lowered ONCE, with the
+# row axis a symbol (`jax.export`), and a row bucket's program is that
+# one lowering called at the bucket's row count. Rows is the only
+# symbolic axis: width, lanes, W, S and P pick the kernel's loops and
+# tables and stay static, as the key has them; the span's offset and
+# length were traced scalars already.
+
+#: what `note_compile` knows a program of a shared trace by
+SHARED_SUFFIX = "_at_rows"
+
+
+class _RowShared:
+    """A jitted function traced once for every row count it is called
+    at. The first call exports it with its operands' leading axes
+    symbolic: `dims` gives each operand's symbol, or None for an operand
+    without a row axis (a pytree operand's leaves all lead with the
+    rows). Every call, the first too, goes through ONE
+    ``jax.jit(exported.call)``: at a row count it has not seen, that
+    lowers the call (no Python of the kernel runs), refines the module
+    to the count and builds or loads the program; from then on the
+    count is a hit in the jit's in-memory cache, for a launch as for
+    the build-ahead, because both call this object. The lock is the
+    once-a-key guard: a key's buckets go to the build threads at the
+    same moment, and each would otherwise export for itself. The
+    export's trace and lowering fire JAX's events on the thread that
+    makes it, so they are booked to the key that thread builds
+    (`_stage_add`), which also counts the export (`traced`)."""
+
+    __slots__ = ("_fn", "_dims", "_call", "_lock")
+
+    def __init__(self, fn, dims: tuple):
+        self._fn, self._dims = fn, dims
+        self._call = None
+        self._lock = threading.Lock()
+
+    def __call__(self, *args):
+        call = self._call
+        if call is None:
+            call = self._export(args)
+        return call(*args)
+
+    def _export(self, args):
+        with self._lock:
+            if self._call is not None:
+                return self._call
+            import jax
+            from jax import export
+
+            scope = export.SymbolicScope()
+            sym = {d: export.symbolic_shape(d, scope=scope)[0]
+                   for d in set(self._dims) - {None}}
+
+            def like(x, d):
+                shape = x.shape if d is None else (sym[d],) + x.shape[1:]
+                return jax.ShapeDtypeStruct(shape, x.dtype)
+
+            exported = export.export(self._fn)(*(
+                jax.tree_util.tree_map(lambda x, d=d: like(x, d), a)
+                for a, d in zip(args, self._dims)))
+
+            def at_rows(*a):
+                return exported.call(*a)
+
+            at_rows.__name__ = at_rows.__qualname__ = \
+                getattr(self._fn, "__name__", "program") + SHARED_SUFFIX
+            entry = getattr(_OPEN, "building", None)
+            if entry is not None:
+                with _STATS_LOCK:
+                    entry["traced"] += 1
+            self._call = jax.jit(at_rows)
+            return self._call
+
+
+class _Programs(NamedTuple):
+    """What a launch of a key and the key's build both call."""
+
+    init: Callable
+    step: Callable
+    gather: Callable
+
+
+#: key -> its _Programs of shared traces; guarded by _BUILD_LOCK. Not in
+#: `_BUILT` (a key's record is JSON), and like the jit caches it
+#: outlives a reset of it
+_SHARED: dict = {}
+
+
+def _programs(launch: ChunkLaunch, key: Optional[tuple]) -> _Programs:
+    """The three programs of `launch`, one trace each for all the row
+    buckets of its key. Two placements keep a trace a bucket, by what
+    is observed of the launch: a launch outside the set (no key: a LONG
+    cluster's exact rows, one row count ever), and a mesh placement,
+    whose `shard_map` body jaxlib's shape refinement cannot take (it
+    segfaults on the module, CPU mesh, JAX 0.9.0; PERF.md section 6,
+    PR 43)."""
+    if key is None or _n_shards(launch.device) > 1:
+        return _Programs(launch.init_fn, launch.step_fn,
+                         _gather_fn(launch.device))
+    with _BUILD_LOCK:
+        programs = _SHARED.get(key)
+        if programs is None:
+            rows = ("rows",) * (2 if launch.val_of is not None else 1)
+            programs = _SHARED[key] = _Programs(
+                _RowShared(launch.init_fn, rows),
+                _RowShared(launch.step_fn, ("rows", "rows", None, None)),
+                _RowShared(_gather_fn(launch.device),
+                           ("rows", "rows", "rows_after")))
+        return programs
+
+
+def _build_rows(launch: ChunkLaunch, key: tuple, rows: int,
+                lower: Optional[int], width: int) -> None:
     """Ask for the three programs of one row bucket, on operands shaped,
     typed and placed exactly as `_init_group`, `_dispatch` and
     `_collect` make them; the step scans no event."""
     import jax
 
     lanes = launch.events.shape[2]
+    init, step, gather = _programs(launch, key)
     with annotate(BUILD_AHEAD, rows=rows):
         vo = None
         if launch.val_of is not None:
             vo = np.zeros((rows,) + launch.val_of.shape[1:],
                           dtype=launch.val_of.dtype)
-        carry = _init_carry(launch, vo, np.zeros((rows,), np.int32))
+        carry = _init_carry(launch, init, vo, np.zeros((rows,), np.int32))
         events = _put(launch, np.zeros((rows, width, lanes),
                                        dtype=launch.events.dtype))
-        out = launch.step_fn(carry, events, np.int32(0), np.int32(0))
+        out = step(carry, events, np.int32(0), np.int32(0))
         if lower is not None:
-            out = (out, _gather_fn(launch.device)(
-                carry, events, np.zeros((lower,), np.int32)))
+            out = (out, gather(carry, events,
+                               np.zeros((lower,), np.int32)))
         jax.block_until_ready(out)
 
 
@@ -1052,7 +1178,7 @@ def _build_task(launch: ChunkLaunch, key: tuple, rows: int,
     try:
         if stop is None or not stop():
             with _building(_BUILT[key]):
-                _build_rows(launch, rows, lower, width)
+                _build_rows(launch, key, rows, lower, width)
             with _BUILD_LOCK:
                 _BUILT[key]["rows"].add(rows)
     finally:
@@ -1146,7 +1272,7 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
             if r not in _BUILT[key]["rows"] and not (stop and stop()):
                 # another caller's `stop` left it unbuilt: build here
                 with _building(_BUILT[key]):
-                    _build_rows(launch, r, low, width)
+                    _build_rows(launch, key, r, low, width)
                 with _BUILD_LOCK:
                     _BUILT[key]["rows"].add(r)
             t1 = time.perf_counter()
@@ -1210,10 +1336,12 @@ def _init_group(launch: ChunkLaunch, chunk: int,
                       dtype=launch.val_of.dtype)
         vo[:B] = launch.val_of
         vo[B:] = launch.val_of[:1]
+    programs = _programs(launch, key)
     with _launching("init", key, rows, width):
-        carry = _init_carry(launch, vo, ne)
+        carry = _init_carry(launch, programs.init, vo, ne)
     return _GroupState(
-        launch=launch, events=_put(launch, events), key=key, width=width,
+        launch=launch, events=_put(launch, events), key=key,
+        programs=programs, width=width,
         chunk=chunk, scheduled=e_pad // chunk,
         slot_rows=slot_rows, carry=carry,
         ok=np.zeros((B,), dtype=bool), overflow=np.zeros((B,), dtype=bool),
@@ -1248,9 +1376,9 @@ def _dispatch(g: _GroupState) -> None:
     n_chunks = _span_chunks(g)
     t0 = time.perf_counter()
     with _launching("step", g.key, g.slot_rows.shape[0], g.width):
-        out = g.launch.step_fn(g.carry, g.events,
-                               np.int32(g.cursor * g.chunk),
-                               np.int32(n_chunks * g.chunk))
+        out = g.programs.step(g.carry, g.events,
+                              np.int32(g.cursor * g.chunk),
+                              np.int32(n_chunks * g.chunk))
     g.pending = (t0, n_chunks, out)
 
 
@@ -1307,7 +1435,6 @@ def _collect(g: _GroupState) -> None:
         return
     # Walk down the set's buckets, one `gather` program a step: the
     # first brings the survivors to the front, the rest only shorten.
-    gather = _gather_fn(g.launch.device)
     survivors = g.slot_rows[alive]
     positions = alive
     rows_down = [r for r in launch_shapes(
@@ -1320,8 +1447,8 @@ def _collect(g: _GroupState) -> None:
             # service built them all before the key's first launch
             build_keys([g.launch], g.chunk, rows=after)
         with _launching("gather", g.key, have, after, g.width):
-            g.carry, g.events = gather(g.carry, g.events,
-                                       _pad_idx(positions, after))
+            g.carry, g.events = g.programs.gather(
+                g.carry, g.events, _pad_idx(positions, after))
         positions = np.arange(alive.size, dtype=np.int32)
         have = after
     g.slot_rows = np.full((have,), -1, dtype=np.int32)

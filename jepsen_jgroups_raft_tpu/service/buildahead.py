@@ -126,9 +126,10 @@ def build_at_start(max_rows: int,
                 if launches else 0)
     for k in schedule.snapshot_built():
         if k["met"] == "start":
-            LOG.info("built %s at the start: %d programs, trace %.2f lower "
-                     "%.2f load %.2f compile %.2f s", schedule.key_name(k),
-                     k["programs"], k["trace_s"], k["lower_s"], k["load_s"],
+            LOG.info("built %s at the start: %d programs from %d traces, "
+                     "trace %.2f lower %.2f load %.2f compile %.2f s",
+                     schedule.key_name(k), k["programs"], k["traced"],
+                     k["trace_s"], k["lower_s"], k["load_s"],
                      k["compile_s"])
     return {"source": "record" if launches else "none",
             "keys": len(launches), "programs": programs,

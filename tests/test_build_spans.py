@@ -93,12 +93,19 @@ print(json.dumps({{
 """
 
 
-@pytest.fixture(scope="module")
-def two_processes(tmp_path_factory):
+#: placement -> JGRAFT_GROUP_DEVICES: the CPU mesh keeps a trace a row
+#: bucket, one device takes the key's three shared traces (ISSUE 43)
+PLACEMENTS = {"mesh": "8", "one-device": "0"}
+
+
+@pytest.fixture(scope="module", params=sorted(PLACEMENTS))
+def two_processes(request, tmp_path_factory):
     """The same key built in two fresh processes that share one
-    persistent cache, which keeps every program."""
+    persistent cache, which keeps every program; on the mesh and on one
+    device."""
     cache = tmp_path_factory.mktemp("xla-cache")
     env = dict(os.environ, JAX_PLATFORMS="cpu", JGRAFT_SCAN_CHUNK="16",
+               JGRAFT_GROUP_DEVICES=PLACEMENTS[request.param],
                JGRAFT_AUTOTUNE="0", JAX_COMPILATION_CACHE_DIR=str(cache),
                JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
                JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
@@ -112,6 +119,7 @@ def two_processes(tmp_path_factory):
             cwd=str(ROOT))
         assert p.returncode == 0, p.stderr[-3000:]
         runs[which] = json.loads(p.stdout.strip().splitlines()[-1])
+        runs[which]["shared"] = request.param == "one-device"
     return runs
 
 
@@ -159,6 +167,11 @@ def test_a_keys_seconds_add_up_to_the_registrys(two_processes, which, stage):
     assert d["build.load"]["n"] + d["build.compile"]["n"] == 8
     assert key["wait_s"] == pytest.approx(d["build.ahead"]["s"], rel=0.01)
     assert d["build.ahead"]["n"] == 8
+    # a stage's `n` is one a program, and one more an export
+    assert key["traced"] == (3 if run["shared"] else 0)
+    assert d["build.trace"]["n"] == 8 + key["traced"] == d["build.lower"]["n"]
+    assert run["compiles"]["programs_from_shared_trace"] == \
+        (8 if run["shared"] else 0)
 
 
 # ------------------------------------------- (b) nesting, and the listener
@@ -204,7 +217,8 @@ def test_the_stages_of_a_program_fit_inside_its_wall(program, monkeypatch):
     rows, width = 24, 80
     calls = {
         "init": lambda: schedule._init_carry(
-            launch, np.zeros((rows,) + launch.val_of.shape[1:],
+            launch, launch.init_fn,
+            np.zeros((rows,) + launch.val_of.shape[1:],
                              launch.val_of.dtype),
             np.zeros((rows,), np.int32)),
     }
@@ -226,6 +240,71 @@ def test_the_stages_of_a_program_fit_inside_its_wall(program, monkeypatch):
     assert snapshot_compiles()["programs_built"] - built == 1
     assert d["build.trace"]["n"] == 1 == d["build.lower"]["n"]
     assert 0 < sum(d[s]["s"] for s in STAGE_SPANS) <= wall
+
+
+@pytest.mark.parametrize("program", ["init", "step", "gather"])
+def test_a_bucket_of_a_shared_trace_is_one_program_of_four_stages(
+        program, monkeypatch):
+    """On one device a row bucket's program is a call of the key's
+    shared trace (ISSUE 43): still one trace event, one lowering and one
+    load or compile a program, inside its wall; the export that came
+    first was one of each stage more, and no program."""
+    import jax
+    import numpy as np
+
+    monkeypatch.setenv("JGRAFT_SCAN_CHUNK", "16")
+    monkeypatch.setenv("JGRAFT_GROUP_DEVICES", "0")
+    install_compile_counters()
+    launch = one_launch(20, seed=4300)
+    assert launch.device is None
+    width, lanes = 112, launch.events.shape[2]
+    key = ("test_build_spans", program, width)   # no launch's key
+    programs = schedule._programs(launch, key)
+
+    def operands(rows):
+        carry = schedule._init_carry(
+            launch, programs.init,
+            np.zeros((rows,) + launch.val_of.shape[1:],
+                     launch.val_of.dtype), np.zeros((rows,), np.int32))
+        events = schedule._put(launch, np.zeros((rows, width, lanes),
+                                                launch.events.dtype))
+        return carry, events
+
+    def call(rows):
+        if program == "init":
+            return lambda: operands(rows)[0]
+        # the operands' own programs are built outside the clock
+        carry, events = jax.block_until_ready(operands(rows))
+        if program == "step":
+            return lambda: programs.step(carry, events, np.int32(0),
+                                         np.int32(0))
+        return lambda: programs.gather(carry, events,
+                                       np.zeros((8,), np.int32))
+
+    def timed(rows):
+        run = call(rows)
+        before = snapshot_spans()
+        built = snapshot_compiles()
+        t0 = time.perf_counter()
+        jax.block_until_ready(run())
+        wall = time.perf_counter() - t0
+        after = snapshot_compiles()
+        return (moved(before, snapshot_spans()), wall,
+                after["programs_built"] - built["programs_built"],
+                after["programs_from_shared_trace"]
+                - built["programs_from_shared_trace"])
+
+    d, wall, built, shared = timed(24)      # the export, and a bucket
+    assert built == 1 == shared
+    assert d["build.trace"]["n"] == 2 == d["build.lower"]["n"]
+    assert 0 < sum(d[s]["s"] for s in STAGE_SPANS) <= wall
+    d, wall, built, shared = timed(40)      # a bucket alone
+    assert built == 1 == shared
+    assert d["build.trace"]["n"] == 1 == d["build.lower"]["n"]
+    assert d["build.load"]["n"] + d["build.compile"]["n"] == 1
+    assert 0 < sum(d[s]["s"] for s in STAGE_SPANS) <= wall
+    d, _, built, _ = timed(40)              # a hit in the jit's cache
+    assert built == 0 == d["build.trace"]["n"]
 
 
 # --------------------------------- (d), (e): who met a key; graftd's start

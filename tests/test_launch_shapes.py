@@ -515,10 +515,10 @@ def test_two_threads_that_need_one_bucket_build_it_once(upto, monkeypatch):
     launch = _one_launch(20)
     real, calls, gate = schedule._build_rows, [], threading.Event()
 
-    def slow(launch, rows, lower, width):
+    def slow(launch, key, rows, lower, width):
         calls.append(rows)
         assert gate.wait(60.0)
-        return real(launch, rows, lower, width)
+        return real(launch, key, rows, lower, width)
 
     monkeypatch.setattr(schedule, "_build_rows", slow)
     got, errors = [], []
@@ -614,3 +614,232 @@ def test_nothing_is_placed_on_the_host_cpu_beside_a_chip(n_rows,
     # the mesh's sharding or the default device, never a concrete one
     assert launch.device is None or hasattr(launch.device, "mesh")
     assert "cpu" not in schedule.launch_key(launch, 128)[-1]
+
+
+# ------------------------------- one trace a program kind (ISSUE 43)
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+#: what JAX's trace event calls the vmapped step of either family, and
+#: a row bucket's call of its shared trace
+STEP, STEP_AT_ROWS = "step_one", "step_one" + schedule.SHARED_SUFFIX
+_TRACED: list = []   # fun_name of every trace event of this process
+
+
+def trace_events():
+    """The trace events since the call, by name, as a live list."""
+    from jax import monitoring
+
+    if not _TRACED:
+        _TRACED.append(None)    # the listener is registered once
+        monitoring.register_event_duration_secs_listener(
+            lambda event, _s, **kw: event == TRACE_EVENT and
+            _TRACED.append(kw.get("fun_name")))
+    start = len(_TRACED)
+
+    class Since:
+        def count(self, name):
+            return _TRACED[start:].count(name)
+
+    return Since()
+
+
+@pytest.fixture()
+def one_device(monkeypatch):
+    """The placement of a one-chip host: no mesh, the default device;
+    and "a new process": no key built, no shared trace made."""
+    monkeypatch.setenv("JGRAFT_GROUP_DEVICES", "0")
+    monkeypatch.setattr(schedule, "_BUILT", {})
+    monkeypatch.setattr(schedule, "_SHARED", {})
+    install_compile_counters()
+
+
+def family_launch(kind, n_rows, seed, chunk, n_ops=10, long_every=9):
+    """A real launch of one window group of `n_rows` histories of the
+    mask (counter) or the domain (register) family, and its key at
+    `chunk`: a seed and a chunk of its own give a test a key nothing
+    else in the process has built."""
+    from jepsen_jgroups_raft_tpu.history.packing import pack_batch
+    from jepsen_jgroups_raft_tpu.ops.dense_scan import dense_plans_grouped
+
+    model = MODELS[kind]()
+    rng = random.Random(seed)
+    encs = [encode_history(
+        random_valid_history(rng, kind, n_ops=n_ops if i % long_every else 6 * n_ops,
+                             n_procs=3, crash_p=0.0, value_range=3), model)
+        for i in range(n_rows)]
+    groups, rest = dense_plans_grouped(model, encs)
+    assert not rest
+    idxs, plan = max(groups, key=lambda g: len(g[0]))
+    assert plan.kind == {"counter": "mask", "register": "domain"}[kind]
+    batch = pack_batch([encs[i] for i in idxs])
+    [launch], _ = schedule.build_dense_launches(
+        model, [(list(idxs), plan, batch)])
+    key, shapes, _ = schedule._key_shapes(launch, chunk,
+                                          launch.events.shape[0], None)
+    return launch, key, shapes.width
+
+
+def built_entry(key):
+    [entry] = [b for b in snapshot_built() if b["key"] == key]
+    return entry
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_a_key_built_whole_is_twenty_six_programs_from_three_traces(
+        kind, one_device):
+    launch, key, _ = family_launch(kind, 12, 4301, chunk=24)
+    assert launch.device is None
+    since, before = trace_events(), snapshot_compiles()
+    assert schedule.build_keys([launch], 24, upto=256) == 26
+    entry = built_entry(key)
+    assert entry["rows"] == list(SERVED_ROWS)
+    assert (entry["traced"], entry["programs"]) == (3, 26)
+    after = snapshot_compiles()
+    assert after["programs_from_shared_trace"] - \
+        before["programs_from_shared_trace"] == 26 == \
+        after["programs_built"] - before["programs_built"]
+    # the kernel's Python ran once; a bucket's trace is the call's
+    assert since.count(STEP) == 1
+    assert since.count(STEP_AT_ROWS) == len(SERVED_ROWS)
+    assert since.count("gather") == 1 == since.count("init_one")
+    assert [k["traced"] for k in schedule.snapshot_build_keys()
+            if k["rows"] == list(SERVED_ROWS)] == [3]
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_a_bucket_of_the_shared_trace_is_bit_equal_to_its_own_jit(
+        kind, one_device):
+    """init, step and gather at two buckets, on a launch's own operands
+    padded as `_init_group` pads them, against the plain
+    ``jax.jit(jax.vmap(...))`` the launch carries."""
+    import jax
+
+    launch, key, width = family_launch(kind, 12, 4302, chunk=40)
+    shared = schedule._programs(launch, key)
+    plain = schedule._programs(launch, None)
+    assert plain.init is launch.init_fn and plain.step is launch.step_fn
+    assert isinstance(shared.step, schedule._RowShared)
+    B, E, lanes = launch.events.shape
+    rng = np.random.default_rng(4302)
+
+    def same(a, b):
+        la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+        assert len(la) == len(lb) > 0
+        for x, y in zip(la, lb):
+            x, y = np.asarray(x), np.asarray(y)
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+
+    for rows, after in ((16, 8), (48, 32)):
+        take = rng.integers(0, B, rows)
+        events = np.zeros((rows, width, lanes), launch.events.dtype)
+        events[:, :E] = launch.events[take]
+        ne, vo = launch.n_events[take], launch.val_of[take]
+        assert events.any() and ne.all()
+        idx = rng.integers(0, rows, after).astype(np.int32)
+        outs = []
+        for p in (shared, plain):
+            carry = p.init(vo, ne)
+            stepped = p.step(carry, events, np.int32(0), np.int32(width))
+            outs.append((carry, stepped,
+                         p.gather(stepped[0], events, idx)))
+        same(*outs)
+        _, _decided, exhausted, ok, _ = outs[0][1]
+        assert np.asarray(exhausted).all() and np.asarray(ok).all()
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_nine_buckets_from_eight_threads_export_once(kind, one_device):
+    """The once-a-key guard: the buckets of a key reach the build
+    threads at the same moment."""
+    launch, key, width = family_launch(kind, 12, 4303, chunk=56)
+    programs = schedule._programs(launch, key)
+    assert schedule._programs(launch, key) is programs
+    since = trace_events()
+    go, errors = threading.Barrier(8), []
+
+    def build(rows):
+        try:
+            go.wait(30.0)
+            for r in rows:
+                schedule._build_rows(launch, key, r, None, width)
+        except BaseException as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=build,
+                                args=(SERVED_ROWS[i::8],))
+               for i in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300.0)
+    assert not errors
+    assert since.count(STEP) == 1 == since.count("init_one")
+    assert since.count(STEP_AT_ROWS) == len(SERVED_ROWS)
+
+
+@pytest.mark.parametrize("kind", sorted(MODELS))
+def test_a_launch_of_a_built_key_walks_down_to_eight_rows_and_traces_nothing(
+        kind, one_device, monkeypatch):
+    """200 rows start at 256; when the short rows are gone the few long
+    ones step down through every `gather` program below, each a hit in
+    the cache the key's build filled."""
+    monkeypatch.setenv("JGRAFT_SCAN_CHUNK", "16")
+    launch, key, width = family_launch(kind, 200, 4304, chunk=16,
+                                       long_every=32)
+    long_rows = int((launch.n_events > launch.n_events.min() * 3).sum())
+    assert 0 < long_rows <= 8 < launch.events.shape[0] - long_rows
+    assert schedule.build_keys([launch], 16, upto=256) > 0
+    assert built_entry(key)["rows"] == list(SERVED_ROWS)
+    before, spans = snapshot_compiles(), snapshot_spans()
+    seen = set(snapshot_launched())
+    [out] = schedule.run_chunked([launch], build_rows=256)
+    assert out.ok.all() and out.evicted_rows > 0
+    after = snapshot_compiles()
+    assert after["shape_misses"] == before["shape_misses"]
+    assert after["programs_built"] == before["programs_built"]
+    for stage in ("build.trace", "build.lower", "build.ahead"):
+        assert snapshot_spans()[stage] == spans[stage]
+    gathers = {s[2:4] for s in snapshot_launched()
+               if s not in seen and s[0] == "gather" and s[1] == key}
+    # (a register campaign's largest window group may start at 192)
+    top = launch_rows(launch.events.shape[0])
+    assert top >= 192 and (16, 8) in gathers
+    assert gathers == set(launch_shapes(top, width).gather)
+
+
+def test_a_mesh_placement_keeps_a_trace_a_bucket_and_the_same_verdicts(
+        monkeypatch):
+    """Which path a mesh placement takes is pinned here: its own jit a
+    row bucket (`shard_map`'s body cannot be refined from a symbolic
+    row axis), observed from the placement, and what it decides is what
+    one device decides."""
+    monkeypatch.setenv("JGRAFT_SCAN_CHUNK", "8")
+    monkeypatch.delenv("JGRAFT_GROUP_DEVICES", raising=False)
+    monkeypatch.setattr(schedule, "_BUILT", {})
+    monkeypatch.setattr(schedule, "_SHARED", {})
+    install_compile_counters()
+    rng = random.Random(4305)
+    hists = campaign(rng, "register", 40)
+    model = CasRegister()
+
+    def verdicts():
+        return [(r["valid?"], r.get("failing-op-index"))
+                for r in check_histories(hists, model, algorithm="jax")]
+
+    launch, key, _ = family_launch("register", 12, 4305, chunk=8)
+    assert launch.device.mesh.size == 8 and key[3] == "mesh8"
+    programs = schedule._programs(launch, key)
+    assert programs.init is launch.init_fn
+    assert programs.step is launch.step_fn
+    assert programs.gather is schedule._gather_fn(launch.device)
+    before = snapshot_compiles()["programs_from_shared_trace"]
+    on_the_mesh = verdicts()
+    assert snapshot_compiles()["programs_from_shared_trace"] == before
+    meshed = [b for b in snapshot_built() if b["key"][3] == "mesh8"]
+    assert meshed and all(b["traced"] == 0 for b in meshed)
+    monkeypatch.setenv("JGRAFT_GROUP_DEVICES", "0")
+    assert verdicts() == on_the_mesh
+    assert any(v is INVALID for v, _ in on_the_mesh)
+    single = [b for b in snapshot_built() if b["key"][3] == "default"]
+    assert single and all(b["traced"] >= 2 for b in single)
+    assert snapshot_compiles()["programs_from_shared_trace"] > before

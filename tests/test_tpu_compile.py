@@ -177,6 +177,61 @@ def test_packed_domain_step_compiles_at_the_widest_window(one_chip,
     assert "f32[" not in text
 
 
+def loop_bodies(compiled) -> list:
+    """What each `while` body of a compiled program holds, as sorted
+    (instruction, count) tuples, bookkeeping instructions apart."""
+    import collections
+    import re
+
+    text = compiled.as_text()
+    computations = [c.strip() for c in text.split("\n\n")]
+    bodies = []
+    for body in re.findall(r"while\(.*?\), condition=%?[\w.-]+, "
+                           r"body=%?([\w.-]+)", text):
+        [comp] = [c for c in computations
+                  if c.startswith(("%" + body + " ", body + " "))]
+        ops = collections.Counter(
+            m.group(1) for line in comp.split("\n")
+            for m in [re.search(r"= \S+ ([a-z][\w-]*)\(",
+                                re.sub(r"\{[^{}]*\}", "", line))] if m)
+        bodies.append(tuple(sorted(
+            (k, v) for k, v in ops.items()
+            if k not in ("get-tuple-element", "parameter", "tuple",
+                         "bitcast", "constant"))))
+    return sorted(bodies)
+
+
+def test_a_bucket_of_the_shared_trace_is_the_same_scan(one_chip,
+                                                       tpu_branches):
+    # ISSUE 43: a row bucket's step is the key's one lowering (rows
+    # symbolic, `jax.export`) called at the bucket's row count. At the
+    # partition cell's W 12 x 128 rows it must compile to the loops the
+    # step's own jit compiles to: the closure's sweep PR 41 counted
+    # (10 fusions, 15 slices, 1 copy), the same three `while` bodies
+    from jax import export
+
+    rows, w = 128, 12
+    init_fn, step_fn = make_dense_chunk_checker(CasRegister(), "domain", w,
+                                                S, macro_p=MACRO_P)
+    carry = carry_like(init_fn.lower(sds((rows, S), one_chip),
+                                     sds((rows,), one_chip)), one_chip)
+    args = (carry, sds((rows, 1024, row_ints(MACRO_P)), one_chip),
+            np.int32(0), np.int32(1024))
+    (b,) = export.symbolic_shape("rows")
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def any_rows(x):
+        return jax.ShapeDtypeStruct((b,) + x.shape[1:], x.dtype)
+
+    exported = export.export(step_fn, platforms=["tpu"])(
+        jax.tree_util.tree_map(any_rows, carry), any_rows(args[1]),
+        scalar, scalar)
+    own = loop_bodies(compile_for(step_fn, *args))
+    shared = loop_bodies(compile_for(jax.jit(exported.call), *args))
+    assert own == shared and len(own) == 3
+    assert (("copy", 1), ("fusion", 10), ("slice", 15)) in shared
+
+
 def test_dense_chunk_pair_compiles_on_four_chip_mesh(mesh4, tpu_branches):
     # parallel/mesh.chunk_sharding's layout: rows over a 1-D mesh, the
     # kernels wrapped in an explicit batch-axis shard_map
