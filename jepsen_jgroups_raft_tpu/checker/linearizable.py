@@ -164,15 +164,32 @@ def _fp_buckets(encs: Sequence[EncodedHistory], model,
     return buckets
 
 
+#: Rows longer than this many events (by their gating bucket's padded
+#: length) are never scanned host-first: the gate's rule is decided
+#: before it is tried. The certifier is Python by the event, and what a
+#: long row's pending crashed ops cost it grows with the row: a 100k-op
+#: register history (146-147k events, 1-3 ops crashed) took 235-326 s
+#: to certify, six of six at tier `backtrack` (my host runs, PR 44),
+#: where the kernels decide it in seconds; and the gate cannot learn
+#: that in time, because it closes a row class only after
+#: `lin_fastpath_min_obs` (64) rows on each side, and a class of
+#: one-history requests that all certify never shows it a kernel row.
+#: The segment route's own threshold: one length from which a history
+#: is "long" for every router.
+LIN_FASTPATH_MAX_EVENTS = LONG_HISTORY_MIN_EVENTS
+
+
 def lin_fastpath_plan(encs: Sequence[EncodedHistory], model) -> list:
     """Group a linearizable-rung batch (what its caller delivers
     together: `check_encoded`'s batch, one request in graftd's lane)
     into the autotuner's gating buckets and consult the gate once per
     bucket: returns ``[(sig, row indices)]`` for the buckets it routes
-    host-first and counts the rows of the others as gated."""
+    host-first and counts the rows of the others as gated. A length
+    bucket past `LIN_FASTPATH_MAX_EVENTS` is gated unasked."""
     plan = []
     for sig, idxs in _fp_buckets(encs, model, len(encs)).items():
-        if autotune.lin_fastpath_route(sig):
+        if sig[2] <= LIN_FASTPATH_MAX_EVENTS and \
+                autotune.lin_fastpath_route(sig):
             plan.append((sig, idxs))
         else:
             _fp_bump(rows_gated=len(idxs))
@@ -270,8 +287,9 @@ def lin_fastpath_observe_kernel(encs: Sequence[EncodedHistory], model,
     if not autotune.autotune_on():
         return
     for sig, idxs in _fp_buckets(encs, model, batch_rows).items():
-        autotune.lin_fastpath_observe_kernel(
-            sig, rows=len(idxs), wall_s=wall_s_per_row * len(idxs))
+        if sig[2] <= LIN_FASTPATH_MAX_EVENTS:   # past it: nothing to learn
+            autotune.lin_fastpath_observe_kernel(
+                sig, rows=len(idxs), wall_s=wall_s_per_row * len(idxs))
 
 
 def _observe_kernel_cost(rest: Sequence[EncodedHistory], model,
@@ -610,8 +628,10 @@ def _check_encoded(
                      max_cpu_configs)
 
     wide = [e.n_slots > SEGMENT_MAX_SLOTS and e.n_events > 0 for e in encs]
-    if algorithm in ("jax", "auto", "pallas") and any(wide):
-        note_wide(wide_rows=sum(wide))
+    if algorithm in ("jax", "auto", "pallas"):
+        n_long = sum(e.n_events >= LONG_HISTORY_MIN_EVENTS for e in encs)
+        if any(wide) or n_long:
+            note_wide(wide_rows=sum(wide), long_rows=n_long)
 
     if algorithm == "auto":
         # Wide-window fast path: a history whose concurrency window is
@@ -747,23 +767,30 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
         # Exact (differentially pinned vs the monolithic kernels);
         # ineligible histories (short, cut-free, non-dense) fall through.
         #
-        # Routed only where measured to win (TPU v5e, 2026-07-30): a
-        # FEW very long histories — depth is the wall-clock driver and
-        # segmentation trades it for basis-redundant width the VPU
-        # absorbs (config #5: 4.0s vs 4.4s monolithic). With many long
-        # histories the monolithic vmap already fills the chip and the
-        # basis redundancy only hurts (16×10k: 12.5 vs 2.0 hist/s);
-        # on CPU the redundant width swamps the host outright (>10×
-        # slower). JGRAFT_SEGMENT=1/0 forces the choice (tests, ablation).
+        # Routed only where measured to win, and today that is nowhere:
+        # PR 44 read one 100k-op register history on a v5e (146,282
+        # events, W 7, 95 segments; five-run medians, one call) at
+        # 3.60 s segmented, 3.44 s of it the kernel, against 2.01 s as
+        # a chunked LONG launch, since PR 41 packed that kernel's
+        # frontier and this one still runs the float matmul a slot
+        # (2026-07-30, before it: 4.0 s against 4.4 s). With many long
+        # histories the monolithic vmap fills the chip and the basis
+        # redundancy only hurts (16×10k: 12.5 vs 2.0 hist/s); on CPU
+        # the redundant width swamps the host outright (>10× slower).
+        # JGRAFT_SEGMENT=1/0 forces the choice (tests, ablation).
         long_idx = [i for i in fits
                     if encs[i].n_events >= LONG_HISTORY_MIN_EVENTS]
-        if long_idx and not _segment_routing_on(len(long_idx)):
+        if long_idx and not _segment_routing_on():
             long_idx = []
         if long_idx:
-            t0 = time.perf_counter()
-            seg = check_segmented_batch([encs[i] for i in long_idx], model)
-            dt = time.perf_counter() - t0
+            # nested in the launch's tile, as `launch.escalate` is: the
+            # kernel inside it is a `launch.device` of its own
+            with span("launch.segment", n=len(long_idx)) as routed:
+                seg = check_segmented_batch([encs[i] for i in long_idx],
+                                            model)
+            dt = routed.s
             n_done = sum(1 for r in seg if r is not None)
+            note_wide(long_rows_segmented=n_done)
             for j, i in enumerate(long_idx):
                 if seg[j] is not None:
                     r = _jx(VALID if seg[j]["valid"] else INVALID, encs[i],
@@ -991,13 +1018,11 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
     return results
 
 
-def _segment_routing_on(n_long: int) -> bool:
-    forced = os.environ.get("JGRAFT_SEGMENT")
-    if forced is not None:
-        return forced == "1"
-    import jax
-
-    return jax.default_backend() == "tpu" and n_long <= 2
+def _segment_routing_on() -> bool:
+    """Whether a launch's long rows go to the segment route first:
+    never, by what PR 44 read (above), unless JGRAFT_SEGMENT=1 forces
+    it (tests, ablation, the next reading)."""
+    return os.environ.get("JGRAFT_SEGMENT") == "1"
 
 
 #: DFS step budget in race mode: enough for any history the harness

@@ -125,7 +125,11 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # wide windows (ISSUE 40): rows past SEGMENT_MAX_SLOTS that
                # entered the kernel ladder and, of those, the rows a host
                # engine decided
-               "wide_rows": 0, "wide_rows_host": 0}
+               "wide_rows": 0, "wide_rows_host": 0,
+               # long histories (ISSUE 44): rows of at least
+               # LONG_HISTORY_MIN_EVENTS events that entered the kernel
+               # ladder and, of those, the rows the segment route decided
+               "long_rows": 0, "long_rows_segmented": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -160,7 +164,8 @@ def _add_stats(**kw) -> None:
 
 def note_wide(**kw) -> None:
     """Record the wide-window counters (ISSUE 40), like `note_cycle`:
-    `wide_rows`, `wide_rows_host`."""
+    `wide_rows`, `wide_rows_host`; and the long-history ones (ISSUE
+    44): `long_rows`, `long_rows_segmented`."""
     _add_stats(**kw)
 
 
@@ -568,11 +573,13 @@ class ChunkLaunch:
     e_sched: Optional[int] = None
     device: Optional[object] = None
     tag: str = "dense-chunk"
-    #: LONG merged clusters keep exact row counts (the legacy path pads
-    #: floor_b=len(sub) for them: extra rows are pure width work on a
-    #: depth-bound launch) and skip recompaction — their row counts are
-    #: tiny, so eviction's value there is the early exit, not bucket
-    #: shrinking, and per-eviction exact shapes would recompile.
+    #: LONG merged clusters keep their own schedule length and nearly
+    #: their own row count (the next power of two from 1, `long_rows`:
+    #: extra rows are pure width work on a depth-bound launch) and skip
+    #: recompaction — their row counts are tiny, so eviction's value
+    #: there is the early exit, not bucket shrinking. The name is from
+    #: before ISSUE 44, when the rows and the device width were exact
+    #: and every such launch built its own programs as it came.
     exact_rows: bool = False
     #: Per-launch chunk override (checker/autotune.py plans): None
     #: inherits the run-wide chunk (JGRAFT_SCAN_CHUNK), a positive
@@ -608,7 +615,7 @@ class GroupOutcome:
 class _GroupState:
     launch: ChunkLaunch
     events: object                     # device [rows, width, lanes]
-    key: Optional[tuple]               # launch-shape key; None: exact rows
+    key: tuple                         # launch-shape key
     programs: "_Programs"              # what the key's launches call
     width: int                         # device event length
     chunk: int                         # this group's resolved chunk size
@@ -662,9 +669,9 @@ def build_dense_launches(model, groups):
     slower per-chunk program than the explicit wrap. Cross-group
     pipelining comes free: all live groups' chunks queue on every
     device, so the host blocking on one group's flags never idles the
-    ring. LONG merged clusters (exact_rows) keep exact row counts on
-    the default device — depth-bound few-row launches, sharding buys
-    nothing."""
+    ring. LONG merged clusters (exact_rows) stay on the default device
+    with nearly their own row count (`long_rows`) — depth-bound few-row
+    launches, sharding buys nothing."""
     from ..ops.dense_scan import MERGE_MAX_EVENTS, make_dense_chunk_checker
     from ..parallel.mesh import chunk_sharding
 
@@ -701,21 +708,26 @@ def build_dense_launches(model, groups):
             model, plan.kind, plan.n_slots, plan.n_states,
             mesh=getattr(placement, "mesh", None),
             macro_p=batch.get("macro_p"))
+        spec = {"model": type(model).__name__,
+                "model_key": repr(model.cache_key()),
+                "kind": plan.kind, "n_slots": int(plan.n_slots),
+                "n_states": int(plan.n_states),
+                "macro_p": batch.get("macro_p"),
+                # always False (nothing is placed on the host cpu);
+                # the field stays so that a host's `launch-keys.json`
+                # reads as it was written
+                "host": False,
+                "fanout": _n_shards(placement)}
+        if exact:
+            # a LONG key (ISSUE 44): its rows and width come from the
+            # LONG ladders of `launch_shapes`; absent from every other
+            # key, so that a record reads as it was written
+            spec["long"] = True
         launches.append(ChunkLaunch(
             events=batch["events"], n_events=batch["n_events"],
             init_fn=init_fn, step_fn=step_fn, val_of=plan.val_of,
             e_sched=e_sched, device=placement, tag=plan.kernel_tag,
-            exact_rows=exact, chunk=chunk_override,
-            spec={"model": type(model).__name__,
-                  "model_key": repr(model.cache_key()),
-                  "kind": plan.kind, "n_slots": int(plan.n_slots),
-                  "n_states": int(plan.n_states),
-                  "macro_p": batch.get("macro_p"),
-                  # always False (nothing is placed on the host cpu);
-                  # the field stays so that a host's `launch-keys.json`
-                  # reads as it was written
-                  "host": False,
-                  "fanout": _n_shards(placement)}))
+            exact_rows=exact, chunk=chunk_override, spec=spec))
         subs.append(list(rows))
     return launches, subs
 
@@ -728,7 +740,7 @@ def key_template(model, spec: dict, width: int, lanes: int,
     `build_dense_launches`, the one home of the placement policy; None
     where this process would not place the key as recorded (another
     fan-out), or the record is of another stream format."""
-    from ..ops.dense_scan import DensePlan
+    from ..ops.dense_scan import MERGE_MAX_EVENTS, DensePlan
 
     macro_p = spec.get("macro_p")
     if lanes != (5 if macro_p is None else 3 + 4 * int(macro_p)):
@@ -738,7 +750,9 @@ def key_template(model, spec: dict, width: int, lanes: int,
                      np.zeros((rows, n_states), dtype=np.int32))
     batch = {"events": np.broadcast_to(np.zeros((1, 1, 1), np.int32),
                                        (rows, width, lanes)),
-             "n_events": np.zeros((rows,), np.int32), "legacy_events": 1}
+             "n_events": np.zeros((rows,), np.int32),
+             "legacy_events": MERGE_MAX_EVENTS + 1 if spec.get("long")
+             else 1}
     if macro_p is not None:
         batch["macro_p"] = int(macro_p)
     [launch], _ = build_dense_launches(model, [(range(rows), plan, batch)])
@@ -772,6 +786,18 @@ def key_template(model, spec: dict, width: int, lanes: int,
 # `build_keys` for its bucket (a service's: for its key whole, once) and
 # waits; what a launch builds all the same is a shape miss
 # (`note_compile`).
+#
+# A LONG launch (ISSUE 44: a cluster past MERGE_MAX_EVENTS legacy
+# events, `ChunkLaunch.exact_rows`, `spec["long"]`) is in the set by two
+# ladders of its own. Its schedule stays its own length (the scan reads
+# not one pad event: a span's offset and length are traced), but the
+# device event length is that length rounded up the LONG width ladder
+# (`long_width`: eight steps an octave, at most an eighth more zeros to
+# place), so that two histories of nearly one length share a key; its
+# rows are a power of two from 1, with no midpoints and no `gather` (a
+# LONG launch never recompacts): two programs a row bucket, and a
+# service builds the buckets up to the launch's own, never up to its
+# batch cap (256 rows of 200,000 events are gigabytes of zeros).
 
 #: smallest row bucket of a launch
 LAUNCH_ROW_FLOOR = 8
@@ -811,13 +837,36 @@ def launch_width(e_pad: int) -> int:
     return _pow2_from(max(int(e_pad), 1), LAUNCH_WIDTH_FLOOR)
 
 
+#: steps an octave of the LONG width ladder
+LONG_WIDTH_STEPS = 8
+
+
+def long_width(e_pad: int) -> int:
+    """Device event length for a LONG launch's schedule of `e_pad`
+    events: the next of LONG_WIDTH_STEPS evenly spaced lengths between
+    two powers of two (…, 65536, 73728, 81920, …, 131072, …), at most
+    an eighth past `e_pad`. The padding is EV_PAD rows the schedule
+    never reaches."""
+    top = launch_width(e_pad)
+    step = max(top // (2 * LONG_WIDTH_STEPS), 1)
+    return min(top, -(-max(int(e_pad), 1) // step) * step)
+
+
+def long_rows(n: int, shards: int = 1) -> int:
+    """Row bucket of a LONG launch of `n` rows: the next power of two
+    from 1 (a LONG cluster is a few rows; each padded row is width
+    work on a depth-bound launch), a multiple of the shard count."""
+    return -(-_pow2_from(max(int(n), 1), 1) // shards) * shards
+
+
 @dataclass(frozen=True)
 class LaunchShapes:
     """The programs of one key: `rows` ascending, every one at the one
-    `width`."""
+    `width`; `long`: a LONG key, which has no `gather`."""
 
     rows: tuple
     width: int
+    long: bool = False
 
     @property
     def init(self) -> tuple:
@@ -829,27 +878,33 @@ class LaunchShapes:
 
     @property
     def gather(self) -> tuple:
-        """(rows before, rows after): onto the next bucket down."""
-        return tuple(zip(self.rows[1:], self.rows[:-1]))
+        """(rows before, rows after): onto the next bucket down; none
+        for a LONG key, which never recompacts."""
+        return () if self.long else tuple(zip(self.rows[1:],
+                                              self.rows[:-1]))
 
     def __len__(self) -> int:
-        return 3 * len(self.rows) - 1 if self.rows else 0
+        return len(self.init) + len(self.step) + len(self.gather)
 
 
-def launch_shapes(max_rows: int, width: int, shards: int = 1
-                  ) -> LaunchShapes:
+def launch_shapes(max_rows: int, width: int, shards: int = 1,
+                  long: bool = False) -> LaunchShapes:
     """The finite set of programs a launch of up to `max_rows` rows of
     one key can ever ask for: it starts at `launch_rows(max_rows)` and
-    recompaction only ever walks down the buckets."""
-    top = launch_rows(max_rows, shards)
+    recompaction only ever walks down the buckets. `long`: a LONG key's,
+    by `long_rows` and `long_width`."""
+    bucket = long_rows if long else launch_rows
+    top = bucket(max_rows, shards)
     rows, n = [], 1
     while True:
-        b = launch_rows(n, shards)
+        b = bucket(n, shards)
         rows.append(b)
         if b >= top:
             break
         n = b + 1
-    return LaunchShapes(tuple(rows), launch_width(width))
+    return LaunchShapes(tuple(rows),
+                        (long_width if long else launch_width)(width),
+                        long)
 
 
 def _n_shards(placement) -> int:
@@ -925,7 +980,8 @@ def snapshot_build_keys() -> list:
         spec = k.pop("spec") or {}
         del k["key"], k["lanes"]
         out.append({**{f: spec.get(f) for f in (
-            "model", "kind", "n_slots", "n_states")}, **k})
+            "model", "kind", "n_slots", "n_states")},
+            "long": bool(spec.get("long")), **k})
     return out
 
 
@@ -936,7 +992,8 @@ def key_name(entry: dict) -> str:
     if spec is None:
         return f"unnamed/w{entry['width']}"
     return (f"{spec['model']}/{spec['kind']}/W{spec['n_slots']}"
-            f"/S{spec['n_states']}/w{entry['width']}")
+            f"/S{spec['n_states']}/w{entry['width']}"
+            + ("/long" if spec.get("long") else ""))
 
 
 @contextlib.contextmanager
@@ -961,20 +1018,18 @@ def snapshot_launched() -> list:
 class _launching:
     """Names the program the calling thread is about to ask for, for
     `note_compile` (a build there is a shape miss) and for
-    `snapshot_launched`. A launch outside the set (`key` None: exact
-    rows) is neither recorded nor ever a miss."""
+    `snapshot_launched`."""
 
     __slots__ = ("shape",)
 
-    def __init__(self, program: str, key, *dims):
-        self.shape = None if key is None else (program, key) + dims
+    def __init__(self, program: str, key: tuple, *dims):
+        self.shape = (program, key) + dims
 
     def __enter__(self):
-        if self.shape is not None:
-            _OPEN.shape = self.shape
-            with _STATS_LOCK:
-                if len(_LAUNCHED) < _LAUNCHED_CAP:
-                    _LAUNCHED.add(self.shape)
+        _OPEN.shape = self.shape
+        with _STATS_LOCK:
+            if len(_LAUNCHED) < _LAUNCHED_CAP:
+                _LAUNCHED.add(self.shape)
         return self
 
     def __exit__(self, *exc):
@@ -1117,13 +1172,14 @@ _SHARED: dict = {}
 
 def _programs(launch: ChunkLaunch, key: Optional[tuple]) -> _Programs:
     """The three programs of `launch`, one trace each for all the row
-    buckets of its key. Two placements keep a trace a bucket, by what
-    is observed of the launch: a launch outside the set (no key: a LONG
-    cluster's exact rows, one row count ever), and a mesh placement,
-    whose `shard_map` body jaxlib's shape refinement cannot take (it
-    segfaults on the module, CPU mesh, JAX 0.9.0; PERF.md section 6,
-    PR 43)."""
-    if key is None or _n_shards(launch.device) > 1:
+    buckets of its key. Two kinds of launch keep a trace a bucket, by
+    what is observed of the launch: a LONG launch (its key has one row
+    bucket, or a few: nothing to share, and its `gather` is never
+    called), and a mesh placement, whose `shard_map` body jaxlib's
+    shape refinement cannot take (it segfaults on the module, CPU mesh,
+    JAX 0.9.0; PERF.md section 6, PR 43). No key (the tests' way to
+    the launch's own jits) does too."""
+    if key is None or launch.exact_rows or _n_shards(launch.device) > 1:
         return _Programs(launch.init_fn, launch.step_fn,
                          _gather_fn(launch.device))
     with _BUILD_LOCK:
@@ -1190,12 +1246,17 @@ def _key_shapes(launch: ChunkLaunch, chunk: int, rows: int,
                 upto: Optional[int]) -> tuple:
     """(key, its LaunchShapes, the row bucket needed) for a launch of
     `rows` rows: the one bucket or, with `upto`, the key WHOLE, every
-    bucket up to the larger of the two."""
-    width = launch_width(_padded_len(launch, launch.chunk or chunk or 1))
+    bucket up to the larger of the two. A LONG key is whole at the
+    launch's own bucket, whatever `upto` says."""
+    e_pad = _padded_len(launch, launch.chunk or chunk or 1)
     shards = _n_shards(launch.device)
-    need = launch_rows(rows, shards)
-    shapes = launch_shapes(max(rows, upto or 0), width, shards)
-    return launch_key(launch, width), shapes, need
+    if launch.exact_rows:
+        shapes = launch_shapes(rows, e_pad, shards, long=True)
+        need = shapes.rows[-1]
+    else:
+        shapes = launch_shapes(max(rows, upto or 0), e_pad, shards)
+        need = launch_rows(rows, shards)
+    return launch_key(launch, shapes.width), shapes, need
 
 
 def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
@@ -1245,7 +1306,7 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
                                                      met)
                 fresh.append(entry)
             built = entry["rows"]
-            lower = dict(zip(shapes.rows[1:], shapes.rows[:-1]))
+            lower = dict(shapes.gather)
             # the heaviest first: a step program's cost grows with its
             # rows
             for r in reversed(shapes.rows if upto else (need,)):
@@ -1308,19 +1369,16 @@ def _init_group(launch: ChunkLaunch, chunk: int,
     chunk = launch.chunk or chunk  # a stored plan's override
     B, E, lanes = launch.events.shape
     e_pad = _padded_len(launch, chunk)
-    if launch.exact_rows:
-        # outside the set: a LONG cluster's own rows and length, its
-        # programs built when it comes
-        rows, width, key = B, e_pad, None
-    else:
-        key, shapes, rows = _key_shapes(launch, chunk, B, build_rows)
-        width = shapes.width
-        built = _BUILT.get(key)
-        if built is None or not built["rows"].issuperset(
-                shapes.rows if build_rows else (rows,)):
-            # a launch never builds: it waits for its bucket or, for a
-            # service, for its key whole
-            build_keys([launch], chunk, upto=build_rows)
+    # a LONG cluster too: its schedule is its own length (`e_pad`), its
+    # rows and device width come from the LONG ladders of the set
+    key, shapes, rows = _key_shapes(launch, chunk, B, build_rows)
+    width = shapes.width
+    built = _BUILT.get(key)
+    if built is None or not built["rows"].issuperset(
+            shapes.rows if build_rows else (rows,)):
+        # a launch never builds: it waits for its bucket or, for a
+        # service, for its key whole
+        build_keys([launch], chunk, upto=build_rows)
     # Row width follows the stream format: 5 legacy fields or
     # 3 + 4·P macro lanes (history/packing.py macro_compact). Pad rows
     # and the tail past E are zeros: EV_PAD no-ops.
@@ -1427,7 +1485,7 @@ def _collect(g: _GroupState) -> None:
         g.wall_s = time.perf_counter() - g.t_start
         return
 
-    if g.key is None:
+    if g.launch.exact_rows:
         return  # no recompaction (see ChunkLaunch.exact_rows)
     have = g.slot_rows.shape[0]
     bucket = launch_rows(int(alive.size), _n_shards(g.launch.device))

@@ -326,17 +326,49 @@ def check_segmented_batch(encs: Sequence[EncodedHistory], model,
                           min_events: int = LONG_HISTORY_MIN_EVENTS,
                           ) -> list:
     """Batch form: all eligible histories' segments fly in ONE kernel
-    launch (the segment axis is the batch axis — config #4's 16×10k
-    histories become ~160 concurrent segment scans). Returns a result
-    dict per history, or None per history that should take the
-    monolithic path."""
-    plans = [plan_segments(model, e, block_events, min_events)
-             for e in encs]
-    live = [i for i, p in enumerate(plans) if p is not None]
+    launch (the segment axis is the batch axis). What `auto` sends it
+    today (`linearizable._segment_routing_on`): nothing — PR 44 read a
+    100k-op register history (~95 segments of ~1,550 events) at 3.60 s
+    here against 2.01 s as a chunked LONG launch, and shorter and
+    paired ones further behind; `JGRAFT_SEGMENT=1` sends it a launch's
+    histories of `LONG_HISTORY_MIN_EVENTS` events or more. Returns a
+    result dict per history, or None per history that should take the
+    monolithic path. The three parts are named for a profiler session
+    (`segment.plan`, `segment.kernel` inside a `launch.device`,
+    `segment.compose`); the caller's `launch.segment` span times them
+    together."""
+    from ..checker.schedule import annotate, launch_span
+
     results: list = [None] * len(encs)
-    if not live:
+    with annotate("segment.plan", histories=len(encs)):
+        plans = [plan_segments(model, e, block_events, min_events)
+                 for e in encs]
+        live = [i for i, p in enumerate(plans) if p is not None]
+        batch = _segment_batch(encs, plans, live) if live else None
+    if batch is None:
         return results
-    # One compiled shape across histories: bucket everything — then
+    live, W, S, E_seg, NB, K_tot, operands, maps = batch
+    # The segment axis is embarrassingly parallel — shard it over the
+    # device mesh (computation follows data; dead padded segments cost
+    # one seed check). This is what makes a SINGLE long history use the
+    # whole mesh, which the monolithic scan never could.
+    kernel = _segment_kernel(model, W, S, E_seg)
+    with launch_span(rows=operands[0].shape[0]), annotate(
+            "segment.kernel", segments=K_tot, basis=NB, events=E_seg):
+        F = np.asarray(  # lint: allow(host-sync) — host composition next
+            kernel(*_place_segments(operands)))[:K_tot]
+    with annotate("segment.compose", histories=len(live)):
+        for i, r in zip(live, _compose(F, maps, NB)):
+            results[i] = r
+    return results
+
+
+def _segment_batch(encs, plans, live):
+    """One compiled shape across the planned histories `live`: bucket
+    everything, build every history's segment and basis arrays, pad the
+    segment axis to the device count. Returns ``(live, W, S, E_seg, NB,
+    K_tot, (events, val_of, seed_mask, seed_state), maps)``, or None
+    where no history is left."""
     # RE-CHECK the basis gates with the batch-bucketed S/W. plan_segments
     # gated each history against its OWN domain size; batching a
     # small-domain many-crash history with a wide-domain one multiplies
@@ -359,7 +391,7 @@ def check_segmented_batch(encs: Sequence[EncodedHistory], model,
             break
         live = [i for i in live if i not in shed]
         if not live:
-            return results
+            return None
     E_seg = 1
     NB = 1
     for i in live:
@@ -390,14 +422,8 @@ def check_segmented_batch(encs: Sequence[EncodedHistory], model,
     seed_mask = np.concatenate(rows_mask)
     seed_state = np.concatenate(rows_state)
 
-    # The segment axis is embarrassingly parallel — shard it over the
-    # device mesh (computation follows data; dead padded segments cost
-    # one seed check). This is what makes a SINGLE long history use the
-    # whole mesh, which the monolithic scan never could.
-    kernel = _segment_kernel(model, W, S, E_seg)
     from ..parallel.mesh import make_mesh
-    mesh = make_mesh()
-    n_dev = mesh.devices.size
+    n_dev = make_mesh().devices.size
     K_tot = events.shape[0]
     K_pad = ((K_tot + n_dev - 1) // n_dev) * n_dev
     if K_pad != K_tot:
@@ -410,19 +436,30 @@ def check_segmented_batch(encs: Sequence[EncodedHistory], model,
             [seed_mask, np.full((K_pad - K_tot, NB), -1, np.int32)])
         seed_state = np.concatenate(
             [seed_state, np.zeros((K_pad - K_tot, NB), np.int32)])
-    import jax as _jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    ax = mesh.axis_names[0]
-    sh3 = NamedSharding(mesh, P(ax, None, None))
-    sh2 = NamedSharding(mesh, P(ax, None))
-    F = np.asarray(kernel(  # lint: allow(host-sync) — host composition next
-        _jax.device_put(events, sh3), _jax.device_put(val_of, sh2),
-        _jax.device_put(seed_mask, sh2),
-        _jax.device_put(seed_state, sh2)))[:K_tot]
+    return (live, W, S, E_seg, NB, K_tot,
+            (events, val_of, seed_mask, seed_state), maps)
 
-    # Host composition: chain each history's segment relations.
+
+def _place_segments(operands) -> tuple:
+    """The kernel's four operands, their segment axis sharded over the
+    device mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..parallel.mesh import make_mesh
+    mesh = make_mesh()
+    ax = mesh.axis_names[0]
+    events, *rest = operands
+    sh2 = NamedSharding(mesh, P(ax, None))
+    return (jax.device_put(events, NamedSharding(mesh, P(ax, None, None))),
+            *(jax.device_put(x, sh2) for x in rest))
+
+
+def _compose(F: np.ndarray, maps: list, NB: int) -> list:
+    """Host composition: chain each history's segment relations; a
+    result dict a history, in `maps`' order."""
+    out = []
     row = 0
-    for i, (K, bidx, p) in zip(live, maps):
+    for K, bidx, p in maps:
         reach = {(0, 0)}
         for k in range(K):
             acc = None
@@ -441,15 +478,14 @@ def check_segmented_batch(encs: Sequence[EncodedHistory], model,
                 break
             ms, sts = np.nonzero(acc)
             reach = set(zip(ms.tolist(), sts.tolist()))
-        valid = bool(reach)
-        results[i] = {
-            "valid": valid,
+        out.append({
+            "valid": bool(reach),
             "segments": K,
             "basis": NB,
             "n_slots": p.n_slots,
-        }
+        })
         row += K
-    return results
+    return out
 
 
 def _pow2(n: int) -> int:

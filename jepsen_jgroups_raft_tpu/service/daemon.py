@@ -1145,6 +1145,12 @@ class CheckingService:
         # dispatcher thread
         out["wide_rows"] = scan["wide_rows"]
         out["wide_rows_host"] = scan["wide_rows_host"]
+        # ISSUE 44: long histories (process-wide; 0 from a service that
+        # never met one): rows of at least LONG_HISTORY_MIN_EVENTS
+        # events that entered the kernel ladder and those of them the
+        # segment route decided (span `launch.segment`)
+        out["long_rows"] = scan["long_rows"]
+        out["long_rows_segmented"] = scan["long_rows_segmented"]
         out["warm"] = self._warm.is_set()
         out["build_ahead"] = dict(self._build_ahead_info)
         # ISSUE 42: what the start cost (absent until warm; a service

@@ -30,6 +30,7 @@ from jepsen_jgroups_raft_tpu.checker.schedule import (LaunchShapes,
                                                       launch_rows,
                                                       launch_shapes,
                                                       launch_width,
+                                                      long_width,
                                                       snapshot_built,
                                                       snapshot_compiles,
                                                       snapshot_launched,
@@ -136,9 +137,14 @@ def test_every_launched_shape_is_enumerated_and_built(kind, n_rows,
         program, key = shape[0], shape[1]
         assert key in built, shape
         width = key[2]
-        assert width == built[key]["width"] == launch_width(width)
+        # a LONG key (another test of this process may have launched
+        # one) has its own two ladders
+        long = ("long", "True") in key[0]
+        assert width == built[key]["width"] == (
+            long_width if long else launch_width)(width)
         shards = int(key[3][4:]) if key[3].startswith("mesh") else 1
-        shapes = launch_shapes(max(built[key]["rows"]), width, shards)
+        shapes = launch_shapes(max(built[key]["rows"]), width, shards,
+                               long=long)
         # a library caller builds a bucket when a launch reaches it
         assert set(built[key]["rows"]) <= set(shapes.rows), shape
         assert shape[2] in built[key]["rows"], shape
