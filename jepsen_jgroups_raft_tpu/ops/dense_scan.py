@@ -45,11 +45,13 @@ Mechanics per event (same event stream as linear_scan — packing.py):
   FORCE w: survivors must hold bit w (mask with the bit column derived
            arithmetically from the dynamic slot id), then the bit is
            recycled by moving the bit-w=1 half onto the bit-w=0 half —
-           one `dynamic_slice` down-shift (kernel_ir.force_arith, which
-           takes a frontier of either representation;
-           switch-free,
-           ISSUE 4 — the old `lax.switch` evaluated all W branches
-           under vmap).
+           W static slices of a zero-extended copy, selected by the
+           slot (kernel_ir.force_arith, which takes a frontier of
+           either representation; switch-free, ISSUE 4 — the old
+           `lax.switch` evaluated all W branches under vmap; no start
+           index a row, ISSUE 45 — one `dynamic_slice` with the slot's
+           offset is a gather under vmap, a loop over the launch's
+           rows on the chip).
 
 The domain table `val_of[S]` is a per-history *input* (id 0 = initial
 state), so one compiled kernel serves a whole batch of histories with
@@ -250,52 +252,56 @@ class GroupCost:
         return (fixed + (ms - fixed) * steps / self.steps[kind]) / 1e3
 
 
-#: One `TPU v5 lite` chip. The mask rows: the second sweep of ISSUE 33
-#: (`scripts/sweep_group_cost.py run`, then `table`: medians of five
-#: warm runs of ONE group whose every row is as wide as its launch —
-#: the batched closure waits for its widest row —, made monotone in W
-#: and S, gaps filled from the next S; the half-length readings
-#: `fixed_ms` comes from, and how the partitions the table picks
-#: compare with the fastest measured: PERF.md section 6, PR 33).
-#: **The domain rows are ISSUE 41's, read on the packed kernel** (the
-#: same sweep, `--kinds register`, five-run medians; the files:
-#: `chiprun_out/p41b/narrow.json`, W 5-10 at S 4 and 8, and
-#: `wide.json`, W 11-12 at S 8; PERF.md section 6, PR 41, call b): a
-#: window costs next to nothing up to W 10 (128 rows: 175 ms at W 5,
-#: 205 at W 10, where the bool kernel read 107 and 512) and x 1.2-1.5
-#: past it, so a served batch merges into one or two groups. S 4 was
-#: read up to 128 rows (larger batches of the cell hold a history of
-#: five values). W 13 is booked from the edge (ISSUE 41 asked for it;
-#: the sweep sent the group through `auto`, whose host budget takes
-#: such rows first, and failed: `_once` now sends it to the kernels).
+#: One `TPU v5 lite` chip, **both halves read in one session on ISSUE
+#: 45's kernel** (FORCE's down-shift as W static slices: it lowered the
+#: 128-row readings by half and more and the 8-row ones by a fifth, and
+#: `best_partition` minimises this table). `scripts/sweep_group_cost.py
+#: run`, then `table`: medians of five warm runs of ONE group whose
+#: every row is as wide as its launch — the batched closure waits for
+#: its widest row —, made monotone in W and S, gaps filled from the next
+#: S; the files: `chiprun_out/p45c/narrow.json` (domain, W 5-10 at S 4
+#: and 8), `wide.json` (W 11-13 at S 8: **W 13 is a reading now**, no
+#: longer booked from the edge) and `mask.json` (W 5-10); PERF.md
+#: section 6, PR 45, call e. **A shape is booked at its launch's device
+#: phase (span `launch.device`) plus the median host part of its row
+#: count** (`cost_from`): with the loop over the rows gone the device
+#: is under half a 128-row group's wall, and the rest of the wall reads
+#: in two modes 60-90 ms apart from shape to shape, which is the
+#: host's and no window's. A window now costs from the first one up (128
+#: rows, S 8: 66 ms at W 5, 80 at W 8, 98 at W 10, 204 at W 12, 400 at
+#: W 13, where ISSUE 41's table read 175, 177, 205, 373 and booked 533),
+#: so a served batch of the partition cell (W 6-13) is three groups
+#: where it was two. S 4 was read up to 128 rows (larger batches of the
+#: cell hold a history of five values) and reads what S 8 does.
 TPU_GROUP_COST = GroupCost(
     rows=(8, 32, 64, 128, 256, 512, 1024),
     ms={"mask": {1: {
-            5: (42.0, 71.0, 107.2, 199.7, 361.1, 822.3, 1796.9),
-            6: (56.7, 77.4, 111.6, 199.7, 361.1, 822.3, 1796.9),
-            7: (56.7, 77.4, 111.6, 199.7, 382.9, 822.3, 1936.7),
-            8: (62.4, 88.1, 123.6, 202.9, 414.4, 883.5, 2106.5),
-            9: (74.1, 111.0, 154.5, 235.2, 563.4),
-            10: (106.2, 150.1, 210.8, 294.0, 733.2)}},
+            5: (26.2, 28.2, 36.5, 54.2, 125.5, 279.3, 842.6),
+            6: (36.2, 34.9, 40.8, 58.5, 132.3, 291.3, 854.0),
+            7: (39.9, 43.3, 50.2, 65.8, 188.8, 339.9, 952.9),
+            8: (53.4, 56.1, 64.1, 81.8, 215.6, 388.5, 1100.3),
+            9: (64.8, 74.8, 90.2, 105.2, 295.5),
+            10: (97.0, 114.3, 138.2, 149.3, 428.0)}},
         "domain": {
             4: {
-                5: (31.9, 59.2, 95.3, 161.4),
-                6: (32.8, 60.7, 95.8, 161.8),
-                7: (34.0, 62.0, 98.5, 173.0),
-                8: (34.5, 62.5, 100.7, 174.4),
-                9: (34.8, 65.3, 106.6, 188.4),
-                10: (36.3, 70.3, 114.8, 205.3)},
+                5: (24.9, 33.3, 44.4, 65.5),
+                6: (26.4, 35.9, 49.3, 68.3),
+                7: (27.8, 36.4, 51.5, 77.5),
+                8: (28.8, 37.1, 52.3, 79.9),
+                9: (29.2, 40.2, 57.4, 88.5),
+                10: (29.2, 41.4, 62.0, 98.4)},
             8: {
-                5: (33.4, 59.2, 97.3, 175.2, 379.5, 690.6, 1548.3),
-                6: (34.8, 60.7, 97.3, 175.2, 379.5, 698.0, 1548.3),
-                7: (36.0, 62.0, 99.1, 175.2, 379.5, 746.2, 1644.1),
-                8: (36.9, 63.3, 100.7, 177.0, 379.5, 769.2, 1725.0),
-                9: (36.9, 65.7, 106.6, 188.4, 450.0),
-                10: (36.9, 70.3, 114.8, 205.3, 490.5),
-                11: (39.8, 82.7, 136.0, 311.8, 577.1),
-                12: (40.7, 89.0, 153.1, 372.6, 728.2)}}},
+                5: (27.1, 33.7, 44.4, 66.2, 182.5, 308.6, 760.0),
+                6: (28.2, 35.9, 49.3, 68.3, 182.5, 312.8, 764.4),
+                7: (29.1, 36.4, 51.5, 77.5, 213.6, 354.0, 858.4),
+                8: (29.1, 37.1, 52.3, 79.9, 215.8, 361.2, 881.0),
+                9: (29.4, 40.2, 57.4, 88.5, 254.9),
+                10: (29.5, 42.3, 62.0, 98.4, 274.9),
+                11: (30.7, 48.0, 73.1, 149.8, 335.4),
+                12: (32.6, 57.8, 98.4, 203.6, 513.4),
+                13: (38.8, 84.2, 153.9, 400.0, 844.7)}}},
     steps={"mask": 2000, "domain": 1614},
-    fixed_ms={"mask": 5.6, "domain": 21.2})
+    fixed_ms={"mask": 10.0, "domain": 14.7})
 
 #: Off the TPU no cost is fitted and a window group is one window: the
 #: host mesh is throughput-bound at these widths (merged launches
@@ -530,10 +536,14 @@ def dense_plans_grouped(model, encs: Sequence[EncodedHistory]):
     return groups, rest
 
 
-def _bit_table(M: int, W: int) -> np.ndarray:
-    """[M, W] static table: bit w of mask m."""
-    return (np.arange(M)[:, None] >> np.arange(W)[None, :]) & 1
-
+def bit_column(M: int, slot):
+    """Bit `slot` of every mask m: [M] int32 for a scalar slot, [M, P]
+    for slots [P]. Arithmetic on the dynamic slot id (ISSUE 45): a
+    `take` from a constant [M, W] table, which this was, is a gather
+    with an index a row under `vmap`."""
+    ids = np.arange(M, dtype=np.int32)  # numpy's: see pack_rows
+    slot = jnp.asarray(slot, jnp.int32)
+    return (ids.reshape((M,) + (1,) * slot.ndim) >> slot) & 1
 
 
 #: The domain frontier's word: bit s of word m = state s reachable in
@@ -867,7 +877,6 @@ def mask_step_parts(model, n_slots: int, macro_p: Optional[int] = None):
     W = int(n_slots)
     M = 1 << W
     slot_ids = jnp.arange(W, dtype=jnp.int32)
-    bit_i32 = jnp.asarray(_bit_table(M, W), jnp.int32)   # [M, W]
 
     def expand_w(w, F, legal_all):
         Fb = F.reshape(M >> (w + 1), 2, 1 << w, 1)
@@ -907,7 +916,7 @@ def mask_step_parts(model, n_slots: int, macro_p: Optional[int] = None):
         # survivor's permanent prefix (base), and its slot leaves the
         # open set.
         onehot = slot_ids == slot
-        col = jnp.take(bit_i32, jnp.clip(slot, 0, W - 1), axis=1)  # [M]
+        col = bit_column(M, jnp.clip(slot, 0, W - 1))         # [M]
         old_d = jnp.sum(jnp.where(onehot, slot_delta, 0))
         base = base + jnp.where(is_force, old_d, 0)
         sums = jnp.where(is_force, sums - col * old_d, sums)
@@ -927,7 +936,7 @@ def mask_step_parts(model, n_slots: int, macro_p: Optional[int] = None):
         dirty = dirty | is_open
         # Maintain sums[m] = Σ_w bit_w(m) · slot_delta[w] as slot
         # w's delta changes from its stale value to this op's.
-        col = jnp.take(bit_i32, jnp.clip(slot, 0, W - 1), axis=1)
+        col = bit_column(M, jnp.clip(slot, 0, W - 1))
         old_d = jnp.sum(jnp.where(onehot, slot_delta, 0))
         new_d = model.mask_delta(f, a, b)
         sums = jnp.where(is_open, sums + col * (new_d - old_d), sums)
@@ -949,8 +958,7 @@ def mask_step_parts(model, n_slots: int, macro_p: Optional[int] = None):
         slot_b = macro_latch_i32(eq, upd, slot_b, pb)
         slot_open = slot_open | upd
         dirty = dirty | (n > 0)
-        cols = jnp.take(bit_i32, jnp.clip(pslot, 0, W - 1),
-                        axis=1)                              # [M, P]
+        cols = bit_column(M, jnp.clip(pslot, 0, W - 1))      # [M, P]
         sums = sums + (cols * jnp.where(valid, new_d - old_d,
                                         0)[None, :]).sum(axis=1)
         slot_delta = macro_latch_i32(eq, upd, slot_delta, new_d)

@@ -54,6 +54,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..history.packing import EV_FORCE, EV_OPEN, MACRO_MAX_OPENS
@@ -228,13 +229,25 @@ def force_arith(F, slot_w):
     the dynamic slot id (the same style as the sort kernel's bitvec
     math) instead of the old `lax.switch` over W static branches, which
     under vmap lowered to select-over-all-branches: every scan step
-    paid W× the one taken branch's [M, S] work. The down-shift by the
-    dynamic bit weight is one `lax.dynamic_slice` of a zero-extended
-    copy — static shapes, no reshape, no scatter; under vmap the
-    batched start lowers to per-row slices (re-ablate on chip if that
-    regresses — both the macro and the JGRAFT_MACRO_EVENTS=0 legacy
-    stream share this dispatch, so the macro A/B stays a pure
-    stream-length comparison).
+    paid W× the one taken branch's [M, S] work.
+
+    The down-shift by the dynamic bit weight 2^slot_w is W = log2(M)
+    STATIC slices of one zero-extended copy of the killed frontier, at
+    offsets 2^0 .. 2^(W-1), combined by selects on `slot_w == w`
+    (ISSUE 45): nothing is indexed by a start that differs a row. Until
+    then it was one `lax.dynamic_slice` of that copy, which `vmap` with
+    a slot a row turns into a gather and the TPU's compiler into a
+    loop over the rows of the launch, a dynamic-slice and a
+    dynamic-update-slice an iteration: 73 % of the device's time in
+    the batched cells. What is shifted in is dead, and so is every
+    configuration that holds the bit afterwards: its source, 2^slot_w
+    up, has the bit clear and was killed; no mask follows the shift.
+    One form for every launch: on one v5e it read ahead of the dynamic
+    slice at 128, at 8 and at ONE row (the LONG launches), windows
+    5-13, and ahead of W rolls and of slice + pad (PERF.md section 6,
+    PR 45). Both the macro and the JGRAFT_MACRO_EVENTS=0 legacy stream
+    share this dispatch, so the macro A/B stays a pure stream-length
+    comparison.
 
     F: the configuration axis M leading, of either representation —
     [M, S] bool (the mask family and the segmented route, S = 1 or the
@@ -243,17 +256,19 @@ def force_arith(F, slot_w):
     where its entry is all False / 0. slot_w pre-clipped to [0, W).
     Returns (F', any_survivor)."""
     M = F.shape[0]
-    tail = (1,) * (F.ndim - 1)
-    ids = jnp.arange(M, dtype=jnp.int32)
-    has = (((ids >> slot_w) & 1) == 1).reshape((M,) + tail)  # bit slot_w
+    # numpy's: a jnp constant is an eager device op a trace
+    ids = np.arange(M, dtype=np.int32).reshape((M,) + (1,) * (F.ndim - 1))
+    has = ((ids >> slot_w) & 1) == 1                       # bit slot_w
     dead = jnp.zeros((), F.dtype)
-    Fk = F & jnp.where(has, ~dead, dead)
+    Fk = jnp.where(has, F, dead)
     alive = jnp.any(Fk != dead)
     ext = jnp.concatenate([Fk, jnp.zeros_like(Fk)], axis=0)  # [2M, ...]
-    shifted = lax.dynamic_slice(
-        ext, (jnp.int32(1) << slot_w,) + (jnp.int32(0),) * (F.ndim - 1),
-        F.shape)
-    return jnp.where(has, dead, shifted), alive
+    shifted = jnp.zeros_like(Fk)
+    for w in range(M.bit_length() - 1):
+        shifted = jnp.where(
+            slot_w == w,
+            lax.slice_in_dim(ext, 1 << w, M + (1 << w), axis=0), shifted)
+    return shifted, alive
 
 
 # ---------------------------------------------------------- stream step

@@ -32,8 +32,10 @@ timed run (`serve_rows`, as graftd's scheduler builds it).
               windows, interleaved rep by rep.
 
 `table` reads the file on any machine: the readings as a `GroupCost`
-(`ops/dense_scan.py`: the table IS the model; the part of a group that
-does not scale with its steps from the two lengths), then, for each
+(`ops/dense_scan.py`: the table IS the model; a shape booked at its
+launch's device phase plus the median host part of its row count,
+`cost_from`; the part of a group that does not scale with its steps
+from the two lengths), then, for each
 batch of `partitions`, the partition `dense_scan.best_partition` picks
 under the file's table and under the program's, beside the fastest
 measured.
@@ -285,8 +287,23 @@ def cost_from(doc: dict):
 
     full = max(s["steps"] for s in doc["shapes"])
     rows = sorted({s["rows"] for s in doc["shapes"]})
-    ms, steps, halves = {}, {}, []   # ms: kind -> S -> W -> rows -> [ms]
+    # What a shape is booked at (ISSUE 45): its launch's device phase
+    # (span `launch.device`) plus the MEDIAN, over the shapes of its
+    # kind, row count and length, of what the wall holds besides. That
+    # rest is the host's (plan, pack, results) and follows the rows,
+    # not the window; since ISSUE 45 it is half a 128-row group's wall
+    # and reads in two modes 60-90 ms apart from shape to shape on one
+    # tree, which the raw walls would book as a window's cost.
+    host = {}
     for s in doc["shapes"]:
+        host.setdefault((s["kind"], s["rows"], s["steps"] > 0.75 * full),
+                        []).append(s["ms"] - s["spans_ms"]["launch.device"])
+    shapes = [dict(s, ms=round(
+        s["spans_ms"]["launch.device"] + statistics.median(
+            host[s["kind"], s["rows"], s["steps"] > 0.75 * full]), 2))
+        for s in doc["shapes"]]
+    ms, steps, halves = {}, {}, []   # ms: kind -> S -> W -> rows -> [ms]
+    for s in shapes:
         if max(s["runs_ms"]) > 3 * min(s["runs_ms"]):
             print(f"  disturbed, left out: {s}")
         elif s["steps"] > 0.75 * full:

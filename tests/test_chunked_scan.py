@@ -73,6 +73,63 @@ def test_chunked_matches_monolithic_dense(model_kind, model, monkeypatch):
         assert _verdicts(hists, model, monkeypatch, chunk=chunk) == ref
 
 
+@pytest.fixture(scope="module")
+def partition_rows_by_window():
+    """64 histories of the partition cell's generator at windows 11-13
+    (`benchmarks/generators/partition.py`: its configuration's caps,
+    values and minority, at a length and a nemesis interval a test can
+    hold, slow majority-side ops so that the windows get there), one
+    seed, every fourth corrupted."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.generators import partition, synth
+    from jepsen_jgroups_raft_tpu.history.synth import build_history
+
+    root = Path(__file__).resolve().parents[1]
+    with open(root / "benchmarks/configs/register-partition-1k.json") as f:
+        config = dict(json.load(f), ops_per_history=180,
+                      nemesis_interval_s=0.7, operation_timeout_s=0.15,
+                      op_latency_ms=[20, 60])
+    want = {11: 22, 12: 21, 13: 21}
+    rng, model, out = random.Random(45), CasRegister(), {}
+    for _ in range(20_000):
+        if all(len(out.get(w, ())) == n for w, n in want.items()):
+            break
+        rows = partition.partition_rows(rng, config)
+        enc = encode_history(build_history(rows), model)
+        got = out.setdefault(enc.n_slots, [])
+        if enc.n_slots not in want or len(got) == want[enc.n_slots]:
+            continue
+        if len(got) % 4 == 3:
+            bad = encode_history(build_history(
+                synth.corrupt(rng, rows, "register")), model)
+            if bad.n_slots == enc.n_slots:
+                enc = bad
+        got.append(enc)
+    assert {w: len(out[w]) for w in want} == want
+    return out
+
+
+@pytest.mark.parametrize("w", [11, 12, 13])
+def test_wide_partition_rows_match_wgl_cpu(partition_rows_by_window, w):
+    """ISSUE 45: FORCE's down-shift is W static slices of the frontier
+    where it was one dynamic slice; at W 11-13 that is 11-13 passes
+    over 2,048-8,192 words a row. The partition cell's own rows at
+    those windows through the chunked wavefront (one group a window,
+    the domain family), every verdict wgl_cpu's."""
+    from jepsen_jgroups_raft_tpu.checker.linearizable import check_encoded
+    from jepsen_jgroups_raft_tpu.checker.wgl_cpu import check_encoded_cpu
+
+    model, encs = CasRegister(), partition_rows_by_window[w]
+    oracle = [check_encoded_cpu(e, model).valid for e in encs]
+    res = check_encoded(encs, model, algorithm="jax", lin_fastpath=False)
+    assert [r["valid?"] for r in res] == oracle
+    assert {r["decided-tier"] for r in res} == {"dense"}
+    assert {r["concurrency-window"] for r in res} == {w}
+    assert True in oracle and False in oracle
+
+
 def test_chunked_matches_monolithic_sort(monkeypatch):
     """Pinned n_configs/n_slots route through the sort-kernel ladder;
     the chunked sort scan must agree with the monolithic rung."""

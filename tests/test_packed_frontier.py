@@ -11,11 +11,18 @@ parent's code, word for word but for the names).
         styles) against the float step on encoded histories: the same
         frontier bit for bit after every scan.
   (ii)  `kernel_ir.force_arith` on [M] words, [M, 1] and [M, S] bools:
-        the same survivors, the same `alive`.
+        the same survivors, the same `alive`; since ISSUE 45 (the
+        down-shift as W static slices selected by the slot) each
+        representation at every window 1-13 and every slot against a
+        numpy kill-and-shift written here, alone and under `vmap` with
+        a slot a row, and the mask family's arithmetic bit column
+        against the table it replaced.
   (iii) whole-history verdicts of `make_dense_history_checker` against
         checker/wgl_cpu.py on seeded register histories with crashed
         ops, windows up to 10.
   (iv)  is tests/test_tpu_compile.py's (one file holds the compiler).
+  (v)   the lowering of the batched step (ISSUE 45): nothing in it is
+        indexed by a start that differs a row.
 """
 
 import os
@@ -317,6 +324,86 @@ def test_force_arith_under_vmap_takes_a_slot_a_row():
     assert (np.asarray(aw) == np.asarray(as_)).all()
 
 
+def _np_force(F, slot):
+    """FORCE on a numpy frontier, configuration axis leading: a
+    configuration without bit `slot` takes over its twin with the bit
+    (the op linearized, its bit recycled), one with it dies."""
+    M = F.shape[0]
+    ids = np.arange(M)
+    without = ids[((ids >> slot) & 1) == 0]
+    out = np.zeros_like(F)
+    out[without] = F[without | (1 << slot)]
+    return out, bool(F[((ids >> slot) & 1) == 1].any())
+
+
+def _random_frontier(rng, rep, shape_m):
+    """A frontier of one of the three representations `force_arith`
+    takes, as numpy: [M] words, [M, 1] bool, [M, S] bool."""
+    if rep == "words":
+        return rng.integers(0, 1 << 8, shape_m).astype(np.uint32) * (
+            rng.random(shape_m) < 0.5)
+    return rng.random(shape_m + ((1,) if rep == "mask" else (4,))) < 0.4
+
+
+REPRESENTATIONS = ["words", "mask", "states"]
+
+
+@pytest.mark.parametrize("rep", REPRESENTATIONS)
+@pytest.mark.parametrize("W", range(1, 14))
+def test_force_arith_is_kill_and_shift_at_every_slot(W, rep):
+    """ISSUE 45: every window the dense families serve, every slot, all
+    three representations, against the numpy oracle above."""
+    rng = np.random.default_rng(100 * W + len(rep))
+    force = jax.jit(force_arith)
+    for slot in range(W):
+        F = _random_frontier(rng, rep, (1 << W,))
+        if slot % 3 == 2:  # nothing holds the forced bit: all die
+            F[((np.arange(1 << W) >> slot) & 1) == 1] = 0
+        got, alive = force(jnp.asarray(F), jnp.int32(slot))
+        want, want_alive = _np_force(F, slot)
+        assert got.dtype == F.dtype and got.shape == F.shape
+        assert (np.asarray(got) == want).all(), (W, slot)
+        assert bool(alive) == want_alive, (W, slot)
+
+
+@pytest.mark.parametrize("rows", [1, 8, 128])
+@pytest.mark.parametrize("rep", REPRESENTATIONS)
+@pytest.mark.parametrize("W", [1, 2, 5, 8, 11, 13])
+def test_force_arith_under_vmap_with_a_slot_a_row(W, rep, rows):
+    """The launch's form: one slot a row (1 row is the LONG launch's, 8
+    and 128 the batched buckets' ends), every slot among them."""
+    rng = np.random.default_rng(1000 * W + 10 * rows + len(rep))
+    F = _random_frontier(rng, rep, (rows, 1 << W))
+    slots = (np.arange(rows) + rng.integers(0, W)) % W
+    got, alive = jax.jit(jax.vmap(force_arith))(
+        jnp.asarray(F), jnp.asarray(slots, jnp.int32))
+    for r in range(rows):
+        want, want_alive = _np_force(F[r], int(slots[r]))
+        assert (np.asarray(got[r]) == want).all(), (W, r, slots[r])
+        assert bool(alive[r]) == want_alive, (W, r, slots[r])
+
+
+@pytest.mark.parametrize("W", range(1, 14))
+def test_bit_column_is_the_bit_table(W):
+    """The mask family's `(ids >> slot) & 1` against the constant
+    [M, W] table a `take` used to read, for every slot, and as [M, P]
+    columns for a macro row's payload slots."""
+    M = 1 << W
+    table = (np.arange(M)[:, None] >> np.arange(W)[None, :]) & 1
+    for slot in range(W):
+        col = dense_scan.bit_column(M, jnp.int32(slot))
+        assert col.shape == (M,) and col.dtype == jnp.int32
+        assert (np.asarray(col) == table[:, slot]).all()
+    pslot = np.arange(4) % W
+    cols = jax.jit(lambda p: dense_scan.bit_column(M, p))(
+        jnp.asarray(pslot, jnp.int32))
+    assert cols.shape == (M, 4)
+    assert (np.asarray(cols) == table[:, pslot]).all()
+    per_row = jax.vmap(lambda s: dense_scan.bit_column(M, s))(
+        jnp.arange(W, dtype=jnp.int32))
+    assert (np.asarray(per_row) == table.T).all()
+
+
 # ------------------------------------------- (iii) whole histories
 
 
@@ -368,3 +455,48 @@ def test_frontier_word_holds_the_state_cap():
     rows = jnp.ones((DENSE_MAX_STATES, DENSE_MAX_STATES), bool)
     assert _unpack_rows(dense_scan.pack_rows(rows),
                         DENSE_MAX_STATES).all()
+
+
+# ------------------------------------- (v) the batched step's lowering
+
+
+@pytest.mark.parametrize("macro", [False, True], ids=["legacy", "macro"])
+@pytest.mark.parametrize("kind", ["domain", "mask"])
+def test_batched_step_indexes_nothing_by_a_start_a_row(kind, macro):
+    """ISSUE 45: the StableHLO of the vmapped `step_one` of the W 8
+    keys the cells launch. A `dynamic_slice` or `take` whose index
+    differs a row lowers to a `stablehlo.gather` with a start a row,
+    and the TPU's compiler runs that as a loop over the rows of the
+    launch (73 % of the device's time until this issue). What may stay
+    is the event fetch: `dynamic_index_in_dim` at `lo + i`, ONE start
+    for every row (under `vmap` a gather whose one slice spans the
+    rows)."""
+    import re
+
+    from jepsen_jgroups_raft_tpu.models import Counter
+    from jepsen_jgroups_raft_tpu.ops.kernel_ir import macro_row_ints
+
+    rows, W, E = 16, 8, 64
+    model, S = (CasRegister(), 8) if kind == "domain" else (Counter(), 1)
+    macro_p = 4 if macro else None
+    lanes = macro_row_ints(macro_p) if macro else 5
+    init_fn, step_fn = dense_scan.make_dense_chunk_checker(
+        model, kind, W, S, macro_p=macro_p)
+    carry = jax.eval_shape(init_fn,
+                           jax.ShapeDtypeStruct((rows, S), jnp.int32),
+                           jax.ShapeDtypeStruct((rows,), jnp.int32))
+    text = step_fn.lower(
+        carry, jax.ShapeDtypeStruct((rows, E, lanes), jnp.int32),
+        np.int32(0), np.int32(E)).as_text()
+    gathers = re.findall(r'"stablehlo\.gather"\(.*', text)
+    assert len(gathers) == 1, gathers
+    # the fetch: one index vector, a slice that holds every row's event
+    assert f"slice_sizes = array<i64: {rows}, 1, {lanes}>" in gathers[0]
+    assert re.search(r"tensor<2xi32>\) -> tensor<%dx1x%dxi32>"
+                     % (rows, lanes), gathers[0]), gathers[0]
+    for op in ("stablehlo.dynamic_slice", "stablehlo.dynamic_gather",
+               "stablehlo.dynamic_update_slice", "stablehlo.scatter",
+               "stablehlo.case"):
+        assert op not in text, op
+    # the span's loop and the closure's: no third
+    assert text.count("stablehlo.while") == 2

@@ -207,7 +207,9 @@ def test_a_bucket_of_the_shared_trace_is_the_same_scan(one_chip,
     # symbolic, `jax.export`) called at the bucket's row count. At the
     # partition cell's W 12 x 128 rows it must compile to the loops the
     # step's own jit compiles to: the closure's sweep PR 41 counted
-    # (10 fusions, 15 slices, 1 copy), the same three `while` bodies
+    # (10 fusions, 15 slices, 1 copy), the same two `while` bodies (the
+    # span and the closure; until ISSUE 45 a third, FORCE's gather run
+    # as a loop over the rows)
     from jax import export
 
     rows, w = 128, 12
@@ -228,8 +230,37 @@ def test_a_bucket_of_the_shared_trace_is_the_same_scan(one_chip,
         scalar, scalar)
     own = loop_bodies(compile_for(step_fn, *args))
     shared = loop_bodies(compile_for(jax.jit(exported.call), *args))
-    assert own == shared and len(own) == 3
+    assert own == shared and len(own) == 2
     assert (("copy", 1), ("fusion", 10), ("slice", 15)) in shared
+
+
+@pytest.mark.parametrize("kind", ["domain", "mask"])
+def test_batched_step_holds_the_span_and_the_closure_and_no_row_loop(
+        one_chip, tpu_branches, kind):
+    # ISSUE 45: the W 8 keys of the three batched cells at 128 rows.
+    # FORCE's down-shift was a `dynamic_slice` with a start a row: a
+    # gather under `vmap`, which this compiler ran as a third `while`
+    # over the rows of the launch (a dynamic-slice and a
+    # dynamic-update-slice an iteration: `while.43` / `while.29` of the
+    # ledger's breakdowns, 73 % of the device's time). Now W static
+    # slices selected by the slot: two loops, no gather, and the one
+    # dynamic slice left in either body is the event fetch (the mask
+    # sweep's concatenate is a dynamic-update-slice at constants)
+    import re
+
+    model, n_states = ((CasRegister(), S) if kind == "domain"
+                       else (Counter(), 1))
+    fns = make_dense_chunk_checker(model, kind, W, n_states,
+                                   macro_p=MACRO_P)
+    compiled = compile_chunk_pair(
+        fns, (sds((128, n_states), one_chip), sds((128,), one_chip)),
+        sds((128, 2048, row_ints(MACRO_P)), one_chip))
+    text = compiled.as_text()
+    assert len(loop_bodies(compiled)) == 2
+    assert " gather(" not in text
+    fetches = re.findall(
+        r" dynamic-slice\(.*?dynamic_slice_sizes=\{([\d,]+)\}", text)
+    assert set(fetches) <= {f"128,1,{row_ints(MACRO_P)}"}, fetches
 
 
 def test_dense_chunk_pair_compiles_on_four_chip_mesh(mesh4, tpu_branches):
