@@ -25,7 +25,7 @@ import time
 from collections import OrderedDict
 from typing import Callable, List, Optional
 
-from ..checker.schedule import span
+from ..checker.schedule import note_span, span
 from ..platform import env_int
 from .request import CheckRequest
 
@@ -38,6 +38,8 @@ def admit_frame(payload) -> CheckRequest:
     zero-copy tensor views and normalize them into a CheckRequest. The
     fingerprint is re-derived server-side over the received bytes
     (`request.admit_encoded`) — the lying-client argument lives there.
+    A header that says what its encoder cost adds that to span
+    `client.encode`; one that does not adds nothing.
     Raises `frame.FrameError` (a ValueError → HTTP 400) on malformed
     frames, ValueError on unknown workloads/rungs exactly like the
     JSON path's `admit`."""
@@ -49,11 +51,17 @@ def admit_frame(payload) -> CheckRequest:
     if getattr(fr, "labels", None) is None:
         raise FrameError(f"expected a submit frame (kind {KIND_SUBMIT}); "
                          "got a stream segment")
-    return admit_encoded(
+    req = admit_encoded(
         workload=fr.workload, labels=fr.labels, encs=fr.encs,
         algorithm=fr.algorithm, deadline_ms=fr.deadline_ms,
         priority=fr.priority, consistency=fr.consistency,
         claimed_fingerprint=fr.fingerprint)
+    if fr.client_encode is not None:
+        # ISSUE 47: the seconds the frame says its encoder took, booked
+        # once the frame is admitted (`n` its units). Evidence: nothing
+        # downstream reads it back.
+        note_span("client.encode", *fr.client_encode)
+    return req
 
 
 def queue_capacity() -> int:

@@ -160,12 +160,15 @@ def _netloc(url: str) -> str:
 class EncodeStats:
     """How a client's binary submissions were encoded (ISSUE 39):
     `columns` of them from their rows' columns, `objects` through `Op`
-    objects (`request.encode_units` says which inputs go where), and the
-    `seconds` all of them spent between `submit` and a finished frame."""
+    objects (`request.encode_units` says which inputs go where), the
+    check `units` they came to (one a history, or one a key where the
+    workload is split), and the `seconds` all of them spent between
+    `submit` and a finished frame."""
 
     def __init__(self):
         self.columns = 0
         self.objects = 0
+        self.units = 0
         self.seconds = 0.0
 
 
@@ -496,9 +499,13 @@ class ServiceClient:
         computed digest doubles as the rendezvous affinity key (replica
         cache locality for free). Histories that arrive as op-dict rows
         are encoded from those rows' columns, `History` objects through
-        their `Op`s: the same frame either way, `encode_stats` counts
-        which (ISSUE 39). The frame is built ONCE; every retry re-sends
-        identical bytes."""
+        their `Op`s, and a workload that is split per key is split here
+        (one unit a key): the same frame either way, `encode_stats`
+        counts which (ISSUE 39). The frame's header says what the
+        encode cost (`client_encode`: the seconds from here to the
+        fingerprint, and the units), which graftd books to span
+        `client.encode` as evidence (ISSUE 47). The frame is built
+        ONCE; every retry re-sends identical bytes."""
         from ..checker.consistency import normalize_consistency
         from .frame import encode_submit_frame
         from .request import encode_units, fingerprint_encodings
@@ -510,12 +517,14 @@ class ServiceClient:
         frame = encode_submit_frame(
             workload, algorithm, consistency,
             [label for label, _ in units], encs,
-            deadline_ms=deadline_ms, priority=priority, fingerprint=fp)
+            deadline_ms=deadline_ms, priority=priority, fingerprint=fp,
+            client_encode_s=time.perf_counter() - t0)
         with self._counter_lock:
             if from_columns:
                 self.encode_stats.columns += 1
             else:
                 self.encode_stats.objects += 1
+            self.encode_stats.units += len(units)
             self.encode_stats.seconds += time.perf_counter() - t0
         rec = self._call("POST", "/submit", retry=retry,
                          affinity=fp if affinity else None, raw=frame)

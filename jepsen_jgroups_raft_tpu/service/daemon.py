@@ -245,6 +245,11 @@ class CheckingService:
             # `request.encode_units`): from their rows' columns, or
             # through `Op` objects
             "encoded_from_columns": 0, "encoded_through_objects": 0,
+            # what the admitted requests hold (ISSUE 47; both wires, a
+            # recorded run, a replayed or adopted record): the histories
+            # they came from and the check units those were cut into,
+            # one a history or one a key (`CheckRequest.n_histories`)
+            "histories_admitted": 0, "units_admitted": 0,
             # cluster tier (ISSUE 11) — always in the schema, zero when
             # clustering is not configured (the seam stays inert)
             "store_hits": 0, "store_puts": 0,
@@ -392,6 +397,7 @@ class CheckingService:
             req._journaled = True
             with self._lock:
                 self._requests[req.id] = req
+            self._count_admitted(req)
             cached = self.cache.get(req.fingerprint)
             if cached is None and self.cluster is not None:
                 # another replica may have verified this fingerprint
@@ -472,6 +478,7 @@ class CheckingService:
             if self._journal is not None:
                 self._journal.append_submit(req)
             self._count("handoff_requests")
+            self._count_admitted(req)
             taken += 1
             cached = self.cache.get(req.fingerprint)
             if cached is None and self.cluster is not None:
@@ -971,6 +978,7 @@ class CheckingService:
             req.cached = True
             req.finish(DONE, results=cached)
             self._count("submitted", "cache_hits", "completed")
+            self._count_admitted(req)
             self._observe_latency(req)
             self._retire(req)
             self._write_trace(req)
@@ -986,6 +994,7 @@ class CheckingService:
                 req.cached = True
                 req.finish(DONE, results=stored)
                 self._count("submitted", "store_hits", "completed")
+                self._count_admitted(req)
                 self._observe_latency(req)
                 self._retire(req)
                 self._write_trace(req)
@@ -1050,6 +1059,7 @@ class CheckingService:
                     self.cluster.best_retry_after(
                         reject.retry_after_s)) from None
             raise reject
+        self._count_admitted(req)
         if self._journal is not None:
             # Durability point: the WAL record is fsync'd BEFORE the
             # 202 becomes visible to the client — an accepted request
@@ -1176,6 +1186,13 @@ class CheckingService:
             for k in keys:
                 if k in self._stats:
                     self._stats[k] += 1
+
+    def _count_admitted(self, req: CheckRequest) -> None:
+        """`histories_admitted` / `units_admitted`: once a request this
+        service took on, however it came and however it is answered."""
+        with self._lock:
+            self._stats["histories_admitted"] += req.n_histories
+            self._stats["units_admitted"] += req.n_rows
 
     def _retire(self, req: CheckRequest) -> None:
         """Enter a terminal request into the bounded retention window;

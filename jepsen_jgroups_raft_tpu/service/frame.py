@@ -90,6 +90,12 @@ class SubmitFrame:
     priority: int = 0
     #: client-claimed fingerprint (advisory; the server re-derives).
     fingerprint: Optional[str] = None  # lint: allow(fp-irrelevant) advisory claim; server-derived digest is the key
+    #: what the client says its encoder cost (ISSUE 47): `(seconds from
+    #: submit to the fingerprint, units encoded)`, or None where the
+    #: header has no well-formed `client_encode`. Evidence for span
+    #: `client.encode` (`admission.admit_frame`) and nothing else: no
+    #: verdict, key, fingerprint or schedule reads it.
+    client_encode: Optional[tuple] = None  # lint: allow(fp-irrelevant) evidence only; never keyed on
 
 
 @dataclass
@@ -142,9 +148,13 @@ def encode_submit_frame(workload: str, algorithm: str, consistency: str,
                         encs: Sequence[EncodedHistory],
                         deadline_ms: Optional[float] = None,
                         priority: int = 0,
-                        fingerprint: Optional[str] = None) -> bytes:
+                        fingerprint: Optional[str] = None,
+                        client_encode_s: Optional[float] = None) -> bytes:
     """Pack an admitted submission (the client-side `encode_history`
-    output) into one submit frame."""
+    output) into one submit frame. `client_encode_s`, where given, goes
+    into the header as `client_encode` with the unit count: the seconds
+    the client's encoder took, which graftd books to span
+    `client.encode` (an optional field: a frame without it is whole)."""
     if len(labels) != len(encs):
         raise FrameError(f"{len(labels)} labels for {len(encs)} "
                          "encodings")
@@ -160,6 +170,9 @@ def encode_submit_frame(workload: str, algorithm: str, consistency: str,
         header["deadline_ms"] = float(deadline_ms)
     if fingerprint is not None:
         header["fingerprint"] = str(fingerprint)
+    if client_encode_s is not None:
+        header["client_encode"] = {"s": round(float(client_encode_s), 6),
+                                   "units": len(encs)}
     buffers: List[np.ndarray] = []
     for e in encs:
         buffers.extend(_unit_buffers(e))
@@ -193,6 +206,20 @@ def encode_segment_frame(session: str, seq: int,
             buffers.append(np.ascontiguousarray(pr, dtype=_I32))
     header = {"session": str(session), "seq": int(seq), "units": meta}
     return _header_and_buffers(KIND_STREAM_SEG, header, buffers)
+
+
+def _client_encode(header: dict) -> Optional[tuple]:
+    """`(seconds, units)` of a header's optional `client_encode`, or
+    None where it is absent or not two finite non-negative numbers: it
+    is evidence, so a malformed one is dropped, never a 400."""
+    said = header.get("client_encode")
+    try:
+        seconds, units = float(said["s"]), int(said["units"])
+    except (TypeError, KeyError, ValueError, OverflowError):
+        return None
+    if not 0.0 <= seconds < float("inf") or units < 0:
+        return None
+    return seconds, units
 
 
 def _take(mv: memoryview, offset: int, n_i32: int, total: int):
@@ -289,7 +316,8 @@ def decode_frame(buf):
                       for u in units],
                 deadline_ms=float(ddl) if ddl is not None else None,
                 priority=int(header.get("priority", 0)),
-                fingerprint=str(fp) if fp is not None else None)
+                fingerprint=str(fp) if fp is not None else None,
+                client_encode=_client_encode(header))
         except (KeyError, TypeError, ValueError) as e:
             raise FrameError(f"bad submit header: {e}") from None
     if kind == KIND_STREAM_SEG:

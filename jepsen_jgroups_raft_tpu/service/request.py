@@ -25,7 +25,7 @@ import numpy as np
 
 from ..checker.base import merge_valid
 from ..checker.schedule import span
-from ..history.ops import NEMESIS, History, Op, OpRow
+from ..history.ops import INVOKE, NEMESIS, History, Op, OpRow
 from ..history.packing import (EncodedHistory, encode_history,
                                encode_vector_on)
 
@@ -47,8 +47,11 @@ MAX_PRIORITY = 8
 #: The tuple-valued workloads are split per key at admission (the same
 #: independent decomposition checker/recorded.py applies to stored
 #: runs), so one submitted multi-register history becomes one check
-#: unit per key. "register"/"counter" accept plain single-key histories
-#: — the shape tests and the benchmark submit.
+#: unit per key, labelled `h{i}/key={key}` in `str(key)` order
+#: (`encode_units`: from the rows' columns where the histories arrive
+#: as op dicts, through `split_by_key` otherwise; the same units either
+#: way). "register"/"counter" accept plain single-key histories, one
+#: unit `h{i}` each. The benchmark submits both kinds.
 def service_workloads() -> dict:
     from ..models import CasRegister, Counter, GSet, ListAppend, TicketQueue
 
@@ -118,6 +121,44 @@ def rows_from_dicts(dicts: Sequence[dict]) -> List[OpRow]:
     return _client_rows(_wire_columns(dicts))
 
 
+def _split_columns(dicts: Sequence[dict], cols) -> list:
+    """`checker.independent.split_by_key` over a wire history's columns
+    (`_wire_columns`' answer): `[(key, the key's dicts, the key's
+    columns)]` in the order the keys first appear among the client
+    rows, the key taken off every value and `index` left the row's
+    place in the whole history. The same rules, so the same refusals:
+    an invocation with no value at all is the ValueError `split_by_key`
+    raises, with the `Op` it would have printed; a value that is not a
+    pair fails to unpack as it does there; a row whose value or key is
+    None belongs to no key."""
+    procs, types, fs, values, index = cols
+    live = range(len(procs))
+    if NEMESIS in procs:
+        live = [j for j in live if procs[j] != NEMESIS]
+    inner = list(values)
+    at: dict = {}  # key -> positions of its rows
+    for j in live:
+        v = values[j]
+        if v is None:
+            if types[j] == INVOKE:
+                op = Op.from_dict({**dicts[j], "index": index[j]})
+                raise ValueError(
+                    f"independent history contains untupled op: {op}")
+            continue
+        key, inner[j] = v
+        if key is None:
+            continue
+        rows = at.get(key)
+        if rows is None:
+            at[key] = [j]
+        else:
+            rows.append(j)
+    return [(key, tuple(map(dicts.__getitem__, pos)), tuple(
+        tuple(map(col.__getitem__, pos))
+        for col in (procs, types, fs, inner, index)))
+        for key, pos in at.items()]
+
+
 class WireHistory(History):
     """`history_from_dicts(dicts).client_ops()` whose `Op`s are built
     when something first reads them: the unit of a submission that was
@@ -126,15 +167,29 @@ class WireHistory(History):
     _attach_counterexamples`, invalid rows only), and the trace record
     reads `to_dicts`, which answers from the rows without them."""
 
-    def __init__(self, dicts: Sequence[dict]):
+    def __init__(self, dicts: Sequence[dict], cols=None):
+        #: `cols`: the rows' columns where they are not `_wire_columns`
+        #: of `dicts`: one key's rows of a split history, the key taken
+        #: off the values and `index` the row's place in the WHOLE
+        #: history (`_split_columns`; what `split_by_key` gives an `Op`)
         self._dicts = dicts
+        self._cols = cols
         self._ops: Optional[List[Op]] = None
 
     @property
     def ops(self) -> List[Op]:
         if self._ops is None:
-            self._ops = history_from_dicts(self._dicts).client_ops().ops
+            self._ops = [
+                Op(process=p, type=t, f=f, value=v,
+                   time=d.get("time", -1), index=i, error=d.get("error"),
+                   extra={k: x for k, x in d.items() if k not in _OP_KEYS})
+                for d, p, t, f, v, i in zip(self._dicts, *self._columns())
+                if p != NEMESIS]
         return self._ops
+
+    def _columns(self):
+        return self._cols if self._cols is not None \
+            else _wire_columns(self._dicts)
 
     def to_dicts(self) -> List[dict]:
         """`[op.to_dict() for op in self]`, key for key and in
@@ -142,8 +197,7 @@ class WireHistory(History):
         if self._ops is not None:
             return super().to_dicts()
         out = []
-        for d, p, t, f, v, i in zip(self._dicts,
-                                    *_wire_columns(self._dicts)):
+        for d, p, t, f, v, i in zip(self._dicts, *self._columns()):
             if p == NEMESIS:
                 continue
             row = {"process": p, "type": t, "f": f, "value": v,
@@ -286,6 +340,16 @@ class CheckRequest:
         return len(self.encs)
 
     @property
+    def n_histories(self) -> int:
+        """The histories this request's units came from: the distinct
+        heads of their labels, `h{i}` of `h{i}` and of `h{i}/key=…`
+        (a recorded run's `{workload}/u{j}` units are one history).
+        Read from the labels, so a frame's and a replayed record's
+        count as the JSON wire's does."""
+        return len({str(label).split("/", 1)[0]
+                    for label, _ in self.units})
+
+    @property
     def terminal(self) -> bool:
         """True once a terminal state landed (first-wins `finish`)."""
         return self._done.is_set()
@@ -371,13 +435,17 @@ def _workload_model(workload: str):
 def build_units(histories: Sequence, workload: str):
     """Normalize raw submission material into (model, units): the
     workload's model instance plus (label, History) pairs — one
-    frontier-check unit each, independent workloads split per key.
-    ONE home for the unit decomposition, shared by server-side `admit`
-    and the binary lane's CLIENT-side encoder (ISSUE 18): both sides
-    must derive identical unit lists from identical histories, or the
-    server-derived fingerprint would diverge from the JSON path's.
-    (Both reach it through `encode_units`, below, which also owns the
-    one other way to the same units and encodings.)"""
+    frontier-check unit each: `h{i}` for history i checked whole, or,
+    for a workload that is split per key, `h{i}/key={key}` a key in
+    `str(key)` order (so `key=10` before `key=2`). The OBJECT way to
+    the units: an `Op` a row, `split_by_key` with an `Op.replace` a
+    row (span `ingest.split`). `encode_units`, below, is what both the
+    server-side `admit` and the binary lane's CLIENT-side encoder call
+    (ISSUE 18: both sides must derive identical unit lists from
+    identical histories, or the server-derived fingerprint would
+    diverge from the JSON path's); it owns the one other way to the
+    same units and encodings, from the rows' columns, and holds that
+    way to this one."""
     model, independent = _workload_model(workload)
     units: List[tuple] = []
     for i, h in enumerate(histories):
@@ -387,7 +455,9 @@ def build_units(histories: Sequence, workload: str):
         if independent:
             from ..checker.independent import split_by_key
 
-            for key, sub in sorted(split_by_key(h).items(),
+            with span("ingest.split"):
+                subs = split_by_key(h)
+            for key, sub in sorted(subs.items(),
                                    key=lambda kv: str(kv[0])):
                 units.append((f"h{i}/key={key}", sub))
         else:
@@ -398,43 +468,69 @@ def build_units(histories: Sequence, workload: str):
 
 
 def encode_units(histories: Sequence, workload: str):
-    """(model, units, encs, from_columns): `build_units` and an
+    """(model, units, encs, from_columns): the unit decomposition and an
     `encode_history` a unit, for both wires (`admit` for a JSON body,
     `ServiceClient._submit_binary` for a frame) so that they cannot
     drift. It adapts on what it can observe in its input, no knob
     (ISSUE 39): histories that all arrive as lists of op DICTS, for a
-    workload that is not split per key and a model with a columnar twin
-    (`encode_pairs_columnar`), are encoded from the rows' columns —
-    `rows_from_dicts`, then the same `pair_ops_indexed`, the same twin
-    and the same `encode_history` body reading `OpRow`s, with no `Op`,
-    `History` walk or `OpPair` an event; the units are `WireHistory`s.
-    Anything else (`History` objects, independent workloads, a model
-    without the twin, ``JGRAFT_ENCODE_VECTOR=0``) takes the object path
-    as it stood, which is the differential oracle
-    (tests/test_submit_columns.py): equal labels, equal `EncodedHistory`
+    model with a columnar twin (`encode_pairs_columnar`), are encoded
+    from the rows' columns — `_wire_columns` once a history, then the
+    same `pair_ops_indexed`, the same twin and the same `encode_history`
+    body reading `OpRow`s, with no `Op`, `History` walk or `OpPair` an
+    event; the units are `WireHistory`s. A workload that is split per
+    key goes the same way (ISSUE 47): the history's rows grouped by key
+    with the key taken off the value (`_split_columns`, span
+    `ingest.split`), each key's rows encoded as above, the units
+    labelled `h{i}/key=…` in `build_units`' order. Anything else
+    (`History` objects, a model without the twin, a model whose
+    admission builds the `History` objects anyway for its cross-key
+    overlay (`list-append`), ``JGRAFT_ENCODE_VECTOR=0``) takes the
+    object path as it stood, `build_units`, which is the differential
+    oracle (tests/test_submit_columns.py, tests/
+    test_split_from_columns.py): equal labels, equal `EncodedHistory`
     arrays, so one fingerprint and one frame, byte for byte; and the
-    same errors, since every rule but the four of `_wire_columns` /
-    `rows_from_dicts` is the same code."""
+    same errors, since every rule but those of `_wire_columns`,
+    `rows_from_dicts` and `_split_columns` is the same code."""
     from ..models.base import Model
 
     model, independent = _workload_model(workload)
     from_columns = (
-        not independent and bool(histories) and encode_vector_on()
+        bool(histories) and encode_vector_on()
         and type(model).encode_pairs_columnar
         is not Model.encode_pairs_columnar
+        and not (independent
+                 and getattr(model, "txn_anomaly_capable", False))
         and all(isinstance(h, (list, tuple))
                 and set(map(type, h)) <= {dict} for h in histories))
     if not from_columns:
         model, units = build_units(histories, workload)
         encs = [encode_history(h, model) for _, h in units]
         return model, units, encs, False
-    # every history's columns first (a malformed row anywhere is
-    # refused before any pairing error, as `build_units` does); a
-    # history's rows then live only while it is encoded, so the
-    # collector never walks a submission's worth of them
-    cols = [_wire_columns(h) for h in histories]
-    encs = [encode_history(_client_rows(c), model) for c in cols]
-    units = [(f"h{i}", WireHistory(h)) for i, h in enumerate(histories)]
+    # every history's columns first, and its split (a malformed row
+    # anywhere is refused before any pairing error, as `build_units`
+    # does); a history's rows then live only while it is encoded, so
+    # the collector never walks a submission's worth of them
+    # (label, the unit's dicts, its columns, one key's?)
+    parts: List[tuple] = []
+    for i, h in enumerate(histories):
+        cols = _wire_columns(h)
+        if not independent:
+            parts.append((f"h{i}", h, cols, False))
+            continue
+        with span("ingest.split"):
+            split = _split_columns(h, cols)
+        parts.extend((f"h{i}/key={key}", dicts, kcols, True)
+                     for key, dicts, kcols in sorted(
+                         split, key=lambda part: str(part[0])))
+    if not parts:
+        raise ValueError("empty submission: no checkable history units")
+    encs = [encode_history(_client_rows(cols), model)
+            for _, _, cols, _ in parts]
+    # a whole history's `WireHistory` reads its columns again when asked
+    # (rarely); a key's keeps them, they are not `_wire_columns` of its
+    # dicts
+    units = [(label, WireHistory(dicts, cols if keyed else None))
+             for label, dicts, cols, keyed in parts]
     return model, units, encs, True
 
 

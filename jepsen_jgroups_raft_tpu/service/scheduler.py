@@ -158,7 +158,18 @@ def bucket_signature(req: CheckRequest) -> tuple:
     invisible here because it happens after concatenation. Mixed-MODEL
     submissions need no scheduler changes: different models simply form
     different buckets, each riding the same formation/linger/execute
-    machinery (the ISSUE-10 acceptance row pins this)."""
+    machinery (the ISSUE-10 acceptance row pins this).
+
+    A request of a workload that is split per key (`multi-register`:
+    one unit a key) is signed like any other, by the bucket of its
+    LONGEST unit, whatever the number of its units. Read before it was
+    kept (ISSUE 47): a key of 100 ops is at most 200 events and ~146 in
+    the mean (a failed cas leaves no event), so the longest of a
+    10k-op history's 100 units lay in bucket 192 in 120 requests of
+    120, and two such requests share a signature as two slices of
+    1k-op histories do; `_choose` then fits two of them under
+    `max_batch_rows` 256 and never a third (`/stats`
+    `batched_requests` over `batches`)."""
     e_max = max((e.n_events for e in req.encs), default=0)
     return (type(req.model).__name__, req.algorithm, req.consistency,
             bucket_rows(max(e_max, 1), 32))

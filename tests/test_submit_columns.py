@@ -7,8 +7,9 @@ an event) and everything else through the object path as it stood
 ORACLE here: equal `EncodedHistory` arrays, so one fingerprint and one
 frame byte for byte; the same `ValueError` text for every malformed
 input; and the inputs the column path does not take (`History`
-objects, independent workloads, ``JGRAFT_ENCODE_VECTOR=0``) counted
-under `objects` by `ServiceClient.encode_stats`. One served case: the
+objects, ``JGRAFT_ENCODE_VECTOR=0``) counted under `objects` by
+`ServiceClient.encode_stats` (workloads split per key: ISSUE 47,
+tests/test_split_from_columns.py). One served case: the
 same histories as a frame and as JSON are one fingerprint, the same
 verdicts, and the JSON one is counted under columns in `/stats`.
 """
@@ -25,7 +26,8 @@ from jepsen_jgroups_raft_tpu.history.packing import encode_history
 from jepsen_jgroups_raft_tpu.service import (CheckingService,
                                              ServiceClient,
                                              serve_in_thread)
-from jepsen_jgroups_raft_tpu.service.frame import encode_submit_frame
+from jepsen_jgroups_raft_tpu.service.frame import (decode_frame,
+                                                   encode_submit_frame)
 from jepsen_jgroups_raft_tpu.service.request import (WireHistory, admit,
                                                      build_units,
                                                      encode_units,
@@ -75,11 +77,12 @@ def object_path(rows, workload: str):
             [encode_history(h, model) for _, h in units])
 
 
-def framed(workload, model, labels, encs):
+def framed(workload, model, labels, encs, client_encode_s=None):
     fp = fingerprint_encodings(model, "auto", encs)
     return fp, encode_submit_frame(workload, "auto", "linearizable",
                                    labels, encs, deadline_ms=None,
-                                   priority=0, fingerprint=fp)
+                                   priority=0, fingerprint=fp,
+                                   client_encode_s=client_encode_s)
 
 
 def assert_same_submission(rows, workload: str) -> None:
@@ -253,8 +256,13 @@ ROUTES = {
     "one-history-object-among-rows": (
         lambda: wire(seeded("counter", "valid", 60))
         + seeded("counter", "valid", 60)[:1], "counter", None, "objects"),
+    # split per key from the rows' columns since ISSUE 47
+    # (tests/test_split_from_columns.py)
     "independent-workload": (_multi_register_rows, "multi-register", None,
-                             "objects"),
+                             "columns"),
+    "independent-history-objects": (
+        lambda: [history_from_dicts(h) for h in _multi_register_rows()],
+        "multi-register", None, "objects"),
     "oracle-arm": (lambda: wire(seeded("register", "valid", 60)),
                    "register", ("JGRAFT_ENCODE_VECTOR", "0"), "objects"),
 }
@@ -271,9 +279,13 @@ def test_encode_stats_says_which_path_a_submission_took(route, monkeypatch):
     assert (st.columns, st.objects) == \
         ((1, 0) if counted == "columns" else (0, 1))
     assert st.seconds > 0.0
-    # whichever way it went, the frame is the object path's
+    # whichever way it went, the frame is the object path's, with what
+    # this client's clock read in its header (ISSUE 47)
     model, labels, encs = object_path(make(), workload)
-    assert cl.frames == [framed(workload, model, labels, encs)[1]]
+    seconds, n_units = decode_frame(cl.frames[0]).client_encode
+    assert 0.0 < seconds <= st.seconds and n_units == len(labels) == st.units
+    assert cl.frames == [framed(workload, model, labels, encs,
+                                client_encode_s=seconds)[1]]
 
 
 def test_admit_goes_through_the_same_function(monkeypatch):
