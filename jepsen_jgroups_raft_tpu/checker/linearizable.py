@@ -806,10 +806,15 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
         # own workloads produce. Pinned n_configs/n_slots are sort-kernel
         # knobs, so an explicit pin keeps the sort path (tests rely on
         # capacity semantics).
-        grouped, rest = (dense_plans_grouped(model,
-                                             [encs[i] for i in fits])
-                         if n_configs is None and n_slots is None
-                         else ([], list(range(len(fits)))))
+        if n_configs is None and n_slots is None:
+            # nested in the launch's host tile (`launch.host`), as
+            # `launch.sync` is in `launch.device`: the domain scan and
+            # the window grouping of the launch's rows
+            with span("launch.group", n=len(fits)):
+                grouped, rest = dense_plans_grouped(
+                    model, [encs[i] for i in fits])
+        else:
+            grouped, rest = [], list(range(len(fits)))
         if grouped and scan_chunk() > 0 and not want_pallas:
             # Chunked wavefront (ISSUE 3, checker/schedule.py): the
             # event scan runs in fixed-size chunks, decided/exhausted
@@ -825,7 +830,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             # restores the monolithic reference launch loop below; the
             # Pallas ablation keeps the monolithic path (its grid
             # kernel owns its own event loop).
-            triples = []
+            planned = []
             for idxs, plan in grouped:
                 sub = [fits[j] for j in idxs]
                 sub_encs = [encs[i] for i in sub]
@@ -835,13 +840,20 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 # process (never for a service: `serve_rows`),
                 # everything else (JGRAFT_AUTOTUNE=0, small groups,
                 # LONG clusters) keeps today's defaults. The
-                # plan's macro payload cap acts here at pack time; its
+                # plan's macro payload cap acts at pack time; its
                 # chunk/fan-out halves act in build_dense_launches.
                 tuned = autotune.tuned_group_plan(
                     model, plan, sub_encs, measure=serve_rows is None)
-                batch = autotune.pack_group(sub_encs, tuned,
-                                            window=plan.n_slots)
-                triples.append((sub, plan, batch, tuned))
+                planned.append((sub, plan, sub_encs, tuned))
+            # the group packs of the launch, nested in `launch.host`
+            # beside `launch.group`: one pass a group over its rows'
+            # concatenated events (history/packing.py `_macro_fill`)
+            with span("launch.pack", n=sum(len(p[0]) for p in planned)):
+                triples = [
+                    (sub, plan,
+                     autotune.pack_group(sub_encs, tuned,
+                                         window=plan.n_slots), tuned)
+                    for sub, plan, sub_encs, tuned in planned]
             launches, subs = build_dense_launches(model, triples)
             with launch_span(rows=sum(len(sub) for sub in subs)):
                 outs = run_chunked(launches, build_rows=serve_rows)

@@ -42,7 +42,8 @@ from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass
-from typing import Iterable, List, Optional, Sequence, Union
+from typing import (Iterable, List, NamedTuple, Optional, Sequence,
+                    Union)
 
 import numpy as np
 
@@ -491,54 +492,148 @@ def bucket_opens(n: int, cap: int = MACRO_MAX_OPENS) -> int:
     return min(_bucket_pow2(max(int(n), 1), 1), cap)
 
 
-def _macro_group_counts(events: np.ndarray):
-    """Shared metadata pass behind the macro row math: (counts, nF,
-    open_idx, force_idx, grp) where counts[i] = opens in macro group i
-    (group i's opens precede force i; group nF is the trailing
-    never-forced run). `max_open_run`, `macro_row_count`, and
-    `macro_compact` all derive from these — one definition so the
-    shard packers' cheap counting pass can never drift from the
-    compaction itself."""
-    events = np.asarray(events, dtype=np.int32)
-    et = events[:, 0] if events.size else np.empty((0,), np.int32)
+class _MacroGroups(NamedTuple):
+    """`_macro_groups`' answer: the rows of a group concatenated, and
+    the macro group of every open and force in them."""
+
+    cat: np.ndarray        # the concatenated events [N, 5] int32
+    open_idx: np.ndarray   # positions of the OPENs in `cat`
+    force_idx: np.ndarray  # positions of the FORCEs
+    ogrp: np.ndarray       # macro group of each open
+    orid: np.ndarray       # row id of each open
+    frid: np.ndarray       # row id of each force
+    counts: np.ndarray     # opens in each group
+    gbase: np.ndarray      # [B + 1] each row's first group; last: all
+
+
+def _macro_groups(rows: Sequence[np.ndarray]) -> _MacroGroups:
+    """The one metadata pass behind the macro row math, over the rows of
+    a GROUP at once: their packed [E_i, 5] event streams are concatenated
+    and every per-row quantity becomes one pass over that array with a
+    row id beside each event.
+
+    A row with nF forces has nF + 1 macro groups (group i's opens
+    precede its force i; the last is the trailing never-forced run) and
+    the groups of a batch are numbered row after row, so an open's
+    group is the forces before it in `cat` plus its row id — and force
+    k's group is k plus ITS row id.
+
+    `max_open_run`, `macro_row_count`, `macro_compact` and the batch
+    packers all derive from these — one definition, so the counting
+    passes can never drift from the compaction itself."""
+    B = len(rows)
+    cat = (np.concatenate(rows) if B else np.empty((0, 5))).astype(
+        np.int32, copy=False).reshape(-1, 5)
+    lens = np.fromiter((len(r) for r in rows), dtype=np.int32, count=B)
+    rid = np.repeat(np.arange(B, dtype=np.int32), lens)
+    et = np.ascontiguousarray(cat[:, 0])
+    is_force = et == EV_FORCE
     open_idx = np.flatnonzero(et == EV_OPEN)
-    force_idx = np.flatnonzero(et == EV_FORCE)
-    grp = np.searchsorted(force_idx, open_idx, side="left")
-    counts = np.bincount(grp, minlength=len(force_idx) + 1)
-    return counts, len(force_idx), open_idx, force_idx, grp
+    force_idx = np.flatnonzero(is_force)
+    # forces at or before each event; an open is no force, so for it
+    # these are the forces strictly before it
+    fcum = np.zeros(len(cat) + 1, dtype=np.int32)
+    np.cumsum(is_force, dtype=np.int32, out=fcum[1:])
+    orid, frid = rid[open_idx], rid[force_idx]
+    ogrp = fcum[1:][open_idx] + orid
+    starts = np.zeros(B + 1, dtype=np.int32)
+    np.cumsum(lens, out=starts[1:])
+    gbase = fcum[starts] + np.arange(B + 1, dtype=np.int32)
+    counts = np.bincount(ogrp, minlength=int(gbase[-1])).astype(
+        np.int32, copy=False)
+    return _MacroGroups(cat, open_idx, force_idx, ogrp, orid, frid, counts,
+                        gbase)
 
 
-def _macro_rows_from_counts(counts: np.ndarray, nF: int, macro_p: int) -> int:
-    """Row-count half of the macro math given a history's (counts, nF)
-    metadata: ⌈opens/P⌉ latch rows per group, minimum one row per
-    FORCE."""
-    n_rows = -(-counts // int(macro_p))
-    n_rows[:nF] = np.maximum(n_rows[:nF], 1)
-    return int(n_rows.sum())
+def _macro_rows(counts: np.ndarray, gbase: np.ndarray, macro_p: int):
+    """Macro rows of every group at payload width P: ⌈opens/P⌉ latch
+    rows, at least one for a FORCE (a force with no fresh open still
+    needs its row), none forced for a row's trailing group. Returns
+    (mbase: each group's first macro row in the batch's running count,
+    with the total last; ne: macro rows a batch row)."""
+    n_rows = -(-counts // np.int32(macro_p))
+    trailing = gbase[1:] - 1
+    tail = n_rows[trailing]
+    np.maximum(n_rows, 1, out=n_rows)
+    n_rows[trailing] = tail
+    mbase = np.zeros(len(n_rows) + 1, dtype=np.int32)
+    np.cumsum(n_rows, out=mbase[1:])
+    at = mbase[gbase]
+    return mbase, at[1:] - at[:-1]
+
+
+def _macro_fill(rows: Sequence[np.ndarray], macro_p: Optional[int],
+                n_events: Optional[int] = None, n_rows: Optional[int] = None,
+                cap: int = MACRO_MAX_OPENS):
+    """Compact the rows of a group WHOLE and write their tensor once:
+    (events [B, E, 3 + 4·P] int32, ne [B] macro rows a row, P).
+
+    `macro_p` None takes `bucket_opens` of the group's longest open run
+    under `cap`. `n_events` pins E (default: the longest macro stream,
+    at least 1); `n_rows` ≥ len(rows) appends EV_PAD rows (0 macro rows
+    each). The layout of a row is `macro_compact`'s — that IS this pass
+    at one row."""
+    g = _macro_groups(rows)
+    P = int(macro_p) if macro_p is not None else \
+        bucket_opens(int(g.counts.max(initial=0)), cap)
+    mbase, ne = _macro_rows(g.counts, g.gbase, P)
+    B = len(rows) if n_rows is None else int(n_rows)
+    if B > len(rows):
+        ne = np.concatenate([ne, np.zeros(B - len(rows), np.int32)])
+    longest = int(ne.max(initial=0))
+    E = n_events or max(longest, 1)
+    if longest > E:
+        raise ValueError("n_events smaller than longest macro stream")
+    L = 3 + 4 * P
+    if B * E * L >= 2 ** 31:
+        raise ValueError("macro tensor past int32 indexing")
+    out = np.zeros(B * E * L, dtype=np.int32)
+    # where a batch row's first macro row lands in `out`, less where it
+    # sits in the batch's running count
+    shift = np.arange(len(rows), dtype=np.int32) * np.int32(E) \
+        - mbase[g.gbase[:-1]]
+    if len(g.open_idx):
+        # rank of each open within its group (opens come group by group)
+        first = np.cumsum(g.counts, dtype=np.int32) - g.counts
+        j = np.arange(len(g.open_idx), dtype=np.int32) - first[g.ogrp]
+        mrow = mbase[g.ogrp] + shift[g.orid] + j // np.int32(P)
+        base = mrow * np.int32(L)
+        out[base] = EV_OPEN  # latch-only (spill / trailing) unless forced
+        out.reshape(-1, L)[:, 2] = np.bincount(mrow, minlength=B * E)
+        base += 3 + 4 * (j % np.int32(P))
+        opens = g.cat.take(g.open_idx, axis=0)
+        for k in range(4):
+            out[base + k] = opens[:, 1 + k]
+    if len(g.force_idx):
+        # a group's FORCE rides its last row, the one before the next
+        # group's first; force k's group is k + its row id
+        nxt = np.arange(1, len(g.force_idx) + 1, dtype=np.int32) + g.frid
+        base = (mbase[nxt] - 1 + shift[g.frid]) * np.int32(L)
+        out[base] = EV_FORCE
+        out[base + 1] = g.cat[:, 1].take(g.force_idx)
+    return out.reshape(B, E, L), ne, P
 
 
 def macro_row_count(events: np.ndarray, macro_p: int) -> int:
     """Macro rows `macro_compact(events, macro_p)` would produce,
-    WITHOUT building them. The per-host packers size the batch-global
-    macro row count E from this counting pass and then compact only
-    their own shard."""
-    counts, nF, _, _, _ = _macro_group_counts(events)
-    return _macro_rows_from_counts(counts, nF, macro_p)
+    WITHOUT building them."""
+    g = _macro_groups([np.reshape(events, (-1, 5))])
+    return int(_macro_rows(g.counts, g.gbase, macro_p)[1][0])
 
 
 def max_open_run(events: np.ndarray) -> int:
     """Longest run of consecutive OPEN events (the quantity P buckets):
     opens are grouped by the number of FORCEs preceding them — the
     trailing group (crashed never-forced opens) counts too."""
-    counts, _, open_idx, _, _ = _macro_group_counts(events)
-    if not len(open_idx):
-        return 0
-    return int(counts.max())
+    return int(_macro_groups([np.reshape(events, (-1, 5))]).counts.max())
 
 
 def macro_compact(events: np.ndarray, macro_p: int) -> np.ndarray:
     """Compact a packed [E, 5] event stream into macro-event rows
-    [E_mac, 3 + 4·P] int32 — the ISSUE-4 tentpole encoding.
+    [E_mac, 3 + 4·P] int32 — the ISSUE-4 tentpole encoding. The
+    compaction is defined a GROUP at a time (`_macro_fill`, which
+    `pack_macro_batch` writes its tensor with); this is its one-row
+    case.
 
     Each run of consecutive OPENs coalesces into the FORCE step that
     ends it: row = [mtype, force_slot, n_opens, (slot, f, a, b)·P].
@@ -553,33 +648,8 @@ def macro_compact(events: np.ndarray, macro_p: int) -> np.ndarray:
     reachability fixpoint over those registers — the soundness argument
     in doc/checker-design.md; macro≡legacy pinned bitwise by
     tests/test_macro_events.py)."""
-    P = int(macro_p)
-    events = np.asarray(events, dtype=np.int32)
-    # Open group = number of FORCEs strictly before the open (group i's
-    # opens precede force i; group nF is the trailing never-forced run).
-    counts, nF, open_idx, force_idx, grp = _macro_group_counts(events)
-    # Rows per group: ⌈opens/P⌉ latch rows, the last one carrying the
-    # group's FORCE; a force with no fresh opens still needs its row.
-    n_rows = -(-counts // P)
-    n_rows[:nF] = np.maximum(n_rows[:nF], 1)
-    row_base = np.concatenate([[0], np.cumsum(n_rows)])
-    total = int(row_base[-1])
-    rows = np.zeros((total, 3 + 4 * P), dtype=np.int32)
-    if nF:
-        frow = row_base[1:nF + 1] - 1
-        rows[frow, 0] = EV_FORCE
-        rows[frow, 1] = events[force_idx, 1]
-    if len(open_idx):
-        # rank of each open within its group
-        starts = np.concatenate([[0], np.cumsum(counts)])
-        j = np.arange(len(open_idx)) - starts[grp]
-        mrow = row_base[grp] + j // P
-        col = 3 + 4 * (j % P)
-        for k in range(4):
-            rows[mrow, col + k] = events[open_idx, 1 + k]
-        rows[:, 2] = np.bincount(mrow, minlength=total)
-    rows[rows[:, 0] == EV_PAD, 0] = EV_OPEN  # latch-only spill/trailing
-    return rows
+    out, ne, _ = _macro_fill([np.reshape(events, (-1, 5))], macro_p)
+    return out[0, : int(ne[0])]
 
 
 def pack_macro_batch(
@@ -594,7 +664,12 @@ def pack_macro_batch(
     of opens holds distinct slots, so it never outgrows the window, and
     P then follows from the kernel's window instead of from the rows a
     batch happens to hold, which keeps it out of the launch-shape set,
-    checker/schedule.py) and pad to a common macro-row count. Returns
+    checker/schedule.py) and pad to a common macro-row count. The
+    compaction is defined a group at a time: the rows' events are
+    concatenated once and compacted in one pass with a row id beside
+    each event, straight into the batch's tensor (`_macro_fill`; no
+    per-row array, no loop over the rows) — `macro_compact` is its
+    one-row case. Returns
     numpy arrays events [B, E_mac, 3+4·P], n_events [B] (MACRO row
     counts — the scheduler's exhaustion/span math runs on these),
     n_slots [B], plus the scalar "macro_p" the kernel builders key on
@@ -607,24 +682,15 @@ def pack_macro_batch(
     encs = list(encoded)
     if not encs:
         raise ValueError("empty batch")
-    P = bucket_opens(window if window is not None
-                     else max(max_open_run(e.events) for e in encs), cap)
-    compacted = [macro_compact(e.events, P) for e in encs]
-    E = n_events or max(max(c.shape[0] for c in compacted), 1)
-    if any(c.shape[0] > E for c in compacted):
-        raise ValueError("n_events smaller than longest macro stream")
-    B = len(encs)
-    events = np.zeros((B, E, 3 + 4 * P), dtype=np.int32)
-    ne = np.zeros((B,), dtype=np.int32)
-    ns = np.zeros((B,), dtype=np.int32)
-    for i, (e, c) in enumerate(zip(encs, compacted)):
-        events[i, : c.shape[0]] = c
-        ne[i] = c.shape[0]
-        ns[i] = e.n_slots
+    events, ne, P = _macro_fill(
+        [e.events for e in encs],
+        None if window is None else bucket_opens(window, cap),
+        n_events=n_events, cap=cap)
     return {
         "events": events,
         "n_events": ne,
-        "n_slots": ns,
+        "n_slots": np.fromiter((e.n_slots for e in encs), dtype=np.int32,
+                               count=len(encs)),
         "macro_p": P,
         "legacy_events": max(e.n_events for e in encs),
     }
@@ -701,42 +767,37 @@ def pack_macro_batch_shard(
     """Per-host twin of `pack_macro_batch` (ISSUE 7 tentpole (b)). The
     batch-GLOBAL shapes — payload width P (longest open run anywhere in
     the batch) and macro row count E — are computed from every
-    history's metadata via the cheap counting pass
-    (`_macro_group_counts` / `macro_row_count`, no row assembly), then
-    ONLY this process's row shard is actually compacted and filled. The
+    history's metadata via the cheap counting pass (`_macro_groups` /
+    `_macro_rows`, no row assembly), then ONLY this process's row shard
+    is actually compacted and filled (`_macro_fill`, the one pass). The
     concatenation of every process's output equals `pack_macro_batch`
     of the whole batch, row for row, so the per-host tensors feed the
     same compiled kernels at the same shapes (soundness:
     doc/checker-design.md §10; identity pinned by
     tests/test_distributed.py). This parallelizes the dominant
-    host-side pack cost — `macro_compact` + array fill — across host
+    host-side pack cost — compaction + array fill — across host
     CPUs."""
     encs = list(encoded)
     if not encs:
         raise ValueError("empty batch")
-    # ONE metadata pass per history: (counts, nF) feeds both the
-    # batch-global payload width P (longest run = counts.max()) and,
-    # at that P, every history's macro row count — the batch-global
-    # half of the pack cost every host pays, so it must not scan the
-    # event arrays twice.
-    metas = [_macro_group_counts(e.events)[:2] for e in encs]
-    P = bucket_opens(max(int(c.max()) if c.size else 0 for c, _ in metas),
-                     cap)
-    row_counts = [_macro_rows_from_counts(c, nF, P) for c, nF in metas]
-    E = n_events or max(max(row_counts), 1)
-    if any(c > E for c in row_counts):
+    # ONE metadata pass over the whole batch feeds both the batch-global
+    # payload width P (the longest open run anywhere) and, at that P,
+    # every history's macro row count — the batch-global half of the
+    # pack cost every host pays, so it must not scan the event arrays
+    # twice.
+    g = _macro_groups([e.events for e in encs])
+    P = bucket_opens(int(g.counts.max()), cap)
+    longest = int(_macro_rows(g.counts, g.gbase, P)[1].max())
+    E = n_events or max(longest, 1)
+    if longest > E:
         raise ValueError("n_events smaller than longest macro stream")
     lo, hi, n_rows = _shard_slice(len(encs), process_index, process_count,
                                   n_rows)
-    B_local = hi - lo
-    events = np.zeros((B_local, E, 3 + 4 * P), dtype=np.int32)
-    ne = np.zeros((B_local,), dtype=np.int32)
-    ns = np.zeros((B_local,), dtype=np.int32)
-    for j, e in enumerate(encs[lo:min(hi, len(encs))]):
-        c = macro_compact(e.events, P)
-        events[j, : c.shape[0]] = c
-        ne[j] = c.shape[0]
-        ns[j] = e.n_slots
+    mine = encs[lo:min(hi, len(encs))]
+    events, ne, _ = _macro_fill([e.events for e in mine], P, n_events=E,
+                                n_rows=hi - lo)
+    ns = np.zeros((hi - lo,), dtype=np.int32)
+    ns[:len(mine)] = [e.n_slots for e in mine]
     return {
         "events": events,
         "n_events": ne,

@@ -132,6 +132,15 @@ class Model:
         (ops/dense_scan.py); the default keeps the general sort kernel."""
         return None
 
+    def dense_domains(self, encs) -> list:
+        """`dense_domain` of every history of a launch (`encs`:
+        EncodedHistory rows), one answer a row, in order. The default is
+        the loop; a model whose domain is a pure function of the rows'
+        events may answer from their concatenation in one pass (the
+        register does), and must give exactly this loop's lists — their
+        order is the kernel's state index."""
+        return [self.dense_domain(e.events) for e in encs]
+
     #: True when the state after linearizing a SET of ops is independent
     #: of their order (e.g. a counter: state = initial + Σ deltas). Such
     #: models need no state dimension at all in the dense kernel — the

@@ -89,6 +89,40 @@ class CasRegister(Model):
         vals.update(int(v) for v in opens[opens[:, 2] == CAS][:, 4])
         return [int(self.initial)] + sorted(vals - {int(self.initial)})
 
+    def dense_domains(self, encs):
+        """`dense_domain` of every row of a launch in ONE pass over the
+        rows' concatenated events: the (row, value) pairs of writes' a
+        and cas' b, deduplicated and sorted by one `np.unique` over
+        row << 32 | (value − INT32_MIN), split at the row boundaries.
+        Each list is [initial] + the ascending signed values without
+        the initial — `dense_domain`'s, value for value."""
+        import numpy as np
+
+        from ..history.packing import EV_OPEN
+
+        B = len(encs)
+        if not B:
+            return []
+        lo = np.iinfo(np.int32).min
+        cat = np.concatenate([e.events for e in encs])
+        rid = np.repeat(np.arange(B, dtype=np.int64),
+                        [len(e.events) for e in encs])
+        et, f = cat[:, 0], np.ascontiguousarray(cat[:, 2])
+        opens = et == EV_OPEN
+        w = np.flatnonzero(opens & (f == WRITE))
+        c = np.flatnonzero(opens & (f == CAS))
+        vals = np.concatenate([cat[:, 3].take(w), cat[:, 4].take(c)])
+        keys = np.unique(
+            (np.concatenate([rid.take(w), rid.take(c)]) << 32)
+            | (vals.astype(np.int64) - lo))
+        initial = int(self.initial)
+        row, val = keys >> 32, (keys & 0xFFFFFFFF) + lo
+        keep = val != initial
+        ends = np.cumsum(np.bincount(row[keep], minlength=B)).tolist()
+        val = val[keep].tolist()
+        return [[initial] + val[a:b]
+                for a, b in zip([0] + ends[:-1], ends)]
+
     def enable_values(self, enc: EncodedOp):
         """Linearizing a write exposes state a; a cas exposes its
         to-value b; a read exposes nothing."""

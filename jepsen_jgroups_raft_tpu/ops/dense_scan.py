@@ -396,8 +396,10 @@ def dense_plans_grouped(model, encs: Sequence[EncodedHistory]):
     `rest` holds the indices that need the sort-kernel ladder (window or
     domain beyond the dense caps); eligibility is per history, so one
     oversized history no longer drags the whole batch off the dense path.
-    Each history's domain is scanned exactly once."""
-    domains = [model.dense_domain(e.events) for e in encs]
+    Every history's domain comes from ONE call, `model.dense_domains`:
+    a model may answer it in one pass over the rows' concatenated events
+    (the register), the default loops over `dense_domain`."""
+    domains = model.dense_domains(encs)
     buckets: dict = {}
     rest: list = []
     for i, (e, d) in enumerate(zip(encs, domains)):

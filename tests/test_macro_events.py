@@ -5,7 +5,10 @@ pad_batch_bucketed round-trips, the JGRAFT_MACRO_EVENTS env-gate
 ablation), a Pallas interpret-mode differential, and the per-run
 scan-stats scope."""
 
+import functools
+import json
 import random
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -24,6 +27,7 @@ from jepsen_jgroups_raft_tpu.history.packing import (EV_FORCE, EV_OPEN,
                                                      encode_history,
                                                      macro_compact,
                                                      macro_events_on,
+                                                     macro_row_count,
                                                      max_open_run,
                                                      pack_batch,
                                                      pack_macro_batch,
@@ -36,6 +40,8 @@ from jepsen_jgroups_raft_tpu.ops.dense_scan import (dense_plans_grouped,
 from jepsen_jgroups_raft_tpu.ops.linear_scan import make_batch_checker
 
 from util import corrupt, random_valid_history
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(autouse=True)
@@ -479,3 +485,335 @@ def test_long_group_policy_keys_on_legacy_event_lengths():
                            model) for _ in range(3)]
     mb = pack_macro_batch(encs)
     assert mb["legacy_events"] == max(e.n_events for e in encs)
+
+
+# --------------------------- the group pass against a row at a time (PR 48)
+#
+# `pack_macro_batch` compacts a group WHOLE (history/packing.py
+# `_macro_fill`). The reference below is the pack as it stood before: a
+# `macro_compact` a row (two `flatnonzero`, a `searchsorted`, two
+# `bincount`...) and a copy of each row into the batch tensor. It lives
+# here, not in the program, so that the two cannot drift together.
+
+
+def _ref_group_counts(events):
+    events = np.asarray(events, dtype=np.int32)
+    et = events[:, 0] if events.size else np.empty((0,), np.int32)
+    open_idx = np.flatnonzero(et == EV_OPEN)
+    force_idx = np.flatnonzero(et == EV_FORCE)
+    grp = np.searchsorted(force_idx, open_idx, side="left")
+    counts = np.bincount(grp, minlength=len(force_idx) + 1)
+    return counts, len(force_idx), open_idx, force_idx, grp
+
+
+def _ref_macro_compact(events, P):
+    events = np.asarray(events, dtype=np.int32)
+    counts, nF, open_idx, force_idx, grp = _ref_group_counts(events)
+    n_rows = -(-counts // P)
+    n_rows[:nF] = np.maximum(n_rows[:nF], 1)
+    row_base = np.concatenate([[0], np.cumsum(n_rows)])
+    total = int(row_base[-1])
+    rows = np.zeros((total, 3 + 4 * P), dtype=np.int32)
+    if nF:
+        frow = row_base[1:nF + 1] - 1
+        rows[frow, 0] = EV_FORCE
+        rows[frow, 1] = events[force_idx, 1]
+    if len(open_idx):
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        j = np.arange(len(open_idx)) - starts[grp]
+        mrow = row_base[grp] + j // P
+        col = 3 + 4 * (j % P)
+        for k in range(4):
+            rows[mrow, col + k] = events[open_idx, 1 + k]
+        rows[:, 2] = np.bincount(mrow, minlength=total)
+    rows[rows[:, 0] == EV_PAD, 0] = EV_OPEN
+    return rows
+
+
+def _ref_max_open_run(events):
+    counts, _, open_idx, _, _ = _ref_group_counts(events)
+    return int(counts.max()) if len(open_idx) else 0
+
+
+def _ref_pack(encs, n_events=None, cap=MACRO_MAX_OPENS, window=None):
+    P = bucket_opens(window if window is not None
+                     else max(_ref_max_open_run(e.events) for e in encs),
+                     cap)
+    compacted = [_ref_macro_compact(e.events, P) for e in encs]
+    E = n_events or max(max(c.shape[0] for c in compacted), 1)
+    B = len(encs)
+    events = np.zeros((B, E, 3 + 4 * P), dtype=np.int32)
+    ne = np.zeros((B,), dtype=np.int32)
+    ns = np.zeros((B,), dtype=np.int32)
+    for i, (e, c) in enumerate(zip(encs, compacted)):
+        events[i, : c.shape[0]] = c
+        ne[i] = c.shape[0]
+        ns[i] = e.n_slots
+    return {"events": events, "n_events": ne, "n_slots": ns, "macro_p": P,
+            "legacy_events": max(e.n_events for e in encs)}
+
+
+def _same_bits(got, want):
+    for k in ("events", "n_events", "n_slots"):
+        assert got[k].dtype == want[k].dtype == np.int32, k
+        assert got[k].shape == want[k].shape, k
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    assert type(got["macro_p"]) is int and got["macro_p"] == want["macro_p"]
+    assert got["legacy_events"] == want["legacy_events"]
+    assert set(got) == set(want)
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_encs(source, ops):
+    """Sixteen histories of a cell's own generator, a quarter of them
+    perturbed and one with a planted read, encoded as admission does."""
+    from benchmarks.generators import partition, synth
+
+    from jepsen_jgroups_raft_tpu.history.synth import build_history
+
+    mix = {"histories_per_request": 16, "perturbed_share": 0.25,
+           "planted_every": 1}
+    name = "register-partition-1k" if source == "partition" else "counter-1k"
+    config = json.loads((ROOT / "benchmarks" / "configs"
+                         / f"{name}.json").read_text())
+    config["ops_per_history"] = ops
+    if source == "partition":
+        # two cuts inside the history, as tests/test_partition_cell.py
+        config.update(nemesis_interval_s=ops / 250.0,
+                      operation_timeout_s=0.15)
+        gen, model = partition, CasRegister()
+    else:
+        config.update(history_kind=source, service_workload=source)
+        gen, model = synth, {"register": CasRegister, "counter": Counter}[
+            source]()
+    [req] = gen.make_requests(random.Random(2**31 + 48), config, mix, 1, 0)
+    return tuple(encode_history(build_history(rows), model) for rows in req)
+
+
+def _ev(*rows):
+    """A hand-built encoding: rows of (etype, slot, f, a, b)."""
+    from jepsen_jgroups_raft_tpu.history.packing import EncodedHistory
+
+    ev = np.asarray(rows, dtype=np.int32).reshape(-1, 5)
+    opens = ev[ev[:, 0] == EV_OPEN]
+    return EncodedHistory(
+        events=ev, op_index=np.arange(len(ev), dtype=np.int32),
+        n_slots=int(opens[:, 1].max()) + 1 if len(opens) else 0,
+        n_ops=len(opens))
+
+
+def _o(slot, a=7):
+    return (EV_OPEN, slot, 1, a, 0)
+
+
+def _f(slot):
+    return (EV_FORCE, slot, 0, 0, 0)
+
+
+#: hand-built rows, by the shape of the stream
+_SHAPES = {
+    # the last events are opens that nothing forces
+    "trailing_opens": _ev(_o(0), _f(0), _o(0), _o(1), _o(2), _o(3)),
+    # forces back to back: the second has no fresh open
+    "force_without_open": _ev(_o(0), _o(1), _f(0), _f(1), _o(0), _f(0)),
+    "forces_first": _ev(_f(0), _f(1), _o(2)),
+    "opens_only": _ev(*[_o(s, a=-s) for s in range(20)]),
+    "one_force": _ev(_f(3)),
+    "no_events": _ev(),
+    "long_run": _ev(*[_o(s, a=-(2 ** 31) + s) for s in range(40)], _f(39),
+                    *[_f(s) for s in range(39)]),
+}
+
+_SOURCES = [(s, ops) for s in ("register", "counter", "partition")
+            for ops in (100, 1000)]
+_ARGS = ([("default", {})]
+         + [(f"cap{c}", {"cap": c}) for c in (1, 2, 3, 8, 16)]
+         + [(f"window{w}", {"window": w}) for w in range(5, 14)]
+         + [("window8_cap3", {"window": 8, "cap": 3}),
+            ("n_events", {"n_events": 1536, "window": 8})])
+
+
+@pytest.mark.parametrize("args", [a for _, a in _ARGS],
+                         ids=[n for n, _ in _ARGS])
+@pytest.mark.parametrize("source,ops", _SOURCES,
+                         ids=[f"{s}{o}" for s, o in _SOURCES])
+def test_group_pack_is_the_row_at_a_time_pack(source, ops, args):
+    """Bit for bit, on the cells' own histories: every payload cap
+    (1, 2, 3 make spill rows), every launch window, `n_events=` given."""
+    encs = list(_cell_encs(source, ops))
+    _same_bits(pack_macro_batch(encs, **args), _ref_pack(encs, **args))
+
+
+@pytest.mark.parametrize("source,ops", _SOURCES,
+                         ids=[f"{s}{o}" for s, o in _SOURCES])
+def test_group_pack_of_one_row_and_the_one_row_helpers(source, ops):
+    """A one-row batch; `macro_compact`, `macro_row_count` and
+    `max_open_run` are the group pass at one row."""
+    for e in _cell_encs(source, ops)[:4]:
+        _same_bits(pack_macro_batch([e], window=8),
+                   _ref_pack([e], window=8))
+        assert max_open_run(e.events) == _ref_max_open_run(e.events)
+        for P in (1, 3, 8):
+            want = _ref_macro_compact(e.events, P)
+            got = macro_compact(e.events, P)
+            assert got.dtype == np.int32 and got.shape == want.shape
+            np.testing.assert_array_equal(got, want)
+            assert macro_row_count(e.events, P) == want.shape[0]
+
+
+@pytest.mark.parametrize("cap", [1, 2, 3, 8, 16])
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_group_pack_of_hand_built_rows(shape, cap):
+    """Each hand-built row alone, first, last and in the middle of a
+    batch of generated rows of very unequal length (4 to 1,000 ops)."""
+    row = _SHAPES[shape]
+    rng = random.Random(48)
+    short = [encode_history(random_valid_history(rng, "register", n_ops=n),
+                            CasRegister()) for n in (4, 9)]
+    long = list(_cell_encs("register", 1000)[:2])
+    got = macro_compact(row.events, cap)
+    np.testing.assert_array_equal(got, _ref_macro_compact(row.events, cap))
+    assert got.shape == (macro_row_count(row.events, cap), 3 + 4 * cap)
+    assert max_open_run(row.events) == _ref_max_open_run(row.events)
+    for encs in ([row], [row] + short + long, long + short + [row],
+                 long[:1] + [row] + short + [row] + long[1:],
+                 [row, row, row]):
+        _same_bits(pack_macro_batch(encs, cap=cap),
+                   _ref_pack(encs, cap=cap))
+        _same_bits(pack_macro_batch(encs, cap=cap, window=13),
+                   _ref_pack(encs, cap=cap, window=13))
+
+
+def test_group_pack_refuses_what_the_row_pack_refused():
+    encs = list(_cell_encs("register", 100))
+    longest = int(pack_macro_batch(encs, window=8)["n_events"].max())
+    with pytest.raises(ValueError, match="n_events smaller"):
+        pack_macro_batch(encs, window=8, n_events=longest - 1)
+    _same_bits(pack_macro_batch(encs, window=8, n_events=longest),
+               _ref_pack(encs, window=8, n_events=longest))
+    with pytest.raises(ValueError, match="empty batch"):
+        pack_macro_batch([])
+
+
+def test_no_loop_over_rows_in_the_group_pack():
+    """The rule this pack was rebuilt to: `pack_macro_batch` and the
+    pass under it hold no statement-level loop, and nothing but the
+    gathers of the rows' `events` / `n_slots` iterates the rows."""
+    import ast
+    import inspect
+
+    from jepsen_jgroups_raft_tpu.history import packing
+
+    for fn in (packing.pack_macro_batch, packing._macro_fill,
+               packing._macro_groups, packing._macro_rows):
+        tree = ast.parse(inspect.getsource(fn))
+        loops = [n for n in ast.walk(tree)
+                 if isinstance(n, (ast.For, ast.While))]
+        # the one `for`: the four payload fields of `_macro_fill`
+        assert [ast.unparse(n.iter) for n in loops] == (
+            ["range(4)"] if fn is packing._macro_fill else []), fn
+        calls = {n.func.id for n in ast.walk(tree)
+                 if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)}
+        assert "macro_compact" not in calls, fn
+
+
+# ---------------------------------------- a launch's domains in one call
+
+
+def _domain_rows():
+    """Register rows that each stress the one-pass answer's order."""
+    W, C, R = 1, 2, 0   # models/register.py WRITE, CAS, READ
+    lo = -(2 ** 31)
+    return {
+        "negative_values": _ev((EV_OPEN, 0, W, -5, 0), _f(0),
+                               (EV_OPEN, 0, W, 3, 0),
+                               (EV_OPEN, 1, C, 3, -9), _f(0), _f(1),
+                               (EV_OPEN, 0, W, 2 ** 31 - 1, 0), _f(0),
+                               (EV_OPEN, 0, W, lo + 1, 0), _f(0)),
+        # a write of the initial (nil) and a cas to it: it stays first
+        "value_equal_to_initial": _ev((EV_OPEN, 0, W, lo, 0), _f(0),
+                                      (EV_OPEN, 0, C, 4, lo), _f(0),
+                                      (EV_OPEN, 0, W, 4, 0), _f(0)),
+        # reads only; a read's value and a cas' FROM value are no states
+        "no_write": _ev((EV_OPEN, 0, R, 6, 0), _f(0),
+                        (EV_OPEN, 0, R, lo, 0), _f(0)),
+        "cas_from_is_no_state": _ev((EV_OPEN, 0, C, 11, 12), _f(0)),
+        "no_events": _ev(),
+        # a FORCE's lanes are not an open's
+        "force_lanes": _ev((EV_FORCE, 0, W, 77, 78), (EV_OPEN, 0, W, 1, 0)),
+    }
+
+
+@pytest.mark.parametrize("initial", [None, 0, 3, -5])
+@pytest.mark.parametrize("shape", sorted(_domain_rows()))
+def test_register_domains_in_one_pass(shape, initial):
+    """`dense_domains(encs)` is `dense_domain` a row, value for value
+    and in its order (the kernel's state index), wherever the row sits."""
+    model = CasRegister(initial)
+    row = _domain_rows()[shape]
+    others = list(_domain_rows().values())
+    cell = list(_cell_encs("register", 100)[:3])
+    for encs in ([row], others, [row] + cell, cell + [row],
+                 cell[:1] + [row] + cell[1:] + others):
+        want = [model.dense_domain(e.events) for e in encs]
+        got = model.dense_domains(encs)
+        assert got == want
+        assert all(type(v) is int for d in got for v in d)
+        assert all(d[0] == int(model.initial) and d[1:] == sorted(d[1:])
+                   and int(model.initial) not in d[1:] for d in got)
+
+
+@pytest.mark.parametrize("source,ops", [("register", 100),
+                                        ("register", 1000),
+                                        ("partition", 100),
+                                        ("partition", 1000)])
+def test_register_domains_of_a_cells_rows(source, ops):
+    model = CasRegister()
+    encs = list(_cell_encs(source, ops))
+    assert model.dense_domains(encs) == [model.dense_domain(e.events)
+                                         for e in encs]
+    assert model.dense_domains([]) == []
+
+
+@pytest.mark.parametrize("kind", ["set", "counter", "queue"])
+def test_default_domains_are_the_loop(kind):
+    """The set model enumerates (or gives up, None) a row at a time and
+    the counter has no domain: the base default is that loop."""
+    from jepsen_jgroups_raft_tpu.models import GSet, TicketQueue
+    from jepsen_jgroups_raft_tpu.models.base import Model
+
+    model = {"set": GSet, "counter": Counter, "queue": TicketQueue}[kind]()
+    assert type(model).dense_domains is Model.dense_domains
+    rng = random.Random(48)
+    encs = [encode_history(random_valid_history(rng, kind, n_ops=n,
+                                                value_range=r), model)
+            for n, r in ((2, 3), (5, 3), (12, 3), (40, 12))]
+    want = [model.dense_domain(e.events) for e in encs]
+    assert model.dense_domains(encs) == want
+    if kind == "set":
+        assert any(d is None for d in want) and \
+            any(d is not None for d in want)
+    if kind == "counter":
+        assert want == [None] * 4
+
+
+def test_grouping_asks_for_the_domains_once(monkeypatch):
+    """`dense_plans_grouped` makes ONE call for a launch's rows, and its
+    plans are those of the row-at-a-time answers."""
+    model = CasRegister()
+    encs = list(_cell_encs("register", 100))
+    want = dense_plans_grouped(model, encs)
+    calls = []
+
+    def counted(self, rows):
+        calls.append(len(rows))
+        return [self.dense_domain(e.events) for e in rows]
+
+    monkeypatch.setattr(CasRegister, "dense_domains", counted)
+    got = dense_plans_grouped(model, encs)
+    assert calls == [len(encs)]
+    assert got[1] == want[1] and len(got[0]) == len(want[0])
+    for (gi, gp), (wi, wp) in zip(got[0], want[0]):
+        assert gi == wi and (gp.kind, gp.n_slots, gp.n_states) == \
+            (wp.kind, wp.n_slots, wp.n_states)
+        np.testing.assert_array_equal(gp.val_of, wp.val_of)

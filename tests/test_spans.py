@@ -346,6 +346,97 @@ def test_nine_spans_tile_the_dispatcher_loop(tmp_path, monkeypatch):
     assert st["programs_built"] == snapshot_compiles()["programs_built"]
 
 
+def test_group_and_pack_are_nested_in_the_host_tile(tmp_path, monkeypatch):
+    """`launch.group` (the domain scan and the window grouping) and
+    `launch.pack` (the group packs) lie inside `launch.host` as
+    `launch.sync` lies inside `launch.device`: they count the launch's
+    rows (all of them; those packed for the dense families), they are no
+    tile, and the host tile still holds them whole."""
+    from benchmarks.layer_metrics import _spans as bench_spans
+
+    from jepsen_jgroups_raft_tpu.service import scheduler
+
+    ended = []      # (thread, name, clock at its end, seconds, n)
+    note = schedule.note_span
+
+    def noting(name, seconds, n=1):
+        ended.append((threading.get_ident(), name, time.perf_counter(),
+                      seconds, n))
+        note(name, seconds, n)
+
+    monkeypatch.setattr(schedule, "note_span", noting)
+    monkeypatch.setattr(scheduler, "note_span", noting)
+    svc = CheckingService(store_root=str(tmp_path), n_workers=1)
+    try:
+        reqs = serve_waves(svc, waves=2, per_wave=3, salt0=700)
+        worker = svc._worker.ident
+    finally:
+        svc.shutdown()
+    assert "launch.group" not in TILING and "launch.pack" not in TILING
+    assert tuple(bench_spans.TILING) == TILING
+    for r in reqs:
+        launch = r.stats["scan"]["spans"]
+        rows = r.stats["batch_rows"]
+        assert launch["launch.group"]["n"] == rows
+        # a request's invalid row writes 23 values, past the domain
+        # family: the ladder's, so three rows in four are packed here
+        assert launch["launch.pack"]["n"] == rows - rows // 4
+        assert launch["launch.group"]["s"] + launch["launch.pack"]["s"] \
+            <= launch["launch.host"]["s"] + 1e-6
+    mine = [e for e in ended if e[0] == worker]
+    hosts = [e for e in mine if e[1] == "launch.host"]
+    assert hosts
+    floor = 0.0     # the end of the tile before this launch's
+    for _, _, end, _, _ in hosts:
+        inner = [e for e in mine if floor < e[2] <= end
+                 and e[1] in ("launch.group", "launch.pack",
+                              "launch.device")]
+        names = [e[1] for e in inner]
+        # the grouping, then the packs, then the wavefront, all before
+        # `launch.host` is noted; the two new ones once a launch
+        assert names[:2] == ["launch.group", "launch.pack"], names
+        assert names.count("launch.group") == names.count("launch.pack") == 1
+        assert inner[0][4] > inner[1][4] > 0
+        # the host tile is the check's wall less the device's seconds:
+        # it holds the two nested spans whole
+        device_s = sum(e[3] for e in inner if e[1] == "launch.device")
+        wall_from = inner[0][2] - inner[0][3]
+        assert end - wall_from >= device_s + inner[0][3] + inner[1][3] - 1e-4
+        floor = end
+
+
+def test_pack_ms_per_row_reads_the_two_nested_spans():
+    """The benchmark's reader of them (`benchmarks/layer_metrics/
+    pack_ms_per_row.py`): both spans' milliseconds a PACKED row, nothing
+    from a program that serves spans but not these two (the parent), and
+    the four cells whose launches pack batches list it."""
+    import json
+
+    from benchmarks.layer_metrics import pack_ms_per_row as reader
+
+    def ctx(before, after):
+        return {"window_s": 51.0, "before": {"stats": {"spans": before}},
+                "after": {"stats": {"spans": after}}}
+
+    host = {"launch.host": {"n": 10, "s": 1.0}}
+    assert reader.read(ctx({}, dict(
+        host, **{"launch.pack": {"n": 150, "s": 0.02},
+                 "launch.group": {"n": 200, "s": 0.01}}))) == \
+        pytest.approx(0.2)
+    assert reader.read(ctx({}, host)) is None
+    assert reader.read({"window_s": 51.0, "before": {"stats": {}},
+                        "after": {"stats": {}}}) is None
+    manifest = json.loads((PKG.parent / "BENCHMARK.json").read_text())
+    [entry] = [m for m in manifest["per_layer"]
+               if m["name"] == "pack_ms_per_row"]
+    assert (entry["layer"], entry["source"], entry["better"],
+            entry["moves"]) == ("kernels", "program_span", "lower",
+                                "verdict_p50_ms")
+    assert entry["workloads"] == [
+        "register-map-10k.campaign-keyed", "counter-1k.campaign",
+        "register-1k.campaign", "register-partition-1k.campaign-wide"]
+
+
 def test_journal_append_feeds_its_latency_window_from_the_span(tmp_path):
     from jepsen_jgroups_raft_tpu.service.request import admit
 
