@@ -82,8 +82,11 @@ HIERARCHY: Tuple[str, ...] = (
     # request finish is leaf-before-journal (first-wins flag flip, then
     # durability outside the flag lock)
     "CheckRequest._finish_lock",
-    # durability tier: group-commit membership, then the handle lock
+    # durability tier: group-commit membership, then the handle lock;
+    # a compaction (or replay seeding its index) holds the mutex across
+    # its steps and takes the handle lock inside it, twice, briefly
     "AdmissionJournal._gcond",
+    "AdmissionJournal._compact_mutex",
     "AdmissionJournal._lock",
     # cross-process publish leaves: the detail-store singleton factory
     # holds the registry lock while constructing/loading the store

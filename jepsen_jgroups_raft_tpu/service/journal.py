@@ -27,11 +27,39 @@ Design points, each load-bearing:
   of the exactly-once-verdict argument (doc/checker-design.md §11).
 * **Compaction is bounded by ``JGRAFT_SERVICE_RETAIN``.** The WAL of an
   always-on daemon would otherwise grow per request forever. Once the
-  finished-pair count exceeds the retention bound, the journal rewrites
-  itself keeping every UNFINISHED entry (those are the durability
-  payload) plus the newest ``retain`` finished pairs (those are the
-  warm-cache payload), via write-temp + ``os.replace`` so a crash
-  mid-compaction leaves either the old or the new file, never neither.
+  finished-pair count exceeds twice the retention bound, the journal
+  rewrites itself keeping every UNFINISHED entry (those are the
+  durability payload) plus the newest ``retain`` finished pairs (those
+  are the warm-cache payload), via write-temp + ``os.replace`` so a
+  crash mid-compaction leaves either the old or the new file, never
+  neither.
+* **A compaction copies byte ranges; it parses nothing** (ISSUE 49).
+  The journal keeps an INDEX of what it wrote: one ``(offset, length,
+  kind, id-or-sid)`` a record, in file order, appended where the bytes
+  are written and seeded by ``replay()``, which scans and CRC-checks
+  the file anyway. The lines are written canonical and would be
+  rewritten canonical, so the kept ones are copied verbatim, in three
+  steps: (a) under the lock, a snapshot of the index and of the file's
+  length; (b) with NO lock, the kept ranges below that length from a
+  read handle of its own into ``wal.jsonl.tmp``, fsync'd every
+  ``COPY_SYNC_BYTES`` so that an appender's fsync never queues behind
+  the copy's dirty pages, and after them the bytes appended during the
+  copy, until a chunk or less is left; (c) under the lock, that rest
+  copied verbatim, fsync, ``os.replace``, the index rebuilt with the
+  new offsets. Appenders wait for (a) and (c) alone: milliseconds where
+  the parsing rewrite held them for as long as it ran (15.6 s at
+  1.14 GB). Past the threshold `append_terminal` and `append_stream`
+  wake a thread of the journal's own; `compact()` runs the same steps
+  on its caller's. A crash during (b) or (c) leaves
+  the old file whole beside a ``.tmp`` nobody reads; after the replace,
+  the new one whole. The CRC of a line is checked where it is
+  load-bearing, at ``replay()``: a line that rots on disk after it was
+  indexed is copied as it is and skipped loudly at the next start, as
+  it would have been had no compaction met it. A journal whose index
+  does not cover its file from byte 0 (opened on an existing WAL and
+  not replayed, an append that failed half-way) compacts once by the
+  parsing scan, under the lock, and has an index afterwards
+  (``journal_compact_scans``).
 * **Journal IO failures degrade durability, not availability.** An
   append that raises OSError is logged and counted
   (``journal_errors``); the request is still admitted. A checking
@@ -93,6 +121,18 @@ APPEND_WINDOW = 4096
 #: Default group-commit linger (ms). See `journal_group_ms`.
 DEFAULT_GROUP_MS = 2
 
+#: Bytes a compaction's copy reads and writes at a time.
+COPY_CHUNK = 4 << 20
+
+#: How often at most a compaction's step (b) goes back for what was
+#: appended while it copied.
+CATCH_UP_ROUNDS = 8
+
+#: Bytes a compaction's copy writes between two fsyncs of its temp file:
+#: an appender's fsync shares the device's queue with the copy's dirty
+#: pages, so the copy never lets more than this many pile up.
+COPY_SYNC_BYTES = 8 << 20
+
 
 def journal_enabled() -> bool:
     """JGRAFT_SERVICE_JOURNAL gate (default on; 0 restores the
@@ -139,6 +179,70 @@ def _crc_line(rec: dict) -> str:
     body = {k: v for k, v in rec.items() if k != "crc"}
     canon = json.dumps(body, sort_keys=True, separators=(",", ":"))
     return format(zlib.crc32(canon.encode()), "08x")
+
+
+def _tag(rec: dict) -> tuple:
+    """``(kind, key)`` of a record, all the compaction's keep rule reads
+    of it: the session id for the stream family, the request id for
+    every other kind."""
+    kind = rec.get("kind")
+    return kind, (str(rec.get("sid")) if kind in STREAM_KINDS
+                  else rec.get("id"))
+
+
+def plan_compaction(tags, retain: int):
+    """The keep rule, over one ``(kind, key)`` a record in file order.
+    Returns ``(keep, finished)``: the positions a compaction writes, in
+    the order it writes them, and the finished pairs and sessions among
+    them (what `_finished_since_compact` restarts from).
+
+    First, in file order: every submit without a terminal marker, every
+    record of a stream session without a fin, and the open + fin of the
+    newest `retain` finished sessions (a finished session's segments are
+    dead weight once its verdict exists). Then the newest `retain`
+    finished ``(submit, terminal)`` pairs. Terminals without a kept
+    submit, and kinds this version does not know, are dropped."""
+    terminals = {}
+    fins = {}
+    for i, (kind, key) in enumerate(tags):
+        if kind == "terminal":
+            terminals[key] = i
+        elif kind == "stream-fin":
+            fins[key] = i
+    # finished sessions, oldest first (their first fin's file order)
+    fin_order = list(fins)
+    drop_fins = set(fin_order[:-retain] if retain else fin_order)
+    keep: List[int] = []
+    pairs = []
+    for i, (kind, key) in enumerate(tags):
+        if kind in STREAM_KINDS:
+            if key not in fins:
+                keep.append(i)          # unfinished: keep whole
+            elif (kind not in ("stream-seg", "stream-bseg")
+                  and key not in drop_fins):
+                keep.append(i)          # finished: open+fin only
+        elif kind == "submit":
+            term = terminals.get(key)
+            if term is None:
+                keep.append(i)
+            else:
+                pairs.append((i, term))
+    for pair in pairs[-retain:]:
+        keep.extend(pair)
+    return keep, min(len(pairs), retain) + len(fins) - len(drop_fins)
+
+
+def _runs(entries):
+    """``(offset, length)`` of each maximal run of index entries that
+    are neighbours in the file as they are in `entries`: what the copy
+    reads in one piece."""
+    runs: List[list] = []
+    for off, n, _kind, _key in entries:
+        if runs and runs[-1][0] + runs[-1][1] == off:
+            runs[-1][1] += n
+        else:
+            runs.append([off, n])
+    return runs
 
 
 def encode_submit(req: CheckRequest) -> dict:
@@ -365,7 +469,7 @@ class AdmissionJournal:
         # _lock like every other write, so compaction/stats never
         # interleave with a group's write+fsync.
         self._gcond = threading.Condition(threading.Lock())
-        # [line, done, ok] per entry
+        # [line, done, ok, kind, key] per entry
         self._gqueue: List[list] = []  # guarded_by(_gcond)
         self._gleader = False  # guarded_by(_gcond)
         self._glast_multi = False   # previous group carried riders?
@@ -376,6 +480,27 @@ class AdmissionJournal:
         # whole WAL a second time for nothing); a journal used without
         # a replay just starts the compaction amortization from zero.
         self._finished_since_compact = 0
+        # What the file holds, for the compaction to copy from: one
+        # (offset, length, kind, key) a record in file order, written
+        # where the bytes are written (`_note_written`) and seeded by
+        # replay(). None where it does not cover the file from byte 0;
+        # _indexed_to is the length of the file it covers.
+        self._index: Optional[List[tuple]] = (  # guarded_by(_lock)
+            None if self.path.exists() and self.path.stat().st_size
+            else [])
+        self._indexed_to = 0  # guarded_by(_lock)
+        # one compaction at a time: the journal's thread, a caller of
+        # compact(), and replay() seeding the index; taken before _lock
+        self._compact_mutex = threading.Lock()
+        self._compact_pending = False  # guarded_by(_lock)
+        self._compactions = 0  # guarded_by(_lock)
+        self._compact_scans = 0  # guarded_by(_lock)
+        self._compact_s = 0.0  # guarded_by(_lock)
+        self._compact_hold_max = 0.0  # guarded_by(_lock)
+        self._compact_bytes = 0  # guarded_by(_lock)
+        #: test seam: called between a compaction's steps (b) and (c),
+        #: with no lock of the journal's held but _compact_mutex
+        self._after_copy = None
         self.append_ms: deque = deque(maxlen=APPEND_WINDOW)
 
     # ------------------------------------------------------------ write
@@ -383,7 +508,40 @@ class AdmissionJournal:
     def _handle(self):  # requires(_lock)
         if self._fh is None or self._fh.closed:
             self._fh = open(self.path, "ab")
+            size = self._fh.tell()
+            if size and self._tail_is_torn(size):
+                # A crash mid-append left a line without its newline:
+                # end it, so that it costs its own record and not the
+                # next one appended as well. The append this handle was
+                # opened for brings the fsync that covers the byte.
+                self._fh.write(b"\n")  # lint: allow(fsync)
+                if self._indexed_to == size:
+                    self._indexed_to += 1
         return self._fh
+
+    def _close_handle(self) -> None:  # requires(_lock)
+        if self._fh is not None and not self._fh.closed:
+            self._fh.close()
+
+    def _tail_is_torn(self, size: int) -> bool:
+        with open(self.path, "rb") as fh:
+            fh.seek(size - 1)
+            return fh.read(1) != b"\n"
+
+    def _note_written(self, at: int, lines) -> None:  # requires(_lock)
+        """Index the records just written at offset `at`, one ``(length,
+        kind, key)`` each. Bytes the index did not see before them (a
+        handle opened on a WAL nobody replayed, another writer) leave
+        the journal without an index until a compaction scans."""
+        if self._index is None:
+            return
+        if at != self._indexed_to:
+            self._index = None
+            return
+        for n, kind, key in lines:
+            self._index.append((at, n, kind, key))
+            at += n
+        self._indexed_to = at
 
     def _append(self, build, fsync: bool,
                 name: str = "journal.mark") -> bool:
@@ -401,7 +559,7 @@ class AdmissionJournal:
                                separators=(",", ":")) + "\n").encode()
             group = journal_group_ms() if fsync else 0
             if group > 0:
-                ok = self._append_grouped(line, group)
+                ok = self._append_grouped(line, group, _tag(rec))
             else:
                 ok = self._append_alone(line, rec, fsync)
         # under the lock: stats() iterates append_ms while holding it
@@ -415,23 +573,28 @@ class AdmissionJournal:
         try:
             with self._lock:
                 fh = self._handle()
+                at = fh.tell()
                 fh.write(line)
                 fh.flush()
                 if fsync:
                     with span("journal.fsync"):
                         os.fsync(fh.fileno())
                 self._appends += 1
+                self._note_written(at, [(len(line),) + _tag(rec)])
         except OSError:
             # Durability degraded, availability kept: the daemon counts
             # and logs, the request is still served (module docstring).
+            # What reached the file is unknown: no index until a scan.
             with self._lock:
                 self._errors += 1
+                self._index = None
             LOG.warning("journal append failed for %s record %s",
                         rec.get("kind"), rec.get("id"), exc_info=True)
             return False
         return True
 
-    def _append_grouped(self, line: bytes, group_ms: int) -> bool:
+    def _append_grouped(self, line: bytes, group_ms: int,
+                        tag: tuple) -> bool:
         """Leader/follower group commit (`journal_group_ms`). The
         caller's entry joins the pending queue; the first appender with
         no leader in flight LEADS: it drains the queue, writes every
@@ -448,7 +611,7 @@ class AdmissionJournal:
         per-append path. Under real concurrency no sleep is needed at
         all — followers pile into the queue during the current group's
         write+fsync and the next leader finds them already waiting."""
-        entry = [line, False, False]   # line, done, ok
+        entry = [line, False, False, *tag]   # line, done, ok, kind, key
         with self._gcond:
             self._gqueue.append(entry)
             while not entry[1] and self._gleader:
@@ -469,6 +632,7 @@ class AdmissionJournal:
                 try:
                     with self._lock:
                         fh = self._handle()
+                        at = fh.tell()
                         fh.write(b"".join(e[0] for e in batch))
                         fh.flush()
                         with span("journal.fsync"):
@@ -476,10 +640,13 @@ class AdmissionJournal:
                         self._appends += len(batch)
                         self._group_commits += 1
                         self._group_records += len(batch)
+                        self._note_written(
+                            at, [(len(e[0]), e[3], e[4]) for e in batch])
                     ok = True
                 except OSError:
                     with self._lock:
                         self._errors += len(batch)
+                        self._index = None
                     LOG.warning("journal group append failed "
                                 "(%d records)", len(batch),
                                 exc_info=True)
@@ -505,15 +672,7 @@ class AdmissionJournal:
         terminal marker is only re-execution on replay (idempotent),
         but a persisted one is a warm cache entry worth the write."""
         ok = self._append(lambda: encode_terminal(req), fsync=True)
-        with self._lock:
-            self._finished_since_compact += 1
-            # amortized: compact once the WAL holds ~2x the retention
-            # bound of finished pairs (each compaction trims back to
-            # `retain`, so the file oscillates between retain and
-            # 2·retain pairs instead of rewriting per append)
-            should = self._finished_since_compact > 2 * self.retain
-        if should:
-            self.compact()
+        self._finished_one()
         return ok
 
     def append_stream(self, rec: dict) -> bool:
@@ -523,26 +682,54 @@ class AdmissionJournal:
         other append."""
         ok = self._append(rec, fsync=True)
         if rec.get("kind") == "stream-fin":
-            with self._lock:
-                self._finished_since_compact += 1
-                should = self._finished_since_compact > 2 * self.retain
-            if should:
-                self.compact()
+            self._finished_one()
         return ok
+
+    def _finished_one(self) -> None:
+        """Count a finished pair or session. Amortized: once the WAL
+        holds ~2x the retention bound of them, wake the compaction
+        thread (each compaction trims back to `retain`, so the file
+        oscillates between retain and 2·retain pairs instead of being
+        rewritten per append). The caller's thread (the dispatcher's,
+        in `_retire`) never compacts; a trigger that finds a compaction
+        pending is dropped, and the count keeps running."""
+        with self._lock:
+            self._finished_since_compact += 1
+            wake = (self._finished_since_compact > 2 * self.retain
+                    and not self._compact_pending)
+            if wake:
+                self._compact_pending = True
+        if wake:
+            threading.Thread(target=self._compact_in_background,
+                             name="journal-compact", daemon=True).start()
+
+    def _compact_in_background(self) -> None:
+        try:
+            self.compact()
+        finally:
+            with self._lock:
+                self._compact_pending = False
 
     # ----------------------------------------------------------- replay
 
     def _scan(self):
-        """(records, skipped): parsed records in file order; corrupt or
-        truncated lines are skipped LOUDLY — a torn tail is the normal
-        crash signature, and it must cost one record, not the file."""
+        """(records, skipped, index, size): parsed records in file
+        order, and for each its index entry (`_index`'s form), of a file
+        of `size` bytes; corrupt or truncated lines are skipped LOUDLY
+        and get no entry — a torn tail is the normal crash signature,
+        and it must cost one record, not the file. `index` is None
+        where a last line without its newline parsed (a crash between
+        the two): its range is not a whole line yet."""
         records: List[dict] = []
+        index: Optional[List[tuple]] = []
         skipped = 0
         try:
             raw = self.path.read_bytes()
         except FileNotFoundError:
-            return records, skipped
+            return records, skipped, index, 0
+        at = 0
         for ln, line in enumerate(raw.split(b"\n"), 1):
+            off, at = at, at + len(line) + 1
             if not line.strip():
                 continue
             try:
@@ -561,7 +748,11 @@ class AdmissionJournal:
                             self.path, ln, e)
                 continue
             records.append(rec)
-        return records, skipped
+            if at > len(raw):
+                index = None
+            elif index is not None:
+                index.append((off, at - off) + _tag(rec))
+        return records, skipped, index, len(raw)
 
     def replay(self) -> dict:
         """Join submits with their terminal markers. Returns::
@@ -580,7 +771,13 @@ class AdmissionJournal:
         per session; a record from a NEWER stream version is skipped
         loudly without touching the request replay, and a WAL with no
         stream records (pre-PR-12) replays exactly as before."""
-        records, skipped = self._scan()
+        with self._compact_mutex:
+            records, skipped, index, size = self._scan()
+            with self._lock:
+                # replay doubles as the scan that seeds the compaction's
+                # index; an append that lands before the next one shows
+                # as bytes the index did not see (`_note_written`)
+                self._index, self._indexed_to = index, size
         submits = {}
         terminals = {}
         streams: dict = {}
@@ -652,7 +849,7 @@ class AdmissionJournal:
         the same per-session dict `replay()["streams"]` holds, or None
         when the session has no (intact) open record."""
         sid = str(sid)
-        records, _ = self._scan()
+        records = self._scan()[0]
         out = {"open": None, "segments": [], "fin": None}
         seen: dict = {}
         for rec in records:
@@ -677,67 +874,150 @@ class AdmissionJournal:
 
     def compact(self) -> None:
         """Rewrite the WAL: every unfinished entry survives, only the
-        newest `retain` finished pairs do. Atomic via temp+replace —
-        a crash mid-compaction leaves a valid journal either way.
-
-        Stream sessions (ISSUE 12) follow the same rule in their own
-        family: an UNFINISHED session keeps every record (open + all
-        segments — that is the resumability payload), a finished one
-        keeps only its open+fin pair (status stays queryable across a
-        restart; the segment payloads are dead weight once a terminal
-        verdict exists), bounded to the newest `retain` finished
-        sessions."""
-        with self._lock:
-            records, _ = self._scan()
-            terminals = {r["id"]: r for r in records
-                         if r.get("kind") == "terminal"}
-            stream_fins = {str(r.get("sid")): r for r in records
-                           if r.get("kind") == "stream-fin"}
-            # finished sessions, oldest first (fin record file order)
-            fin_order = list(stream_fins)
-            drop_fins = set(fin_order[:-self.retain]
-                            if self.retain else fin_order)
-            keep: List[dict] = []
-            finished_pairs = []
-            for rec in records:
-                kind = rec.get("kind")
-                if kind in STREAM_KINDS:
-                    sid = str(rec.get("sid"))
-                    if sid not in stream_fins:
-                        keep.append(rec)      # unfinished: keep whole
-                    elif (kind not in ("stream-seg", "stream-bseg")
-                          and sid not in drop_fins):
-                        keep.append(rec)      # finished: open+fin only
-                    continue
-                if kind != "submit":
-                    continue
-                term = terminals.get(rec["id"])
-                if term is None:
-                    keep.append(rec)
+        newest `retain` finished pairs do (`plan_compaction` has the
+        rule, the stream family's half included). Atomic via
+        temp+replace — a crash mid-compaction leaves a valid journal
+        either way (the temp file of one that failed or died is
+        overwritten by the next and never read by replay()). Synchronous,
+        on the caller's thread; one that finds another compaction
+        running waits for it first."""
+        with self._compact_mutex:
+            with span("journal.compact") as sp:
+                try:
+                    copied = self._compact_indexed()
+                    if copied is None:
+                        copied = self._compact_scanned()
+                except OSError:
+                    copied = None
+                    LOG.warning("journal compaction failed; keeping the "
+                                "uncompacted WAL", exc_info=True)
+            with self._lock:
+                if copied is None:
+                    self._errors += 1
                 else:
-                    finished_pairs.append((rec, term))
-            for sub, term in finished_pairs[-self.retain:]:
-                keep.extend((sub, term))
-            tmp = self.path.with_suffix(".jsonl.tmp")
-            try:
+                    self._compactions += 1
+                    self._compact_s += sp.s
+                    self._compact_bytes += copied
+
+    def _note_hold(self, seconds: float) -> None:  # requires(_lock)
+        self._compact_hold_max = max(self._compact_hold_max, seconds)
+
+    def _copy_ranges(self, src, tmp_fh, ranges) -> int:
+        """Copy the byte `ranges` of the file `src` to `tmp_fh`,
+        `COPY_CHUNK` at a time, fsync'd every `COPY_SYNC_BYTES` and at
+        the end. Returns the bytes copied."""
+        copied = unsynced = 0
+        src = src.fileno()
+        for off, n in ranges:
+            while n:
+                buf = os.pread(src, min(n, COPY_CHUNK), off)
+                if not buf:
+                    raise OSError("journal shorter than its index says")
+                tmp_fh.write(buf)
+                off += len(buf)
+                n -= len(buf)
+                copied += len(buf)
+                unsynced += len(buf)
+                if unsynced >= COPY_SYNC_BYTES:
+                    tmp_fh.flush()
+                    os.fsync(tmp_fh.fileno())
+                    unsynced = 0
+        tmp_fh.flush()
+        os.fsync(tmp_fh.fileno())
+        return copied
+
+    def _compact_indexed(self) -> Optional[int]:  # requires(_compact_mutex)
+        """The compaction from the index (module docstring): bytes
+        copied, or None where the index does not cover the file. No
+        record is parsed, CRC'd or re-encoded."""
+        with self._lock:
+            with span("journal.compact_hold") as hold:   # step (a)
+                try:
+                    covered = (self._index is not None and self._indexed_to
+                               == os.path.getsize(self.path))
+                except FileNotFoundError:
+                    covered = False
+                if covered:
+                    index = list(self._index)
+                    end0 = self._indexed_to
+                    finished0 = self._finished_since_compact
+            self._note_hold(hold.s)
+        if not covered:
+            return None
+        keep, finished = plan_compaction([e[2:] for e in index],
+                                         self.retain)
+        kept = [index[i] for i in keep]
+        tmp = self.path.with_suffix(".jsonl.tmp")
+        with open(self.path, "rb") as src, open(tmp, "wb") as tmp_fh:
+            head = self._copy_ranges(src, tmp_fh, _runs(kept))   # (b)
+            # ... and what was appended during the copy, for as long as
+            # it is more than a chunk (the copy outruns the appenders;
+            # the rounds are bounded for the day it does not): bytes,
+            # whole records or not, so that step (c) finds a few records
+            tail = 0
+            for _ in range(CATCH_UP_ROUNDS):
+                more = os.fstat(src.fileno()).st_size - end0 - tail
+                if more <= COPY_CHUNK:
+                    break
+                tail += self._copy_ranges(src, tmp_fh,
+                                          [(end0 + tail, more)])
+            if self._after_copy is not None:
+                self._after_copy()
+            with self._lock:
+                with span("journal.compact_hold") as hold:   # step (c)
+                    end1 = os.fstat(src.fileno()).st_size
+                    tail += self._copy_ranges(
+                        src, tmp_fh, [(end0 + tail, end1 - end0 - tail)])
+                    tmp_fh.close()
+                    self._close_handle()
+                    os.replace(tmp, self.path)
+                    if self._index is not None \
+                            and self._indexed_to == end1:
+                        at, moved = 0, []
+                        for _off, n, kind, key in kept:
+                            moved.append((at, n, kind, key))
+                            at += n
+                        moved.extend(
+                            (off - end0 + head, n, kind, key)
+                            for off, n, kind, key
+                            in self._index[len(index):])
+                        self._index = moved
+                        self._indexed_to = head + tail
+                    else:
+                        self._index = None
+                    # what finished during (b) is kept either way
+                    self._finished_since_compact += finished - finished0
+                self._note_hold(hold.s)
+        return head + tail
+
+    def _compact_scanned(self) -> int:  # requires(_compact_mutex)
+        """The compaction of a journal without an index: parse and CRC
+        every line, write the kept records re-encoded, all of it under
+        the lock. Leaves an index behind. Returns the bytes written."""
+        with self._lock:
+            with span("journal.compact_hold") as hold:
+                records = self._scan()[0]
+                tags = [_tag(rec) for rec in records]
+                keep, finished = plan_compaction(tags, self.retain)
+                tmp = self.path.with_suffix(".jsonl.tmp")
+                index, at = [], 0
                 with open(tmp, "wb") as fh:
-                    for rec in keep:
-                        fh.write((json.dumps(
-                            rec, sort_keys=True,
-                            separators=(",", ":")) + "\n").encode())
+                    for i in keep:
+                        line = (json.dumps(
+                            records[i], sort_keys=True,
+                            separators=(",", ":")) + "\n").encode()
+                        fh.write(line)
+                        index.append((at, len(line)) + tags[i])
+                        at += len(line)
                     fh.flush()
                     os.fsync(fh.fileno())
-                if self._fh is not None and not self._fh.closed:
-                    self._fh.close()
+                self._close_handle()
                 os.replace(tmp, self.path)
-            except OSError:
-                self._errors += 1
-                LOG.warning("journal compaction failed; keeping the "
-                            "uncompacted WAL", exc_info=True)
-                return
-            self._finished_since_compact = (
-                min(len(finished_pairs), self.retain)
-                + len(stream_fins) - len(drop_fins))
+                self._index, self._indexed_to = index, at
+                self._finished_since_compact = finished
+                self._compact_scans += 1
+            self._note_hold(hold.s)
+        return at
 
     # ------------------------------------------------------------ stats
 
@@ -754,6 +1034,15 @@ class AdmissionJournal:
                 "journal_group_occupancy_mean": round(
                     self._group_records / self._group_commits, 3)
                 if self._group_commits else 0.0,
+                # compactions (ISSUE 49): how many, their wall seconds
+                # and bytes, the longest single hold of the lock by one,
+                # and how many had to scan for want of an index
+                "journal_compactions": self._compactions,
+                "journal_compact_s": round(self._compact_s, 4),
+                "journal_compact_hold_ms_max": round(
+                    self._compact_hold_max * 1000.0, 3),
+                "journal_compact_bytes": self._compact_bytes,
+                "journal_compact_scans": self._compact_scans,
             }
         if samples:
             out["journal_append_p50_ms"] = round(
@@ -761,6 +1050,9 @@ class AdmissionJournal:
         return out
 
     def close(self) -> None:
-        with self._lock:
-            if self._fh is not None and not self._fh.closed:
-                self._fh.close()
+        """Close the handle, after a compaction that is running has
+        finished (one the process dies under leaves the old file whole
+        and a temp file nobody reads)."""
+        with self._compact_mutex:
+            with self._lock:
+                self._close_handle()

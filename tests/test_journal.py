@@ -20,14 +20,17 @@ import subprocess
 import sys
 import threading
 import time
+from pathlib import Path
 
 import pytest
 
 from jepsen_jgroups_raft_tpu.checker.linearizable import (check_encoded,
                                                           check_histories)
+from jepsen_jgroups_raft_tpu.checker.schedule import open_span, snapshot_spans
 from jepsen_jgroups_raft_tpu.models import CasRegister
 from jepsen_jgroups_raft_tpu.service import (CheckingService, ServiceClient,
-                                             ServiceError, serve_in_thread)
+                                             ServiceError, journal,
+                                             serve_in_thread)
 from jepsen_jgroups_raft_tpu.service.client import backoff_delay
 from jepsen_jgroups_raft_tpu.service.journal import (AdmissionJournal,
                                                      decode_request,
@@ -37,6 +40,7 @@ from jepsen_jgroups_raft_tpu.service.request import admit
 from util import H, free_port, random_valid_history
 
 WAIT_S = 120.0  # bound, not a sleep (first XLA compile dominates)
+REPO = Path(__file__).resolve().parents[1]
 
 
 def valid_hist(n_ops=20, seed=7):
@@ -188,6 +192,405 @@ class TestJournalRecords:
         j.append_submit(admit([valid_hist(seed=10)], "register"))
         out = j.replay()
         assert out["skipped"] == 1 and len(out["unfinished"]) == 1
+
+
+# ------------------------------------- compaction from the index (ISSUE 49)
+
+
+def sub_rec(rid, pad=0):
+    """A submit record by hand: what the compaction reads of a record
+    is its kind and id, and a hand-made one is cheap at any size."""
+    return {"kind": "submit", "v": 1, "id": rid, "fingerprint": "f" + rid,
+            "pad": "x" * pad}
+
+
+def term_rec(rid):
+    return {"kind": "terminal", "v": 1, "id": rid, "fingerprint": "f" + rid,
+            "status": "done", "results": [{"valid?": True}]}
+
+
+def s_open(sid):
+    return journal.encode_stream_open(sid, "register", "CasRegister", "auto",
+                                      "linearizable", 1)
+
+
+def s_seg(sid, seq):
+    return journal.encode_stream_segment(sid, seq, [[]], f"d{seq}")
+
+
+def s_bseg(sid, seq):
+    return {"kind": "stream-bseg", "v": 1,
+            "stream_v": journal.STREAM_VERSION, "sid": sid, "seq": seq,
+            "digest": f"b{seq}", "units": []}
+
+
+def s_fin(sid):
+    return journal.encode_stream_fin(sid, "done", results=[{"valid?": True}])
+
+
+def _pairs(n, tag="p"):
+    out = []
+    for i in range(n):
+        out += [sub_rec(f"{tag}{i}"), term_rec(f"{tag}{i}")]
+    return out
+
+
+def _stream(sid, fin=True):
+    return [s_open(sid), s_seg(sid, 1), s_bseg(sid, 2)] + (
+        [s_fin(sid)] if fin else [])
+
+
+def _everything():
+    """Every family, finished and not, past `retain` 2 in both."""
+    return (_pairs(2, "a") + [sub_rec("open0")] + _stream("live0", fin=False)
+            + _stream("s0") + _pairs(2, "b") + _stream("s1")
+            + [sub_rec("late"), sub_rec("open1")] + _stream("s2")
+            + [s_seg("live0", 3), term_rec("late")] + _pairs(1, "c")
+            + [{"kind": "from-the-future", "v": 1, "id": "u0"}])
+
+
+def _corrupt_a_middle_line(path):
+    lines = path.read_bytes().splitlines(keepends=True)
+    k = len(lines) // 2
+    lines[k] = lines[k].replace(b'"v":1', b'"v":0', 1)
+    path.write_bytes(b"".join(lines))
+
+
+def _tear_the_tail(path):
+    with open(path, "ab") as f:
+        f.write(b'{"kind":"submit","id":"torn-entry","v":1,"uni')
+
+
+#: name -> (records, retain, what a crash did to the file afterwards)
+DIFFERENTIAL = {
+    "pairs-past-retain": (_pairs(3) + [sub_rec("open")] + _pairs(4, "q"),
+                          2, None),
+    "pairs-under-retain": ([sub_rec("open")] + _pairs(2), 4, None),
+    "streams-past-retain": (_stream("s0") + _stream("live", fin=False)
+                            + _stream("s1") + _stream("s2")
+                            + [s_seg("live", 3)], 2, None),
+    "streams-under-retain": (_stream("s0") + _stream("live", fin=False),
+                             4, None),
+    "every-family": (_everything(), 2, None),
+    "duplicate-records": (_pairs(2) + [term_rec("p0"), sub_rec("p1"),
+                                       s_open("s0"), s_fin("s0"),
+                                       s_fin("s0")] + _pairs(2, "q"),
+                          2, None),
+    "corrupt-line-in-the-middle": (_everything(), 2,
+                                   _corrupt_a_middle_line),
+    "torn-tail": (_everything(), 2, _tear_the_tail),
+    "nothing-finished": ([sub_rec("a"), sub_rec("b")]
+                         + _stream("live", fin=False), 2, None),
+}
+
+
+def write_wal(root, records, retain):
+    """A journal that wrote `records` itself and has not compacted."""
+    j = AdmissionJournal(root, retain=1 << 30)
+    for rec in records:
+        assert j._append(dict(rec), fsync=False)
+    j.retain = retain
+    return j
+
+
+def reopened(src, root, retain, replay):
+    root.mkdir()
+    (root / "wal.jsonl").write_bytes(src.path.read_bytes())
+    j = AdmissionJournal(root, retain=retain)
+    if replay:
+        j.replay()
+    return j
+
+
+def index_matches_file(j):
+    """The index is what a scan of the file would seed."""
+    with j._lock:
+        return j._index == j._scan()[2] and \
+            j._indexed_to == j.path.stat().st_size
+
+
+class TestIndexedCompaction:
+    @pytest.mark.parametrize("case", sorted(DIFFERENTIAL))
+    def test_indexed_equals_scanned_byte_for_byte(self, tmp_path, case,
+                                                  monkeypatch):
+        records, retain, damage = DIFFERENTIAL[case]
+        writer = write_wal(tmp_path / "w", records, retain)
+        writer.close()
+        if damage is not None:
+            damage(writer.path)
+        scanned = reopened(writer, tmp_path / "scan", retain, replay=False)
+        scanned.compact()
+        assert scanned.stats()["journal_compact_scans"] == 1
+        indexed = reopened(writer, tmp_path / "index", retain, replay=True)
+        # nothing of a record is parsed, CRC'd or re-encoded from here on
+        calls = []
+        for mod, name in ((journal, "_crc_line"), (journal.json, "loads"),
+                          (journal.json, "dumps")):
+            real = getattr(mod, name)
+            monkeypatch.setattr(
+                mod, name, lambda *a, _r=real, _n=name, **kw:
+                (calls.append(_n), _r(*a, **kw))[1])
+        indexed.compact()
+        monkeypatch.undo()
+        assert calls == []
+        st = indexed.stats()
+        assert (st["journal_compactions"], st["journal_compact_scans"]) \
+            == (1, 0)
+        want = scanned.path.read_bytes()
+        assert indexed.path.read_bytes() == want
+        assert st["journal_compact_bytes"] == len(want)
+        assert indexed._finished_since_compact == \
+            scanned._finished_since_compact
+        assert index_matches_file(indexed) and index_matches_file(scanned)
+        if damage is None:
+            # the index a journal keeps of its own writes says the same
+            writer.compact()
+            assert writer.stats()["journal_compact_scans"] == 0
+            assert writer.path.read_bytes() == want
+            assert index_matches_file(writer)
+        # and a second compaction of what is left changes nothing
+        indexed.compact()
+        assert indexed.path.read_bytes() == want
+
+    def test_appends_during_a_compaction_are_kept_once(self, tmp_path):
+        j = write_wal(tmp_path, _pairs(5) + [sub_rec("open")], retain=2)
+        during = [sub_rec("new0"), term_rec("open"), sub_rec("new1"),
+                  term_rec("new1"), s_open("s9")]
+        seen = []
+
+        def append_them():
+            for rec in during:
+                assert j._append(dict(rec), fsync=True)
+                if rec["kind"] == "terminal":
+                    j._finished_one()
+
+        def append_from_another_thread():
+            seen.append(j.path.with_suffix(".jsonl.tmp").stat().st_size)
+            t = threading.Thread(target=append_them)
+            t.start()
+            t.join(WAIT_S)
+            assert not t.is_alive()
+
+        j._after_copy = append_from_another_thread
+        j.compact()
+        j._after_copy = None
+        assert seen and seen[0] > 0          # step (b) had been written
+        lines = j.path.read_bytes().splitlines()
+        for rec in during:
+            key = rec.get("id") or rec["sid"]
+            assert sum(1 for ln in lines if f'"{key}"'.encode() in ln
+                       and f'"kind":"{rec["kind"]}"'.encode() in ln) == 1
+        # the two newest pairs of before, then the appends, verbatim
+        assert [journal._tag(journal.json.loads(ln)) for ln in lines] == [
+            ("submit", "open"), ("submit", "p3"), ("terminal", "p3"),
+            ("submit", "p4"), ("terminal", "p4")] + [
+                journal._tag(r) for r in during]
+        # retain's 2, and the two that finished during the copy
+        assert j._finished_since_compact == 4
+        assert index_matches_file(j)
+        out = AdmissionJournal(tmp_path, retain=2).replay()
+        assert out["skipped"] == 1           # new0: no payload to decode
+        assert sorted(sub["id"] for sub, _ in out["finished"]) == [
+            "new1", "open", "p3", "p4"]
+        assert list(out["streams"]) == ["s9"]
+        # the next compaction copies from the rebuilt index
+        j.compact()
+        assert j.stats()["journal_compact_scans"] == 0
+        assert index_matches_file(j)
+
+    def test_an_append_does_not_wait_for_the_copy(self, tmp_path):
+        """2,050 finished pairs of 128 KB: an append that starts while
+        step (b) copies has returned before the step ends, and the lock
+        was held for a small part of the compaction."""
+        j = AdmissionJournal(tmp_path, retain=1 << 30)
+        for i in range(2050):
+            j._append(sub_rec(f"r{i}", pad=128 << 10), fsync=False)
+            j._append(term_rec(f"r{i}"), fsync=False)
+        j.retain = 1024
+        req = admit([valid_hist(seed=11)], "register")
+        acked = threading.Event()
+        appender = threading.Thread(
+            target=lambda: j.append_submit(req) and acked.set())
+        real_copy = j._copy_ranges
+
+        def copy(src, tmp_fh, ranges):
+            if not appender.ident:
+                appender.start()
+            return real_copy(src, tmp_fh, ranges)
+
+        acked_by_the_end_of_the_copy = []
+        j._copy_ranges = copy
+        j._after_copy = lambda: acked_by_the_end_of_the_copy.append(
+            acked.wait(WAIT_S))
+        j.compact()
+        appender.join(WAIT_S)
+        assert acked_by_the_end_of_the_copy == [True]
+        st = j.stats()
+        assert st["journal_compactions"] == 1
+        assert st["journal_compact_bytes"] > 1024 * (128 << 10)
+        assert st["journal_compact_hold_ms_max"] < \
+            0.25 * 1000 * st["journal_compact_s"]
+        [back] = AdmissionJournal(tmp_path).replay()["unfinished"]
+        assert back.id == req.id
+        assert index_matches_file(j)
+
+    @pytest.mark.parametrize("crash", ["after-the-copy",
+                                       "before-the-replace"])
+    def test_a_crash_mid_compaction_leaves_the_old_file(
+            self, tmp_path, crash, monkeypatch):
+        j = write_wal(tmp_path, _everything(), retain=2)
+        before = j.path.read_bytes()
+        tmp = j.path.with_suffix(".jsonl.tmp")
+        if crash == "after-the-copy":
+            def die():
+                raise Boom()
+
+            j._after_copy = die
+            with pytest.raises(Boom):
+                j.compact()
+            j._after_copy = None
+        else:
+            def no_replace(a, b):
+                raise OSError("power cut")
+
+            monkeypatch.setattr(journal.os, "replace", no_replace)
+            j.compact()                      # counted, not raised
+            monkeypatch.undo()
+            assert j.stats()["journal_errors"] == 1
+        assert j.stats()["journal_compactions"] == 0
+        assert j.path.read_bytes() == before
+        assert tmp.exists() and tmp.stat().st_size > 0
+        # a restart reads the old file whole and never the leftover
+        want = reopened(j, tmp_path / "clean", 2, replay=False).replay()
+        got = AdmissionJournal(tmp_path, retain=2).replay()
+        assert got["skipped"] == want["skipped"]
+        assert got["finished"] == want["finished"]
+        assert got["streams"] == want["streams"]
+        # the journal that survived the failure appends and compacts on,
+        # over the leftover
+        assert j._append(sub_rec("after"), fsync=True)
+        j.compact()
+        assert j.stats()["journal_compactions"] == 1
+        assert not tmp.exists()
+        assert b'"id":"after"' in j.path.read_bytes()
+        assert index_matches_file(j)
+
+    def test_unindexed_journal_scans_once_then_copies(self, tmp_path):
+        write_wal(tmp_path, _everything(), retain=2).close()
+        j = AdmissionJournal(tmp_path, retain=2)    # never replayed
+        assert j._append(sub_rec("mine"), fsync=True)
+        j.compact()
+        st = j.stats()
+        assert (st["journal_compactions"], st["journal_compact_scans"]) \
+            == (1, 1)
+        assert index_matches_file(j)
+        for rec in _pairs(3, "z"):
+            assert j._append(rec, fsync=True)
+        j.compact()
+        st = j.stats()
+        assert (st["journal_compactions"], st["journal_compact_scans"]) \
+            == (2, 1)
+        assert index_matches_file(j)
+        ids = [journal.json.loads(ln).get("id")
+               for ln in j.path.read_bytes().splitlines()]
+        assert ids[-4:] == ["z1", "z1", "z2", "z2"] and "mine" in ids
+
+    def test_a_failed_append_costs_the_index_not_the_journal(
+            self, tmp_path, monkeypatch):
+        j = write_wal(tmp_path, _pairs(3), retain=2)
+
+        def broken_fsync(fd):
+            raise OSError("disk on fire")
+
+        monkeypatch.setattr(os, "fsync", broken_fsync)
+        assert j.append_submit(admit([valid_hist(seed=12)],
+                                     "register")) is False
+        monkeypatch.undo()
+        j.compact()                          # what was written? scan.
+        assert j.stats()["journal_compact_scans"] == 1
+        assert index_matches_file(j)
+        assert len(AdmissionJournal(tmp_path).replay()["unfinished"]) == 1
+
+    def test_the_threshold_wakes_a_thread_of_the_journals_own(
+            self, tmp_path):
+        j = AdmissionJournal(tmp_path, retain=2)
+        where = []
+        j._after_copy = lambda: where.append(
+            (threading.current_thread().name, open_span()))
+        before = snapshot_spans()
+        for i in range(5):
+            r = admit([valid_hist(seed=50 + i)], "register")
+            j.append_submit(r)
+            r.finish("done", results=[{"valid?": True}])
+            assert j.append_terminal(r)
+            # the caller's thread never compacts
+            assert open_span() is None
+        deadline = time.monotonic() + WAIT_S
+        while not j.stats()["journal_compactions"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        j.close()
+        assert where and set(where) == {("journal-compact",
+                                         "journal.compact")}
+        assert threading.current_thread().name != "journal-compact"
+        after = snapshot_spans()
+        n = j.stats()["journal_compactions"]
+        assert after["journal.compact"]["n"] - before.get(
+            "journal.compact", {"n": 0})["n"] == n
+        assert after["journal.compact_hold"]["n"] - before.get(
+            "journal.compact_hold", {"n": 0})["n"] == 2 * n
+        out = j.replay()
+        assert len(out["finished"]) <= 4 and not out["unfinished"]
+
+    def test_compact_hold_share_reads_a_served_journal(self, tmp_path):
+        """The benchmark's reader (`benchmarks/layer_metrics/
+        compact_hold_share.py`) over two snapshots of what graftd serves
+        under `/stats`: the holds' seconds of the window's, 0 where no
+        compaction ran, nothing from a parent's stats."""
+        from benchmarks.layer_metrics import compact_hold_share as reader
+
+        j = write_wal(tmp_path, _pairs(5), retain=2)
+
+        def stats():
+            return {"stats": dict(j.stats(), spans=snapshot_spans())}
+
+        first = stats()
+        idle = {"window_s": 2.0, "before": first, "after": stats()}
+        assert reader.read(idle) == 0.0
+        j.compact()
+        ctx = {"window_s": 2.0, "before": first, "after": stats()}
+        held = ctx["after"]["stats"]["spans"]["journal.compact_hold"]["s"] \
+            - first["stats"]["spans"].get("journal.compact_hold",
+                                          {"s": 0.0})["s"]
+        assert held > 0
+        assert reader.read(ctx) == pytest.approx(100 * held / 2.0)
+        assert held >= 1e-3 * j.stats()["journal_compact_hold_ms_max"]
+        parent = {"stats": {k: v for k, v in ctx["after"]["stats"].items()
+                            if not k.startswith("journal_compact")}}
+        assert reader.read(dict(ctx, after=parent)) is None
+        manifest = journal.json.loads(
+            (REPO / "BENCHMARK.json").read_text())
+        [entry] = [m for m in manifest["per_layer"]
+                   if m["name"] == "compact_hold_share"]
+        assert entry == {"name": "compact_hold_share", "unit": "%",
+                         "better": "lower", "source": "program_span",
+                         "layer": "journal", "moves": "hist_per_s",
+                         "workloads": ["register-map-10k.campaign-keyed"]}
+
+    def test_a_torn_tail_costs_its_own_record_only(self, tmp_path):
+        """The first append after a crash mid-append starts a line of
+        its own: the torn one is ended, not continued."""
+        j = AdmissionJournal(tmp_path)
+        j.append_submit(admit([valid_hist(seed=13)], "register"))
+        j.close()
+        _tear_the_tail(j.path)
+        j2 = AdmissionJournal(tmp_path)
+        assert len(j2.replay()["unfinished"]) == 1
+        assert j2.append_submit(admit([valid_hist(seed=14)], "register"))
+        assert index_matches_file(j2)
+        j2.close()
+        out = AdmissionJournal(tmp_path).replay()
+        assert out["skipped"] == 1 and len(out["unfinished"]) == 2
 
 
 # ------------------------------------------------------- crash recovery

@@ -308,20 +308,23 @@ class TestLockOrder:
                 assert f"class {cls}" in tier, entry
 
     def test_mutation_compact_inside_journal_lock_fires_cycle(self):
-        # move append_terminal's compact() call INSIDE `with
-        # self._lock:` — compact() itself takes the (non-reentrant)
-        # lock, so the mutation is a guaranteed self-deadlock
+        # the trigger hands the compaction to a thread of the journal's
+        # own, outside `with self._lock:`. Mutation: compact there and
+        # then, INSIDE the lock — compact() takes the (non-reentrant)
+        # lock for its first step, so it is a guaranteed self-deadlock
         text = (SERVICE / "journal.py").read_text()
-        before = ("            should = self._finished_since_compact"
-                  " > 2 * self.retain\n"
-                  "        if should:\n"
-                  "            self.compact()\n")
+        before = ("            if wake:\n"
+                  "                self._compact_pending = True\n"
+                  "        if wake:\n"
+                  "            threading.Thread("
+                  "target=self._compact_in_background,\n")
         assert before in text
         mutated = text.replace(before, (
-            "            should = self._finished_since_compact"
-            " > 2 * self.retain\n"
-            "            if should:\n"
-            "                self.compact()\n"))
+            "            if wake:\n"
+            "                self.compact()\n"
+            "        if False:\n"
+            "            threading.Thread("
+            "target=self._compact_in_background,\n"))
         f = lockorder.analyze_sources(
             {"journal.py": src_of(mutated, "service/journal.py")},
             hierarchy=None)

@@ -993,6 +993,65 @@ class TestJournalStreamRecords:
         assert out["streams"]["done"]["fin"] is not None
         assert out["streams"]["done"]["segments"] == []
 
+    def test_segments_appended_during_a_compaction_survive(self, tmp_path):
+        """A compaction copies without the journal's lock (ISSUE 49): a
+        session that appends, and one that finishes, while it copies
+        are in the replaced file as they were acknowledged, and the
+        finished one counts towards the next compaction."""
+        import threading
+
+        from jepsen_jgroups_raft_tpu.service.journal import (
+            encode_stream_fin)
+
+        j = AdmissionJournal(tmp_path / "j", retain=1)
+        for sid in ("old", "older"):
+            j.append_stream(encode_stream_open(sid, "register",
+                                               "CasRegister", "auto",
+                                               "linearizable", 1))
+            j.append_stream(encode_stream_segment(sid, 1, [[]], "d"))
+        j.append_stream(encode_stream_fin("older", "done"))
+        j.append_stream(encode_stream_open("live", "register",
+                                           "CasRegister", "auto",
+                                           "linearizable", 1))
+        j.close()                        # let the trigger's thread end
+        assert j._finished_since_compact == 1
+
+        def meanwhile():
+            t = threading.Thread(target=lambda: (
+                j.append_stream(encode_stream_segment("live", 1, [[]],
+                                                      "d1")),
+                j.append_stream(encode_stream_segment("old", 2, [[]],
+                                                      "d2")),
+                j.append_stream(encode_stream_fin(
+                    "old", "done", results=[{"valid?": True}]))))
+            t.start()
+            t.join(60)
+            assert not t.is_alive()
+
+        j._after_copy = meanwhile
+        j.compact()
+        j._after_copy = None
+        j.close()
+        # the compaction kept `older`; `old` finished during its copy
+        assert j._finished_since_compact == 2
+        assert j.stats()["journal_compact_scans"] == 0
+        out = AdmissionJournal(tmp_path / "j", retain=1).replay()
+        assert out["skipped"] == 0
+        assert [r["seq"] for r in out["streams"]["live"]["segments"]] == [1]
+        # `old` was unfinished at the snapshot: kept whole, fin and all
+        assert [r["seq"] for r in out["streams"]["old"]["segments"]] == [
+            1, 2]
+        assert out["streams"]["old"]["fin"]["results"] == [
+            {"valid?": True}]
+        assert out["streams"]["older"]["segments"] == []
+        # the next compaction trims `old` to its pair and drops `older`
+        j.compact()
+        j.close()
+        out = AdmissionJournal(tmp_path / "j", retain=1).replay()
+        assert sorted(out["streams"]) == ["live", "old"]
+        assert out["streams"]["old"]["segments"] == []
+        assert out["streams"]["old"]["fin"] is not None
+
     def test_fixture_wal_crc_discipline(self, tmp_path):
         """Stream records ride the same CRC'd JSONL discipline: a
         hand-built record with a valid CRC replays; a rotted one is
