@@ -49,7 +49,6 @@ SIZES = {
     "mask_ops": (1000, 80),
     "sort_histories": (32, 6),
     "sort_ops": (200, 60),
-    "long_ops": (100_000, 6_000),
     "cycle_rows": (8, 3),
     "cycle_ops_small": (300, 40),
     "cycle_ops_big": (800, 90),
@@ -375,7 +374,6 @@ def phase_families(sz, rng, rehearse):
     from jepsen_jgroups_raft_tpu.checker.schedule import stats_scope
     from jepsen_jgroups_raft_tpu.history.packing import encode_history
     from jepsen_jgroups_raft_tpu.history.synth import (build_history,
-                                                       corrupt,
                                                        random_valid_history)
     from jepsen_jgroups_raft_tpu.models.counter import Counter
     from jepsen_jgroups_raft_tpu.models.register import CasRegister
@@ -410,28 +408,6 @@ def phase_families(sz, rng, rehearse):
                            ref_rows=len(encs), algorithm="jax")
     need(line["valid"] and line["invalid"], "sort: both verdicts must occur")
     line["rows_past_dense_slots"] = wide
-    emit(line)
-
-    # segment: BASELINE config 5, one long register history (and a
-    # corrupted copy, so the segmented kernel answers both ways).
-    phase("family-segment")
-    h = random_valid_history(rng, "register", n_ops=sz["long_ops"],
-                             n_procs=5, crash_p=0.01, max_crashes=4)
-    encs = [encode_history(x, reg) for x in (h, corrupt(rng, h))]
-    if rehearse:
-        # the segment route is TPU-only by default; a rehearsal walks
-        # it through the existing force knob, for this call only
-        os.environ["JGRAFT_SEGMENT"] = "1"
-    try:
-        res, line = run_family("segment", encs, reg, "dense-seg", rng,
-                               ref_rows=2, algorithm="auto")
-    finally:
-        if rehearse:
-            del os.environ["JGRAFT_SEGMENT"]
-    segs = [r.get("segments", 0) for r in res]
-    need(all(s > 1 for s in segs), f"segment: not segmented: {segs}")
-    line.update(ops=sz["long_ops"], events=int(encs[0].n_events),
-                segments=segs)
     emit(line)
 
     # cycle closure: the sequential rung on rows with a planted

@@ -9,14 +9,6 @@ existing Checker protocol").
 Algorithms:
   * ``"jax"``  — pack to event tensors, run the on-device frontier kernel
                  (ops/linear_scan.py); batched across histories.
-  * ``"pallas"`` — like "jax" but dense-domain batches run the Pallas
-                 kernel (ops/pallas_scan.py, frontier pinned in VMEM;
-                 interpret mode off-TPU). Proven on TPU v5e hardware
-                 2026-07-30; the vmapped XLA dense kernel measured ~2.3×
-                 faster on the north-star batch (it parallelizes the tiny
-                 per-history frontiers across the batch, the Pallas grid
-                 is sequential), so "auto" keeps dense — this selector is
-                 the explicit choice and the ablation hook.
   * ``"cpu"``  — the unbounded host frontier search (wgl_cpu.py).
   * ``"dfs"``  — the knossos/porcupine-style DFS-with-undo (dfs_cpu.py):
                  a genuinely different search order.
@@ -38,7 +30,6 @@ in which case we escalate instead of reporting.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Optional, Sequence
@@ -51,10 +42,9 @@ from ..history.packing import (EncodedHistory, bucket_rows, encode_history,
                                pack_macro_batch, pad_batch_bucketed)
 from ..ops.dense_scan import (MASK_DENSE_MAX_SLOTS, MERGE_MAX_EVENTS,
                               dense_plans_grouped, make_dense_batch_checker)
-from ..ops.kernel_ir import SEGMENT_MAX_SLOTS
+from ..ops.kernel_ir import WIDE_WINDOW_SLOTS
 from ..ops.linear_scan import (DEFAULT_N_CONFIGS, MAX_SLOTS, bucket_slots,
                                make_batch_checker, make_sort_chunk_checker)
-from ..ops.segment_scan import LONG_HISTORY_MIN_EVENTS, check_segmented_batch
 from ..platform import degraded_note, env_int, install_compile_counters
 from . import autotune
 from .base import Checker, INVALID, UNKNOWN, VALID
@@ -92,7 +82,7 @@ DEFAULT_MAX_CPU_CONFIGS = 1 << 18
 #: oracles), and "race" already runs its own host engine concurrently.
 #: Shared with graftd's dispatch fast lane (service/scheduler.py) so
 #: the two surfaces can never drift.
-LIN_FASTPATH_ALGOS = ("auto", "jax", "pallas")
+LIN_FASTPATH_ALGOS = ("auto", "jax")
 
 
 def lin_fastpath_on() -> bool:
@@ -174,9 +164,10 @@ def _fp_buckets(encs: Sequence[EncodedHistory], model,
 #: that in time, because it closes a row class only after
 #: `lin_fastpath_min_obs` (64) rows on each side, and a class of
 #: one-history requests that all certify never shows it a kernel row.
-#: The segment route's own threshold: one length from which a history
-#: is "long" for every router.
-LIN_FASTPATH_MAX_EVENTS = LONG_HISTORY_MIN_EVENTS
+#: The same length is where a row starts to count as LONG in `/stats`
+#: `long_rows` (`_check_encoded`): one threshold for the lane's gate and
+#: the counter.
+LIN_FASTPATH_MAX_EVENTS = 8192
 
 
 def lin_fastpath_plan(encs: Sequence[EncodedHistory], model) -> list:
@@ -627,9 +618,9 @@ def _check_encoded(
         return _race(encs, model, n_configs, n_slots, witness,
                      max_cpu_configs)
 
-    wide = [e.n_slots > SEGMENT_MAX_SLOTS and e.n_events > 0 for e in encs]
-    if algorithm in ("jax", "auto", "pallas"):
-        n_long = sum(e.n_events >= LONG_HISTORY_MIN_EVENTS for e in encs)
+    wide = [e.n_slots > WIDE_WINDOW_SLOTS and e.n_events > 0 for e in encs]
+    if algorithm in LIN_FASTPATH_ALGOS:
+        n_long = sum(e.n_events >= LIN_FASTPATH_MAX_EVENTS for e in encs)
         if any(wide) or n_long:
             note_wide(wide_rows=sum(wide), long_rows=n_long)
 
@@ -653,18 +644,17 @@ def _check_encoded(
             note_wide(wide_rows_host=sum(
                 results[i] is not None for i in first))
 
-    if algorithm in ("jax", "auto", "pallas"):
+    if algorithm in LIN_FASTPATH_ALGOS:
         undecided = [e if results[i] is None else None
                      for i, e in enumerate(encs)]
         todo = [e for e in undecided if e is not None]
-        want_pallas = "pallas" if algorithm == "pallas" else None
         # A backend failure propagates: a check that asked for the
         # accelerator never carries on on the host in its place.
         jax_res = _jax_pass(todo, model, n_configs, n_slots,
-                            kernel=want_pallas, serve_rows=serve_rows)
+                            serve_rows=serve_rows)
         it = iter(jax_res)
         results = [r if r is not None else next(it) for r in results]
-        if algorithm in ("jax", "pallas"):
+        if algorithm == "jax":
             for i, r in enumerate(results):
                 if r is None:
                     results[i] = {
@@ -727,14 +717,12 @@ def _escalate(encs, results, left, model, algorithm, witness,
                 results[i] = r2
 
 
-def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
+def _jax_pass(encs, model, n_configs=None, n_slots=None,
               note: bool = True, serve_rows: Optional[int] = None):
     """Run the on-device pass over a batch of encoded histories. Returns a
     result dict per history, or None where the kernel could not certify a
     verdict (window beyond MAX_SLOTS, or frontier overflow at top
-    capacity) — the caller escalates those. `kernel="pallas"` (or the
-    JGRAFT_KERNEL=pallas env override) routes dense-domain groups through
-    the Pallas kernel instead of the XLA dense kernel. `serve_rows`: see
+    capacity) — the caller escalates those. `serve_rows`: see
     `check_encoded`."""
     results: list[Optional[dict]] = [None] * len(encs)
     cap = n_slots or MAX_SLOTS
@@ -746,61 +734,15 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                 note_tier("trivial")
             results[i] = {"valid?": VALID, "algorithm": "trivial",
                           "op-count": 0, "decided-tier": "trivial"}
-    # Resolved before any routing: the group loop below rebinds `kernel`
-    # to the compiled callable, and the segment router must also honor
-    # an explicit pallas request (an ablation asking for pallas must not
-    # silently measure the segmented XLA kernel).
-    want_pallas = (kernel == "pallas" or
-                   os.environ.get("JGRAFT_KERNEL") == "pallas")
     # Macro-event compaction (ISSUE-4 tentpole): every kernel family
     # consumes the macro stream unless JGRAFT_MACRO_EVENTS=0 pins the
     # legacy one-event-per-step stream (the differential/ablation path;
     # verdicts are bitwise-identical either way). Eligibility/grouping
     # above stays on the legacy encoding — only kernel consumption
-    # switches. The segmented long-history path keeps legacy events
-    # (its cut/basis planner reasons about per-event quiescence).
+    # switches.
     _group_pack = pack_macro_batch if macro_events_on() else pack_batch
-    if fits and n_configs is None and n_slots is None and not want_pallas:
-        # Long histories first: the segmented scan (ops/segment_scan.py)
-        # cuts a 100k+-event stream at quiescent boundaries and runs the
-        # segments concurrently — the blockwise treatment of SURVEY §5.7.
-        # Exact (differentially pinned vs the monolithic kernels);
-        # ineligible histories (short, cut-free, non-dense) fall through.
-        #
-        # Routed only where measured to win, and today that is nowhere:
-        # PR 44 read one 100k-op register history on a v5e (146,282
-        # events, W 7, 95 segments; five-run medians, one call) at
-        # 3.60 s segmented, 3.44 s of it the kernel, against 2.01 s as
-        # a chunked LONG launch, since PR 41 packed that kernel's
-        # frontier and this one still runs the float matmul a slot
-        # (2026-07-30, before it: 4.0 s against 4.4 s). With many long
-        # histories the monolithic vmap fills the chip and the basis
-        # redundancy only hurts (16×10k: 12.5 vs 2.0 hist/s); on CPU
-        # the redundant width swamps the host outright (>10× slower).
-        # JGRAFT_SEGMENT=1/0 forces the choice (tests, ablation).
-        long_idx = [i for i in fits
-                    if encs[i].n_events >= LONG_HISTORY_MIN_EVENTS]
-        if long_idx and not _segment_routing_on():
-            long_idx = []
-        if long_idx:
-            # nested in the launch's tile, as `launch.escalate` is: the
-            # kernel inside it is a `launch.device` of its own
-            with span("launch.segment", n=len(long_idx)) as routed:
-                seg = check_segmented_batch([encs[i] for i in long_idx],
-                                            model)
-            dt = routed.s
-            n_done = sum(1 for r in seg if r is not None)
-            note_wide(long_rows_segmented=n_done)
-            for j, i in enumerate(long_idx):
-                if seg[j] is not None:
-                    r = _jx(VALID if seg[j]["valid"] else INVALID, encs[i],
-                            dt / max(n_done, 1), kernel="dense-seg",
-                            note=note)
-                    r["segments"] = seg[j]["segments"]
-                    results[i] = r
-            fits = [i for i in fits if results[i] is None]
     if fits:
-        # Dense-bitset kernel next: exact (no overflow, no escalation)
+        # Dense-bitset kernel first: exact (no overflow, no escalation)
         # and ~10× the sort kernel when the model's state domain is
         # enumerable and the window is small — the shapes the reference's
         # own workloads produce. Pinned n_configs/n_slots are sort-kernel
@@ -815,7 +757,7 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                     model, [encs[i] for i in fits])
         else:
             grouped, rest = [], list(range(len(fits)))
-        if grouped and scan_chunk() > 0 and not want_pallas:
+        if grouped and scan_chunk() > 0:
             # Chunked wavefront (ISSUE 3, checker/schedule.py): the
             # event scan runs in fixed-size chunks, decided/exhausted
             # rows are evicted and survivors recompacted between
@@ -826,10 +768,10 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             # covers the event length the MONOLITHIC kernel would scan
             # (pad_batch_bucketed's floor_e=32 series for short
             # groups, exact for LONG ones) so `early_exit` reports
-            # genuinely skipped reference work. JGRAFT_SCAN_CHUNK=0
-            # restores the monolithic reference launch loop below; the
-            # Pallas ablation keeps the monolithic path (its grid
-            # kernel owns its own event loop).
+            # genuinely skipped reference work. JGRAFT_SCAN_CHUNK=0 is
+            # the only way into the monolithic launch loop below, kept
+            # because the differential matrices of tests/ use it as the
+            # reference this wavefront is compared against.
             planned = []
             for idxs, plan in grouped:
                 sub = [fits[j] for j in idxs]
@@ -902,26 +844,11 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
                         batch["events"], (plan.val_of,),
                         floor_b=len(sub) if exact else 8,
                         floor_e=None if exact else 32)
-                    tag = plan.kernel_tag
-                    if want_pallas and plan.kind == "domain":
-                        # Pallas path (ops/pallas_scan.py): same search,
-                        # frontier pinned in VMEM. Interpret off-TPU.
-                        import jax
-
-                        from ..ops.pallas_scan import (
-                            make_pallas_batch_checker)
-                        kernel = make_pallas_batch_checker(
-                            model, plan.n_slots, plan.n_states,
-                            ev.shape[1],
-                            interpret=jax.default_backend() != "tpu",
-                            macro_p=batch.get("macro_p"))
-                        tag = "pallas"
-                    else:
-                        kernel = make_dense_batch_checker(
-                            model, plan.kind, plan.n_slots, plan.n_states,
-                            macro_p=batch.get("macro_p"))
+                    kernel = make_dense_batch_checker(
+                        model, plan.kind, plan.n_slots, plan.n_states,
+                        macro_p=batch.get("macro_p"))
                     ok, _ = kernel(ev, val_of)
-                    launched.append((sub, tag, ok, B))
+                    launched.append((sub, plan.kernel_tag, ok, B))
                     n_launched += len(sub)
                 t_prev = t0
                 for g, (sub, tag, ok, B) in enumerate(launched):
@@ -1028,13 +955,6 @@ def _jax_pass(encs, model, n_configs=None, n_slots=None, kernel=None,
             if not remaining:
                 break
     return results
-
-
-def _segment_routing_on() -> bool:
-    """Whether a launch's long rows go to the segment route first:
-    never, by what PR 44 read (above), unless JGRAFT_SEGMENT=1 forces
-    it (tests, ablation, the next reading)."""
-    return os.environ.get("JGRAFT_SEGMENT") == "1"
 
 
 #: DFS step budget in race mode: enough for any history the harness
@@ -1145,9 +1065,8 @@ def _check_dfs(enc: EncodedHistory, model, witness: bool = False,
 
 def kernel_tier(tag: str) -> str:
     """Decided-tier name of a kernel tag (ISSUE 13 attribution): the
-    mask kernel is its own (cheapest) tier, every other dense-family
-    kernel (domain / pallas / segmented) reports "dense", the sort
-    ladder "sort"."""
+    mask kernel is its own (cheapest) tier, the domain kernel reports
+    "dense", the sort ladder "sort"."""
     if "mask" in tag:
         return "mask"
     if "sort" in tag:

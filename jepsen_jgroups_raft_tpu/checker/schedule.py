@@ -122,14 +122,14 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # loaded by calling a key's shared trace at a row count
                # (`_RowShared`); on one device, every program of the set
                "programs_from_shared_trace": 0,
-               # wide windows (ISSUE 40): rows past SEGMENT_MAX_SLOTS that
+               # wide windows (ISSUE 40): rows past WIDE_WINDOW_SLOTS that
                # entered the kernel ladder and, of those, the rows a host
                # engine decided
                "wide_rows": 0, "wide_rows_host": 0,
                # long histories (ISSUE 44): rows of at least
-               # LONG_HISTORY_MIN_EVENTS events that entered the kernel
-               # ladder and, of those, the rows the segment route decided
-               "long_rows": 0, "long_rows_segmented": 0}
+               # LIN_FASTPATH_MAX_EVENTS events that entered the kernel
+               # ladder
+               "long_rows": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -164,8 +164,8 @@ def _add_stats(**kw) -> None:
 
 def note_wide(**kw) -> None:
     """Record the wide-window counters (ISSUE 40), like `note_cycle`:
-    `wide_rows`, `wide_rows_host`; and the long-history ones (ISSUE
-    44): `long_rows`, `long_rows_segmented`."""
+    `wide_rows`, `wide_rows_host`; and the long-history one (ISSUE
+    44): `long_rows`."""
     _add_stats(**kw)
 
 

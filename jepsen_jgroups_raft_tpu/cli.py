@@ -84,7 +84,7 @@ def _add_test_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--store", default="store",
                    help="results directory root")
     p.add_argument("--algorithm", default="auto",
-                   choices=["auto", "jax", "pallas", "cpu", "dfs", "race"],
+                   choices=["auto", "jax", "cpu", "dfs", "race"],
                    help="linearizability engine (:algorithm :jax analogue; "
                         "race = kernel vs DFS, first finisher wins, the "
                         "knossos.competition analogue)")
@@ -396,7 +396,7 @@ def main(argv=None) -> int:
     c.add_argument("--workload", default=None, choices=sorted(WORKLOADS),
                    help="override the workload recorded in test.json")
     c.add_argument("--algorithm", default="auto",
-                   choices=["auto", "jax", "pallas", "cpu", "dfs", "race"])
+                   choices=["auto", "jax", "cpu", "dfs", "race"])
     c.add_argument("--platform", default=None, choices=["cpu", "tpu"])
     c.set_defaults(fn=cmd_check)
     args = ap.parse_args(argv)

@@ -13,9 +13,10 @@ three pattern-level (PR 1), three CFG/dataflow (the graftcheck tier,
 * :mod:`.lock_discipline` — ``// GUARDED_BY(mu)`` fields in
   ``native/src`` are only touched under their mutex (or in
   ``// REQUIRES(mu)`` helpers).
-* :mod:`.flow.kernel_contract` — Pallas BlockSpec/grid/out_shape
-  arithmetic verified statically under sampled contract bindings,
-  with Mosaic tiling rules and a VMEM budget.
+* :mod:`.flow.kernel_contract` — the kernels' cap constants and
+  chunk-carry accounting held to a VMEM budget, and the
+  BlockSpec/grid/out_shape arithmetic of any ``pallas_call`` under
+  sampled contract bindings (no such call is in the tree since PR 50).
 * :mod:`.flow.heal` — every nemesis fault-injection path heals,
   registers for teardown, or carries ``# lint: allow(unhealed)``.
 * :mod:`.flow.resource` — acquire/release balance across exception

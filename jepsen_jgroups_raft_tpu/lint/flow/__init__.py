@@ -4,10 +4,12 @@ PR 1's graftlint analyzers are pattern-level — one AST shape, one
 finding. The invariants this package polices are *path* properties that
 pattern matching cannot express:
 
-* ``kernel_contract`` — Pallas/launch shape arithmetic (BlockSpec, grid,
-  out_shape, VMEM footprint) holds for every legal symbol binding, so a
-  kernel misconfiguration is a lint error before it is a runtime XLA
-  failure on paid TPU time.
+* ``kernel_contract`` — launch shape arithmetic (the kernels' caps and
+  chunk carries against the VMEM footprint; BlockSpec, grid and
+  out_shape of a ``pallas_call``, of which the tree holds none since
+  PR 50) holds for every legal symbol binding, so a kernel
+  misconfiguration is a lint error before it is a runtime XLA failure
+  on paid TPU time.
 * ``heal`` — every nemesis path that injects a fault reaches the
   matching heal/restore (or registers the affliction for teardown) on
   *all* exits including exception edges; deliberate unhealed faults

@@ -29,23 +29,22 @@ Rules
 ``kernel-vmem-budget``
     Per-program resident block bytes (Σ in/out blocks) exceed the VMEM
     budget (default ~12 MiB of the ~16 MiB/core, CLI-configurable), or
-    a contract's named budget invariant fails (e.g. ``tile_histories``
-    must keep the lane-expanded event block inside
-    ``_EVENTS_VMEM_BUDGET`` for every legal (S, E)).
+    a contract's named budget invariant fails (e.g. the chunked dense
+    carry plus a macro event slab at the caps, ``_ir_chunk_budget``).
 ``kernel-unresolved``
     The analyzer could not evaluate a shape it needed — a loud finding,
     never a silent pass, so adding symbols to a kernel without extending
     its contract fails the gate instead of going unchecked.
 
-Scan set (CLI): ``ops/kernel_ir.py``, ``ops/pallas_scan.py``,
-``ops/segment_scan.py``, ``ops/dense_scan.py``, ``ops/linear_scan.py``,
-``parallel/mesh.py``, ``history/packing.py`` — the kernel IR carries
-THE chunk-carry bindings for every family that chunks through it
-(``_ir_chunk_budget``; the per-family duplicates are gone, PR 6), the
-other non-Pallas files are covered for their declared cap/budget
+Scan set (CLI): ``ops/kernel_ir.py``, ``ops/dense_scan.py``,
+``ops/linear_scan.py``, ``parallel/mesh.py``, ``history/packing.py`` —
+the kernel IR carries THE chunk-carry bindings for every family that
+chunks through it (``_ir_chunk_budget``; the per-family duplicates are
+gone, PR 6), the other files are covered for their declared cap/budget
 constants (incl. the macro-event ``MACRO_MAX_OPENS`` payload cap, whose
-67-lane rows the Pallas tile and chunk-slab bindings sample) and for
-any ``pallas_call`` a future PR adds there.
+67-lane rows the chunk-slab bindings sample) and for any
+``pallas_call`` a future PR adds there (none is in the tree since the
+Pallas kernel left, PR 50).
 """
 
 from __future__ import annotations
@@ -86,36 +85,6 @@ class Contract:
     #: ("kernel-unresolved", ...) so it stays loud under a
     #: kernel-vmem-budget baseline.
     custom: Optional[callable] = None
-
-
-def _pallas_scan_tile_budget(interp: Interp) -> List[str]:
-    """tile_histories(S, E, R) must keep the lane-expanded event block
-    ([R·E, T·S] int32 = T·S·E·R·4 bytes) inside _EVENTS_VMEM_BUDGET for
-    every legal (S, E, R) — the exact invariant its docstring claims.
-    R samples both stream formats: 5 legacy fields and the widest
-    macro-event row (3 + 4·MACRO_MAX_OPENS = 67 lanes; the macro cap is
-    pinned by history/packing.py's own contract, so widening it fails
-    the gate until these bindings are re-proven)."""
-    out = []
-    budget = interp.module_env.get("_EVENTS_VMEM_BUDGET")
-    fn = interp.functions.get("tile_histories")
-    if not isinstance(budget, int) or fn is None:
-        return ["tile_histories/_EVENTS_VMEM_BUDGET not resolvable"]
-    for S in (1, 2, 4, 8, 16):
-        for E in (8, 64, 512, 4096, 131072):
-            for R in (5, 35, 67):
-                T = interp.exec_fn(fn, {"n_states": S, "n_events": E,
-                                        "row_ints": R})
-                if not isinstance(T, int):
-                    out.append(
-                        f"tile_histories({S}, {E}, {R}) not evaluable")
-                    continue
-                if T * S * E * R * 4 > budget and T > 1:
-                    out.append(
-                        f"tile_histories({S}, {E}, {R}) = {T}: event "
-                        f"block {T * S * E * R * 4} B exceeds "
-                        f"_EVENTS_VMEM_BUDGET {budget} B")
-    return out
 
 
 def _ir_chunk_budget(interp: Interp) -> List[str]:
@@ -240,42 +209,15 @@ def _ir_chunk_budget(interp: Interp) -> List[str]:
 
 
 CONTRACTS: Dict[str, Contract] = {
-    "ops/pallas_scan.py": Contract(
-        symbols={"W": (5,), "S": (1, 4, 16), "E": (8, 64, 512),
-                 "T": (1, 4, 32), "G": (1, 2, 8),
-                 "R": (5, 35, 67), "interpret": (False,)},
-        # the legal envelope tile_histories/make_pallas_batch_checker
-        # guarantee: lane axis filled but never overfilled, E padded to
-        # a multiple of 8 (Mosaic sublane rule — R is odd in both
-        # stream formats, so E itself carries the rule), and for T > 1
-        # the tile budget caps the lane-expanded event block at
-        # _EVENTS_VMEM_BUDGET (T = 1 is the irreducible minimum tile).
-        where=lambda b: (b["T"] * b["S"] <= 128 and b["E"] % 8 == 0
-                         and (b["T"] == 1 or
-                              b["T"] * b["S"] * b["E"] * b["R"] * 4
-                              <= 6 << 20)),
-        const_asserts=[
-            # Pinned EXACTLY at the value the where-clause envelope
-            # above samples (not just ≤ VMEM): raising the budget
-            # would legalize bigger tiles that the envelope would then
-            # silently stop sampling — fail here until both move
-            # together.
-            ("_EVENTS_VMEM_BUDGET", 6 << 20,
-             "events VMEM budget outgrew the contract's sampled "
-             "envelope (the where-clause bound); move both together"),
-            ("_LANE_TARGET", 128, "lane target beyond the 128-lane VPU"),
-        ],
-        custom=_pallas_scan_tile_budget,
-    ),
     "history/packing.py": Contract(const_asserts=[
         # The macro payload cap is load-bearing for every kernel
-        # family's proven bindings: the Pallas tile budget and the
-        # chunk-slab checks sample rows at 3 + 4·16 = 67 lanes, so a
-        # cap bump must fail here until those bindings are re-proven.
+        # family's proven bindings: the chunk-slab checks sample rows
+        # at 3 + 4·16 = 67 lanes, so a cap bump must fail here until
+        # those bindings are re-proven.
         ("MACRO_MAX_OPENS", 16,
          "macro open cap outgrew the proven kernel-contract bindings "
-         "(R = 67-lane rows); re-prove the Pallas tile and chunk-slab "
-         "budgets before raising it"),
+         "(R = 67-lane rows); re-prove the chunk-slab budgets before "
+         "raising it"),
         ("3 + 4 * MACRO_MAX_OPENS", 67,
          "macro row width beyond the proven R samples"),
     ]),
@@ -322,12 +264,6 @@ CONTRACTS: Dict[str, Contract] = {
     "ops/linear_scan.py": Contract(const_asserts=[
         ("MAX_SLOTS", 127,
          "window cap would consume the sentinel bit of the last word"),
-    ]),
-    "ops/segment_scan.py": Contract(const_asserts=[
-        ("MAX_BASIS * SEGMENT_MAX_CELLS * 4", 16 << 20,
-         "segment seed-basis frontier at the caps exceeds VMEM"),
-        ("DEFAULT_BLOCK_EVENTS * 5 * 4", 16 << 20,
-         "segment event slab exceeds VMEM"),
     ]),
     "parallel/mesh.py": Contract(),
 }
@@ -390,7 +326,7 @@ def _merge_sibling_consts(interp: Interp, tree: ast.Module,
                           path: str) -> None:
     """Resolve relative-import constants (`from .sibling import NAME`,
     `from ..pkg.mod import NAME`) so cross-module cap expressions stay
-    checkable — segment_scan uses dense_scan's caps, and dense_scan's
+    checkable — dense_scan re-asserts kernel_ir's caps, and kernel_ir's
     macro-row bindings use history/packing.py's MACRO_MAX_OPENS."""
     base = Path(path).parent
     for stmt in tree.body:

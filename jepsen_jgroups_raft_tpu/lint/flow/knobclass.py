@@ -91,7 +91,6 @@ KNOB_CLASS: Dict[str, str] = {
     "JGRAFT_GREEDY_CERTIFY": ROUTING,
     "JGRAFT_GROUP_DEVICES": ROUTING,
     "JGRAFT_HOIST": ROUTING,
-    "JGRAFT_KERNEL": ROUTING,
     "JGRAFT_LIN_FASTPATH": ROUTING,
     "JGRAFT_LIN_FASTPATH_ABORT": ROUTING,
     "JGRAFT_LIN_FASTPATH_MIN_OBS": ROUTING,
@@ -108,7 +107,6 @@ KNOB_CLASS: Dict[str, str] = {
     # space); no knob touches how any candidate's verdict is computed
     "JGRAFT_SEARCH_EDIT_SPACE": ROUTING,
     "JGRAFT_SEARCH_GUIDED": ROUTING,
-    "JGRAFT_SEGMENT": ROUTING,
     "JGRAFT_SERVICE_BATCH_WAIT_MS": ROUTING,
     "JGRAFT_SERVICE_MAX_BATCH_ROWS": ROUTING,
     "JGRAFT_STREAM_GREEDY_MAX_EVENTS": ROUTING,

@@ -62,8 +62,8 @@ class Model:
 
     def cache_key(self) -> tuple:
         """Hashable identity of this model's compiled-kernel semantics.
-        Every kernel cache (ops/dense_scan, ops/pallas_scan,
-        ops/linear_scan, parallel/mesh) keys on it. The default assumes a
+        Every kernel cache (ops/dense_scan, ops/linear_scan,
+        parallel/mesh) keys on it. The default assumes a
         model is fully determined by its class + initial state; a subclass
         whose `jax_step`/`mask_delta` depends on extra constructor
         parameters MUST extend the tuple, or equivalent-looking models

@@ -13,8 +13,6 @@ routing layer (doc/checker-design.md):
   (register) and order-independent models (counter, mask mode); exact,
   overflow-free.
 * `linear_scan` — the general sort-dedup frontier scan (windows ≤127).
-* `pallas_scan` — the dense scan as a Pallas kernel, frontier in VMEM
-  (opt-in via JGRAFT_KERNEL=pallas).
 """
 
 from .dense_scan import (  # noqa: F401

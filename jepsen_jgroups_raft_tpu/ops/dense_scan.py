@@ -175,8 +175,8 @@ def _merge_long_groups() -> bool:
     MERGE_LONG_MAX_SPREAD — and the default is TPU-ONLY: the host mesh
     is throughput-bound at these widths, so the same merge that wins
     1.36× on the chip measured config-4 CPU at 0.61 hist/s vs 1.34
-    per-window (2026-07-31 CPU suite) — the segment-routing asymmetry
-    again. JGRAFT_MERGE_LONG=1 forces merged anywhere, =0 forbids."""
+    per-window (2026-07-31 CPU suite). JGRAFT_MERGE_LONG=1 forces merged
+    anywhere, =0 forbids."""
     forced = os.environ.get("JGRAFT_MERGE_LONG")
     if forced is not None:
         return forced == "1"
@@ -632,15 +632,13 @@ def expand_packed(w: int, F, R_w, n_states: int):
 def hoist_transitions() -> bool:
     """Whether the DOMAIN kernel keeps transition matrices in the scan
     carry (refreshed once per OPEN) instead of re-deriving them from
-    model.jax_step inside every closure sweep. (The segment kernel
-    stays carry-hoisted unconditionally: its auto route is TPU-only —
-    where hoisted is the measured winner — and CPU reaches it only via
-    the JGRAFT_SEGMENT=1 correctness soaks. The mask kernel's legality
-    hoist won on BOTH platforms and has no style switch.) Backend-keyed
-    at build time, measured 2026-07-31 both ways on idle hardware:
+    model.jax_step inside every closure sweep. (The mask kernel's
+    legality hoist won on BOTH platforms and has no style switch.)
+    Backend-keyed at build time, measured 2026-07-31 both ways on idle
+    hardware:
 
       * v5e: hoisted wins every affected config (config 4 merged
-        2.415 → 2.15-2.33 s, config 5 segmented 4.7 → 3.96 s) — per
+        2.415 → 2.15-2.33 s) — per
         step, fusion count is the wall and the hoist removes W
         jax_step+T builds from each sweep iteration.
       * CPU host: hoisted LOSES big at small batch (config 5 B=1

@@ -4,8 +4,8 @@ instantiates.
 Before this module existed, every performance feature grew 4× by hand:
 chunking (ISSUE 3) and macro-event compaction (ISSUE 4) were each ported
 separately into the dense/mask kernels (ops/dense_scan.py), the sort
-ladder (ops/linear_scan.py), the segment kernel and the Pallas tile
-kernel — four copies of the event-row decode, the macro-latch
+ladder (ops/linear_scan.py) and two kernels since deleted (PR 50) —
+four copies of the event-row decode, the macro-latch
 application, the arithmetic FORCE dispatch, the chunk-carry schema and
 the decided/exhausted flag semantics. This module is the single home of
 that shared machinery; each family now keeps ONLY its state-
@@ -98,15 +98,9 @@ DENSE_MAX_SLOTS = 13
 DENSE_MAX_STATES = 16
 DENSE_MAX_CELLS = 65536  # 2^W · S
 
-#: The caps every route held until ISSUE 40. The segmented long-history
-#: route (ops/segment_scan.py) keeps them: MAX_BASIS frontiers of this
-#: many cells are what the kernel-contract analyzer holds to the VMEM
-#: budget. A window past SEGMENT_MAX_SLOTS is WIDE: only the plain dense
-#: family holds it, and `ops/dense_scan.TPU_GROUP_COST` was first read
-#: up to it (`/stats` `wide_rows`, `wide_rows_host`: counted, never
-#: routed on).
-SEGMENT_MAX_SLOTS = 10
-SEGMENT_MAX_CELLS = 8192
+#: A window past this is WIDE in `/stats` `wide_rows` / `wide_rows_host`
+#: (the dense cap until ISSUE 40): counted, never routed on.
+WIDE_WINDOW_SLOTS = 10
 
 #: Mask mode has no state dimension (S² → 1), so it affords a wider
 #: window: 2^12 bool cells + an int32 subset-sum lane per history.
@@ -145,7 +139,7 @@ CYCLE_TILE = 256
 
 def scan_unroll() -> int:
     """Events per lax.scan step across the event-scan kernels (dense,
-    mask, segment, sort) — an ablation knob, JGRAFT_SCAN_UNROLL to
+    mask, sort) — an ablation knob, JGRAFT_SCAN_UNROLL to
     override. Default 1 EVERYWHERE: CPU-mesh measurements did not
     survive re-measurement through the production path (a hand-built
     kernel probe showed unroll=2 at 1.49× on a B=4 × 15.7k-event
@@ -168,7 +162,7 @@ def macro_row_ints(macro_p: int = MACRO_MAX_OPENS) -> int:
     can emit (the MACRO_MAX_OPENS cap). Pure arithmetic on purpose —
     the kernel-contract analyzer (lint/flow/kernel_contract.py)
     executes it statically at the cap to re-prove the chunk event slabs
-    and the Pallas lane-expanded block against the VMEM budgets."""
+    against the VMEM budgets."""
     return 3 + 4 * macro_p
 
 
@@ -250,9 +244,9 @@ def force_arith(F, slot_w):
     comparison.
 
     F: the configuration axis M leading, of either representation —
-    [M, S] bool (the mask family and the segmented route, S = 1 or the
-    padded states) or [M] words whose bits are the states (the domain
-    family, ops/dense_scan.dense_step_parts); a configuration is dead
+    [M, 1] bool (the mask family) or [M] words whose bits are the states
+    (the domain family, ops/dense_scan.dense_step_parts); a
+    configuration is dead
     where its entry is all False / 0. slot_w pre-clipped to [0, W).
     Returns (F', any_survivor)."""
     M = F.shape[0]

@@ -6,7 +6,7 @@ compaction — amortizes kernel work across the ROWS of one caller's
 batch. This module extends the amortization across CALLERS: pending
 requests whose encodings pack into the same shape bucket are coalesced
 into one `check_encoded` batch, so many small tenant histories ride a
-single dense/sort/Pallas launch; per-request verdicts are demuxed back
+single dense or sort launch; per-request verdicts are demuxed back
 by row count after the wavefront evicts them.
 
 Soundness of the coalescing (doc/checker-design.md §8): every kernel

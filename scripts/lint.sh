@@ -5,7 +5,7 @@
 #                   ship it; CI images may).
 #   2. graftlint  — the pattern analyzers: taxonomy soundness, jit/trace
 #                   hygiene, native lock discipline.
-#   3. graftcheck — the CFG/dataflow tier (lint/flow/): Pallas kernel
+#   3. graftcheck — the CFG/dataflow tier (lint/flow/): kernel
 #                   contracts, nemesis fault↔heal pairing, resource leaks
 #                   across exception paths; gated on the checked-in
 #                   baseline (lint/baseline.json) so only REGRESSIONS fail.

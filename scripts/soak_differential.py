@@ -66,7 +66,7 @@ def parse_args(argv=None):
     p.add_argument("--strict-unknown", action="store_true",
                    help="treat product-path unknown verdicts as failures")
     p.add_argument("--product-algorithm", default="auto",
-                   choices=["auto", "jax", "pallas", "race", "dfs"],
+                   choices=["auto", "jax", "race", "dfs"],
                    help="algorithm for the product path — soaks every "
                         "engine behind the same oracle (default auto)")
     p.add_argument("--pin-capacity", type=int, default=None,
@@ -74,8 +74,8 @@ def parse_args(argv=None):
                         "(n_configs) — routes kernel-checked histories "
                         "through the general sort kernel instead of the "
                         "dense planner (auto's wide-window DFS rung still "
-                        "applies; incompatible with pallas/dfs, which "
-                        "would silently ignore or bypass the pin)")
+                        "applies; incompatible with dfs, which would "
+                        "silently ignore the pin)")
     p.add_argument("--platform", default="cpu", choices=["cpu", "default"],
                    help="cpu (default; pinned 8-device host mesh, "
                         "reproducible anywhere) or default backend (TPU "
@@ -86,11 +86,9 @@ def parse_args(argv=None):
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.pin_capacity is not None and \
-            args.product_algorithm in ("pallas", "dfs"):
-        # A pinned capacity disables dense-group planning, so "pallas"
-        # would silently measure the sort kernel (and dfs ignores the
-        # pin entirely) — refuse rather than produce mislabeled
-        # evidence (round-4 review finding).
+            args.product_algorithm == "dfs":
+        # dfs ignores the pin entirely — refuse rather than produce
+        # mislabeled evidence (round-4 review finding).
         print("--pin-capacity is incompatible with "
               f"--product-algorithm {args.product_algorithm}",
               file=sys.stderr)

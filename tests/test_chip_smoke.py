@@ -19,7 +19,7 @@ SMOKE = os.path.join(REPO, "chip_smoke.py")
 ONE_CHIP_PHASES = [
     "gate", "compile-cache", "synth", "library-default",
     "library-forced-kernel", "library-host-reference", "service",
-    "family-mask", "family-sort", "family-segment", "family-cycle",
+    "family-mask", "family-sort", "family-cycle",
     "compile-cache-after", "done"]
 FOUR_CHIP_PHASES = ["gate", "compile-cache", "mesh", "compile-cache-after",
                     "done"]

@@ -7,9 +7,11 @@ import pytest
 from jepsen_jgroups_raft_tpu.cli import main
 from jepsen_jgroups_raft_tpu.core.serve import _index_html, _run_dirs
 
-pytestmark = pytest.mark.slow
+#: runs a deployment or a server: seconds each
+slow = pytest.mark.slow
 
 
+@slow
 def test_cli_test_command_local_native(tmp_path):
     """Full CLI run over the local native deployment: exit 0 and a
     populated store dir."""
@@ -33,6 +35,7 @@ def test_cli_test_command_local_native(tmp_path):
     assert results["valid?"] is True
 
 
+@slow
 def test_cli_test_command_inmemory_with_nemesis(tmp_path):
     store = tmp_path / "store"
     rc = main([
@@ -50,6 +53,18 @@ def test_cli_rejects_unknown_workload():
         main(["test", "--workload", "nope"])
 
 
+@pytest.mark.parametrize("argv", [["test"], ["check", "some-run"]],
+                         ids=["test", "check"])
+def test_algorithm_pallas_is_refused(argv, capsys):
+    """The Pallas arm left with PR 50: both parsers that take
+    `--algorithm` refuse the name in argparse's own words."""
+    with pytest.raises(SystemExit) as e:
+        main(argv + ["--algorithm", "pallas"])
+    assert e.value.code == 2
+    assert "invalid choice: 'pallas'" in capsys.readouterr().err
+
+
+@slow
 def test_serve_index_lists_runs(tmp_path):
     run = tmp_path / "store" / "t" / "20260729T000000"
     run.mkdir(parents=True)
@@ -64,6 +79,7 @@ def test_serve_index_lists_runs(tmp_path):
     assert "history.jsonl" in page
 
 
+@slow
 def test_serve_http_end_to_end(tmp_path):
     """The results server over real HTTP: index lists a recorded run
     with its verdict badge, artifact files are fetchable, and path
@@ -109,6 +125,7 @@ def test_serve_http_end_to_end(tmp_path):
         httpd.server_close()
 
 
+@slow
 def test_cli_weak_election_flag_reverts_to_parity_model(tmp_path):
     """--weak-election must reach the workload (VERDICT r4 #5): the
     default election run checks the cross-node majority model (its

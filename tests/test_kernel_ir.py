@@ -4,7 +4,7 @@ The kernel IR (ops/kernel_ir.py) now owns the stream decode, macro
 latch, FORCE dispatch, chunk-carry schema and both drivers; every
 family only supplies its state lowering. These tests prove the
 refactor preserved behavior bit for bit: for each family (dense
-domain, dense mask, sort; Pallas in interpret mode) × stream format
+domain, dense mask, sort) × stream format
 (macro on/off) × driver (monolithic vs chunked), verdicts are
 identical to each other and to the CPU oracle — the exact contract
 the pre-refactor per-family code was pinned to by
@@ -161,31 +161,6 @@ class TestSortFamily:
         assert not ovf_chunk.any()
         assert list(np.asarray(ok_mono)) == oracle
         assert list(ok_chunk) == oracle
-
-
-class TestPallasFamily:
-    @pytest.mark.parametrize("macro", [False, True])
-    def test_pallas_interpret_matches_oracle(self, macro):
-        # Interpret mode is slow: one small batch per stream format.
-        from jepsen_jgroups_raft_tpu.ops.pallas_scan import (
-            make_pallas_batch_checker)
-
-        model = CasRegister()
-        encs, oracle = _mixed_batch("register", model, n=4, n_ops=10,
-                                    seed=31)
-        plan = dense_plan(model, encs)
-        assert plan is not None and plan.kind == "domain"
-        macro_p = None
-        if macro:
-            batch = pack_macro_batch(encs)
-            macro_p = batch["macro_p"]
-        else:
-            batch = pack_batch(encs)
-        kern = make_pallas_batch_checker(
-            model, plan.n_slots, plan.n_states, batch["events"].shape[1],
-            interpret=True, macro_p=macro_p)
-        ok, _ = kern(batch["events"], plan.val_of)
-        assert list(np.asarray(ok)) == oracle
 
 
 class TestIrPieces:

@@ -152,22 +152,16 @@ def build():
 
 
 class TestKernelContract:
-    def test_production_pallas_kernel_resolves_and_passes(self):
-        # acceptance: every production kernel in ops/ accepted unchanged —
-        # and NOT vacuously (the call is found and evaluated under the
-        # full contract sample set).
-        src = SourceFile.load(PKG / "ops" / "pallas_scan.py")
-        import ast
-        calls = kernel_contract._enclosing_chain(ast.parse(src.text))
-        assert len(calls) == 1
-        contract = kernel_contract._contract_for(src.path)
-        assert len(kernel_contract._bindings(contract)) > 10
-        assert kernel_contract.analyze_source(src) == []
+    def test_every_contract_names_a_file_of_the_tree(self):
+        # a binding for a deleted module fails here, not at the next
+        # scripts/lint.sh: the CLI only scans the files it finds
+        gone = [f for f in kernel_contract.CONTRACTS
+                if not (PKG / f).is_file()]
+        assert not gone, gone
 
     def test_production_shape_files_clean(self):
         for f in ("ops/kernel_ir.py", "ops/dense_scan.py",
-                  "ops/linear_scan.py", "ops/segment_scan.py",
-                  "parallel/mesh.py"):
+                  "ops/linear_scan.py", "parallel/mesh.py"):
             src = SourceFile.load(PKG / Path(f))
             assert kernel_contract.analyze_source(src) == [], f
 
@@ -261,14 +255,15 @@ class TestKernelContract:
         assert "kernel-unresolved" in rules_of(kc(bad))
 
     def test_budget_const_contract_fires_on_mutated_budget(self):
-        # pallas_scan's contract pins _EVENTS_VMEM_BUDGET under usable
+        # kernel_ir's contract pins the dense cell cap under usable
         # VMEM; inflating it must fail the gate
-        text = (PKG / "ops" / "pallas_scan.py").read_text()
-        assert "_EVENTS_VMEM_BUDGET = 6 << 20" in text
-        mutated = text.replace("_EVENTS_VMEM_BUDGET = 6 << 20",
-                               "_EVENTS_VMEM_BUDGET = 64 << 20")
-        found = kc(mutated, path="ops/pallas_scan.py")
-        assert "kernel-vmem-budget" in rules_of(found)
+        text = (PKG / "ops" / "kernel_ir.py").read_text()
+        assert "DENSE_MAX_CELLS = 65536" in text
+        mutated = text.replace("DENSE_MAX_CELLS = 65536",
+                               "DENSE_MAX_CELLS = 65536 << 7")
+        found = kc(mutated, path="ops/kernel_ir.py")
+        assert any(f.rule == "kernel-vmem-budget"
+                   and "DENSE_MAX_CELLS * 4" in f.message for f in found)
 
 
 # ------------------------------------------------------------------ heal

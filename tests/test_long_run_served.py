@@ -1,15 +1,16 @@
 """The `register-100k` deployment of BENCHMARK.json (ISSUE 44), held on
 the CPU at a size a test can hold: seeded `synth` register histories
-long enough to be LONG for every router (past `LONG_HISTORY_MIN_EVENTS`
-8,192 events: 5,800-6,400 ops at the generator's ~1.46 events an op),
-valid, perturbed and planted, through a `CheckingService` one a request
-against the plain reference, by the chunked LONG route and by the
-segment route; the LONG keys of the launch-shape set (the two ladders,
-the padded launch against the unpadded one, programs shared inside a
-ladder step, the host's record built at graftd's start); the fast
-lane's length cap; the routing rule; and the tracing that came with the
-cell: span `launch.segment`, counters `long_rows` and
-`long_rows_segmented`, the three readers, the generator's gate."""
+long enough to be LONG (past `LIN_FASTPATH_MAX_EVENTS` 8,192 events:
+5,800-6,400 ops at the generator's ~1.46 events an op, and 10,000 ops,
+the second shape PR 44 read on the chip), valid, perturbed and planted,
+through a `CheckingService` one a request and two of unlike windows a
+request against the plain reference, as chunked LONG launches; the LONG
+keys of the launch-shape set (the two ladders, the padded launch
+against the unpadded one, programs shared inside a ladder step, the
+host's record built at graftd's start); the fast lane's length cap; and
+the tracing that came with the cell: counter `long_rows`, the readers
+(two of them read 0 since PR 50 deleted the segment route they guarded,
+from a constant `/stats` serves for them), the generator's gate."""
 
 import json
 import random
@@ -32,19 +33,18 @@ from util_bench import bare_ctx, example_ctx  # noqa: E402
 from jepsen_jgroups_raft_tpu.checker import autotune, linearizable  # noqa: E402
 from jepsen_jgroups_raft_tpu.checker import schedule  # noqa: E402
 from jepsen_jgroups_raft_tpu.checker.linearizable import (  # noqa: E402
-    check_encoded, fastpath_counters, lin_fastpath_plan)
+    LIN_FASTPATH_MAX_EVENTS, check_encoded, fastpath_counters,
+    lin_fastpath_plan)
 from jepsen_jgroups_raft_tpu.checker.schedule import (  # noqa: E402
     LONG_WIDTH_STEPS, build_dense_launches, launch_shapes, launch_width,
     long_rows, long_width, run_chunked, snapshot_built, snapshot_compiles,
-    snapshot_spans, snapshot_stats)
+    snapshot_stats)
 from jepsen_jgroups_raft_tpu.history.packing import (  # noqa: E402
     encode_history, pack_macro_batch)
 from jepsen_jgroups_raft_tpu.history.synth import build_history  # noqa: E402
 from jepsen_jgroups_raft_tpu.models import CasRegister  # noqa: E402
 from jepsen_jgroups_raft_tpu.ops.dense_scan import (  # noqa: E402
     MERGE_MAX_EVENTS, dense_plans_grouped, make_dense_batch_checker)
-from jepsen_jgroups_raft_tpu.ops.segment_scan import (  # noqa: E402
-    LONG_HISTORY_MIN_EVENTS)
 from jepsen_jgroups_raft_tpu.platform import install_compile_counters  # noqa: E402
 from jepsen_jgroups_raft_tpu.service import buildahead  # noqa: E402
 from jepsen_jgroups_raft_tpu.service.daemon import CheckingService  # noqa: E402
@@ -56,10 +56,10 @@ REF = mf.load_module(ROOT, "references", CONFIG["reference"])
 WAIT_S = 300.0
 MODEL = CasRegister()
 KINDS = ("valid", "perturbed", "planted")
-#: the routes a long row can take, with what makes the CPU take each:
-#: the segment route is the TPU's unless forced, and its CPU cost gate
-#: (`CPU_STEP_CELL_BUDGET`) holds a basis of one crashed slot
-ROUTES = {"chunked": ("0", 3), "segment": ("1", 1)}
+#: (ops of the first served history, more ops a history after it) by the
+#: fixture's parameter: just past the LONG threshold, and the 10k-op
+#: history PR 44 read on the chip (~14.6k events)
+LENGTHS = {"6k-ops": (5800, 300), "10k-ops": (10_000, 0)}
 
 
 def rows_of(seed, n_ops, max_crashes=3, kind="valid"):
@@ -77,26 +77,20 @@ def encoded(rows):
     return encode_history(build_history(rows), MODEL)
 
 
-def spans_n(name):
-    return snapshot_spans().get(name, {"n": 0})["n"]
+# ------------------------------------------------------------- served
 
 
-# ------------------------------------------------- served, both routes
-
-
-@pytest.fixture(scope="module", params=sorted(ROUTES))
+@pytest.fixture(scope="module", params=sorted(LENGTHS))
 def served(request):
-    """Three long histories of the configuration, one a request, through
-    a `CheckingService` by one route; what came back, what the reference
-    says, and what the registry counted meanwhile."""
-    forced, max_crashes = ROUTES[request.param]
-    mp = pytest.MonkeyPatch()
-    mp.setenv("JGRAFT_SEGMENT", forced)
-    hists = {kind: rows_of(4400 + 7 * k, 5800 + 300 * k, max_crashes, kind)
+    """Three long histories of the configuration at one length, one a
+    request, through a `CheckingService`; what came back and what the
+    registry counted meanwhile."""
+    n_ops, step = LENGTHS[request.param]
+    hists = {kind: rows_of(4400 + 7 * k, n_ops + step * k, kind=kind)
              for k, kind in enumerate(KINDS)}
     for h in hists.values():
-        assert encoded(h).n_events >= LONG_HISTORY_MIN_EVENTS
-    before = dict(snapshot_stats(), segments=spans_n("launch.segment"))
+        assert encoded(h).n_events >= LIN_FASTPATH_MAX_EVENTS
+    before = snapshot_stats()
     misses = snapshot_compiles()["shape_misses"]
     svc = CheckingService(store_root=None)
     got = {}
@@ -109,13 +103,10 @@ def served(request):
         stats = svc.stats()
     finally:
         svc.shutdown(wait=True)
-        mp.undo()
-    after = dict(snapshot_stats(), segments=spans_n("launch.segment"))
-    return {"route": request.param, "hists": hists, "got": got,
-            "stats": stats,
+    return {"hists": hists, "got": got, "stats": stats,
             "shape_misses": stats["shape_misses"] - misses,
-            "moved": {k: after[k] - before[k] for k in (
-                "long_rows", "long_rows_segmented", "segments")}}
+            "long_rows": snapshot_stats()["long_rows"]
+            - before["long_rows"]}
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -124,26 +115,17 @@ def test_a_served_long_history_agrees_with_the_reference(served, kind):
     assert want is (kind == "valid") or kind == "perturbed"
     res = served["got"][kind]
     assert res["valid?"] is want
-    assert res["decided-tier"] == "dense"
-    assert res["kernel"] == ("dense-seg" if served["route"] == "segment"
-                             else "dense")
+    assert res["decided-tier"] == "dense" and res["kernel"] == "dense"
+    assert res["chunked"] is True
 
 
 def test_the_registry_counts_the_long_rows_that_were_sent(served):
-    segmented = 3 if served["route"] == "segment" else 0
-    assert served["moved"] == {"long_rows": 3,
-                               "long_rows_segmented": segmented,
-                               "segments": segmented}
-    # `/stats` serves the two counters and the span, from the totals
-    for name in ("long_rows", "long_rows_segmented"):
-        assert served["stats"][name] >= served["moved"][name]
-    if segmented:
-        assert served["stats"]["spans"]["launch.segment"]["n"] >= 3
+    assert served["long_rows"] == 3
+    # `/stats` serves the counter, from the totals
+    assert served["stats"]["long_rows"] >= 3
 
 
 def test_a_served_long_launch_has_a_key_of_the_set(served):
-    if served["route"] != "chunked":
-        pytest.skip("the segment kernel is outside the set")
     keys = [k for k in served["stats"]["build_keys"] if k["long"]]
     assert keys, served["stats"]["build_keys"]
     for k in keys:
@@ -151,6 +133,31 @@ def test_a_served_long_launch_has_a_key_of_the_set(served):
         assert k["width"] == long_width(k["width"]) and k["rows"] == [1]
         assert k["met"] in ("launch", "start")
     assert served["shape_misses"] == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_two_long_rows_of_unlike_windows_in_one_request_agree_with_the_reference(  # noqa: E501
+        kind):
+    """The shape PR 44 read on the chip at 1.91 s a pair: two long
+    histories of one request whose windows differ (no op crashed in the
+    first, up to three in the second), each against the reference."""
+    pair = [rows_of(4900, 5800, max_crashes=0, kind=kind),
+            rows_of(4907, 6100, max_crashes=3, kind=kind)]
+    encs = [encoded(h) for h in pair]
+    assert encs[0].n_slots < encs[1].n_slots
+    assert min(e.n_events for e in encs) >= LIN_FASTPATH_MAX_EVENTS
+    before = snapshot_stats()["long_rows"]
+    svc = CheckingService(store_root=None)
+    try:
+        r = svc.submit([build_history(h) for h in pair],
+                       workload="register")
+        assert r.wait(WAIT_S) and r.status == "done", r.error
+    finally:
+        svc.shutdown(wait=True)
+    assert snapshot_stats()["long_rows"] == before + 2
+    for h, res in zip(pair, r.results):
+        assert res["valid?"] is frontier.linearizable(h, REF)
+        assert res["kernel"] == "dense" and res["chunked"] is True
 
 
 # ------------------------------------------------------ the two ladders
@@ -292,29 +299,7 @@ def test_the_fast_lane_does_not_scan_a_long_row(monkeypatch):
     plan = lin_fastpath_plan([long_enc, short_enc], MODEL)
     assert [idxs for _, idxs in plan] == [[1]]
     assert fastpath_counters()["rows_gated"] == gated + 1
-    assert linearizable.LIN_FASTPATH_MAX_EVENTS == LONG_HISTORY_MIN_EVENTS
-
-
-# ------------------------------------------------------ the routing rule
-
-
-@pytest.mark.parametrize("forced,backend,want", [
-    ("1", "cpu", True), ("1", "tpu", True), ("0", "tpu", False),
-    (None, "cpu", False), (None, "tpu", False)])
-def test_which_route_auto_sends_long_rows(monkeypatch, forced, backend,
-                                          want):
-    """What PR 44 read on the chip (PERF.md section 6): the chunked LONG
-    launch decides one 100k-op history 1.8 times sooner than the segment
-    route, and shorter and paired ones by more, so `auto` sends it
-    none on any backend; the knob still forces it."""
-    import jax
-
-    if forced is None:
-        monkeypatch.delenv("JGRAFT_SEGMENT", raising=False)
-    else:
-        monkeypatch.setenv("JGRAFT_SEGMENT", forced)
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert linearizable._segment_routing_on() is want
+    assert long_enc.n_events >= LIN_FASTPATH_MAX_EVENTS == 8192
 
 
 # ------------------------------------------------------------ the readers
@@ -360,11 +345,52 @@ def test_a_parent_with_spans_and_without_the_counters_reads_nothing(name):
     assert reader(name).read(bare_ctx(reader(name).EXAMPLE)) is None
 
 
+@pytest.fixture(scope="module")
+def window():
+    """A reader's context as `benchmarks/run.py` makes it, from the
+    `/stats` of a real `CheckingService` at the two ends of a window in
+    which one long row was served."""
+    svc = CheckingService(store_root=None)
+    try:
+        before = svc.stats()
+        r = svc.submit([build_history(rows_of(4950, 5800, max_crashes=0))],
+                       workload="register")
+        assert r.wait(WAIT_S) and r.status == "done", r.error
+        after = svc.stats()
+    finally:
+        svc.shutdown(wait=True)
+    empty = {"fastpath": {}, "tiers": {}}
+    return dict(example_ctx({}), before={"stats": before, **empty},
+                after={"stats": after, **empty})
+
+
+def test_stats_serve_long_rows_and_of_the_segment_route_a_zero(window):
+    before, after = window["before"]["stats"], window["after"]["stats"]
+    assert after["long_rows"] == before["long_rows"] + 1
+    # the constant the benchmark's readers need, and no span
+    assert before["long_rows_segmented"] == 0 == after["long_rows_segmented"]
+    assert "launch.segment" not in after["spans"]
+    assert after["spans"]["launch.device"]["n"] > \
+        before["spans"]["launch.device"]["n"]
+
+
+@pytest.mark.parametrize("name", ["segment_share",
+                                  "long_rows_segmented_share"])
+def test_the_two_routing_readers_read_zero_from_this_program(window, name):
+    """PR 50 deleted the route the two guarded: in a window with a long
+    row both read 0.0, as on every ledger line since PR 44, until a
+    `benchmark` PR takes them out (ROADMAP B1 (s)). The harness's own
+    `test_span_metrics.py` wants `segment_share` finite in a rehearsal,
+    so `null` is not this PR's to give."""
+    assert reader(name).read(window) == 0.0
+    assert reader("programs_built_in_window").read(window) is not None
+
+
 # ------------------------------------------------- the generator's gate
 
 
 @pytest.mark.parametrize("cap,ops,served", [
-    (LONG_HISTORY_MIN_EVENTS, 100_000, True),   # this tree, the file
+    (LIN_FASTPATH_MAX_EVENTS, 100_000, True),   # this tree, the file
     (None, 100_000, False),                     # the tree ISSUE 44 met
     (1 << 20, 100_000, False),                  # a cap past the rows
     (None, 80, True), (None, 2_000, True)])     # rows the lane may scan

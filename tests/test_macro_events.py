@@ -2,8 +2,7 @@
 differentials across the dense/mask/sort kernels and the chunked
 scheduler (incl. crashed-op trailing latches, P-bucket boundary shapes,
 pad_batch_bucketed round-trips, the JGRAFT_MACRO_EVENTS env-gate
-ablation), a Pallas interpret-mode differential, and the per-run
-scan-stats scope."""
+ablation), and the per-run scan-stats scope."""
 
 import functools
 import json
@@ -279,41 +278,6 @@ def test_sort_kernel_overflow_flags_match():
             model, C, 8, macro_p=mac["macro_p"])(mac["events"])
         np.testing.assert_array_equal(np.asarray(ok1), np.asarray(ok2))
         np.testing.assert_array_equal(np.asarray(ov1), np.asarray(ov2))
-
-
-def test_pallas_interpret_macro_differential():
-    """Tiny-shape Pallas differential in interpret mode: the macro tile
-    kernel agrees with the legacy tile kernel and the XLA dense kernel."""
-    from jepsen_jgroups_raft_tpu.ops.pallas_scan import (
-        make_pallas_batch_checker)
-
-    rng = random.Random(41)
-    model = CasRegister()
-    hists = [corrupt(rng, random_valid_history(rng, "register", n_ops=10))
-             if i % 2 else random_valid_history(rng, "register", n_ops=10)
-             for i in range(4)]
-    encs = [encode_history(h, model) for h in hists]
-    grouped, _ = dense_plans_grouped(model, encs)
-    for idxs, plan in grouped:
-        if plan.kind != "domain":
-            continue
-        sub = [encs[i] for i in idxs]
-        legacy, mac = pack_batch(sub), pack_macro_batch(sub)
-        ok_ref, _ = make_dense_batch_checker(
-            model, plan.kind, plan.n_slots, plan.n_states)(
-                legacy["events"], plan.val_of)
-        ok_leg, _ = make_pallas_batch_checker(
-            model, plan.n_slots, plan.n_states,
-            legacy["events"].shape[1], interpret=True)(
-                legacy["events"], plan.val_of)
-        ok_mac, _ = make_pallas_batch_checker(
-            model, plan.n_slots, plan.n_states, mac["events"].shape[1],
-            interpret=True, macro_p=mac["macro_p"])(
-                mac["events"], plan.val_of)
-        np.testing.assert_array_equal(np.asarray(ok_ref),
-                                      np.asarray(ok_leg))
-        np.testing.assert_array_equal(np.asarray(ok_ref),
-                                      np.asarray(ok_mac))
 
 
 # --------------------------------------------------------------- env gate

@@ -35,7 +35,7 @@ from jepsen_jgroups_raft_tpu.history.packing import encode_history  # noqa: E402
 from jepsen_jgroups_raft_tpu.history.synth import build_history  # noqa: E402
 from jepsen_jgroups_raft_tpu.models import CasRegister  # noqa: E402
 from jepsen_jgroups_raft_tpu.ops.kernel_ir import (  # noqa: E402
-    DENSE_MAX_SLOTS, SEGMENT_MAX_SLOTS)
+    DENSE_MAX_SLOTS, WIDE_WINDOW_SLOTS)
 from jepsen_jgroups_raft_tpu.service.daemon import CheckingService  # noqa: E402
 
 CELL = "register-partition-1k.campaign-wide"
@@ -169,7 +169,7 @@ def test_a_full_size_history_stays_inside_the_widest_dense_window(seed):
         windows[window(rows)] += 1
     assert max(windows) <= CONFIG["max_crashes"] + CONFIG["processes"] \
         == DENSE_MAX_SLOTS
-    assert max(windows) > SEGMENT_MAX_SLOTS   # the cell is wide
+    assert max(windows) > WIDE_WINDOW_SLOTS   # the cell is wide
 
 
 def test_the_file_keeps_the_sources_shapes_and_states_its_cuts():
@@ -206,7 +206,7 @@ def seeded(request):
     flat = [v for req in want for v in req]
     assert True in flat and False in flat
     windows = [window(h) for req in reqs for h in req]
-    assert min(windows) < SEGMENT_MAX_SLOTS < max(windows)
+    assert min(windows) < WIDE_WINDOW_SLOTS < max(windows)
     return reqs, want
 
 
@@ -282,7 +282,7 @@ def test_the_tier_that_decides_a_window(by_window, w, tier):
     assert r["valid?"] is True and r["concurrency-window"] == w
     assert r["decided-tier"] == tier
     moved = {k: snapshot_stats()[k] - before[k] for k in COUNTERS}
-    assert moved["wide_rows"] == (w > SEGMENT_MAX_SLOTS)
+    assert moved["wide_rows"] == (w > WIDE_WINDOW_SLOTS)
     assert moved["wide_rows_host"] == (tier == "host")
 
 

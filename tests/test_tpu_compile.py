@@ -28,7 +28,8 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from jepsen_jgroups_raft_tpu.checker.schedule import DEFAULT_SCAN_CHUNK
+from jepsen_jgroups_raft_tpu.checker.schedule import (DEFAULT_SCAN_CHUNK,
+                                                      long_width)
 from jepsen_jgroups_raft_tpu.models.counter import Counter
 from jepsen_jgroups_raft_tpu.models.register import CasRegister
 from jepsen_jgroups_raft_tpu.ops.dense_scan import (make_dense_batch_checker,
@@ -40,7 +41,6 @@ from jepsen_jgroups_raft_tpu.ops.kernel_ir import (CYCLE_TILE,
                                                    make_cycle_closure_tiled)
 from jepsen_jgroups_raft_tpu.ops.linear_scan import (make_batch_checker,
                                                      make_sort_chunk_checker)
-from jepsen_jgroups_raft_tpu.ops.segment_scan import make_segment_kernel
 from jepsen_jgroups_raft_tpu.parallel.mesh import BATCH_AXIS
 
 # BASELINE config 1 as the checker buckets it
@@ -277,12 +277,24 @@ def test_dense_chunk_pair_compiles_on_four_chip_mesh(mesh4, tpu_branches):
     assert "all-reduce" not in text and "all-gather" not in text
 
 
-def test_segment_kernel_compiles(one_chip):
-    # chip_smoke's config-5 history: ~100 segments of <=2048 events
-    fn = make_segment_kernel(CasRegister(), 8, 4, 2048)
-    k, basis = 101, 32
-    compile_for(fn, sds((k, 2048, 5), one_chip), sds((k, 4), one_chip),
-                sds((k, basis), one_chip), sds((k, basis), one_chip))
+@pytest.mark.parametrize("w,macro_p", [(5, 6), (6, 6), (7, 8), (8, 8)],
+                         ids=["W5", "W6", "W7", "W8"])
+def test_a_long_launch_compiles_at_the_long_cells_shapes(one_chip,
+                                                         tpu_branches,
+                                                         w, macro_p):
+    # register-100k.long-run's four LONG keys as a host's
+    # `launch-keys.json` records them (PERF.md section 4): ONE row, S 8,
+    # the window's payload bucket, and a 100k-op history's 146,282
+    # events as ~half as many macro steps on the LONG width ladder. A
+    # LONG key is `init` and `step`: it never recompacts
+    width = long_width(146_282 // 2)
+    assert width == 73_728
+    fns = make_dense_chunk_checker(CasRegister(), "domain", w, S,
+                                   macro_p=macro_p)
+    compiled = compile_chunk_pair(
+        fns, (sds((1, S), one_chip), sds((1,), one_chip)),
+        sds((1, width, row_ints(macro_p)), one_chip))
+    assert f"u32[1,{1 << w}]" in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_nodes,tiled", [(256, False), (768, True)],
@@ -291,37 +303,3 @@ def test_cycle_closure_kernel_compiles(one_chip, n_nodes, tiled):
     fn = (make_cycle_closure_tiled(n_nodes, CYCLE_TILE) if tiled
           else make_cycle_closure(n_nodes))
     compile_for(fn, sds((8, n_nodes, n_nodes), one_chip))
-
-
-def pallas_call_for(macro_p, n_events, tile):
-    from jepsen_jgroups_raft_tpu.ops.pallas_scan import _build_call
-
-    r = row_ints(macro_p)
-    grid = B // tile
-    fn = _build_call(CasRegister(), W, S, n_events, tile, grid, r,
-                     False, macro_p)
-    return fn, (grid * tile, n_events, r), (grid, tile * S)
-
-
-def test_pallas_legacy_kernel_compiles(one_chip):
-    fn, ev, val = pallas_call_for(None, 1752, 16)
-    compiled = compile_for(fn, sds(ev, one_chip), sds(val, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_macro_stream_exceeds_scoped_vmem(one_chip):
-    """Pins a finding, not a wish: with the macro stream (the default
-    since PR 4) `tile_histories` charges T*S*E*R*4 bytes against its
-    6 MiB budget, but T*S = 64 < 128 lanes, so Mosaic pads the block to
-    128 lanes and double-buffers it — past the 16 MiB scoped-VMEM limit.
-    `--algorithm pallas` is not on the `auto` path; ROADMAP D2 decides
-    whether the kernel is repaired or retired. Whichever it is, this
-    test changes with it."""
-    from jepsen_jgroups_raft_tpu.ops.pallas_scan import tile_histories
-
-    n_events = 1000
-    tile = tile_histories(S, n_events, row_ints(MACRO_P))
-    assert tile * S < 128
-    fn, ev, val = pallas_call_for(MACRO_P, n_events, tile)
-    with pytest.raises(Exception, match="(?i)vmem"):
-        fn.lower(sds(ev, one_chip), sds(val, one_chip)).compile()
