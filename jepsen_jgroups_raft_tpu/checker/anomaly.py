@@ -1,4 +1,5 @@
-"""Transactional anomaly rung: G0 / G1c / G-single certification (ISSUE 19).
+"""Transactional anomaly rung: G0 / G1c / G-single / G2 certification
+(ISSUE 19; G2 and the real-time plane: ISSUE 51).
 
 PAPER.md's L0 layer is history verification, and ecosystem-wide the
 transactional half of that story is Elle: build the dependency graph a
@@ -10,7 +11,7 @@ closure over class-restricted submatrices — the blocked closure kernel
 (ops/kernel_ir.make_cycle_closure_tiled) where a launch pays for
 itself, host numpy/Tarjan otherwise.
 
-Two plane sources share the certifier:
+Three plane sources share the certifier:
 
   * **Register-shaped histories** — `checker.cycle.build_sc_graph(...,
     want_planes=True)` labels the PR-13 edges it already derives
@@ -40,16 +41,36 @@ Two plane sources share the certifier:
     Elements appended more than once per key are unidentifiable:
     conservatively they contribute no wr/ww edges (rw edges stay sound
     — EVERY append of a missing element must follow the observer).
+  * **Transaction histories** (`checker/txn_graph.explain`, ISSUE
+    51) — Elle's list-append proper: an op is a whole multi-key
+    transaction, the graph is over transactions, and beside ww / wr /
+    rw it carries an **rt** plane (T1 completed before T2 was invoked;
+    a process's own order rides inside it, so its po plane is empty).
+    The verdict of such a row is decided in its launch; this module
+    names the anomaly of a row the launch flagged.
 
-Anomaly classes over the planes (Adya / Elle, session flavor — po
-rides along because our transactions are single ops and the session is
-the transaction boundary evidence):
+Anomaly classes over the planes (Adya / Elle; po rides along where
+the graph's transactions are single ops and the session is the
+transaction boundary evidence; where the graph carries an rt plane it
+joins po in the second round below):
 
   * **G0**  — cycle in po ∪ ww (write-order contradiction);
   * **G1c** — cycle in po ∪ ww ∪ wr needing a wr edge (reported only
     when G0 is clean: the sharpest class wins);
   * **G-single** — exactly one rw edge closes an otherwise po∪ww∪wr
-    path: rw edge (u, v) with v ⇝ u in the closure of po ∪ ww ∪ wr.
+    path: rw edge (u, v) with v ⇝ u in the closure of po ∪ ww ∪ wr;
+  * **G2** — a cycle is there in po ∪ ww ∪ wr ∪ rw and none of the
+    three holds: every cycle needs two anti-dependencies or more
+    (write skew). A serializability violation like the others: a
+    component whose only cycles are of this kind used to leave the
+    certifier with nothing found and the history `valid`.
+
+Each also in its **-realtime** form (`G0-realtime`, …): the four are
+looked for without the rt plane first and, if none holds, again with
+rt beside po, so a `-realtime` name says that the cycle needs an edge
+of real time (a strict-serializability violation of a history that may
+be serializable). Sharpest wins: the plain four in their order, then
+the `-realtime` four.
 
 Soundness is the cycle-tier argument verbatim (doc/checker-design.md
 §21): every edge holds in every legal serial execution of the required
@@ -73,6 +94,9 @@ from .cycle import (_condense_env, _use_kernel, closure_fn, cycle_max_ops,
                     cycle_witness, host_has_cycle, tarjan_scc)
 
 PLANE_NAMES = ("po", "ww", "wr", "rw")
+#: anomaly classes, sharpest first; each again with the rt plane joined
+CLASSES = ("G0", "G1c", "G-single", "G2")
+CLASS_ORDER = CLASSES + tuple(c + "-realtime" for c in CLASSES)
 
 
 # ------------------------------------------------- list-append inference
@@ -247,11 +271,30 @@ def _closure_reach(adj: np.ndarray, kernel: Optional[bool]) -> np.ndarray:
 def _certify_component(planes: Dict[str, np.ndarray],
                        op_of: List[int],
                        kernel: Optional[bool]) -> dict:
-    """Class certification over one (sub)graph's planes. Witnesses are
-    minimized: shortest cycle through the earliest reachable node
-    (cycle_witness's BFS), history op indices."""
-    out: dict = {"G0": None, "G1c": None, "G-single": None}
-    c0 = (planes["po"] | planes["ww"]).astype(np.uint8)
+    """Class certification over one (sub)graph's planes: the sharpest
+    class that holds, without the rt plane or, failing that, with it
+    (`-realtime`). Witnesses are minimized: shortest cycle through the
+    earliest reachable node (cycle_witness's BFS), history op
+    indices."""
+    out: dict = dict.fromkeys(CLASS_ORDER)
+    rounds = [("", planes["po"])]
+    rt = planes.get("rt")
+    if rt is not None and rt.any():
+        rounds.append(("-realtime", planes["po"] | rt))
+    for suffix, order in rounds:
+        hit = _sharpest_class(order, planes, op_of, kernel)
+        if hit is not None:
+            out[hit[0] + suffix] = hit[1]
+            break
+    return out
+
+
+def _sharpest_class(order: np.ndarray, planes: Dict[str, np.ndarray],
+                    op_of: List[int], kernel: Optional[bool]
+                    ) -> Optional[tuple]:
+    """(class, witness) of the sharpest class that holds over `order`
+    (po, or po ∪ rt) and the ww / wr / rw planes, or None."""
+    c0 = (order | planes["ww"]).astype(np.uint8)
     c1 = (c0 | planes["wr"]).astype(np.uint8)
 
     def wit(sub: np.ndarray) -> Optional[List[int]]:
@@ -259,34 +302,36 @@ def _certify_component(planes: Dict[str, np.ndarray],
         return [op_of[v] for v in path] if path else None
 
     if host_has_cycle(c0):
-        out["G0"] = {"cycle": wit(c0)}
-        return out
+        return "G0", {"cycle": wit(c0)}
     if host_has_cycle(c1):
-        out["G1c"] = {"cycle": wit(c1)}
-        return out
-    # G-single: one rw edge closing a po∪ww∪wr path — the WEAKEST
-    # class, only consulted when G0/G1c are clean (the sharpest class
-    # names the anomaly; a G0 cycle would make any G-single report
-    # redundant noise)
+        return "G1c", {"cycle": wit(c1)}
+    # G-single: one rw edge closing a po∪ww∪wr path, only consulted
+    # when G0/G1c are clean (the sharpest class names the anomaly; a
+    # G0 cycle would make any G-single report redundant noise)
     rw_edges = np.argwhere(planes["rw"] > 0)
-    if len(rw_edges):
-        reach = _closure_reach(c1, kernel)
-        best: Optional[List[int]] = None
-        best_edge = None
-        for u, v in rw_edges:
-            u, v = int(u), int(v)
-            if not reach[v, u]:
-                continue
-            path = _shortest_path(c1, v, u)
-            if path is not None and (best is None
-                                     or len(path) < len(best) - 1):
-                best = [u] + path
-                best_edge = (u, v)
-        if best is not None:
-            out["G-single"] = {"cycle": [op_of[v] for v in best],
-                               "rw-edge": [op_of[best_edge[0]],
-                                           op_of[best_edge[1]]]}
-    return out
+    if not len(rw_edges):
+        return None
+    reach = _closure_reach(c1, kernel)
+    best: Optional[List[int]] = None
+    best_edge = None
+    for u, v in rw_edges:
+        u, v = int(u), int(v)
+        if not reach[v, u]:
+            continue
+        path = _shortest_path(c1, v, u)
+        if path is not None and (best is None
+                                 or len(path) < len(best) - 1):
+            best = [u] + path
+            best_edge = (u, v)
+    if best is not None:
+        return "G-single", {"cycle": [op_of[v] for v in best],
+                            "rw-edge": [op_of[best_edge[0]],
+                                        op_of[best_edge[1]]]}
+    # G2: a cycle that needs two rw edges or more
+    c2 = (c1 | planes["rw"]).astype(np.uint8)
+    if host_has_cycle(c2):
+        return "G2", {"cycle": wit(c2)}
+    return None
 
 
 def _shortest_path(adj: np.ndarray, src: int, dst: int
@@ -331,7 +376,7 @@ def certify_planes(g: dict, kernel: Optional[bool] = None) -> dict:
     note_cycle(cycle_nodes_pre=n)
     condense = _condense_env()
     condense = True if condense is None else condense
-    anomalies: dict = {"G0": None, "G1c": None, "G-single": None}
+    anomalies: dict = dict.fromkeys(CLASS_ORDER)
     if condense:
         comps = tarjan_scc(g["adj"])
         nontrivial = sorted((sorted(c) for c in comps if len(c) >= 2),
@@ -352,12 +397,10 @@ def certify_planes(g: dict, kernel: Optional[bool] = None) -> dict:
     # independently, so a G0 in one and a G-single in another must
     # still collapse to the G0 name — identical to what the direct arm
     # reports, where _certify_component already stops at the sharpest)
-    if anomalies["G0"] is not None:
-        anomalies["G1c"] = None
-        anomalies["G-single"] = None
-    elif anomalies["G1c"] is not None:
-        anomalies["G-single"] = None
-    return anomalies
+    sharpest = next((c for c in CLASS_ORDER if anomalies[c] is not None),
+                    None)
+    return {c: (hit if c == sharpest else None)
+            for c, hit in anomalies.items()}
 
 
 def certify_history(history, kernel: Optional[bool] = None) -> dict:

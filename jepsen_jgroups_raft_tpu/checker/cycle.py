@@ -261,8 +261,8 @@ def host_has_cycle(adj: np.ndarray) -> bool:
     NetworkX-free host oracle the closure kernel is differentially
     pinned against (and the off-TPU production arm)."""
     n = int(adj.shape[0])
-    color = np.zeros(n, dtype=np.int8)  # 0 white, 1 gray, 2 black
-    succ = [np.flatnonzero(adj[i]) for i in range(n)]
+    color = [0] * n  # 0 white, 1 gray, 2 black
+    succ = _successors(adj)
     for root in range(n):
         if color[root]:
             continue
@@ -272,7 +272,7 @@ def host_has_cycle(adj: np.ndarray) -> bool:
             v, j = stack[-1]
             if j < len(succ[v]):
                 stack[-1] = (v, j + 1)
-                w = int(succ[v][j])
+                w = succ[v][j]
                 if color[w] == 1:
                     return True
                 if color[w] == 0:
@@ -316,6 +316,16 @@ def cycle_witness(adj: np.ndarray) -> Optional[List[int]]:
     return None
 
 
+def _successors(adj: np.ndarray) -> List[List[int]]:
+    """Each node's successors, ascending: one `nonzero` over the whole
+    matrix (a call a row was most of a 1,000-node Tarjan's time)."""
+    n = int(adj.shape[0])
+    rows, cols = np.nonzero(adj)
+    start = np.searchsorted(rows, np.arange(n + 1)).tolist()
+    cols = cols.tolist()
+    return [cols[start[i]:start[i + 1]] for i in range(n)]
+
+
 def tarjan_scc(adj: np.ndarray) -> List[List[int]]:
     """Strongly connected components of a dense adjacency matrix —
     host ITERATIVE Tarjan (explicit work stack; the required-op graphs
@@ -327,7 +337,7 @@ def tarjan_scc(adj: np.ndarray) -> List[List[int]]:
     "non-trivial SCC exists" ⇔ "cycle exists", with no kernel launch
     (doc/checker-design.md §21)."""
     n = int(adj.shape[0])
-    succ = [np.flatnonzero(adj[i]).tolist() for i in range(n)]
+    succ = _successors(adj)
     index = [-1] * n
     low = [0] * n
     on_stack = [False] * n

@@ -396,6 +396,27 @@ def check_encoded(
     from ..parallel import distributed
 
     consistency = _normalize_rung(consistency)
+    if getattr(model, "txn_graph", False):
+        # A transaction model's rows (ISSUE 51) go to the cycle family
+        # as the others go to theirs: the transactions' dependency
+        # graph, inferred and closed inside this launch
+        # (checker/txn_graph.py). The verdict is strict
+        # serializability, which is the linearizable rung with a
+        # transaction as the op; there is no weaker rung of it here.
+        from .txn_graph import check_txn_rows
+
+        if consistency != "linearizable":
+            raise ValueError(
+                f"{type(model).__name__} rows are checked at the "
+                f"linearizable rung (strict serializability) only, "
+                f"not {consistency!r}")
+        results = check_txn_rows(encs, model, serve_rows=serve_rows,
+                                 explain_flagged=serve_rows is None)
+        note = degraded_note()
+        if note:
+            for r in results:
+                r.setdefault("platform-degraded", note)
+        return results
     if consistency != "linearizable":
         from .consistency import apply_rung
 
@@ -1105,6 +1126,11 @@ def check_encoded_host(enc: EncodedHistory, model, witness: bool = False,
     and at the linearizable rung the same pre-frontier certify fast
     path runs (ISSUE 14; `lin_fastpath=False` skips it, e.g. graftd's
     fast lane having already certified at dispatch)."""
+    if getattr(model, "txn_graph", False):
+        # a transaction row's host arm: the same edges, no launch
+        from .txn_graph import check_txn_rows
+
+        return check_txn_rows([enc], model, kernel=False)[0]
     if enc.n_events == 0:
         note_tier("trivial")
         return {"valid?": VALID, "algorithm": "trivial", "op-count": 0,

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import itertools
 import os
 import sys
@@ -129,7 +130,16 @@ _STATS_ZERO = {"chunks_run": 0, "evicted_rows": 0, "groups_run": 0,
                # long histories (ISSUE 44): rows of at least
                # LIN_FASTPATH_MAX_EVENTS events that entered the kernel
                # ladder
-               "long_rows": 0}
+               "long_rows": 0,
+               # transaction graphs (ISSUE 51): rows of a transaction
+               # model that entered `txn_graph.check_txn_rows`, their
+               # nodes and edges, the rows a flag or a non-cycle anomaly
+               # refuted, the closure programs launched, and the
+               # multiply-adds of the squarings that ran in them
+               # (rows of the bucket x N^3 a squaring)
+               "txn_rows": 0, "txn_nodes": 0, "txn_edges": 0,
+               "txn_rows_flagged": 0, "closure_launches": 0,
+               "closure_macs": 0}
 _STATS = dict(_STATS_ZERO)
 #: (scope dict, owner thread id) pairs; guarded by _STATS_LOCK,
 #: innermost last. The owner id makes attribution THREAD-AFFINE under
@@ -166,6 +176,12 @@ def note_wide(**kw) -> None:
     """Record the wide-window counters (ISSUE 40), like `note_cycle`:
     `wide_rows`, `wide_rows_host`; and the long-history one (ISSUE
     44): `long_rows`."""
+    _add_stats(**kw)
+
+
+def note_txn(**kw) -> None:
+    """Record the transaction-graph counters (ISSUE 51), like
+    `note_wide`."""
     _add_stats(**kw)
 
 
@@ -594,6 +610,61 @@ class ChunkLaunch:
     #: set (`launch_shapes`); None falls back to the pair's identity.
     spec: Optional[dict] = None
 
+    # What the launch-shape set's machinery (`launch_key`, `build_keys`,
+    # `_build_task`) asks of a launch; `ClosureLaunch` answers the same
+    # five for its one program a row bucket.
+
+    @property
+    def lanes(self) -> int:
+        return int(self.events.shape[2])
+
+    @property
+    def n_rows(self) -> int:
+        return int(self.events.shape[0])
+
+    @staticmethod
+    def n_programs(lower: Optional[int]) -> int:
+        """Programs of one row bucket: `init`, `step` and, onto the
+        bucket `lower`, `gather`."""
+        return 3 if lower is not None else 2
+
+    def key_shapes(self, chunk: int, rows: int,
+                   upto: Optional[int]) -> tuple:
+        """(key, its LaunchShapes, the row bucket needed) for a launch
+        of `rows` rows: the one bucket or, with `upto`, the key WHOLE,
+        every bucket up to the larger of the two. A LONG key is whole
+        at the launch's own bucket, whatever `upto` says."""
+        e_pad = _padded_len(self, self.chunk or chunk or 1)
+        shards = _n_shards(self.device)
+        if self.exact_rows:
+            shapes = launch_shapes(rows, e_pad, shards, long=True)
+            need = shapes.rows[-1]
+        else:
+            shapes = launch_shapes(max(rows, upto or 0), e_pad, shards)
+            need = launch_rows(rows, shards)
+        return launch_key(self, shapes.width), shapes, need
+
+    def build_rows(self, key: tuple, rows: int, lower: Optional[int],
+                   width: int) -> None:
+        """On operands shaped, typed and placed exactly as
+        `_init_group`, `_dispatch` and `_collect` make them; the step
+        scans no event."""
+        import jax
+
+        init, step, gather = _programs(self, key)
+        vo = None
+        if self.val_of is not None:
+            vo = np.zeros((rows,) + self.val_of.shape[1:],
+                          dtype=self.val_of.dtype)
+        carry = _init_carry(self, init, vo, np.zeros((rows,), np.int32))
+        events = _put(self, np.zeros((rows, width, self.lanes),
+                                     dtype=self.events.dtype))
+        out = step(carry, events, np.int32(0), np.int32(0))
+        if lower is not None:
+            out = (out, gather(carry, events,
+                               np.zeros((lower,), np.int32)))
+        jax.block_until_ready(out)
+
 
 @dataclass
 class GroupOutcome:
@@ -742,6 +813,10 @@ def key_template(model, spec: dict, width: int, lanes: int,
     fan-out), or the record is of another stream format."""
     from ..ops.dense_scan import MERGE_MAX_EVENTS, DensePlan
 
+    if spec.get("kind") == CLOSURE_KIND:
+        launch = ClosureLaunch(spec, int(width), int(rows))
+        return launch if lanes == launch.lanes and \
+            spec == closure_spec(model, spec.get("n_nodes", 0)) else None
     macro_p = spec.get("macro_p")
     if lanes != (5 if macro_p is None else 3 + 4 * int(macro_p)):
         return None
@@ -757,6 +832,116 @@ def key_template(model, spec: dict, width: int, lanes: int,
         batch["macro_p"] = int(macro_p)
     [launch], _ = build_dense_launches(model, [(range(rows), plan, batch)])
     return launch if launch.spec == spec else None
+
+
+# ---------------------------------------------------- closure programs
+# ISSUE 51. A transaction graph's launch (checker/txn_graph.py) runs ONE
+# program, `ops/kernel_ir.make_txn_closure`, and its key is a value like
+# a dense key's: the node bucket (`spec`), one lane, the edge width, the
+# placement; its row buckets are the set's (`launch_shapes`), up to
+# `closure_rows_cap`, one program each and no gather. So it goes through
+# `build_keys`, `/stats` `build_keys`, `launch-keys.json` and graftd's
+# build at start like any key, and a launch that builds is a shape miss.
+
+CLOSURE_KIND = "closure"
+#: cells (rows x N x N) of one closure launch's plane; more rows are
+#: launched in parts
+CLOSURE_MAX_CELLS = 1 << 26
+
+
+def closure_rows_cap(n_nodes: int) -> int:
+    """Rows of one closure launch at node bucket `n_nodes`: a power of
+    two, LAUNCH_ROW_FLOOR at the least (64 at N 1,024: each of a few
+    live [B, N, N] int32 arrays is then 256 MB)."""
+    cap = LAUNCH_ROW_FLOOR
+    while cap * 2 * n_nodes * n_nodes <= CLOSURE_MAX_CELLS:
+        cap *= 2
+    return cap
+
+
+def closure_spec(model, n_nodes: int) -> dict:
+    return {"model": type(model).__name__,
+            "model_key": repr(model.cache_key()), "kind": CLOSURE_KIND,
+            "n_nodes": int(n_nodes), "n_slots": 0, "n_states": 0}
+
+
+@functools.lru_cache(maxsize=None)
+def _closure_program(n_nodes: int):
+    from ..ops.kernel_ir import make_txn_closure
+
+    return make_txn_closure(n_nodes)
+
+
+@dataclass
+class ClosureLaunch:
+    """One closure launch, or the template of its key: `spec`
+    (`closure_spec`), the edges a row (`width`), the rows."""
+
+    spec: dict
+    width: int
+    rows: int
+    device = None
+    exact_rows = False
+    lanes = 1
+
+    @property
+    def n_nodes(self) -> int:
+        return int(self.spec["n_nodes"])
+
+    @property
+    def program(self):
+        return _closure_program(self.n_nodes)
+
+    @property
+    def n_rows(self) -> int:
+        return self.rows
+
+    @staticmethod
+    def n_programs(lower: Optional[int]) -> int:
+        return 1
+
+    def key_shapes(self, chunk: int, rows: int,
+                   upto: Optional[int]) -> tuple:
+        """The set's row buckets up to `closure_rows_cap`, one program
+        each and no gather (`long`: the shape of such a key)."""
+        top = min(max(rows, upto or 0), closure_rows_cap(self.n_nodes))
+        return (launch_key(self, self.width),
+                LaunchShapes(launch_shapes(top, self.width).rows,
+                             self.width, long=True), launch_rows(rows))
+
+    def build_rows(self, key: tuple, rows: int, lower: Optional[int],
+                   width: int) -> None:
+        import jax
+
+        jax.block_until_ready(self.program(self.pad_codes(rows, width)))
+
+    def pad_codes(self, rows: int, width: int) -> np.ndarray:
+        """A launch's operand with no edge in it: every code past the
+        planes."""
+        n = self.n_nodes
+        return np.full((rows, width), 3 * n * n, dtype=np.int32)
+
+
+def run_closure(launch: ClosureLaunch, serve_rows: Optional[int] = None):
+    """(operand, run) of one closure launch: `operand` is the
+    [row bucket, width] int32 array of pads the caller writes its rows'
+    edge codes into, `run()` launches it and gives (flags [bucket, 4],
+    squarings [3]). The launch never builds: this waits for its bucket
+    or, for a service (`serve_rows`), for its key whole, as
+    `_init_group` does."""
+    key, shapes, need = launch.key_shapes(0, launch.rows, serve_rows)
+    built = _BUILT.get(key)
+    if built is None or not built["rows"].issuperset(
+            shapes.rows if serve_rows else (need,)):
+        build_keys([launch], upto=serve_rows)
+    operand = launch.pad_codes(need, launch.width)
+
+    def run():
+        with _launching(CLOSURE_KIND, key, need, launch.width):
+            flags, iters = launch.program(operand)
+            return np.asarray(flags), np.asarray(iters)  # lint: allow(host-sync)
+
+    return operand, run
 
 
 # --------------------------------------------------- the launch-shape set
@@ -929,7 +1114,7 @@ def launch_key(launch: ChunkLaunch, width: int) -> tuple:
     kernel = (tuple(sorted((k, str(v)) for k, v in spec.items()))
               if spec is not None
               else (("tag", launch.tag), ("fns", id(launch.step_fn))))
-    return (kernel, int(launch.events.shape[2]), int(width),
+    return (kernel, launch.lanes, int(width),
             _placement_name(launch.device))
 
 
@@ -948,7 +1133,7 @@ _LAUNCHED_CAP = 8192
 
 def _new_key_entry(launch: ChunkLaunch, width: int, met: str) -> dict:
     entry = {"rows": set(), "spec": launch.spec, "width": width,
-             "lanes": int(launch.events.shape[2]), "met": met,
+             "lanes": launch.lanes, "met": met,
              "wait_s": 0.0, "programs": 0, "traced": 0}
     entry.update((stage + "_s", 0.0) for stage in BUILD_STAGES)
     return entry
@@ -981,7 +1166,9 @@ def snapshot_build_keys() -> list:
         del k["key"], k["lanes"]
         out.append({**{f: spec.get(f) for f in (
             "model", "kind", "n_slots", "n_states")},
-            "long": bool(spec.get("long")), **k})
+            "long": bool(spec.get("long")),
+            **({"n_nodes": spec["n_nodes"]} if "n_nodes" in spec else {}),
+            **k})
     return out
 
 
@@ -991,6 +1178,8 @@ def key_name(entry: dict) -> str:
     spec = entry["spec"]
     if spec is None:
         return f"unnamed/w{entry['width']}"
+    if spec.get("kind") == CLOSURE_KIND:
+        return f"{spec['model']}/closure/N{spec['n_nodes']}/w{entry['width']}"
     return (f"{spec['model']}/{spec['kind']}/W{spec['n_slots']}"
             f"/S{spec['n_states']}/w{entry['width']}"
             + ("/long" if spec.get("long") else ""))
@@ -1194,28 +1383,12 @@ def _programs(launch: ChunkLaunch, key: Optional[tuple]) -> _Programs:
         return programs
 
 
-def _build_rows(launch: ChunkLaunch, key: tuple, rows: int,
-                lower: Optional[int], width: int) -> None:
-    """Ask for the three programs of one row bucket, on operands shaped,
-    typed and placed exactly as `_init_group`, `_dispatch` and
-    `_collect` make them; the step scans no event."""
-    import jax
-
-    lanes = launch.events.shape[2]
-    init, step, gather = _programs(launch, key)
+def _build_rows(launch, key: tuple, rows: int, lower: Optional[int],
+                width: int) -> None:
+    """Ask for the programs of one row bucket of `launch`'s key (a
+    dense key's three, a closure key's one)."""
     with annotate(BUILD_AHEAD, rows=rows):
-        vo = None
-        if launch.val_of is not None:
-            vo = np.zeros((rows,) + launch.val_of.shape[1:],
-                          dtype=launch.val_of.dtype)
-        carry = _init_carry(launch, init, vo, np.zeros((rows,), np.int32))
-        events = _put(launch, np.zeros((rows, width, lanes),
-                                       dtype=launch.events.dtype))
-        out = step(carry, events, np.int32(0), np.int32(0))
-        if lower is not None:
-            out = (out, gather(carry, events,
-                               np.zeros((lower,), np.int32)))
-        jax.block_until_ready(out)
+        launch.build_rows(key, rows, lower, width)
 
 
 #: threads that build a key's row buckets side by side (XLA releases
@@ -1240,23 +1413,6 @@ def _build_task(launch: ChunkLaunch, key: tuple, rows: int,
     finally:
         with _BUILD_LOCK:
             _PENDING.pop((key, rows), None)
-
-
-def _key_shapes(launch: ChunkLaunch, chunk: int, rows: int,
-                upto: Optional[int]) -> tuple:
-    """(key, its LaunchShapes, the row bucket needed) for a launch of
-    `rows` rows: the one bucket or, with `upto`, the key WHOLE, every
-    bucket up to the larger of the two. A LONG key is whole at the
-    launch's own bucket, whatever `upto` says."""
-    e_pad = _padded_len(launch, launch.chunk or chunk or 1)
-    shards = _n_shards(launch.device)
-    if launch.exact_rows:
-        shapes = launch_shapes(rows, e_pad, shards, long=True)
-        need = shapes.rows[-1]
-    else:
-        shapes = launch_shapes(max(rows, upto or 0), e_pad, shards)
-        need = launch_rows(rows, shards)
-    return launch_key(launch, shapes.width), shapes, need
 
 
 def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
@@ -1297,9 +1453,8 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
             _BUILDERS = ThreadPoolExecutor(
                 BUILD_THREADS, thread_name_prefix="build-ahead")
         for launch in launches:
-            key, shapes, need = _key_shapes(
-                launch, chunk,
-                launch.events.shape[0] if rows is None else rows, upto)
+            key, shapes, need = launch.key_shapes(
+                chunk, rows if rows is not None else launch.n_rows, upto)
             entry = _BUILT.get(key)
             if entry is None:
                 entry = _BUILT[key] = _new_key_entry(launch, shapes.width,
@@ -1321,7 +1476,7 @@ def build_keys(launches: List[ChunkLaunch], chunk: Optional[int] = None,
                               fut))
     if not waits:
         return 0
-    n = sum(3 if w[3] is not None else 2 for w in waits)
+    n = sum(w[0].n_programs(w[3]) for w in waits)
     named = (annotate("launch.build", key=key_name(_BUILT[waits[0][1]]),
                       programs=n)
              if met == "launch" else contextlib.nullcontext())
@@ -1371,7 +1526,7 @@ def _init_group(launch: ChunkLaunch, chunk: int,
     e_pad = _padded_len(launch, chunk)
     # a LONG cluster too: its schedule is its own length (`e_pad`), its
     # rows and device width come from the LONG ladders of the set
-    key, shapes, rows = _key_shapes(launch, chunk, B, build_rows)
+    key, shapes, rows = launch.key_shapes(chunk, B, build_rows)
     width = shapes.width
     built = _BUILT.get(key)
     if built is None or not built["rows"].issuperset(

@@ -131,6 +131,10 @@ def encode_history(
     """
 
     ops = list(history)
+    if getattr(model, "txn_graph", False):
+        # a transaction model owns its unit's event stream (micro-op
+        # rows beside OPEN and FORCE; models/listappend_txn.py)
+        return model.encode_ops(ops)
     pairs = pair_ops_indexed(ops)
     cols = (model.encode_pairs_columnar(pairs)
             if encode_vector_on() else None)

@@ -16,6 +16,7 @@ from .leader import LeaderModel  # noqa: F401
 from .setmodel import GSet  # noqa: F401
 from .queuemodel import TicketQueue  # noqa: F401
 from .listappend import ListAppend  # noqa: F401
+from .listappend_txn import ListAppendTxn  # noqa: F401
 
 #: name → constructor, used by workloads and the CLI.
 MODELS = {
@@ -25,4 +26,5 @@ MODELS = {
     "set": GSet,
     "queue": TicketQueue,
     "list-append": ListAppend,
+    "list-append-txn": ListAppendTxn,
 }

@@ -463,28 +463,79 @@ def make_cycle_closure(n_nodes: int):
     Entries stay in {0, 1} (re-binarized every iteration), so the
     int32 row sums are bounded by N ≤ CYCLE_MAX_NODES — no overflow.
     """
-    n = int(n_nodes)
-    n_iter = max(1, (max(n, 2) - 1).bit_length())
+    n_iter = closure_squarings(n_nodes)
 
     def closure(adj):
-        def cond(c):
-            i, _, changed = c
-            return changed & (i < n_iter)
-
-        def body(c):
-            i, a, _ = c
-            prod = jnp.einsum("bij,bjk->bik", a, a,
-                              preferred_element_type=jnp.int32)
-            nxt = jnp.minimum(a + jnp.minimum(prod, 1), 1)
-            return (i + 1, nxt, jnp.any(nxt != a))
-
-        _, closed, _ = lax.while_loop(
-            cond, body, (jnp.int32(0), adj.astype(jnp.int32),
-                         jnp.bool_(True)))
+        closed, _ = square_to_fixpoint(adj.astype(jnp.int32), n_iter)
         diag = jnp.diagonal(closed, axis1=1, axis2=2)
         return jnp.any(diag > 0, axis=1), closed
 
     return jax.jit(closure)
+
+
+def closure_squarings(n_nodes: int) -> int:
+    """Squarings that close any graph on `n_nodes` nodes: after k of
+    them R holds every path of length <= 2^k."""
+    return max(1, (max(int(n_nodes), 2) - 1).bit_length())
+
+
+def square_to_fixpoint(a, n_iter: int):
+    """`R <- R | R.R` on [B, N, N] int32 0/1 matrices until a squaring
+    changes nothing in any row, `n_iter` times at most: (the closure,
+    the squarings that ran)."""
+    def cond(c):
+        i, _, changed = c
+        return changed & (i < n_iter)
+
+    def body(c):
+        i, a, _ = c
+        prod = jnp.einsum("bij,bjk->bik", a, a,
+                          preferred_element_type=jnp.int32)
+        nxt = jnp.minimum(a + jnp.minimum(prod, 1), 1)
+        return (i + 1, nxt, jnp.any(nxt != a))
+
+    i, closed, _ = lax.while_loop(
+        cond, body, (jnp.int32(0), a, jnp.bool_(True)))
+    return closed, i
+
+
+def make_txn_closure(n_nodes: int):
+    """The closure program of a transaction graph's launch
+    (checker/txn_graph.py, ISSUE 51): ``program(codes)`` with codes
+    [B, E] int32, one edge each as ``(plane * N + source) * N + target``
+    over three planes (0: real time and write order, 1: reads-from, 2:
+    anti-dependencies; a pad is ``3 * N * N``, past the table and
+    dropped), returns (flags [B, 4] bool, squarings [3] int32).
+
+    The device scatters the edges into the planes and closes three
+    unions by `square_to_fixpoint`, each started from the closure
+    before it (the closure of `closed(A) | B` is the closure of
+    `A | B`): plane 0, + plane 1, + plane 2. Flags a row: a cycle in
+    the first, in the second, a plane-2 edge (u, v) whose v reaches u
+    in the second (one anti-dependency closing a path of the rest), a
+    cycle in the third. `squarings` is what ran: B * N^3 multiply-adds
+    each."""
+    n = int(n_nodes)
+    n_iter = closure_squarings(n)
+
+    def cyclic(closed):
+        return jnp.any(jnp.diagonal(closed, axis1=1, axis2=2) > 0, axis=1)
+
+    def program(codes):
+        b = codes.shape[0]
+        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+        planes = jnp.zeros((b, 3 * n * n), dtype=jnp.int8).at[
+            rows, codes].set(1, mode="drop").reshape(b, 3, n, n)
+        k0, i0 = square_to_fixpoint(planes[:, 0].astype(jnp.int32), n_iter)
+        k1, i1 = square_to_fixpoint(
+            jnp.maximum(k0, planes[:, 1].astype(jnp.int32)), n_iter)
+        rw = planes[:, 2].astype(jnp.int32)
+        single = jnp.any((rw * jnp.swapaxes(k1, 1, 2)) > 0, axis=(1, 2))
+        k2, i2 = square_to_fixpoint(jnp.maximum(k1, rw), n_iter)
+        return (jnp.stack([cyclic(k0), cyclic(k1), single, cyclic(k2)],
+                          axis=1), jnp.stack([i0, i1, i2]))
+
+    return jax.jit(program)
 
 
 def cycle_closure_tile(n_nodes: int, tile: int) -> int:

@@ -1165,6 +1165,14 @@ class CheckingService:
         # B1 (s) takes the readers and this line out together)
         out["long_rows"] = scan["long_rows"]
         out["long_rows_segmented"] = 0
+        # ISSUE 51: transaction graphs (process-wide; 0 from a service
+        # that never met a `list-append-txn` row): the rows, their nodes
+        # and edges, the rows refuted, the closure programs launched and
+        # the multiply-adds of the squarings that ran in them
+        for name in ("txn_rows", "txn_nodes", "txn_edges",
+                     "txn_rows_flagged", "closure_launches",
+                     "closure_macs"):
+            out[name] = scan[name]
         out["warm"] = self._warm.is_set()
         out["build_ahead"] = dict(self._build_ahead_info)
         # ISSUE 42: what the start cost (absent until warm; a service

@@ -51,9 +51,17 @@ MAX_PRIORITY = 8
 #: (`encode_units`: from the rows' columns where the histories arrive
 #: as op dicts, through `split_by_key` otherwise; the same units either
 #: way). "register"/"counter" accept plain single-key histories, one
-#: unit `h{i}` each. The benchmark submits both kinds.
+#: unit `h{i}` each. "list-append-txn" (ISSUE 51) is Elle's list-append
+#: with a TRANSACTION as the op: multi-key, and never split (a
+#: transaction is atomic over its keys): one unit `h{i}` a history,
+#: decided by the transaction graph inside the launch
+#: (checker/txn_graph.py). "list-append" is the older single-op face of
+#: that workload: split per key, with a cross-key overlay at admission
+#: on the JSON wire only. The benchmark submits "register", "counter",
+#: "multi-register" and "list-append-txn".
 def service_workloads() -> dict:
-    from ..models import CasRegister, Counter, GSet, ListAppend, TicketQueue
+    from ..models import (CasRegister, Counter, GSet, ListAppend,
+                          ListAppendTxn, TicketQueue)
 
     return {
         "register": (CasRegister, False),
@@ -63,6 +71,7 @@ def service_workloads() -> dict:
         "set": (GSet, False),
         "queue": (TicketQueue, False),
         "list-append": (ListAppend, True),
+        "list-append-txn": (ListAppendTxn, False),
     }
 
 
@@ -111,6 +120,14 @@ def _client_rows(cols) -> List[OpRow]:
     if NEMESIS in cols[0]:
         rows = [r for r in rows if r.process != NEMESIS]
     return rows
+
+
+def _client_columns(cols) -> tuple:
+    """`_wire_columns`' answer with the nemesis rows left out."""
+    if NEMESIS not in cols[0]:
+        return cols
+    live = [j for j, p in enumerate(cols[0]) if p != NEMESIS]
+    return tuple(list(map(col.__getitem__, live)) for col in cols)
 
 
 def rows_from_dicts(dicts: Sequence[dict]) -> List[OpRow]:
@@ -319,14 +336,17 @@ class CheckRequest:
     replayed: bool = False
     attached_to: Optional[str] = None
     #: transactional-anomaly overlay (ISSUE 19): stamped at ADMISSION
-    #: for txn_anomaly_capable models (list-append) from the UNDECOMPOSED
+    #: for txn_anomaly_capable models (`list-append` alone; a
+    #: `list-append-txn` unit is certified inside its launch and its
+    #: anomalies are in its result) from the UNDECOMPOSED
     #: multi-key histories — the per-key units cannot see cross-key
     #: cycles, and the fingerprint hashes only per-unit encodings, so
     #: this rides outside the result cache on purpose: a cached unit
     #: result-set stays reusable while the overlay is recomputed per
     #: submission (two submissions CAN share per-key encodings yet
     #: differ in cross-key session order). The binary lane
-    #: (admit_encoded) ships encodings only, so it has no overlay.
+    #: (admit_encoded) ships encodings only, so it has no overlay: a
+    #: `list-append` frame is answered from its per-key units alone.
     txn_anomalies: Optional[dict] = None
     #: `admit` encoded it from its rows' columns (`encode_units`); the
     #: daemon counts the two ways in `/stats`. Says how the encoding was
@@ -418,6 +438,17 @@ class CheckRequest:
         if include_results and self.results is not None:
             d["valid?"] = self.verdict()
             d["results"] = self.results
+            if getattr(self.model, "txn_graph", False):
+                # a transaction workload's units were certified inside
+                # their launch: the same summary the admission overlay
+                # gives `list-append`, read off the results (so on both
+                # wires, and on a cached or replayed answer)
+                d["txn-anomalies"] = {
+                    "valid?": d["valid?"],
+                    "histories": [{"valid?": r.get("valid?"),
+                                   "anomalies": r.get("anomalies", {}),
+                                   "nodes": r.get("nodes", 0)}
+                                  for r in self.results]}
         return d
 
 
@@ -481,7 +512,10 @@ def encode_units(histories: Sequence, workload: str):
     key goes the same way (ISSUE 47): the history's rows grouped by key
     with the key taken off the value (`_split_columns`, span
     `ingest.split`), each key's rows encoded as above, the units
-    labelled `h{i}/key=…` in `build_units`' order. Anything else
+    labelled `h{i}/key=…` in `build_units`' order. A transaction model
+    (`list-append-txn`, ISSUE 51) goes the same way by its own twin:
+    `ListAppendTxn.encode_columns` fills a unit's micro-op rows from the
+    columns, `encode_ops` (the object path) is its oracle. Anything else
     (`History` objects, a model without the twin, a model whose
     admission builds the `History` objects anyway for its cross-key
     overlay (`list-append`), ``JGRAFT_ENCODE_VECTOR=0``) takes the
@@ -494,10 +528,11 @@ def encode_units(histories: Sequence, workload: str):
     from ..models.base import Model
 
     model, independent = _workload_model(workload)
+    txn = getattr(model, "txn_graph", False)
     from_columns = (
         bool(histories) and encode_vector_on()
-        and type(model).encode_pairs_columnar
-        is not Model.encode_pairs_columnar
+        and (txn or type(model).encode_pairs_columnar
+             is not Model.encode_pairs_columnar)
         and not (independent
                  and getattr(model, "txn_anomaly_capable", False))
         and all(isinstance(h, (list, tuple))
@@ -524,8 +559,13 @@ def encode_units(histories: Sequence, workload: str):
                          split, key=lambda part: str(part[0])))
     if not parts:
         raise ValueError("empty submission: no checkable history units")
-    encs = [encode_history(_client_rows(cols), model)
-            for _, _, cols, _ in parts]
+    if txn:
+        # a transaction unit: its micro-op rows filled from the columns
+        encs = [model.encode_columns(_client_columns(cols))
+                for _, _, cols, _ in parts]
+    else:
+        encs = [encode_history(_client_rows(cols), model)
+                for _, _, cols, _ in parts]
     # a whole history's `WireHistory` reads its columns again when asked
     # (rarely); a key's keeps them, they are not `_wire_columns` of its
     # dicts

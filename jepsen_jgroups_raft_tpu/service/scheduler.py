@@ -325,7 +325,10 @@ class BatchScheduler:
             if (r.terminal or r.cancelled.is_set()
                     or r.consistency != "linearizable"
                     or r.algorithm not in LIN_FASTPATH_ALGOS
-                    or r.force_host or not r.encs):
+                    or r.force_host or not r.encs
+                    or getattr(r.model, "txn_graph", False)):
+                # (a transaction unit has no host certifier: its graph
+                # is inferred and closed inside the launch)
                 live.append(r)
                 continue
             # the lane TRIED this request (scanned it, or was told by
@@ -691,6 +694,22 @@ class BatchScheduler:
         from ..checker.counterexample import attach_counterexample
         from ..checker.linearizable import DEFAULT_MAX_CPU_CONFIGS
 
+        if getattr(r.model, "txn_graph", False):
+            # a flagged transaction row's witness is its anomaly's name
+            # and cycle, from the edges its launch inferred off its
+            # encoding (so on both wires)
+            from ..checker.txn_graph import explain
+
+            todo = [res for res in mine if res.get("valid?") is INVALID]
+            if todo:
+                with span("demux.counterexample", n=len(todo)):
+                    for res in todo:
+                        try:
+                            explain(res)
+                        except Exception:
+                            LOG.warning("anomaly explanation failed for "
+                                        "%s", r.id, exc_info=True)
+            return
         todo = [(label, hist, res) for (label, hist), res
                 in zip(r.units, mine)
                 if res.get("valid?") is INVALID

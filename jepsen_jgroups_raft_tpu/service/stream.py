@@ -1085,6 +1085,11 @@ class StreamManager:
             raise ValueError(
                 f"workload {workload!r} splits per key at admission; "
                 "stream each key as its own unit instead")
+        if getattr(model_factory, "txn_graph", False):
+            raise ValueError(
+                f"workload {workload!r} is decided by a cycle search over "
+                "the WHOLE history's transaction graph, which has no "
+                "carried frontier to extend; submit the finished history")
         units = int(units)
         if not 1 <= units <= 256:
             raise ValueError(f"units must be in [1, 256] (got {units})")

@@ -12,9 +12,11 @@ import random
 import numpy as np
 import pytest
 
-from jepsen_jgroups_raft_tpu.checker.anomaly import (TxnAnomalyChecker,
+from jepsen_jgroups_raft_tpu.checker.anomaly import (CLASS_ORDER,
+                                                     TxnAnomalyChecker,
                                                      build_txn_graph,
                                                      certify_history,
+                                                     certify_planes,
                                                      certify_submission)
 from jepsen_jgroups_raft_tpu.checker.independent import \
     IndependentLinearizable
@@ -181,6 +183,61 @@ def test_planted_anomalies_fire_at_the_right_class():
     assert r["valid?"] is True and not r["anomalies"], r
 
 
+# ------------------------------- G2 and the real-time plane (ISSUE 51)
+
+
+def _planes(n, **edges):
+    """A plane-labeled graph on `n` nodes from `plane=[(u, v), ...]`."""
+    planes = {c: np.zeros((n, n), dtype=np.uint8)
+              for c in ("po", "ww", "wr", "rw", "rt")}
+    for name, pairs in edges.items():
+        for u, v in pairs:
+            planes[name][u, v] = 1
+    adj = np.zeros((n, n), dtype=np.uint8)
+    for p in planes.values():
+        adj |= p
+    return {"n": n, "adj": adj, "planes": planes,
+            "op_index": list(range(10, 10 + n))}
+
+
+#: name -> the graph whose sharpest class it is
+PLANE_CASES = {
+    "G0": _planes(2, ww=[(0, 1), (1, 0)]),
+    "G1c": _planes(2, ww=[(0, 1)], wr=[(1, 0)]),
+    "G-single": _planes(2, wr=[(0, 1)], rw=[(1, 0)]),
+    "G2": _planes(2, rw=[(0, 1), (1, 0)]),
+    "G0-realtime": _planes(2, ww=[(0, 1)], rt=[(1, 0)]),
+    "G1c-realtime": _planes(2, wr=[(0, 1)], rt=[(1, 0)]),
+    "G-single-realtime": _planes(2, rw=[(0, 1)], rt=[(1, 0)]),
+    "G2-realtime": _planes(3, rw=[(0, 1), (1, 2)], rt=[(2, 0)]),
+    # a plain class beats a sharper one that needs real time
+    "G2 ": _planes(4, rw=[(0, 1), (1, 0)], ww=[(2, 3)], rt=[(3, 2)]),
+}
+
+
+@pytest.mark.parametrize("condense", ("1", "0"))
+@pytest.mark.parametrize("name", sorted(PLANE_CASES))
+def test_planes_are_named_by_their_sharpest_class(name, condense,
+                                                  monkeypatch):
+    monkeypatch.setenv("JGRAFT_CYCLE_CONDENSE", condense)
+    out = certify_planes(PLANE_CASES[name], kernel=False)
+    assert list(out) == list(CLASS_ORDER)
+    found = {k: v for k, v in out.items() if v is not None}
+    assert list(found) == [name.strip()]
+    cycle = found[name.strip()]["cycle"]
+    assert len(cycle) >= 2 and set(cycle) <= set(range(10, 14))
+
+
+def test_a_graph_without_an_rt_plane_is_certified_as_before():
+    g = _planes(2, wr=[(0, 1)], rw=[(1, 0)])
+    del g["planes"]["rt"]
+    out = certify_planes(g, kernel=False)
+    assert [k for k, v in out.items() if v is not None] == ["G-single"]
+    # the single-op overlay's graphs carry none (its units are served at
+    # weaker rungs too, where a real-time edge would refute too much)
+    assert "rt" not in build_txn_graph(_g0_history())["planes"]
+
+
 def test_gsingle_witness_names_the_rw_edge():
     r = certify_history(_gsingle_history())
     w = r["anomalies"]["G-single"]
@@ -235,12 +292,12 @@ def test_checker_facade_and_skip_marker(monkeypatch):
     assert res["valid?"] is False
     # node-cap skip is stamped, never silent
     monkeypatch.setenv("JGRAFT_CYCLE_MAX_OPS", "2")
-    from jepsen_jgroups_raft_tpu.checker.schedule import (consume_stats,
-                                                          stats_scope)
+    from jepsen_jgroups_raft_tpu.checker.schedule import stats_scope
 
-    with stats_scope():
+    # the scope's own count: the process's total holds what every
+    # earlier test of this worker skipped
+    with stats_scope() as scope:
         r = certify_history(_g0_history())
-        scope = consume_stats()
     assert r["valid?"] == "unknown"
     assert r["cycle-skipped-size"] > 2
     assert scope["cycle_size_skips"] == 1

@@ -30,6 +30,7 @@ from jepsen_jgroups_raft_tpu.checker.schedule import (BUILD_STAGES,
                                                       snapshot_spans)
 from jepsen_jgroups_raft_tpu.history.packing import encode_history
 from jepsen_jgroups_raft_tpu.models import Counter
+from jepsen_jgroups_raft_tpu.ops import dense_scan
 from jepsen_jgroups_raft_tpu.platform import install_compile_counters
 from jepsen_jgroups_raft_tpu.service import buildahead
 from jepsen_jgroups_raft_tpu.service.daemon import CheckingService
@@ -332,6 +333,13 @@ def two_starts(tmp_path_factory):
         mp.setenv("JGRAFT_LIN_FASTPATH", "0")
         mp.setenv("JGRAFT_SCAN_CHUNK", "16")
         autotune.reset_for_tests()
+        # "a new process" holds no kernel pair and no key's shared
+        # traces either: another test file of this xdist worker may have
+        # launched this key (tests/test_launch_shapes.py does), and the
+        # first start would then find all five programs in that file's
+        # jit caches
+        mp.setattr(dense_scan, "_KERNEL_CACHE", {})
+        mp.setattr(schedule, "_SHARED", {})
         rng = random.Random(4270)
         hists = [random_valid_history(rng, "counter", n_ops=10, n_procs=4,
                                       crash_p=0.0) for _ in range(8)]

@@ -680,8 +680,7 @@ def family_launch(kind, n_rows, seed, chunk, n_ops=10, long_every=9):
     batch = pack_batch([encs[i] for i in idxs])
     [launch], _ = schedule.build_dense_launches(
         model, [(list(idxs), plan, batch)])
-    key, shapes, _ = schedule._key_shapes(launch, chunk,
-                                          launch.events.shape[0], None)
+    key, shapes, _ = launch.key_shapes(chunk, launch.events.shape[0], None)
     return launch, key, shapes.width
 
 

@@ -38,7 +38,8 @@ from jepsen_jgroups_raft_tpu.ops.kernel_ir import (CYCLE_TILE,
                                                    SORT_DEFAULT_CONFIGS,
                                                    macro_row_ints,
                                                    make_cycle_closure,
-                                                   make_cycle_closure_tiled)
+                                                   make_cycle_closure_tiled,
+                                                   make_txn_closure)
 from jepsen_jgroups_raft_tpu.ops.linear_scan import (make_batch_checker,
                                                      make_sort_chunk_checker)
 from jepsen_jgroups_raft_tpu.parallel.mesh import BATCH_AXIS
@@ -303,3 +304,16 @@ def test_cycle_closure_kernel_compiles(one_chip, n_nodes, tiled):
     fn = (make_cycle_closure_tiled(n_nodes, CYCLE_TILE) if tiled
           else make_cycle_closure(n_nodes))
     compile_for(fn, sds((8, n_nodes, n_nodes), one_chip))
+
+
+def test_txn_closure_program_compiles_at_the_cells_node_bucket(one_chip):
+    """The closure program of `list-append-1k.campaign-txn` (ISSUE 51):
+    N 1,024, sixteen edges a node, the smallest row bucket (the cell's
+    64 rows compile the same program in 15 s with 2.35 GB of
+    temporaries). It holds the scatter, and the three closures' loops
+    around an `s32` product."""
+    compiled = compile_for(make_txn_closure(1024),
+                           sds((8, 16384), one_chip))
+    text = compiled.as_text()
+    assert "scatter(" in text
+    assert text.count("s32[8,1024,1024]") >= 3
